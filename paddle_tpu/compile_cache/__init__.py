@@ -18,7 +18,10 @@ stop at the process boundary; this package extends them across it:
   families;
 - ``manifest``: ``WarmupManifest`` — the batch signatures a serving
   process actually compiled, so a restart pre-warms exactly the
-  observed lattice from cache.
+  observed lattice from cache;
+- ``placement``: where the caches live — JAX's persistent compilation
+  cache where ``JAX_COMPILATION_CACHE_DIR`` says, else at one fixed
+  path inside the checkout, with this package's AOT cache beside it.
 
 Wired into the three compile sites: ``jit.to_static`` (non-
 differentiating calls), ``jit.TrainStep``, and the serving
@@ -39,11 +42,16 @@ from .fingerprint import (  # noqa: F401
     mark_compile_relevant, mesh_fingerprint,
 )
 from .manifest import WarmupManifest  # noqa: F401
+from .placement import (  # noqa: F401
+    aot_cache_dir, cache_root, fresh_scratch_dir, place_jax_cache,
+)
 from .store import CacheStore  # noqa: F401
 
 __all__ = [
     "CompileCache", "CacheStore", "WarmupManifest",
     "default_cache", "reset_default_cache", "stats",
+    "cache_root", "place_jax_cache", "aot_cache_dir",
+    "fresh_scratch_dir",
     "cache_key", "function_fingerprint", "layer_fingerprint",
     "mesh_fingerprint", "environment_fingerprint", "avals_signature",
     "bytes_fingerprint", "compile_relevant_flags", "mark_compile_relevant",

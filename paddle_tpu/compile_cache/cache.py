@@ -186,9 +186,16 @@ class CompileCache:
     def _materialize(self, record):
         kind = record["kind"]
         if kind == KIND_EXECUTABLE:
+            import jax
             from jax.experimental import serialize_executable
-            payload = pickle.loads(record["payload"])
-            return serialize_executable.deserialize_and_load(*payload)
+            device_ids, payload = pickle.loads(record["payload"])
+            # load onto the devices the executable was compiled for, in
+            # their assignment order; left out, JAX loads a one-device
+            # program onto every local device and the first call fails
+            by_id = {d.id: d for d in jax.devices()}
+            return serialize_executable.deserialize_and_load(
+                *payload,
+                execution_devices=[by_id[i] for i in device_ids])
         if kind == KIND_STABLEHLO:
             import jax
             from jax import export as jexport
@@ -211,8 +218,12 @@ class CompileCache:
         if _serialize_supported():
             try:
                 from jax.experimental import serialize_executable
+                device_ids = [
+                    d.id for d in
+                    compiled.runtime_executable().local_devices()]
                 payload = pickle.dumps(
-                    serialize_executable.serialize(compiled), protocol=4)
+                    (device_ids, serialize_executable.serialize(compiled)),
+                    protocol=4)
                 kind = KIND_EXECUTABLE
             except Exception:  # noqa: BLE001 - a genuine serialize
                 # failure on a supporting backend: count it, fall

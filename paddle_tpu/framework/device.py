@@ -62,7 +62,7 @@ def current_jax_device():
 def device_count(device_type: str = "tpu") -> int:
     if device_type == "cpu":
         return len(jax.devices("cpu"))
-    return len([d for d in jax.devices() if d.platform.lower() != "cpu"]) or 0
+    return len([d for d in jax.devices() if d.platform == "tpu"])
 
 
 def is_compiled_with_cuda() -> bool:  # compat shim

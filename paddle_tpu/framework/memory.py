@@ -3,7 +3,7 @@
 Reference: paddle/fluid/memory/stats.cc (peak/current allocation stats) →
 paddle.device.cuda.max_memory_allocated etc. TPU-native: XLA owns the
 allocator, so stats come from the PJRT device (`memory_stats()`); where
-the runtime doesn't expose them (CPU backend, tunneled devices), usage is
+the runtime doesn't expose them (the CPU backend), usage is
 computed from the live jax.Array set and the peak is maintained as the
 max observed across queries (exact current usage, observed peak).
 """

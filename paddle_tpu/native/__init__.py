@@ -10,20 +10,27 @@ pybind11 is unavailable, so the ABI is plain C + ctypes):
 - host_tracer.cc — profiler span recorder + chrome-trace export
 - shm_ring.cc    — shared-memory DataLoader batch transport
 
-`lib()` returns the loaded CDLL or None (callers must degrade gracefully to
-their pure-Python fallbacks so the framework works without a compiler).
+`lib()` returns the loaded CDLL or None (callers degrade to their
+pure-Python fallbacks so the framework works without a compiler) — never
+in silence: why it is None is logged once and kept in `status()`.
 """
 from __future__ import annotations
 
 import ctypes
 import hashlib
+import logging
 import os
 import subprocess
 import threading
 
+logger = logging.getLogger(__name__)
+
 _lock = threading.Lock()
 _lib = None
 _tried = False
+# how the last lib() call ended: "built from csrc/", "loaded (built
+# earlier from the same csrc/)", or why there is no library
+_status = "not loaded yet"
 
 _SRC = ("tcp_store.cc", "host_tracer.cc", "shm_ring.cc")
 
@@ -36,55 +43,78 @@ def _src_digest(srcs) -> str:
     return h.hexdigest()
 
 
-def _build(src_dir: str, out_path: str) -> bool:
+def _build(src_dir: str, out_path: str) -> str:
+    """Compile csrc/ into ``out_path``; returns "" or why it failed."""
     srcs = [os.path.join(src_dir, s) for s in _SRC]
     cmd = ["g++", "-O2", "-fPIC", "-shared", "-std=c++17", "-pthread",
            "-o", out_path] + srcs + ["-lrt"]
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True,
                               timeout=120)
-        return proc.returncode == 0
-    except Exception:
-        return False
+    except FileNotFoundError:
+        return "g++ is not installed"
+    except subprocess.TimeoutExpired:
+        return "g++ did not finish in 120 s"
+    if proc.returncode != 0:
+        return f"g++ failed: {proc.stderr.strip()[-300:]}"
+    return ""
+
+
+def status() -> str:
+    """One line on the native library after ``lib()``: built from the
+    committed csrc/, loaded from an earlier build, or why it is absent
+    and the pure-Python fallbacks (TCPStore, host tracer, DataLoader
+    transport) are in use."""
+    return _status
 
 
 def lib():
     """Load (building if needed) the native library; None if unavailable."""
-    global _lib, _tried
+    global _lib, _tried, _status
     with _lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        here = os.path.dirname(os.path.abspath(__file__))
-        src_dir = os.path.join(here, "csrc")
-        out = os.path.join(here, "libpaddle_tpu_native.so")
-        srcs = [os.path.join(src_dir, s) for s in _SRC]
-        # staleness is keyed on a content hash of the sources (mtimes are
-        # not preserved by git checkout); the .so is never committed.
-        stamp = out + ".sha256"
-        try:
-            digest = _src_digest(srcs)
-        except OSError:
-            return None  # sources missing: degrade to pure-Python fallbacks
-        stale = not os.path.exists(out)
-        if not stale:
-            try:
-                with open(stamp) as f:
-                    stale = f.read().strip() != digest
-            except OSError:
-                stale = True
-        if stale:
-            if not _build(src_dir, out):
-                return None
-            with open(stamp, "w") as f:
-                f.write(digest)
-        try:
-            cdll = ctypes.CDLL(out)
-        except OSError:
-            return None
-        _configure(cdll)
-        _lib = cdll
+        _lib, _status = _load()
+        if _lib is None:
+            logger.warning("native library unavailable (%s): the "
+                           "pure-Python fallbacks are in use", _status)
         return _lib
+
+
+def _load():
+    """``(cdll, status)``; cdll is None where status says why."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    src_dir = os.path.join(here, "csrc")
+    out = os.path.join(here, "libpaddle_tpu_native.so")
+    srcs = [os.path.join(src_dir, s) for s in _SRC]
+    # staleness is keyed on a content hash of the sources (mtimes are
+    # not preserved by git checkout); the .so is never committed.
+    stamp = out + ".sha256"
+    try:
+        digest = _src_digest(srcs)
+    except OSError as e:
+        return None, f"csrc/ sources missing: {e}"
+    stale = not os.path.exists(out)
+    if not stale:
+        try:
+            with open(stamp) as f:
+                stale = f.read().strip() != digest
+        except OSError:
+            stale = True
+    if stale:
+        why = _build(src_dir, out)
+        if why:
+            return None, why
+        with open(stamp, "w") as f:
+            f.write(digest)
+    try:
+        cdll = ctypes.CDLL(out)
+    except OSError as e:
+        return None, f"dlopen failed: {e}"
+    _configure(cdll)
+    return cdll, ("built from csrc/" if stale else
+                  "loaded (built earlier from the same csrc/)")
 
 
 def build_capi() -> str | None:

@@ -115,20 +115,39 @@ SITE_KINDS: Dict[str, str] = {
     "jit": "jit",
 }
 
-# Per-chip peak dense-matmul FLOP/s and HBM bandwidth by jax backend.
-# TPU defaults to the v5e bf16 numbers bench.py has always used; CPU
-# and GPU peaks vary too much host to host to pretend — override via
-# FLAGS_device_peak_flops / FLAGS_device_peak_bytes_per_s there.
-_PLATFORM_PEAKS: Dict[str, Tuple[float, float]] = {
-    "tpu": (197e12, 819e9),
+# THE peaks table: per-chip peak dense-matmul FLOP/s and HBM bandwidth,
+# keyed by the ``device_kind`` jax reports, each with where the numbers
+# come from. bench.py's MFU and the roofline gauges below both read it.
+# A device that is not listed has no peaks: ``chip_peaks`` raises, and
+# the gauges stay unset (or take FLAGS_device_peak_flops /
+# FLAGS_device_peak_bytes_per_s) rather than borrow another chip's.
+DEVICE_PEAKS: Dict[str, dict] = {
+    "TPU v5 lite": {
+        "flops": 197e12, "bytes_per_s": 819e9,
+        "source": "Google Cloud documentation, 'TPU v5e': 197 TFLOP/s "
+                  "bf16, 16 GB HBM at 819 GB/s per chip"},
 }
+
+
+def chip_peaks(device_kind: str) -> dict:
+    """``{"flops", "bytes_per_s", "source"}`` of one chip of this
+    ``device_kind``; an unknown kind is an error, not a default."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device_kind {device_kind!r} in "
+            f"xstats.DEVICE_PEAKS (known: {sorted(DEVICE_PEAKS)}); add "
+            f"the chip with its source before reporting a utilization "
+            f"on it") from None
 
 
 def device_peaks() -> dict:
     """Resolve the (peak FLOP/s, peak bytes/s) pair: explicit flags
-    first, then the per-platform table, else 0 (= unknown; MFU gauges
-    stay unset rather than report garbage). Cached per
-    flags-generation — the stepprof join reads this every step."""
+    first, then the ``DEVICE_PEAKS`` entry of this device's kind, else
+    0 (= unknown; MFU gauges stay unset rather than report garbage).
+    Cached per flags-generation — the stepprof join reads this every
+    step."""
     global _peaks_cache
     gen = _flags_generation()
     if gen is not None and _peaks_cache[0] == gen and \
@@ -140,23 +159,19 @@ def device_peaks() -> dict:
 
 
 def _device_peaks_uncached() -> dict:
+    import jax
     flops = float(_flag("FLAGS_device_peak_flops", 0.0))
     bps = float(_flag("FLAGS_device_peak_bytes_per_s", 0.0))
     source = "flag" if (flops or bps) else "table"
-    platform = None
-    try:
-        import jax
-        platform = jax.default_backend()
-    except Exception:  # noqa: BLE001 - peaks must resolve pre-backend
-        pass
+    dev = jax.devices()[0]
     if not (flops and bps):
-        t_flops, t_bps = _PLATFORM_PEAKS.get(platform or "", (0.0, 0.0))
-        flops = flops or t_flops
-        bps = bps or t_bps
+        entry = DEVICE_PEAKS.get(dev.device_kind, {})
+        flops = flops or entry.get("flops", 0.0)
+        bps = bps or entry.get("bytes_per_s", 0.0)
     if not (flops or bps):
         source = "unknown"
-    return {"flops": flops, "bytes_per_s": bps,
-            "source": source, "platform": platform}
+    return {"flops": flops, "bytes_per_s": bps, "source": source,
+            "platform": dev.platform, "device_kind": dev.device_kind}
 
 
 def signature_of(tree) -> tuple:
@@ -250,6 +265,18 @@ class ExecEntry:
         self.sig_arg_bytes = _sig_arg_bytes(signature)
         self._compiled = compiled
         self._lower_thunk = lower_thunk
+
+    def program_text(self) -> Optional[str]:
+        """The program behind this entry — the attached executable's
+        optimized HLO, else the lowering's StableHLO — or None once the
+        analysis has been read and the handles dropped. What a caller
+        greps to see which path an executable took (a Pallas kernel is
+        a ``tpu_custom_call`` in both forms)."""
+        if self._compiled is not None:
+            return self._compiled.as_text()
+        if self._lower_thunk is not None:
+            return self._lower_thunk().as_text()
+        return None
 
     def roofline(self, peaks: Optional[dict] = None) -> dict:
         """Arithmetic intensity vs the platform ridge point."""

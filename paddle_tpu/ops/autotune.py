@@ -15,15 +15,19 @@ from typing import Callable, Dict, List, Sequence, Tuple
 
 import jax
 
+from ..framework import place as _place
+
 _CACHE_ENV = "PADDLE_TPU_AUTOTUNE_CACHE"
 _cache: Dict[str, list] = {}
 _loaded = False
 
 
 def _cache_path() -> str:
-    return os.environ.get(
-        _CACHE_ENV, os.path.join(os.path.expanduser("~"),
-                                 ".paddle_tpu_autotune.json"))
+    """Where ``PADDLE_TPU_AUTOTUNE_CACHE`` says, else inside the
+    checkout next to the compile caches."""
+    from ..compile_cache import cache_root
+    return os.environ.get(_CACHE_ENV) or os.path.join(
+        cache_root(), "autotune.json")
 
 
 def _load():
@@ -34,28 +38,24 @@ def _load():
     try:
         with open(_cache_path()) as f:
             _cache.update(json.load(f))
-    except Exception:
+    except FileNotFoundError:
         pass
 
 
 def _save():
-    try:
-        with open(_cache_path(), "w") as f:
-            json.dump(_cache, f)
-    except Exception:  # pragma: no cover — read-only home
-        pass
+    path = _cache_path()
+    os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
+    tmp = f"{path}.{os.getpid()}.tmp"
+    with open(tmp, "w") as f:
+        json.dump(_cache, f)
+    os.replace(tmp, path)
 
 
 def enabled() -> bool:
-    """Autotuning only makes sense on a real accelerator (interpret-mode
-    timings are meaningless) and is opt-out via FLAGS."""
+    """Autotuning only makes sense on the chip (interpret-mode timings
+    are meaningless) and is opt-out via FLAGS."""
     from ..framework.flags import flag_value
-    if not flag_value("FLAGS_use_autotune"):
-        return False
-    try:
-        return jax.devices()[0].platform.lower() != "cpu"
-    except Exception:  # pragma: no cover
-        return False
+    return bool(flag_value("FLAGS_use_autotune")) and _place.on_tpu()
 
 
 def _cache_key(kernel: str, key: Sequence) -> str:
@@ -74,14 +74,20 @@ def pick(kernel: str, key: Sequence, candidates: List[Tuple],
          make_fn: Callable[[Tuple], Callable], args,
          warmup: int = 1, iters: int = 3) -> Tuple:
     """Return the fastest candidate configuration for ``kernel`` at
-    ``key``, timing each with ``make_fn(cand)(*args)`` on first use."""
+    ``key``, timing each with ``make_fn(cand)(*args)`` on first use.
+
+    A candidate may fail only by asking for more of a device resource
+    than there is (a tile too large for VMEM: the compiler's
+    RESOURCE_EXHAUSTED) — that is what the search is meant to skip.
+    Any other failure is the kernel's and is raised; so is a search in
+    which no candidate compiled."""
     _load()
     ck = _cache_key(kernel, key)
     if ck in _cache:
         return tuple(_cache[ck])
     if not enabled() or len(candidates) == 1:
         return candidates[0]
-    best, best_t = candidates[0], float("inf")
+    best, best_t, refused = None, float("inf"), []
     for cand in candidates:
         try:
             fn = make_fn(cand)
@@ -95,10 +101,17 @@ def pick(kernel: str, key: Sequence, candidates: List[Tuple],
                 out = fn(*args)
             jax.block_until_ready(out)
             dt = (time.perf_counter() - t0) / iters
-        except Exception:
+        except jax.errors.JaxRuntimeError as e:
+            if "RESOURCE_EXHAUSTED" not in str(e):
+                raise
+            refused.append(f"{cand}: {str(e).splitlines()[0][:200]}")
             continue
         if dt < best_t:
             best, best_t = cand, dt
+    if best is None:
+        raise RuntimeError(
+            f"autotune {ck}: none of {len(candidates)} candidates "
+            f"fits the device: " + "; ".join(refused))
     _cache[ck] = list(best)
     _save()
     return best
@@ -112,15 +125,24 @@ PAGED_KERNELS = ("paged_decode", "paged_chunked")
 
 def paged_block_candidates(kind: str, seq: int, num_heads: int,
                            head_dim: int, page_size: int,
-                           pages_per_seq: int) -> List[Tuple]:
+                           pages_per_seq: int,
+                           quantized: bool = False) -> List[Tuple]:
     """Block-size table for the fused paged kernels: every legal
     ``(block_q, block_h, pages_per_tile)``.
 
+    Legal means what the TPU lowering takes: the last two dims of a
+    block are the whole array's or multiples of the native (8, 128)
+    tile. Heads is the second-minor dim of the q and pool blocks
+    ``[.., block_h, D]`` and the minor dim of a quantized pool's scale
+    blocks ``[page_size, block_h]``; block_q is the second-minor dim
+    of the ``[block_q, 1]`` position/valid columns.
+
     - block_q tiles the query window (decode is structurally S == 1;
-      chunked windows tile at powers of two up to the 128-row register
-      tile, the same ladder flash uses);
-    - block_h is the head-block per grid program (head_dim is the lane
-      dim, so a head-block trades grid programs for VMEM working set);
+      chunked windows tile at multiples of 8 up to the 128-row register
+      tile, the same ladder flash uses, or take the window whole);
+    - block_h is the head-block per grid program: all heads first (the
+      default — always legal), then 8-multiples that divide them
+      (128-multiples under quantized pools);
     - pages_per_tile makes the K-tile a page-size multiple: a tile
       spanning n table-adjacent pages is realized as n table-steered
       block loads per program (pool pages are not address-adjacent, so
@@ -131,7 +153,10 @@ def paged_block_candidates(kind: str, seq: int, num_heads: int,
     else:
         bqs = sorted({c for c in (8, 16, 32, 64, 128)
                       if c <= seq and seq % c == 0} | {seq})
-    bhs = [c for c in (1, 2, 4) if num_heads % c == 0] or [1]
+    tile = 128 if quantized else 8
+    bhs = [num_heads] + [c for c in (8, 16, 32, 64, 128)
+                         if c % tile == 0 and c < num_heads
+                         and num_heads % c == 0]
     ppts = [c for c in (1, 2, 4) if pages_per_seq % c == 0] or [1]
     return [(bq, bh, ppt) for bq in bqs for bh in bhs for ppt in ppts]
 
@@ -151,8 +176,8 @@ def paged_blocks(kind: str, seq: int, num_heads: int, head_dim: int,
     if enabled():
         hit = cached(kern, (seq, num_heads, head_dim, page_size,
                             pages_per_seq, dtype, bool(quantized)))
-    defaults = (1 if kind == "decode" else _fit_pow2(seq),
-                1, 1) if hit is None else hit
+    defaults = (1 if kind == "decode" else _fit_block_q(seq),
+                num_heads, 1) if hit is None else hit
     bq, bh, ppt = (o if o is not None else d
                    for o, d in zip(overrides, defaults))
     if seq % bq or num_heads % bh or pages_per_seq % ppt:
@@ -163,11 +188,8 @@ def paged_blocks(kind: str, seq: int, num_heads: int, head_dim: int,
     return int(bq), int(bh), int(ppt)
 
 
-def _fit_pow2(seq: int, cap: int = 128) -> int:
-    blk = 1
-    c = 2
-    while c <= min(seq, cap):
-        if seq % c == 0:
-            blk = c
-        c *= 2
-    return blk if seq % blk == 0 else seq
+def _fit_block_q(seq: int, cap: int = 128) -> int:
+    """Largest 8-multiple power of two up to ``cap`` that divides the
+    window, else the window whole (both legal tiles)."""
+    fits = [c for c in (8, 16, 32, 64, 128) if c <= cap and seq % c == 0]
+    return max(fits) if fits else seq

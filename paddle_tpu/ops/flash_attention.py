@@ -13,14 +13,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-
-def _on_tpu() -> bool:
-    """True only on an actual TPU backend — the Pallas kernels carry
-    pltpu compiler params that no other platform can compile."""
-    try:
-        return jax.devices()[0].platform.lower() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+from ..framework import place as _place
 
 
 def preferred(q, k, v, mask, causal) -> bool:
@@ -42,7 +35,7 @@ def preferred(q, k, v, mask, causal) -> bool:
 def supported(q, k, v, mask, causal) -> bool:
     if mask is not None:
         return False
-    if not _on_tpu():
+    if not _place.on_tpu():
         return False
     # block constraints: seq multiple of 128, head_dim in {64,128,256}
     b, sq, h, d = q.shape
@@ -102,7 +95,7 @@ def pretune(batch, num_heads, seq_len, head_dim, dtype="bfloat16",
     from . import autotune
     from .pallas_attention import mha
 
-    if not _on_tpu() or not autotune.enabled():
+    if not autotune.enabled():
         return None
     sk = kv_len or seq_len
     cands = _block_candidates(seq_len, sk)

@@ -318,8 +318,8 @@ def paged_attention_update(q, k, v, k_pool, v_pool, block_tables,
     FLAGS_decode_pallas_attention at trace time (the serving decoder
     pins the value at construction instead, so a flag flip can never
     silently disagree with an already-compiled executable). The pure
-    body below stays the reference and the automatic fallback for
-    unsupported shapes.
+    body below stays the reference the kernels are tested against; it
+    never answers for them — a call the kernel cannot serve raises.
 
     ``mesh`` is the serving replica's tensor-parallel mesh
     (serving/mesh.py) with weights and pools heads-sharded over 'mp'.
@@ -329,8 +329,9 @@ def paged_attention_update(q, k, v, k_pool, v_pool, block_tables,
     heads-block of q and the pools). The pure-JAX path ignores the mesh
     entirely — write/gather/attend are all heads-pointwise, and GSPMD
     partitions them from the operands' committed shardings; that path
-    is the oracle the shard_map dispatch is tested against. Heads that
-    don't divide mp fall back to pure JAX.
+    is the oracle the shard_map dispatch is tested against.
+    (``ServingMesh.validate_heads`` refuses heads that mp does not
+    divide before any of this is traced.)
 
     Returns (attn_out [B, S, H, D], k_pool', v_pool').
     """
@@ -366,18 +367,24 @@ def paged_attention_update(q, k, v, k_pool, v_pool, block_tables,
         return out, k_pool, v_pool
     if use_pallas:
         from . import pallas_paged_attention as ppa
-        if ppa.supported(q, k_pool, block_tables, page_size, kind):
-            if sharded:
-                out = _sharded_paged_attention(
-                    mesh, q, k_pool, v_pool, block_tables, ctx_len,
-                    valid, positions, page_size=page_size, kind=kind,
-                    scale=scale)
-            else:
-                out = ppa.paged_attention(
-                    q, k_pool, v_pool, block_tables, ctx_len, valid,
-                    positions, page_size=page_size, kind=kind,
-                    scale=scale)
-            return out, k_pool, v_pool
+        if not ppa.supported(q, k_pool, block_tables, page_size, kind):
+            raise ValueError(
+                f"the fused paged-attention kernel was asked for "
+                f"(FLAGS_decode_pallas_attention) and cannot serve "
+                f"kind={kind!r} q{tuple(q.shape)} page_size={page_size}"
+                f" tables{tuple(block_tables.shape)}; see "
+                f"pallas_paged_attention.supported")
+        if sharded:
+            out = _sharded_paged_attention(
+                mesh, q, k_pool, v_pool, block_tables, ctx_len,
+                valid, positions, page_size=page_size, kind=kind,
+                scale=scale)
+        else:
+            out = ppa.paged_attention(
+                q, k_pool, v_pool, block_tables, ctx_len, valid,
+                positions, page_size=page_size, kind=kind,
+                scale=scale)
+        return out, k_pool, v_pool
     ks = gather_pool(k_pool, block_tables, out_dtype=q.dtype)
     vs = gather_pool(v_pool, block_tables, out_dtype=q.dtype)
     if kind == "decode":

@@ -27,11 +27,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jaxlib renamed TPUCompilerParams -> CompilerParams across pallas
-# releases; resolve whichever this jaxlib ships so the kernels build
-# (and the interpret-mode CPU tests run) on either side of the rename.
-CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
+from ..framework import place as _place
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -48,14 +44,6 @@ def _i0():
     """Index-map zero as i32: under jax_enable_x64 a bare python 0 traces as
     i64 and Mosaic refuses the mixed-width index tuple."""
     return jnp.int32(0)
-
-
-def _interpret() -> bool:
-    """Run kernels in interpreter mode off-TPU (CPU tests/debug)."""
-    try:
-        return jax.devices()[0].platform.lower() == "cpu"
-    except Exception:  # pragma: no cover
-        return True
 
 
 # ---------------------------------------------------------------- forward
@@ -149,10 +137,10 @@ def _mha_fwd(q, k, v, causal, sm_scale, block_q, block_k):
             jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, sq, LANES), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
-        interpret=_interpret(),
+        interpret=not _place.on_tpu(),
     )(qr, kr, vr)
     return out.reshape(b, h, sq, d), lse
 
@@ -302,10 +290,10 @@ def _mha_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
                                lambda bh, i, j: (bh, i, _i0())),
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=_interpret(),
+        interpret=not _place.on_tpu(),
     )(qr, kr, vr, dor, lse, di)
 
     dkv_kernel = functools.partial(_mha_bwd_dkv_kernel, sm_scale=sm_scale,
@@ -335,10 +323,10 @@ def _mha_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
         ],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=_interpret(),
+        interpret=not _place.on_tpu(),
     )(qr, kr, vr, dor, lse, di)
 
     return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),

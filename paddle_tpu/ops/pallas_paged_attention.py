@@ -29,8 +29,8 @@ sizes come from ``ops/autotune.py``'s paged tables; a K-tile spanning
 times with per-subtile index maps (table-adjacent pages are not
 pool-adjacent, so one BlockSpec cannot cover them).
 
-Masking parity with the pure-JAX reference (which this module NEVER
-replaces — ``paged_attention_update`` keeps it as the fallback):
+Masking parity with the pure-JAX reference (kept in
+ops/paged_attention.py as the oracle the kernels are tested against):
 
 - trash page / stale table entries: tiles past a row's context load
   whatever the table points at (often page 0, the trash page) and are
@@ -47,8 +47,10 @@ ops/paged_attention.py) dequantize inside the tile load: the int8 page
 tile and its per-(slot, head) scales are fetched through the same block
 table and widened to f32 right before the QK^T dot.
 
-``interpret=True`` off-TPU (like ``pallas_attention._interpret``) keeps
-tier-1 CPU coverage of every kernel path without a TPU.
+The kernels compile for the chip on a TPU and run in interpret mode
+on any other backend (``framework.place.on_tpu``), which keeps tier-1
+CPU coverage of every kernel path; tests/test_chip_compile.py compiles
+them for a described v5e at gpt3_1p3b widths.
 """
 from __future__ import annotations
 
@@ -59,7 +61,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_attention import LANES, NEG_INF, CompilerParams, _i0, _interpret
+from ..framework import place as _place
+from .pallas_attention import LANES, NEG_INF, _i0
 from .paged_attention import is_quantized_pool
 
 __all__ = ["paged_attention", "prefill_flash", "supported",
@@ -67,14 +70,19 @@ __all__ = ["paged_attention", "prefill_flash", "supported",
 
 
 def supported(q, k_pool, block_tables, page_size: int, kind: str) -> bool:
-    """Can the fused kernel serve this call? (The caller falls back to
-    the pure-JAX gather reference when not.) Shapes are unconstrained —
-    tiles are page-granular so any (page_size, head_dim) works in
-    interpret mode and pads to the native tile on TPU; only the kind
-    and rank are structural."""
+    """Can the fused kernel serve this call? Only structure decides:
+    the kind, the ranks, and pages of at least two slots (Mosaic
+    refuses the one-slot tile). Tile legality is not a property of the
+    shapes but of the blocks, and ``autotune.paged_blocks`` only picks
+    legal ones: all heads per block, the window whole or in 8-multiples.
+    So any (heads, head_dim, page_size >= 2) compiles for the chip —
+    tests/test_chip_compile.py holds the gpt3_1p3b widths to that for
+    f32/bf16/int8 pools — short of a tile that outgrows VMEM, which the
+    compiler refuses by name. A caller that asked for the kernel and
+    gets False here raises; nothing answers in the kernel's place."""
     if kind not in ("decode", "chunked"):
         return False
-    if q.ndim != 4:
+    if q.ndim != 4 or page_size < 2:
         return False
     values = k_pool[0] if is_quantized_pool(k_pool) else k_pool
     return values.ndim == 4 and block_tables.ndim == 2
@@ -129,7 +137,7 @@ def _paged_kernel(tables_ref, ctx_ref, q_ref, pos_ref, val_ref, *refs,
                 if kind == "decode":
                     mask = t_glob < ctx_b
                 else:
-                    mask = (t_glob <= pos[:, None]) & (live[:, None] > 0)
+                    mask = (t_glob <= pos) & (live > 0)
                 # static unroll over the head block: rank-2 dots only
                 # (Mosaic's MXU path; no batched dot_general)
                 for i in range(block_h):
@@ -168,7 +176,7 @@ def _paged_kernel(tables_ref, ctx_ref, q_ref, pos_ref, val_ref, *refs,
     def _emit():
         for i in range(block_h):
             l = jnp.maximum(l_ref[i, :, 0], jnp.float32(1e-30))
-            o_ref[:, i, :] = (acc_ref[i] / l[:, None]).astype(o_ref.dtype)
+            o_ref[:, i, :] = acc_ref[i] / l[:, None]
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, ctx_len, valid,
@@ -196,8 +204,10 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_len, valid,
 
     tables = block_tables.astype(jnp.int32)
     ctx = ctx_len.astype(jnp.int32)
-    pos = positions.astype(jnp.int32)
-    val = valid.astype(jnp.int32)
+    # [B, S, 1]: a (block_q, 1) column is a legal tile (the last dim is
+    # the whole array's) and broadcasts against the [block_q, T] scores
+    pos = positions.astype(jnp.int32)[..., None]
+    val = valid.astype(jnp.int32)[..., None]
 
     if quantized:
         k_vals, k_sc = k_pool
@@ -210,7 +220,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_len, valid,
         return (bi, qb, hb, _i0())
 
     def row_map(bi, hb, qb, pt, ts, cs):
-        return (bi, qb)
+        return (bi, qb, _i0())
 
     def kv_map(j):
         def _map(bi, hb, qb, pt, ts, cs):
@@ -223,7 +233,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_len, valid,
         return _map
 
     q_spec = pl.BlockSpec((None, bq, bh, d), q_map)
-    row_spec = pl.BlockSpec((None, bq), row_map)
+    row_spec = pl.BlockSpec((None, bq, 1), row_map)
     tile_spec = lambda j: pl.BlockSpec((None, page_size, bh, d), kv_map(j))  # noqa: E731
     scale_spec = lambda j: pl.BlockSpec((None, page_size, bh), sc_map(j))  # noqa: E731
 
@@ -259,12 +269,15 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_len, valid,
         return pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, s, h, d), q.dtype),
-            compiler_params=CompilerParams(
+            # f32 out, cast by the caller below: Mosaic has no layout
+            # for a 16-bit [block_q, D] row store when D is not a
+            # whole 128-lane tile
+            out_shape=jax.ShapeDtypeStruct((b, s, h, d), jnp.float32),
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "parallel",
                                      "arbitrary"),
             ),
-            interpret=_interpret(),
+            interpret=not _place.on_tpu(),
         )(tables, ctx, *inputs)
 
     # pallas_call has no JVP rule, but eager dispatch records ops under
@@ -273,7 +286,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_len, valid,
     # actual backward() through it fails.
     call = jax.custom_vjp(_run)
     call.defvjp(lambda *a: (_run(*a), None), _nondiff_bwd)
-    return call(tables, ctx, *inputs)
+    return call(tables, ctx, *inputs).astype(q.dtype)
 
 
 def _nondiff_bwd(_res, _g):
@@ -296,7 +309,7 @@ def prefill_flash(q, k, v, scale, use_flash: bool = True):
     from .flash_attention import (attention_bshd, flash_attention_bshd,
                                   supported as flash_ok)
     sq, sk = q.shape[1], k.shape[1]
-    if _interpret():
+    if not _place.on_tpu():
         # causal mha masks top-left aligned windows only, and its
         # blocks must be 128-lane multiples — sub-128 bucketed windows
         # take the dense reference instead
@@ -328,7 +341,8 @@ def pretune_paged(kind, batch, seq, num_heads, head_dim, page_size,
     if not autotune.enabled():
         return None
     cands = autotune.paged_block_candidates(
-        kind, seq, num_heads, head_dim, page_size, pages_per_seq)
+        kind, seq, num_heads, head_dim, page_size, pages_per_seq,
+        quantized=quantized)
     if len(cands) <= 1:
         return cands[0] if cands else None
     keys = jax.random.split(jax.random.PRNGKey(0), 3)

@@ -180,8 +180,8 @@ def ring_attention(q, k, v, mesh: Mesh, seq_axis: str = "sep",
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     axis_size = mesh.shape[seq_axis]
     if use_flash is None:
-        from .flash_attention import _on_tpu
-        use_flash = _on_tpu() and flash_ring_supported(q, axis_size)
+        from ..framework import place
+        use_flash = place.on_tpu() and flash_ring_supported(q, axis_size)
     baxes = tuple(a for a in batch_axes
                   if a in mesh.axis_names and mesh.shape[a] > 1)
     nb = 1

@@ -26,12 +26,12 @@ import sys
 import tempfile
 import threading
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Union
 
 from .worker import read_announce_file
 
 __all__ = ["ReplicaSupervisor", "ProcessReplicaFactory",
-           "SubprocessReplica"]
+           "SubprocessReplica", "one_chip_env"]
 
 
 def _flag(name, default):
@@ -78,22 +78,37 @@ class SubprocessReplica:
             return None
 
 
+def one_chip_env(index: int) -> Dict[str, str]:
+    """The environment overlay that gives a worker chip ``index`` of
+    this host and no other. A chip belongs to one process at a time and
+    a worker started with the parent's environment claims every chip of
+    the host, so a fleet of one-chip replicas passes
+    ``env=lambda rid: one_chip_env(rid % chips)`` — from a parent that
+    itself stays off the chips."""
+    return {"TPU_VISIBLE_CHIPS": str(int(index)),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1"}
+
+
 class ProcessReplicaFactory:
     """Builds worker subprocesses. ``extra_args`` go to the worker
     CLI verbatim (e.g. ``["--stub", "--stub-device-ms", "8"]`` or
     ``["--model-prefix", "/models/m_v3"]``); ``env`` overlays the
     parent environment — the usual overlay is
     ``FLAGS_compile_cache_dir`` + ``JAX_PLATFORMS``, making every
-    spawn a warm start."""
+    spawn a warm start. A callable ``env(replica_id) -> dict`` gives
+    each worker an overlay of its own, which is how each gets its own
+    chip (``one_chip_env``)."""
 
     def __init__(self, *, extra_args: Optional[List[str]] = None,
-                 env: Optional[Dict[str, str]] = None,
+                 env: Union[Dict[str, str],
+                            Callable[[int], Dict[str, str]], None] = None,
                  host: str = "127.0.0.1",
                  python: Optional[str] = None,
                  announce_dir: Optional[str] = None,
                  stdout=None, stderr=None):
         self.extra_args = list(extra_args or [])
-        self.env = dict(env or {})
+        self.env = env if callable(env) else dict(env or {})
         self.host = host
         self.python = python or sys.executable
         self.announce_dir = announce_dir or tempfile.mkdtemp(
@@ -112,7 +127,8 @@ class ProcessReplicaFactory:
                "--announce", announce,
                "--name", f"replica-{replica_id}"] + self.extra_args
         env = dict(os.environ)
-        env.update(self.env)
+        env.update(self.env(replica_id) if callable(self.env)
+                   else self.env)
         proc = subprocess.Popen(
             cmd, env=env,
             stdout=self.stdout if self.stdout is not None

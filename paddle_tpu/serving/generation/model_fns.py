@@ -346,9 +346,16 @@ class CachedDecoder:
             from ... import compile_cache as cc
             cache = cc.default_cache()
             if cache is not None:
+                # under a live mesh the executable is compiled for the
+                # operands' committed layouts (weights by spec tree,
+                # pools by heads): lowered without them it would want
+                # everything replicated and refuse the first call
+                live = self.serving_mesh.live
                 specs = jax.tree_util.tree_map(
                     lambda a: jax.ShapeDtypeStruct(
-                        tuple(a.shape), np.dtype(a.dtype)), args)
+                        tuple(a.shape), np.dtype(a.dtype),
+                        sharding=a.sharding if live and isinstance(
+                            a, jax.Array) else None), args)
                 key, parts = cc.cache_key(
                     self.fingerprint(), list(specs),
                     mesh=self.serving_mesh.mesh_for_cache_key(),

@@ -106,10 +106,11 @@ def test_lse_residual_shape():
 def test_preferred_gates_by_seq_length(monkeypatch):
     # measured policy (PERF.md): XLA softmax path below FLAGS_flash_min_seqlen,
     # Pallas kernel at/above it — preferred() implements the routing
+    from paddle_tpu.framework import place
     from paddle_tpu.ops import flash_attention as fa
     import paddle_tpu
 
-    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    monkeypatch.setattr(place, "on_tpu", lambda: True)
     mk = lambda s: jnp.zeros((2, s, 4, 64), jnp.bfloat16)
     assert fa.supported(mk(512), mk(512), mk(512), None, True)
     assert not fa.preferred(mk(512), mk(512), mk(512), None, True)

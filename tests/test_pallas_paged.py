@@ -221,6 +221,7 @@ def test_supported_gates():
     t = jnp.zeros((2, 2), jnp.int32)
     q = jnp.zeros((2, 1, H, D))
     assert supported(q, kp, t, PS, "decode")
+    assert not supported(q, kp, t, 1, "decode")
     assert supported(q, (jnp.zeros((3, PS, H, D), jnp.int8),
                          jnp.zeros((3, PS, H))), t, PS, "chunked")
     assert not supported(q, kp, t, PS, "prefill")
@@ -275,18 +276,32 @@ def test_autotune_interpret_guard():
 
 
 def test_paged_block_candidates_legal():
-    for kind, seq in [("decode", 1), ("chunked", 24), ("chunked", 128)]:
-        cands = autotune.paged_block_candidates(kind, seq, H, D, PS, 4)
-        assert cands
-        for bq, bh, ppt in cands:
-            assert seq % bq == 0 and H % bh == 0 and 4 % ppt == 0
-    assert autotune.paged_block_candidates("decode", 1, H, D, PS, 4)[0]
+    """Every candidate is a tile the TPU lowering takes: a block dim is
+    the whole array's or a multiple of the native tile — 8 for the
+    heads and window rows, 128 where heads is the minor dim (the
+    quantized pools' scale blocks). gpt3_1p3b's 16 heads included."""
+    for heads in (H, 16, 32):
+        for quantized in (False, True):
+            for kind, seq in [("decode", 1), ("chunked", 5),
+                              ("chunked", 24), ("chunked", 128)]:
+                cands = autotune.paged_block_candidates(
+                    kind, seq, heads, D, PS, 4, quantized=quantized)
+                assert cands
+                for bq, bh, ppt in cands:
+                    assert seq % bq == 0 and heads % bh == 0 \
+                        and 4 % ppt == 0
+                    assert bh == heads or \
+                        bh % (128 if quantized else 8) == 0
+                    assert bq == seq or bq % 8 == 0
+    assert (1, 8, 1) in autotune.paged_block_candidates(
+        "decode", 1, 16, D, PS, 4)
 
 
 def test_paged_blocks_defaults_and_override_validation():
-    assert autotune.paged_blocks("decode", 1, H, D, PS, 4) == (1, 1, 1)
-    bq, bh, ppt = autotune.paged_blocks("chunked", 24, H, D, PS, 4)
-    assert 24 % bq == 0 and (bh, ppt) == (1, 1)
+    assert autotune.paged_blocks("decode", 1, H, D, PS, 4) == (1, H, 1)
+    assert autotune.paged_blocks("chunked", 24, H, D, PS, 4) == (8, H, 1)
+    # a window no 8-multiple divides is taken whole
+    assert autotune.paged_blocks("chunked", 5, H, D, PS, 4) == (5, H, 1)
     with pytest.raises(ValueError):
         autotune.paged_blocks("chunked", 24, H, D, PS, 4,
                               overrides=(5, None, None))

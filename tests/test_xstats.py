@@ -127,7 +127,8 @@ class TestExecRegistry:
                    "FLAGS_device_peak_bytes_per_s": 1e11})
         peaks = xstats.device_peaks()
         assert peaks == {"flops": 1e12, "bytes_per_s": 1e11,
-                         "source": "flag", "platform": "cpu"}
+                         "source": "flag", "platform": "cpu",
+                         "device_kind": "cpu"}
 
     def test_device_peaks_unknown_on_bare_cpu(self, fresh_xstats):
         set_flags({"FLAGS_device_peak_flops": 0.0,

@@ -30,7 +30,6 @@ import shutil
 import statistics
 import subprocess
 import sys
-import tempfile
 import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -119,6 +118,11 @@ def run_child(args):
 
 # -------------------------------------------------------------- parent
 def _save_model(prefix, hidden, layers, with_seq):
+    # the children run on the CPU (``_trial``); so does this parent —
+    # a parent on the default backend would hold a chip it never uses
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+
     import paddle_tpu as paddle
     import paddle_tpu.nn as nn
 
@@ -179,7 +183,10 @@ def main():
     if args.child:
         return run_child(args)
 
-    tmp = tempfile.mkdtemp(prefix="coldstart-")
+    from paddle_tpu.compile_cache import fresh_scratch_dir
+
+    # (the cold side wipes its cache per trial below)
+    tmp = fresh_scratch_dir("bench_coldstart")
     prefix = os.path.join(tmp, "model")
     cache_dir = os.path.join(tmp, "cache")
     try:

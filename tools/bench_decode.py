@@ -56,8 +56,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 import numpy as np  # noqa: E402
 
-from tools._bench_common import (  # noqa: E402
-    backend_unavailable, emit_record, skip_record)
+from tools._bench_common import emit_record  # noqa: E402
 
 
 def _median(xs):
@@ -66,18 +65,7 @@ def _median(xs):
 
 
 def main():
-    args = _parse_args()
-    try:
-        return _run(args)
-    except Exception as e:  # noqa: BLE001 - an unreachable backend is
-        # a structured skip, not a crash (shared classifier; see
-        # tools/_bench_common.py for the BENCH_r04 story)
-        if not backend_unavailable(e):
-            raise
-        emit_record(skip_record(
-            f"backend unreachable, decode bench skipped: "
-            f"{type(e).__name__}: {str(e)[:300]}"), out=args.out)
-        return 0
+    return _run(_parse_args())
 
 
 def _parse_args():

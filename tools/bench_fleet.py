@@ -56,7 +56,6 @@ import argparse
 import json
 import os
 import sys
-import tempfile
 import threading
 import time
 import urllib.error
@@ -67,8 +66,19 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 import numpy as np  # noqa: E402
 
-from tools._bench_common import (  # noqa: E402
-    backend_unavailable, emit_record, skip_record)
+from tools._bench_common import emit_record  # noqa: E402
+
+# One process owns a chip. This parent spawns workers, so it never asks
+# jax for its default backend: the phases that compute in-process pin
+# themselves to the CPU first (``_parent_on_cpu``), and the real workers
+# run where ``_WORKER_PLATFORM`` says. Only ``--mesh`` is one process
+# that computes on whatever jax finds.
+_WORKER_PLATFORM = "cpu"
+
+
+def _parent_on_cpu():
+    import jax
+    jax.config.update("jax_platforms", "cpu")
 
 
 def _median(xs):
@@ -270,7 +280,7 @@ def _real_factory(fleet, prefix, cache_dir, warmup, **kw):
                     "--max-batch-size", "8",
                     "--seq-buckets",
                     ",".join(str(s) for s in _SEQ_BUCKETS)],
-        env={"JAX_PLATFORMS": "cpu",
+        env={"JAX_PLATFORMS": _WORKER_PLATFORM,
              "FLAGS_compile_cache_dir": cache_dir}, **kw)
 
 
@@ -317,6 +327,7 @@ def _drive_lattice(url):
 def _phase_scaleout(args, workdir):
     """Cold (fresh cache, lattice warmup) vs warm (shared cache +
     manifest replay) spawn->ready time for a real replica."""
+    _parent_on_cpu()
     from paddle_tpu.serving import fleet
 
     prefix = _build_artifact(workdir, "model_v1", seed=0)
@@ -526,17 +537,15 @@ def _phase_mesh(args):
 
 def _run_mesh(args):
     import jax
+
+    from paddle_tpu.compile_cache import place_jax_cache
+    place_jax_cache()
     mp = int(args.mesh_mp)
     if len(jax.devices()) < mp:
-        # structured skip, same contract as an unreachable backend:
-        # a 1-chip host cannot hold an mp-way replica
-        emit_record(skip_record(
-            f"mesh unavailable: {len(jax.devices())} device(s) < "
-            f"mp={mp}; run under "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count={mp} "
-            f"or on a multi-chip backend",
-            metric="serving_tp_decode"), out=args.out)
-        return 0
+        sys.exit(f"--mesh needs {mp} devices for mp={mp} and jax found "
+                 f"{len(jax.devices())}; run under XLA_FLAGS="
+                 f"--xla_force_host_platform_device_count={mp} or on "
+                 f"a host with that many chips")
     mesh = _phase_mesh(args)
     record = {
         "metric": "serving_tp_decode",
@@ -682,7 +691,6 @@ def _phase_trace_overhead(args):
 
 
 def _run_trace(args):
-    import jax
     acct = _phase_trace_accounting(args)
     record = {
         "metric": "fleet_trace_span_accounting",
@@ -694,7 +702,7 @@ def _run_trace(args):
         "config": {
             "replicas": args.replicas,
             "device_ms": args.device_ms,
-            "backend": jax.default_backend(),
+            "backend": _WORKER_PLATFORM,
             "host_cores": os.cpu_count(),
         },
     }
@@ -715,20 +723,11 @@ def main():
     if args.loadgen:
         print(json.dumps(_loadgen_main(json.loads(args.loadgen))))
         return 0
-    try:
-        if args.mesh:
-            return _run_mesh(args)
-        if args.trace:
-            return _run_trace(args)
-        return _run(args)
-    except Exception as e:  # noqa: BLE001 - an unreachable backend is
-        # a structured skip, not a crash (tools/_bench_common.py)
-        if not backend_unavailable(e):
-            raise
-        emit_record(skip_record(
-            f"backend unreachable, fleet bench skipped: "
-            f"{type(e).__name__}: {str(e)[:300]}"), out=args.out)
-        return 0
+    if args.mesh:
+        return _run_mesh(args)
+    if args.trace:
+        return _run_trace(args)
+    return _run(args)
 
 
 def _parse_args():
@@ -775,10 +774,6 @@ def _parse_args():
 
 
 def _run(args):
-    import jax
-    if jax.default_backend() == "cpu":
-        jax.config.update("jax_platforms", "cpu")
-
     scaling = _phase_scaling(args)
     record = {
         "metric": "fleet_aggregate_qps",
@@ -791,11 +786,14 @@ def _run(args):
         "config": {
             "replicas": args.replicas,
             "device_ms": args.device_ms,
-            "backend": jax.default_backend(),
+            "backend": _WORKER_PLATFORM,
             "host_cores": os.cpu_count(),
         },
     }
-    workdir = tempfile.mkdtemp(prefix="bench-fleet-")
+    from paddle_tpu.compile_cache import fresh_scratch_dir
+
+    # the phases below build their own cold and shared caches inside it
+    workdir = fresh_scratch_dir("bench_fleet")
     if not args.skip_scaleout:
         record["scale_out"], prefix_v1, cache = \
             _phase_scaleout(args, workdir)

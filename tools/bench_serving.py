@@ -39,8 +39,6 @@ import numpy as np  # noqa: E402
 import paddle_tpu as paddle  # noqa: E402
 import paddle_tpu.nn as nn  # noqa: E402
 from paddle_tpu import inference, serving  # noqa: E402
-from tools._bench_common import (  # noqa: E402
-    backend_unavailable, skip_record)
 
 
 def build_predictor(tmpdir, hidden=256, layers=2):
@@ -367,18 +365,7 @@ def main():
     ap.add_argument("--json", action="store_true",
                     help="machine-readable output only")
     args = ap.parse_args()
-    try:
-        return run_pipeline(args) if args.pipeline \
-            else run_default(args)
-    except Exception as e:  # noqa: BLE001 - an unreachable backend is
-        # a structured skip, not a crash (shared classifier in
-        # tools/_bench_common.py)
-        if not backend_unavailable(e):
-            raise
-        print(json.dumps(skip_record(
-            f"backend unreachable, serving bench skipped: "
-            f"{type(e).__name__}: {str(e)[:300]}")))
-        return 0
+    return run_pipeline(args) if args.pipeline else run_default(args)
 
 
 if __name__ == "__main__":

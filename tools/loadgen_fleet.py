@@ -601,21 +601,12 @@ def run(out=None, verbose=True):
 
 
 def main():
-    from _bench_common import emit_record, skip_record
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--out", default=None,
                     help="write the JSON record here")
     ap.add_argument("--quiet", action="store_true")
     args = ap.parse_args()
-    try:
-        record = run(out=args.out, verbose=not args.quiet)
-    except Exception as e:  # noqa: BLE001 - classified below
-        from _bench_common import backend_unavailable
-        if not backend_unavailable(e):
-            raise
-        emit_record(skip_record(f"{type(e).__name__}: {e}",
-                                bench="loadgen_fleet"), args.out)
-        return
+    record = run(out=args.out, verbose=not args.quiet)
     json.dump(record, sys.stdout, indent=1, sort_keys=True)
     print()
 
