@@ -211,6 +211,11 @@ class TrainStep:
         from ..compile_cache import fingerprint as fpmod
         opt = self.optimizer
         parts = [
+            # what the fingerprints below do not hash: the code under
+            # paddle_tpu/ops/ the step is traced from. Bump with any
+            # edit there that changes the lowered program, metadata
+            # included (v2: the flash_attention named scope)
+            "ops:v2",
             fpmod.layer_fingerprint(self.model),
             fpmod.function_fingerprint(self.loss_fn),
             "specs:" + shard_api.spec_tree_hash(
@@ -762,10 +767,13 @@ class TrainStep:
         recorder."""
         from ..observability.goodput import default_ledger
         from ..observability.stepprof import default_profiler
+        from ..profiler import RecordEvent
         ledger = default_ledger()
         ledger.begin("step")
         try:
-            out = self._call_inner(*batch, n_inputs=n_inputs)
+            # on the device trace's clock while a profiler session runs
+            with RecordEvent("train::step"):
+                out = self._call_inner(*batch, n_inputs=n_inputs)
         finally:
             wall_s = ledger.end()
             try:

@@ -170,7 +170,11 @@ def flash_attention_bshd(q, k, v, causal=False, scale=None):
         # shrink to divisors of the sequence (supported() guarantees
         # seq % 128 == 0, so the halving bottoms out at >= 128)
         bq, bk = clip_blocks(bq, bk, sq, sk)
-    out = mha(qt, kt, vt, causal=causal, sm_scale=s, block_q=bq, block_k=bk)
+    # metadata only: names the kernel's device time in a profile (the
+    # backward pass is scoped where it is traced, pallas_attention.py)
+    with jax.named_scope("flash_attention"):
+        out = mha(qt, kt, vt, causal=causal, sm_scale=s, block_q=bq,
+                  block_k=bk)
     return jnp.swapaxes(out, 1, 2)
 
 

@@ -340,30 +340,46 @@ def paged_attention_update(q, k, v, k_pool, v_pool, block_tables,
     if use_pallas is None:
         from ..framework.flags import flag_value
         use_pallas = bool(flag_value("FLAGS_decode_pallas_attention"))
+    # the scopes are metadata (every operation's op_name begins
+    # "paged_attention/kv_write|kv_gather|attend"): they name this
+    # layer's device time in a profile and change no operation
+    with jax.named_scope("paged_attention"):
+        return _paged_attention_update(
+            q, k, v, k_pool, v_pool, block_tables, ctx_len, valid,
+            positions, page_size=page_size, kind=kind,
+            use_flash=use_flash, use_pallas=use_pallas, mesh=mesh)
+
+
+def _paged_attention_update(q, k, v, k_pool, v_pool, block_tables,
+                            ctx_len, valid, positions, *, page_size,
+                            kind, use_flash, use_pallas, mesh):
     mp = _mesh_mp(mesh)
     heads = q.shape[2]
     sharded = use_pallas and mp > 0 and heads % mp == 0
     b, s = q.shape[0], q.shape[1]
-    slots = flat_slots(block_tables, positions, valid, page_size)
-    slots_flat = slots.reshape(b * s)
-    # the pool scatter stays OUTSIDE shard_map: the flat
-    # [P*page, H, D] reshape keeps the heads dim intact, so GSPMD
-    # partitions the write from the pool's committed sharding
-    k_pool = write_pool(k_pool, slots_flat,
-                        k.reshape(b * s, *k.shape[2:]))
-    v_pool = write_pool(v_pool, slots_flat,
-                        v.reshape(b * s, *v.shape[2:]))
+    with jax.named_scope("kv_write"):
+        slots = flat_slots(block_tables, positions, valid, page_size)
+        slots_flat = slots.reshape(b * s)
+        # the pool scatter stays OUTSIDE shard_map: the flat
+        # [P*page, H, D] reshape keeps the heads dim intact, so GSPMD
+        # partitions the write from the pool's committed sharding
+        k_pool = write_pool(k_pool, slots_flat,
+                            k.reshape(b * s, *k.shape[2:]))
+        v_pool = write_pool(v_pool, slots_flat,
+                            v.reshape(b * s, *v.shape[2:]))
     scale = 1.0 / math.sqrt(q.shape[-1])
     if kind == "prefill":
-        if sharded:
-            out = _sharded_prefill_flash(mesh, q, k, v, scale, use_flash)
-        elif use_pallas:
-            from .pallas_paged_attention import prefill_flash
-            out = prefill_flash(q, k, v, scale, use_flash=use_flash)
-        else:
-            from .flash_attention import attention_bshd
-            out = attention_bshd(q, k, v, causal=True, scale=scale,
-                                 use_flash=use_flash)
+        with jax.named_scope("attend"):
+            if sharded:
+                out = _sharded_prefill_flash(mesh, q, k, v, scale,
+                                             use_flash)
+            elif use_pallas:
+                from .pallas_paged_attention import prefill_flash
+                out = prefill_flash(q, k, v, scale, use_flash=use_flash)
+            else:
+                from .flash_attention import attention_bshd
+                out = attention_bshd(q, k, v, causal=True, scale=scale,
+                                     use_flash=use_flash)
         return out, k_pool, v_pool
     if use_pallas:
         from . import pallas_paged_attention as ppa
@@ -374,21 +390,26 @@ def paged_attention_update(q, k, v, k_pool, v_pool, block_tables,
                 f"kind={kind!r} q{tuple(q.shape)} page_size={page_size}"
                 f" tables{tuple(block_tables.shape)}; see "
                 f"pallas_paged_attention.supported")
-        if sharded:
-            out = _sharded_paged_attention(
-                mesh, q, k_pool, v_pool, block_tables, ctx_len,
-                valid, positions, page_size=page_size, kind=kind,
-                scale=scale)
-        else:
-            out = ppa.paged_attention(
-                q, k_pool, v_pool, block_tables, ctx_len, valid,
-                positions, page_size=page_size, kind=kind,
-                scale=scale)
+        # the fused kernels read K/V through the table themselves:
+        # their gather is inside "attend"
+        with jax.named_scope("attend"):
+            if sharded:
+                out = _sharded_paged_attention(
+                    mesh, q, k_pool, v_pool, block_tables, ctx_len,
+                    valid, positions, page_size=page_size, kind=kind,
+                    scale=scale)
+            else:
+                out = ppa.paged_attention(
+                    q, k_pool, v_pool, block_tables, ctx_len, valid,
+                    positions, page_size=page_size, kind=kind,
+                    scale=scale)
         return out, k_pool, v_pool
-    ks = gather_pool(k_pool, block_tables, out_dtype=q.dtype)
-    vs = gather_pool(v_pool, block_tables, out_dtype=q.dtype)
-    if kind == "decode":
-        out = _decode_attention(q, ks, vs, ctx_len, scale)
-    else:
-        out = _chunked_attention(q, ks, vs, positions, valid, scale)
+    with jax.named_scope("kv_gather"):
+        ks = gather_pool(k_pool, block_tables, out_dtype=q.dtype)
+        vs = gather_pool(v_pool, block_tables, out_dtype=q.dtype)
+    with jax.named_scope("attend"):
+        if kind == "decode":
+            out = _decode_attention(q, ks, vs, ctx_len, scale)
+        else:
+            out = _chunked_attention(q, ks, vs, positions, valid, scale)
     return out, k_pool, v_pool

@@ -389,12 +389,17 @@ def _mha_vjp_bwd(causal, sm_scale, block_q, block_k, res, g):
     q, k, v, out, lse = res
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    if q.shape[2] < BWD_PALLAS_MIN_SEQ:
-        _, vjp_fn = jax.vjp(
-            lambda qq, kk, vv: _mha_reference(qq, kk, vv, causal, sm_scale),
-            q, k, v)
-        return vjp_fn(g)
-    return _mha_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k)
+    # a custom_vjp's backward is traced on its own, outside the scope
+    # its forward was called in (ops/flash_attention.py)
+    with jax.named_scope("flash_attention"):
+        if q.shape[2] < BWD_PALLAS_MIN_SEQ:
+            _, vjp_fn = jax.vjp(
+                lambda qq, kk, vv: _mha_reference(qq, kk, vv, causal,
+                                                  sm_scale),
+                q, k, v)
+            return vjp_fn(g)
+        return _mha_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q,
+                        block_k)
 
 
 mha.defvjp(_mha_vjp_fwd, _mha_vjp_bwd)

@@ -135,36 +135,46 @@ def set_span_sink(fn):
 
 class RecordEvent:
     """Span marker usable as context manager or begin/end pair — same surface
-    as paddle.profiler.RecordEvent; also emits a jax named span so device
-    traces correlate. ``args`` (a shallow dict, e.g. the serving layer's
-    ``{"rows": 8, "padded": 8}``) lands in the chrome-trace event's
-    ``args`` field and can be extended during the span via
-    ``set_arg`` — the serving pipeline stamps measured stage times onto
-    its spans this way."""
+    as paddle.profiler.RecordEvent. The span goes to two sinks: the
+    in-process ``_HostTracer`` (chrome-trace export, the span sink), and a
+    ``jax.profiler.TraceAnnotation``, which while a jax profiler session
+    runs lands on the calling thread's line of the ``/host:CPU`` plane of
+    the same ``.xplane.pb`` as the device's ``XLA Ops``, on one clock;
+    while none runs it costs a flag check. ``args`` (a shallow dict, e.g.
+    the serving layer's ``{"rows": 8, "padded": 8}``) lands in the
+    chrome-trace event's ``args`` field and in the annotation's stats, and
+    can be extended during the span via ``set_arg`` — the serving
+    pipeline stamps measured stage times onto its spans this way."""
 
     def __init__(self, name, event_type=None, args=None):
         self.name = name
         self.args = dict(args) if args else None
-        self._jax_ctx = None
+        self._annotation = None
         self._start = None
 
     def set_arg(self, key, value):
         if self.args is None:
             self.args = {}
         self.args[key] = value
+        if self._annotation is not None:
+            try:
+                self._annotation.set_metadata(**{key: value})
+            except Exception:  # noqa: BLE001 - as in begin()
+                pass
 
     def begin(self):
         self._start = time.perf_counter_ns()
         try:
-            self._jax_ctx = jax.named_scope(self.name)
-            self._jax_ctx.__enter__()
-        except Exception:
-            self._jax_ctx = None
+            self._annotation = jax.profiler.TraceAnnotation(
+                self.name, **(self.args or {}))
+            self._annotation.__enter__()
+        except Exception:  # noqa: BLE001 - telemetry must never fail
+            self._annotation = None   # the instrumented code path
 
     def end(self):
-        if self._jax_ctx is not None:
-            self._jax_ctx.__exit__(None, None, None)
-            self._jax_ctx = None
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         if self._start is not None:
             end = time.perf_counter_ns()
             _tracer.add(self.name, self._start, end,

@@ -51,6 +51,7 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 from ...observability import tracing
+from ...profiler import RecordEvent
 from ..bucketing import ShapeBucketPolicy
 from ..request import (DeadlineExceededError, QueueFullError,
                        QuotaExceededError, ServerClosedError)
@@ -288,6 +289,21 @@ class _ActiveSeq:
 _EVENTS = ("submitted", "completed", "rejected", "timed_out",
            "cancelled", "failed", "parked", "preempted", "resumed")
 
+# the loop thread's timeline: every moment of GenerationServer._loop
+# falls in exactly one of these, each an ``engine::<phase>``
+# RecordEvent and a cumulative sum in DecodeMetrics
+PHASES = ("admit", "prefill", "decode_feeds", "decode_call",
+          "sample_emit", "bookkeeping", "wait")
+
+
+def _tail_buckets_ms(lo: float = 0.05, hi: float = 60e3,
+                     ratio: float = 1.05) -> tuple:
+    """Geometric bucket bounds fine enough to read a tail from the
+    difference of two cumulative snapshots: a quantile is then known
+    to within ``ratio``."""
+    n = int(np.ceil(np.log(hi / lo) / np.log(ratio)))
+    return tuple(float(lo * ratio ** i) for i in range(n + 1))
+
 
 class DecodeMetrics:
     """Decode-serving metric families on the PR 3 registry, plus
@@ -357,12 +373,24 @@ class DecodeMetrics:
         self._f_spec_acc = reg.counter(
             "paddle_decode_spec_accepted_tokens_total",
             "proposed tokens the target model accepted", ("server",))
+        tail = _tail_buckets_ms()
+        self._f_stall = reg.histogram(
+            "paddle_decode_stream_stall_ms",
+            "what every running stream waited beyond a decode step: "
+            "end of one iteration's sample_emit to the start of the "
+            "next decode call (admissions and prefill groups in it)",
+            ("server",), buckets=tail)
+        self._f_qwait = reg.histogram(
+            "paddle_decode_queue_wait_ms",
+            "submit to slot: how long a request waited to be admitted",
+            ("server",), buckets=tail)
         for fam in (self._f_events, self._f_tokens, self._f_inter,
                     self._f_step, self._f_occ, self._f_pages,
                     self._f_pool_bytes,
                     self._f_evict, self._f_compile, self._f_ttft,
                     self._f_pfx_hits, self._f_pfx_reused,
-                    self._f_spec_prop, self._f_spec_acc):
+                    self._f_spec_prop, self._f_spec_acc,
+                    self._f_stall, self._f_qwait):
             fam.clear(server=name)
         self._events = {e: self._f_events.labels(server=name, event=e)
                         for e in _EVENTS}
@@ -382,6 +410,8 @@ class DecodeMetrics:
         self._c_pfx_reused = self._f_pfx_reused.labels(server=name)
         self._c_spec_prop = self._f_spec_prop.labels(server=name)
         self._c_spec_acc = self._f_spec_acc.labels(server=name)
+        self._h_stall = self._f_stall.labels(server=name)
+        self._h_qwait = self._f_qwait.labels(server=name)
         self._w_inter = PercentileWindow(int(window))
         self._w_ttft = PercentileWindow(int(window))
         self._w_step = {s: PercentileWindow(int(window))
@@ -391,6 +421,67 @@ class DecodeMetrics:
         self._page_capacity = int(page_capacity)
         self._pool_bytes = 0
         self._pool_dtype = "model"
+        # ---- the loop thread's timeline and what it dispatched, all
+        # cumulative since the server started: the difference of two
+        # snapshots is exact for any window
+        self._loop_s = dict.fromkeys(PHASES, 0.0)
+        self._phase: Optional[str] = None     # the open phase
+        self._phase_t0 = 0.0                  # perf_counter at its start
+        self._prefill = {"prompt_tokens": 0, "padded_tokens": 0}
+        self._prefill_by_shape: Dict[str, int] = {}
+        self._prefill_call_s_by_shape: Dict[str, float] = {}
+
+    def switch_phase(self, phase: Optional[str], now: float):
+        """The loop thread leaves its open phase at ``now`` (a
+        ``perf_counter`` reading) and enters ``phase``; None closes the
+        timeline (the loop ended)."""
+        with self._lock:
+            if self._phase is not None:
+                self._loop_s[self._phase] += now - self._phase_t0
+            self._phase, self._phase_t0 = phase, now
+
+    def observe_prefill_dispatch(self, padded_rows: int, seq_bucket: int,
+                                 prompt_tokens: int, call_ms: float):
+        """One prefill group of the padded shape ``padded_rows`` x
+        ``seq_bucket`` whose decoder call took ``call_ms`` (what
+        ``step_ms["prefill"]`` holds)."""
+        shape = f"{int(padded_rows)}x{int(seq_bucket)}"
+        with self._lock:
+            p = self._prefill
+            p["prompt_tokens"] += int(prompt_tokens)
+            p["padded_tokens"] += int(padded_rows) * int(seq_bucket)
+            self._prefill_by_shape[shape] = \
+                self._prefill_by_shape.get(shape, 0) + 1
+            self._prefill_call_s_by_shape[shape] = \
+                self._prefill_call_s_by_shape.get(shape, 0.0) \
+                + call_ms / 1e3
+
+    def observe_queue_wait(self, waits_s: Sequence[float]):
+        self._h_qwait.observe_many([w * 1e3 for w in waits_s])
+
+    def observe_stream_stall(self, ms: float):
+        self._h_stall.observe(float(ms))
+
+    @staticmethod
+    def _cumulative(child) -> dict:
+        pairs = child.buckets()
+        return {"le": [ub for ub, _ in pairs[:-1]],   # +Inf is implied
+                "counts": [int(c) for _, c in pairs]}
+
+    def _engine_snapshot(self) -> dict:
+        """The ``"engine"`` section (lock held)."""
+        loop_s = dict(self._loop_s)
+        if self._phase is not None:
+            # the open phase so far: the phases then sum to the loop
+            # thread's wall time at this very moment
+            loop_s[self._phase] += time.perf_counter() - self._phase_t0
+        return {"loop_s": loop_s,
+                "prefill": dict(self._prefill,
+                                by_shape=dict(self._prefill_by_shape),
+                                call_s_by_shape=dict(
+                                    self._prefill_call_s_by_shape)),
+                "stream_stall_ms": self._cumulative(self._h_stall),
+                "queue_wait_ms": self._cumulative(self._h_qwait)}
 
     def count(self, event: str, n: int = 1):
         self._events[event].inc(n)
@@ -459,6 +550,7 @@ class DecodeMetrics:
                 "step_ms": {s: w.snapshot()
                             for s, w in self._w_step.items()},
                 "batch_occupancy": {"mean": occ, "steps": self._occ_n},
+                "engine": self._engine_snapshot(),
                 "kv_pages": {"capacity": self._page_capacity,
                              "used": int(self._g_used.value),
                              "free": int(self._g_free.value),
@@ -643,6 +735,7 @@ class GenerationServer:
         self._loop_running = False
         self._worker: Optional[threading.Thread] = None
         self._steps = 0
+        self._span: Optional[RecordEvent] = None   # the open phase's
         # readiness gate (mirrors InferenceServer): not-ready until a
         # warmup pass completes, so a fleet router skips cold engines
         self._ready_gate = bool(
@@ -1088,12 +1181,51 @@ class GenerationServer:
                 self._manifest.record(feeds, site=site)
 
     # ------------------------------------------------------ worker
+    def _enter_phase(self, phase: Optional[str], **args) -> float:
+        """The loop thread leaves its open phase and enters ``phase``
+        (one of ``PHASES``; None when the loop ends): the
+        ``engine::<phase>`` span closes and the next opens at one
+        clock reading, which is returned, so every moment of the loop
+        is in exactly one span and one cumulative sum of
+        ``metrics_snapshot()["engine"]["loop_s"]``."""
+        if self._span is not None:
+            self._span.end()
+        now = time.perf_counter()
+        self.metrics.switch_phase(phase, now)
+        self._span = None
+        if phase is not None:
+            self._span = RecordEvent("engine::" + phase, args=args)
+            self._span.begin()
+        return now
+
+    def _enter_decode_call(self, active: List[_ActiveSeq],
+                           context_tokens: int,
+                           stall_t0: Optional[float]):
+        """``engine::decode_call`` opens, and says on the span what
+        the step's attention has to read: ``context_tokens`` cached
+        positions over ``active`` live lanes (the work behind
+        ``paged_attn_roofline``, on the trace's own clock).
+        ``stall_t0`` is when this loop iteration began, if it began
+        with a live stream (the end of the last iteration's
+        sample_emit): what every running stream has waited since, the
+        admissions and prefill groups of this iteration, is the
+        stream stall."""
+        now = self._enter_phase("decode_call", active=len(active),
+                                context_tokens=int(context_tokens))
+        if stall_t0 is not None:
+            self.metrics.observe_stream_stall((now - stall_t0) * 1e3)
+
     def _loop(self):
         with self._lock:
             self._loop_running = True
         try:
             while True:
+                # unlocked: nothing but this thread writes _slots
+                now = self._enter_phase("admit")
+                stall_t0 = now if any(
+                    s is not None for s in self._slots) else None
                 self._admit_and_prefill()
+                self._enter_phase("bookkeeping")
                 with self._lock:
                     self._evict_expired_streams()
                     active = [s for s in self._slots if s is not None]
@@ -1103,13 +1235,15 @@ class GenerationServer:
                     if not active:
                         if self._closed and not self._queue:
                             return
+                        self._enter_phase("wait")
                         self._lock.wait(0.05)
                         continue
                 if self.draft is not None:
-                    self._spec_iteration(active)
+                    self._spec_iteration(active, stall_t0)
                 else:
-                    self._decode_iteration(active)
+                    self._decode_iteration(active, stall_t0)
         finally:
+            self._enter_phase(None)
             with self._lock:
                 self._loop_running = False
 
@@ -1309,6 +1443,14 @@ class GenerationServer:
                                           self.kv.free_pages)
         if not admitted:
             return
+        # submit to slot: the clock is read after the admission loop
+        # (lock, deadline sweep, prefix lookup, page allocation), as
+        # the generate::queue span below reads it. A parked stream
+        # that resumes waited in a lane, not in the queue
+        got_slot = time.monotonic()
+        self.metrics.observe_queue_wait(
+            [got_slot - seq.req.submit_t for seq in admitted
+             if not seq.req.n_done])
         t_adm = time.time_ns()
         for seq in admitted:
             if seq.req.trace is not None:
@@ -1345,6 +1487,8 @@ class GenerationServer:
     def _prefill_group(self, seqs: List[_ActiveSeq], seq_bucket: int):
         rows = len(seqs)
         padded = min(self.policy.bucket_batch(rows), self.max_batch)
+        prompt_tokens = sum(len(seq.req.prompt) for seq in seqs)
+        self._enter_phase("prefill")
         ids = np.full((padded, seq_bucket), self.pad_token_id, np.int64)
         lens = np.zeros(padded, np.int32)
         tables = np.zeros((padded, self.pages_per_seq), np.int32)
@@ -1376,14 +1520,20 @@ class GenerationServer:
                                error=f"{type(e).__name__}: {e}")
             return
         ms = (time.perf_counter() - t0) * 1e3
+        self._enter_phase("bookkeeping")
         self.metrics.observe_step("prefill", ms)
+        self.metrics.observe_prefill_dispatch(padded, seq_bucket,
+                                              prompt_tokens, ms)
         try:
             # stepprof envelope per prefill group: joins with the
-            # generate_prefill executable for paddle_mfu{kind=prefill}
+            # generate_prefill executable for paddle_mfu{kind=prefill}.
+            # ms is the host's time of the call (dispatch, execution
+            # and the logits' fetch), so it goes under host_ms: nothing
+            # here knows the device's own time
             from ...observability.stepprof import default_profiler
             default_profiler().record_step(
                 ms, kind="prefill", step=self._steps,
-                device_ms=ms, occupancy=rows,
+                host_ms=ms, occupancy=rows,
                 kv_pages_used=self.kv.used_pages)
         except Exception:  # noqa: BLE001 - profiling is garnish
             pass
@@ -1401,6 +1551,7 @@ class GenerationServer:
             (ids.shape, "int64"), (lens.shape, "int32"),
             (tables.shape, "int32")])
         self._publish_prompts(seqs)
+        self._enter_phase("sample_emit")
         self._sample_and_emit(seqs, logits[:rows])
 
     def _prefill_chunked_group(self, seqs: List[_ActiveSeq],
@@ -1410,6 +1561,10 @@ class GenerationServer:
         prefix pages through the block tables (kind="chunked")."""
         rows = len(seqs)
         padded = min(self.policy.bucket_batch(rows), self.max_batch)
+        # the tokens this dispatch computes: the unmatched tails
+        prompt_tokens = sum(len(seq.req.prompt) - seq.prefix_len
+                            for seq in seqs)
+        self._enter_phase("prefill")
         ids = np.full((padded, seq_bucket), self.pad_token_id, np.int64)
         start = np.zeros(padded, np.int32)
         seg = np.zeros(padded, np.int32)
@@ -1443,14 +1598,17 @@ class GenerationServer:
                                error=f"{type(e).__name__}: {e}")
             return
         ms = (time.perf_counter() - t0) * 1e3
+        self._enter_phase("bookkeeping")
         self.metrics.observe_step("prefill", ms)
+        self.metrics.observe_prefill_dispatch(padded, seq_bucket,
+                                              prompt_tokens, ms)
         try:
             # envelope for the suffix-prefill step (same prefill kind
             # as the cold path: one MFU stream per step kind)
             from ...observability.stepprof import default_profiler
             default_profiler().record_step(
                 ms, kind="prefill", step=self._steps,
-                device_ms=ms, occupancy=rows,
+                host_ms=ms, occupancy=rows,
                 kv_pages_used=self.kv.used_pages)
         except Exception:  # noqa: BLE001 - profiling is garnish
             pass
@@ -1469,6 +1627,7 @@ class GenerationServer:
             (ids.shape, "int64"), (start.shape, "int32"),
             (seg.shape, "int32"), (tables.shape, "int32")])
         self._publish_prompts(seqs)
+        self._enter_phase("sample_emit")
         self._sample_and_emit(seqs, logits[:rows])
 
     def _publish_prompts(self, seqs: List[_ActiveSeq]):
@@ -1486,7 +1645,9 @@ class GenerationServer:
                 seq.published = True
 
     # ---- one decode iteration ----
-    def _decode_iteration(self, active: List[_ActiveSeq]):
+    def _decode_iteration(self, active: List[_ActiveSeq],
+                          stall_t0: Optional[float] = None):
+        self._enter_phase("decode_feeds")
         tokens = np.zeros(self.max_batch, np.int64)
         positions = np.zeros(self.max_batch, np.int32)
         mask = np.zeros(self.max_batch, bool)
@@ -1496,6 +1657,9 @@ class GenerationServer:
             positions[seq.slot] = seq.ctx
             mask[seq.slot] = True
             ctx_after[seq.slot] = seq.ctx + 1
+        # the context this step's attention reads: each live lane's
+        # cached positions, the one it writes among them
+        self._enter_decode_call(active, int(ctx_after.sum()), stall_t0)
         t_wall = time.time_ns()
         t0 = time.perf_counter()
         try:
@@ -1514,6 +1678,7 @@ class GenerationServer:
             return
         self.kv.k, self.kv.v = k2, v2
         ms = (time.perf_counter() - t0) * 1e3
+        self._enter_phase("bookkeeping")
         self._steps += 1
         self.metrics.observe_step("decode", ms)
         self.metrics.observe_occupancy(len(active))
@@ -1524,7 +1689,7 @@ class GenerationServer:
             from ...observability.stepprof import default_profiler
             default_profiler().record_step(
                 ms, kind="decode", step=self._steps,
-                device_ms=ms, occupancy=len(active),
+                host_ms=ms, occupancy=len(active),
                 kv_pages_used=self.kv.used_pages,
                 attrs={"prefix_tokens_reused":
                        self.prefix.tokens_reused
@@ -1548,11 +1713,13 @@ class GenerationServer:
             (self._tables.shape, "int32")])
         for seq in active:
             seq.ctx += 1
+        self._enter_phase("sample_emit")
         self._sample_and_emit(active,
                               logits[[s.slot for s in active]])
 
     # ---- one speculative iteration: draft proposes, target verifies
-    def _spec_iteration(self, active: List[_ActiveSeq]):
+    def _spec_iteration(self, active: List[_ActiveSeq],
+                        stall_t0: Optional[float] = None):
         """Draft-then-verify (Leviathan et al.): the draft model
         proposes ``spec_k`` tokens per lane through its own paged pools
         (same block tables), then the target scores the whole
@@ -1563,6 +1730,12 @@ class GenerationServer:
         already-reserved pages and are rolled back by truncating
         ``ctx``/``draft_ctx`` — the pool itself is never mutated."""
         b, k = self.max_batch, self.spec_k
+        # draft proposal and verify are one engine::decode_call: what
+        # step_ms["decode"] times under speculation
+        # (the verify window reads each lane's context and its k + 1
+        # new positions)
+        self._enter_decode_call(
+            active, sum(s.ctx + k + 1 for s in active), stall_t0)
         t_wall = time.time_ns()
         t0 = time.perf_counter()
         try:
@@ -1591,6 +1764,7 @@ class GenerationServer:
             return
         self.kv.k, self.kv.v = k2, v2
         ms = (time.perf_counter() - t0) * 1e3
+        self._enter_phase("bookkeeping")
         self._steps += 1
         self.metrics.observe_step("decode", ms)
         self.metrics.observe_occupancy(len(active))
@@ -1598,6 +1772,7 @@ class GenerationServer:
             (ids.shape, "int64"), (start.shape, "int32"),
             (seg.shape, "int32"), (self._tables.shape, "int32")])
         # ---- accept-and-resample per lane (host)
+        self._enter_phase("sample_emit")
         toks_lists: List[List[int]] = []
         accs: List[int] = []
         n_accepted = 0
@@ -1618,11 +1793,12 @@ class GenerationServer:
             s.draft_ctx = min(s.draft_ctx, s.ctx)
             toks_lists.append(emitted)
             accs.append(acc)
+        self._enter_phase("bookkeeping")
         try:
             from ...observability.stepprof import default_profiler
             default_profiler().record_step(
                 ms, kind="decode", step=self._steps,
-                device_ms=ms, occupancy=len(active),
+                host_ms=ms, occupancy=len(active),
                 kv_pages_used=self.kv.used_pages,
                 attrs={"spec_proposed": k * len(active),
                        "spec_accepted": n_accepted,
@@ -1649,6 +1825,7 @@ class GenerationServer:
                            "emitted": len(toks),
                            "draft_ms": round(draft_ms, 3),
                            "occupancy": len(active)})
+        self._enter_phase("sample_emit")
         self._emit_batch(active, toks_lists)
 
     def _draft_propose(self, active: List[_ActiveSeq], k: int):

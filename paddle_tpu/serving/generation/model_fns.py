@@ -297,7 +297,13 @@ class CachedDecoder:
                     "max_positions": self.max_positions,
                     "donate": self._donate,
                     "use_pallas": self.use_pallas,
-                    "kv_dtype": self.kv_dtype, "v": 3}
+                    # "v" stands for what layer_fingerprint does not
+                    # hash: the code under paddle_tpu/ops/ these
+                    # programs are traced from. Bump it with any edit
+                    # there that changes the lowered program, metadata
+                    # included (v4: named scopes in paged and flash
+                    # attention)
+                    "kv_dtype": self.kv_dtype, "v": 4}
             # mesh axes + weight spec-tree hash join the geometry ONLY
             # when the mesh is live: an inert (None / 1-device) mesh
             # must reuse today's fingerprints byte-for-byte, and a mesh

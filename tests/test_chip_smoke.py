@@ -36,8 +36,13 @@ BUCKETS = (32, 64)
 
 @pytest.fixture
 def aot_cache(tmp_path):
-    """The smoke runs with the repo's AOT cache on (``main`` sets it)."""
+    """The smoke runs with the repo's AOT cache on (``main`` sets it),
+    in a process of its own: ``exec_table`` reads every executable the
+    registry holds, so what an earlier test file of this worker left
+    there (no memory analysis) goes first."""
     from paddle_tpu.compile_cache import reset_default_cache
+    from paddle_tpu.observability import xstats
+    xstats.reset_for_tests()
     paddle.set_flags({"FLAGS_compile_cache_dir": str(tmp_path / "aot")})
     reset_default_cache()
     yield str(tmp_path / "aot")

@@ -347,7 +347,10 @@ def test_int8_logits_parity_through_cached_decoder():
             tok = np.asarray(last).argmax(-1).astype(np.int64)
             logits, k, v, _ = dec.decode(tok, ctx, np.ones(B, bool),
                                          ctx + 1, tables, k, v)
-            ctx += 1
+            # a new array: the call above may still be reading the old
+            # one (dispatch is asynchronous and the CPU backend aliases
+            # numpy buffers), and `ctx += 1` in place raced with it
+            ctx = ctx + 1
             last = logits
             logits_seq.append(np.asarray(logits))
         outs[kd] = logits_seq
