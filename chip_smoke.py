@@ -14,8 +14,9 @@ layers, 16 heads, vocab 50304) with random weights made from a seed:
   compiler's memory analysis to fill what the weights leave; a few
   requests of different lengths, one pair sharing a prefix, streamed
   to the end and held to the plain full forward of the same weights;
-  once with default flags, once with the fused Pallas paged kernel
-  over an int8 pool;
+  once over the model's dtype, once over an int8 pool, both times on
+  the path a server built with defaults takes (on a TPU the fused
+  Pallas paged kernels; the log says which);
 - *restart*: the AOT compile cache is on for all of it; after the
   first serve pass a fresh server over the same weights replays the
   warm-up manifest from the cache (hits, no misses) and serves from
@@ -389,18 +390,21 @@ def drive(srv, traffic, vocab_size) -> dict:
 
 
 def serve_once(model, *, num_pages, seq_buckets, traffic, name,
-               parity_tol) -> dict:
+               parity_tol, use_pallas=None) -> dict:
     """One server's life: start, the smoke's traffic through
     ``submit_generate``, streams read to the end, parity of the short
     request, drain, page accounting."""
     from paddle_tpu.serving.generation import GenerationServer
 
     srv = GenerationServer(model, num_pages=num_pages,
+                           use_pallas=use_pallas,
                            **_server_kwargs(seq_buckets, name))
     log(f"[{name}] GenerationServer max_batch {srv.max_batch} page_size "
         f"{srv.page_size} tables {srv.pages_per_seq} pages/seq, pool "
         f"{srv.kv.num_pages} pages {fmt(srv.kv.pool_bytes())} "
-        f"({srv.kv_dtype or 'model dtype'}), pallas {srv.use_pallas}")
+        f"({srv.kv_dtype or 'model dtype'}); decode attention: "
+        f"{'the fused paged kernel' if srv.use_pallas else 'the pure-JAX gather'}"
+        f" ({'by default' if use_pallas is None else 'asked for'})")
     t0 = time.perf_counter()
     streams = drive(srv, traffic, model.config.vocab_size)
     secs = time.perf_counter() - t0
@@ -445,41 +449,43 @@ def serve_once(model, *, num_pages, seq_buckets, traffic, name,
 
 
 def phase_serve(model, *, limit_bytes, seq_buckets, name,
-                use_pallas=False, kv_dtype="", parity_tol=0.0625,
+                use_pallas=None, kv_dtype="", parity_tol=0.0625,
                 restart=False) -> dict:
-    """A serve pass under one pair of decode flags; ``restart`` adds
-    the warm restart from the AOT cache."""
+    """A serve pass over one pool dtype; ``use_pallas`` None is the
+    path a server built with defaults takes (on a TPU the fused paged
+    kernel, and the decode executable must then hold it). ``restart``
+    adds the warm restart from the AOT cache."""
     import paddle_tpu as paddle
     from paddle_tpu.framework import place
 
-    paddle.set_flags({"FLAGS_decode_pallas_attention": bool(use_pallas),
-                      "FLAGS_decode_kv_dtype": kv_dtype})
+    paddle.set_flags({"FLAGS_decode_kv_dtype": kv_dtype})
     try:
         pages = plan_kv_pool(model, limit_bytes=limit_bytes,
                              seq_buckets=seq_buckets, kv_dtype=kv_dtype)
         traffic = make_traffic(model.config, 16, seq_buckets)
         out = serve_once(model, num_pages=pages, seq_buckets=seq_buckets,
                          traffic=traffic, name=name,
-                         parity_tol=parity_tol)
-        if use_pallas and place.on_tpu() and not any(
+                         parity_tol=parity_tol, use_pallas=use_pallas)
+        if use_pallas is not False and place.on_tpu() and not any(
                 r["kernel"] for r in out["rows"]
                 if r["site"] == "generate_decode"):
             raise RuntimeError(
-                "FLAGS_decode_pallas_attention is on and the decode "
-                "executable holds no tpu_custom_call")
+                "on a TPU the decode step attends through the fused "
+                "paged kernel, and the decode executable holds no "
+                "tpu_custom_call")
         gc.collect()              # the first server's pool, before the next
         if restart:
             phase_restart(model, num_pages=pages,
                           seq_buckets=seq_buckets, name=name,
-                          traffic=traffic, expect=out["streams"])
+                          traffic=traffic, expect=out["streams"],
+                          use_pallas=use_pallas)
         return out
     finally:
-        paddle.set_flags({"FLAGS_decode_pallas_attention": False,
-                          "FLAGS_decode_kv_dtype": ""})
+        paddle.set_flags({"FLAGS_decode_kv_dtype": ""})
 
 
 def phase_restart(model, *, num_pages, seq_buckets, name, traffic,
-                  expect):
+                  expect, use_pallas=None):
     """What tests/test_compile_cache.py::TestServingSite does to stand
     for a restart: the in-process cache handle is dropped, a fresh
     server over the same weights replays the manifest its predecessor's
@@ -492,6 +498,7 @@ def phase_restart(model, *, num_pages, seq_buckets, name, traffic,
     xstats.default_exec_registry().clear()
     cc.reset_default_cache()
     srv = GenerationServer(model, num_pages=num_pages,
+                           use_pallas=use_pallas,
                            **_server_kwargs(seq_buckets, name))
     before = cc.stats()
     t0 = time.perf_counter()
@@ -706,10 +713,9 @@ def main(argv=None) -> int:
         model = make_serve_model(gpt3_1p3b())
         timed("serve", phase_serve, model=model, limit_bytes=limit,
               seq_buckets=(64, 256), name="smoke", restart=True)
-        timed("serve-pallas-int8", phase_serve, model=model,
+        timed("serve-int8", phase_serve, model=model,
               limit_bytes=limit, seq_buckets=(64, 256),
-              name="smoke-pallas-int8", use_pallas=True,
-              kv_dtype="int8", parity_tol=0.25)
+              name="smoke-int8", kv_dtype="int8", parity_tol=0.25)
     else:
         # both comparisons are of one program against its sharded self:
         # a mesh changes the order of the sums, and neither a greedy

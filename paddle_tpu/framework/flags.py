@@ -216,16 +216,6 @@ define_flag("FLAGS_decode_spec_k", 0,
             "[max_batch, k+1] step with accept-and-resample, so "
             "output distribution matches non-speculative sampling "
             "(0 = off; ignored without a draft model)")
-define_flag("FLAGS_decode_pallas_attention", False,
-            "route the decode/chunked serving attention through the "
-            "fused Pallas paged kernels (ops/pallas_paged_attention.py: "
-            "K/V read through the block table inside the kernel, online "
-            "softmax per page tile, no materialized gather) and serving "
-            "prefill through the pallas_attention.mha flash path; off = "
-            "the pure-JAX gather reference (always kept as fallback for "
-            "unsupported shapes). Read once at GenerationServer "
-            "construction — flipping it mid-process affects new servers "
-            "only, never a compiled decoder")
 define_flag("FLAGS_decode_kv_dtype", "",
             "KV pool storage dtype for serving: '' = model dtype, "
             "'float32', 'bfloat16', or 'int8' (symmetric absmax "
@@ -235,7 +225,7 @@ define_flag("FLAGS_decode_kv_dtype", "",
             "shrinks pool bytes ~3.5-4x, and auto pool sizing "
             "(FLAGS_decode_kv_pages=0) grants sub-f32 dtypes 2x pages "
             "= ~2x resident sequences per chip. Read once at server "
-            "construction, like FLAGS_decode_pallas_attention")
+            "construction and pinned for the server's lifetime")
 define_flag("FLAGS_decode_warmup_from_manifest", False,
             "pre-compile a constructed GenerationServer's decode step "
             "and recorded prefill buckets from its persisted warmup "
@@ -250,7 +240,7 @@ define_flag("FLAGS_serving_mesh_mp", 1,
             "single-shard (today's exact behavior: same fingerprints, "
             "no recompiles). num_heads must divide evenly or "
             "construction fails fast. Read once at server/backend "
-            "construction, like FLAGS_decode_pallas_attention")
+            "construction and pinned for its lifetime")
 
 # Persistent compile cache (paddle_tpu.compile_cache — cold-start
 # amortization across processes).

@@ -161,11 +161,12 @@ class GPTKVCache:
     structure-agnostic — pools are opaque pytrees whose leaves get
     wrapped/unwrapped at the boundaries.
 
-    ``use_pallas`` pins the fused-kernel routing decision
-    (ops/pallas_paged_attention.py) for every layer of this forward;
-    None defers to FLAGS_decode_pallas_attention at trace time. The
-    serving decoder always pins it (model_fns.CachedDecoder) so a flag
-    flip cannot disagree with an already-compiled executable.
+    ``use_pallas`` names who attends (the fused kernels of
+    ops/pallas_paged_attention.py or the pure-JAX body) for every
+    layer of this forward; None leaves it to
+    ``ops.paged_attention.kernel_by_default`` at trace time. The
+    serving decoder always pins it (model_fns.CachedDecoder), so its
+    fingerprint says what its executables hold.
     """
 
     __slots__ = ("kind", "page_size", "k", "v", "block_tables",

@@ -118,17 +118,61 @@ def pick(kernel: str, key: Sequence, candidates: List[Tuple],
 
 
 # ------------------- fused paged serving kernels (pallas_paged_attention)
+#
+# Their blocks are constants: a serving call sits inside a trace, where
+# nothing can be timed, and a server does not time kernels when it
+# starts. What is here was found on a v5e at the benchmark's two serving
+# shapes, 24 layers a call, f32 pools of 16-slot pages (PERF.md section
+# 6, PR 26): 16 heads of 128 over 128-page tables, 7 of 16 lanes live
+# with 2,815 positions; 16 heads of 64 over 64-page tables, 32 lanes
+# with 12,893.
 
-# Kernel names under which paged block choices persist in the cache.
-PAGED_KERNELS = ("paged_decode", "paged_chunked")
+# VMEM the decode kernel's page buffers may take (K and V, two slots
+# each): half the 16 MiB a v5e program gets unasked.
+PAGED_DECODE_VMEM_BYTES = 8 * 1024 * 1024
+# Pages a decode grid program copies and attends at a time. More pages
+# amortize the loop and the running softmax's rescale over more
+# positions; fewer waste less of a lane's last, part-filled chunk.
+# 128-wide heads, ms a call: 1 page 2.27, 2 1.84, 4 1.83, 8 1.90, 16 2.10
+# (the gather: 63.4).
+PAGED_DECODE_PAGES_PER_CHUNK = 4
+
+
+def paged_decode_chunk(page_size: int, rows: int, lanes: int,
+                       itemsize: int, pages_per_seq: int,
+                       override=None) -> int:
+    """Pages per chunk of the decode kernel for a pool whose page is
+    ``[page_size, rows, lanes]``: the constant, cut to what the table
+    holds and to what fits the kernel's VMEM (a buffer row pads to the
+    native tile: 8 sublanes of 32 bits, 128 lanes)."""
+    if override is not None:
+        if override < 1:
+            raise ValueError(f"pages_per_chunk must be >= 1, got "
+                             f"{override}")
+        return int(override)
+    sublanes = 8 * max(1, 4 // itemsize)
+    page_bytes = (page_size * -(-rows // sublanes) * sublanes
+                  * -(-lanes // 128) * 128 * itemsize)
+    fit = PAGED_DECODE_VMEM_BYTES // (4 * page_bytes)
+    return int(max(1, min(PAGED_DECODE_PAGES_PER_CHUNK, pages_per_seq,
+                          fit)))
+
+
+# Pages a grid-kernel program attends for decode (a tile of that many
+# table-steered blocks): fewer grid steps a lane, more blocks a step to
+# steer. 64-wide heads, ms a call: 1 page 17.6, 2 15.0, 4 14.0, 8 14.3,
+# 16 14.9 (the gather: 63.3). At 128-wide heads the same kernel takes
+# 8.7 at 4 pages, the page-copying kernel 1.83: every table slot, live
+# or dead, costs this kernel some 0.18 us of block steering.
+PAGED_DECODE_PAGES_PER_TILE = 4
 
 
 def paged_block_candidates(kind: str, seq: int, num_heads: int,
                            head_dim: int, page_size: int,
                            pages_per_seq: int,
                            quantized: bool = False) -> List[Tuple]:
-    """Block-size table for the fused paged kernels: every legal
-    ``(block_q, block_h, pages_per_tile)``.
+    """Every legal ``(block_q, block_h, pages_per_tile)`` of the grid
+    kernel (chunked windows; decode over quantized pools).
 
     Legal means what the TPU lowering takes: the last two dims of a
     block are the whole array's or multiples of the native (8, 128)
@@ -157,27 +201,24 @@ def paged_block_candidates(kind: str, seq: int, num_heads: int,
     bhs = [num_heads] + [c for c in (8, 16, 32, 64, 128)
                          if c % tile == 0 and c < num_heads
                          and num_heads % c == 0]
-    ppts = [c for c in (1, 2, 4) if pages_per_seq % c == 0] or [1]
+    ppts = [c for c in (1, 2, 4, 8) if pages_per_seq % c == 0] or [1]
     return [(bq, bh, ppt) for bq in bqs for bh in bhs for ppt in ppts]
 
 
 def paged_blocks(kind: str, seq: int, num_heads: int, head_dim: int,
-                 page_size: int, pages_per_seq: int, *, dtype: str = "",
-                 quantized: bool = False,
+                 page_size: int, pages_per_seq: int, *,
                  overrides=(None, None, None)) -> Tuple[int, int, int]:
-    """Resolve ``(block_q, block_h, pages_per_tile)`` for one paged
-    kernel call: explicit overrides win, then a persisted
-    ``pretune_paged`` result, then conservative defaults. Serving calls
-    sit inside a trace where timing is impossible, and ``enabled()`` is
-    False off-TPU — interpret mode must never trigger the timer (the
-    guard tests/test_pallas_paged.py self-tests)."""
-    kern = "paged_decode" if kind == "decode" else "paged_chunked"
-    hit = None
-    if enabled():
-        hit = cached(kern, (seq, num_heads, head_dim, page_size,
-                            pages_per_seq, dtype, bool(quantized)))
-    defaults = (1 if kind == "decode" else _fit_block_q(seq),
-                num_heads, 1) if hit is None else hit
+    """``(block_q, block_h, pages_per_tile)`` for one grid-kernel call:
+    explicit overrides, else all heads and, for a window, 8-multiples
+    of its rows up to 128 with one page a tile; for decode, the largest
+    tile up to PAGED_DECODE_PAGES_PER_TILE pages that divides the
+    table."""
+    if kind == "decode":
+        defaults = (1, num_heads, max(
+            c for c in (1, 2, 4, 8)
+            if c <= PAGED_DECODE_PAGES_PER_TILE and pages_per_seq % c == 0))
+    else:
+        defaults = (_fit_block_q(seq), num_heads, 1)
     bq, bh, ppt = (o if o is not None else d
                    for o, d in zip(overrides, defaults))
     if seq % bq or num_heads % bh or pages_per_seq % ppt:

@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["flat_slots", "write_pool", "gather_pool",
-           "paged_attention_update", "is_quantized_pool",
+           "paged_attention_update", "kernel_by_default", "is_quantized_pool",
            "quantize_kv_rows", "dequantize_kv", "kv_pool_bytes",
            "resolve_kv_dtype"]
 
@@ -266,22 +266,6 @@ def _sharded_paged_attention(mesh, q, k_pool, v_pool, block_tables,
         q, k_pool, v_pool, block_tables, ctx_len, valid, positions)
 
 
-def _sharded_prefill_flash(mesh, q, k, v, scale, use_flash):
-    """Heads-sharded prefill through the flash kernel: each rank runs
-    the Pallas mha on its H/mp heads of the window."""
-    from jax.sharding import PartitionSpec as P
-
-    from ..distributed.mesh_utils import manual_shard_map
-    from .pallas_paged_attention import prefill_flash
-
-    def body(q_loc, k_loc, v_loc):
-        return prefill_flash(q_loc, k_loc, v_loc, scale,
-                             use_flash=use_flash)
-
-    spec = P(None, None, "mp", None)
-    return manual_shard_map(body, mesh, (spec, spec, spec), spec)(q, k, v)
-
-
 def paged_attention_update(q, k, v, k_pool, v_pool, block_tables,
                            ctx_len, valid, positions, *, page_size: int,
                            kind: str, use_flash: bool = True,
@@ -312,14 +296,17 @@ def paged_attention_update(q, k, v, k_pool, v_pool, block_tables,
     prefix AND causally within the window. With positions starting at
     0 this computes the same math as prefill, via the gather path.
 
-    ``use_pallas`` routes decode/chunked through the fused Pallas
-    read-through-table kernels and prefill through the mha flash path
-    (ops/pallas_paged_attention.py); None consults
-    FLAGS_decode_pallas_attention at trace time (the serving decoder
-    pins the value at construction instead, so a flag flip can never
-    silently disagree with an already-compiled executable). The pure
-    body below stays the reference the kernels are tested against; it
-    never answers for them — a call the kernel cannot serve raises.
+    ``use_pallas`` names who attends for decode/chunked: True the
+    fused Pallas kernels that read K/V through the block table
+    (ops/pallas_paged_attention.py), False the pure body below, which
+    gathers every slot a table can address and is the reference the
+    kernels are tested against. None (the default) is decided by what
+    the code can observe (``kernel_by_default``): the kernels on a TPU
+    wherever they can serve the call, the pure body elsewhere — off
+    the chip a kernel runs in Pallas's interpreter, a test's tool and
+    not a path. The pure body never answers for a kernel that was
+    asked for by name: such a call the kernel cannot serve raises.
+    Prefill reads no pool and is ``attention_bshd`` either way.
 
     ``mesh`` is the serving replica's tensor-parallel mesh
     (serving/mesh.py) with weights and pools heads-sharded over 'mp'.
@@ -337,9 +324,6 @@ def paged_attention_update(q, k, v, k_pool, v_pool, block_tables,
     """
     if kind not in KINDS:
         raise ValueError(f"kind must be one of {KINDS}, got {kind!r}")
-    if use_pallas is None:
-        from ..framework.flags import flag_value
-        use_pallas = bool(flag_value("FLAGS_decode_pallas_attention"))
     # the scopes are metadata (every operation's op_name begins
     # "paged_attention/kv_write|kv_gather|attend"): they name this
     # layer's device time in a profile and change no operation
@@ -350,13 +334,26 @@ def paged_attention_update(q, k, v, k_pool, v_pool, block_tables,
             use_flash=use_flash, use_pallas=use_pallas, mesh=mesh)
 
 
+def kernel_by_default(page_size: int) -> bool:
+    """Whether a call that names no path takes the fused kernels: on a
+    TPU, for pages the kernels can tile (``pallas_paged_attention.
+    supported``'s structural half; the ranks are the model's)."""
+    from ..framework import place
+    return place.on_tpu() and page_size >= 2
+
+
 def _paged_attention_update(q, k, v, k_pool, v_pool, block_tables,
                             ctx_len, valid, positions, *, page_size,
                             kind, use_flash, use_pallas, mesh):
+    from . import pallas_paged_attention as ppa
+    if use_pallas is None:
+        use_pallas = kernel_by_default(page_size) and ppa.supported(
+            q, k_pool, block_tables, page_size, kind)
     mp = _mesh_mp(mesh)
     heads = q.shape[2]
     sharded = use_pallas and mp > 0 and heads % mp == 0
     b, s = q.shape[0], q.shape[1]
+    scale = 1.0 / math.sqrt(q.shape[-1])
     with jax.named_scope("kv_write"):
         slots = flat_slots(block_tables, positions, valid, page_size)
         slots_flat = slots.reshape(b * s)
@@ -367,26 +364,17 @@ def _paged_attention_update(q, k, v, k_pool, v_pool, block_tables,
                             k.reshape(b * s, *k.shape[2:]))
         v_pool = write_pool(v_pool, slots_flat,
                             v.reshape(b * s, *v.shape[2:]))
-    scale = 1.0 / math.sqrt(q.shape[-1])
     if kind == "prefill":
         with jax.named_scope("attend"):
-            if sharded:
-                out = _sharded_prefill_flash(mesh, q, k, v, scale,
-                                             use_flash)
-            elif use_pallas:
-                from .pallas_paged_attention import prefill_flash
-                out = prefill_flash(q, k, v, scale, use_flash=use_flash)
-            else:
-                from .flash_attention import attention_bshd
-                out = attention_bshd(q, k, v, causal=True, scale=scale,
-                                     use_flash=use_flash)
+            from .flash_attention import attention_bshd
+            out = attention_bshd(q, k, v, causal=True, scale=scale,
+                                 use_flash=use_flash)
         return out, k_pool, v_pool
     if use_pallas:
-        from . import pallas_paged_attention as ppa
         if not ppa.supported(q, k_pool, block_tables, page_size, kind):
             raise ValueError(
                 f"the fused paged-attention kernel was asked for "
-                f"(FLAGS_decode_pallas_attention) and cannot serve "
+                f"(use_pallas=True) and cannot serve "
                 f"kind={kind!r} q{tuple(q.shape)} page_size={page_size}"
                 f" tables{tuple(block_tables.shape)}; see "
                 f"pallas_paged_attention.supported")

@@ -1,56 +1,69 @@
 """Fused Pallas paged-attention serving kernels (TPU).
 
-ROADMAP item 2: the decode hot path used to materialize the whole
-``[B, pages*page_size, H, D]`` context with ``gather_pool`` before
-attending (ops/paged_attention.py) — an HBM round-trip per generated
-token per layer. These kernels read K/V *through the block table inside
-the kernel* instead: the grid's innermost (arbitrary) dimension walks a
-sequence's logical pages, a ``PrefetchScalarGridSpec`` scalar-prefetch
-block table steers each page tile's ``BlockSpec`` index map at the pool
-directly, and a FlashAttention-style online softmax (running max /
-denominator in VMEM scratch, Dao et al. 2022) accumulates across page
-tiles — no gathered context ever exists.
+The pure-JAX body (ops/paged_attention.py) materializes the whole
+``[B, pages*page_size, H, D]`` context with ``gather_pool`` before it
+attends: an HBM round trip of every slot a block table *can* address,
+per generated token per layer, whatever the context is. These kernels
+read K/V *through the block table* instead, for the pages a lane's
+``ctx_len`` says are live, with a FlashAttention-style online softmax
+(Dao et al. 2022) kept on chip — no gathered context ever exists. On a
+TPU they are the path (``paged_attention.kernel_by_default``); the pure
+body stays as the oracle they are tested against.
 
-One kernel body serves both serving kinds that read through the table:
+Two kernels:
 
-- ``decode``: S == 1, mask ``t < ctx_len[b]`` (PagedAttention decode,
-  Kwon et al. SOSP '23);
-- ``chunked``: arbitrary S window (shared-prefix suffix prefill and the
-  spec-decode verify window) with the per-(row, position) causality
-  mask ``t <= positions[b, s] & valid[b, s]``.
+- ``_decode_kernel`` — ``decode`` (S == 1, mask ``t < ctx_len[b]``;
+  PagedAttention decode, Kwon et al. SOSP '23) over float pools. Grid
+  ``(B,)``: one program a lane, an in-kernel loop over
+  ``ceil(ctx_len / page_size)`` pages in chunks of
+  ``autotune.paged_decode_chunk`` pages, copied HBM -> VMEM by the
+  program's own double-buffered DMAs from a pool that stays in HBM
+  (``memory_space=pl.ANY``). A dead page costs nothing, a dead lane one
+  empty grid step. One query row against a page is 0.5 FLOP a byte, so
+  the scores and the weighted sum run on the vector unit in f32: no
+  M = 1 matmul. It takes heads of whole 128-lane rows
+  (``decode_copies_pages``): Mosaic slices an HBM array along whole lane
+  tiles only.
+- ``_paged_kernel`` — ``chunked`` windows (shared-prefix suffix prefill
+  and the spec-decode verify window, mask ``t <= positions[b, s] &
+  valid[b, s]``), and decode over quantized pools or narrower heads
+  (float pools on the vector unit as above, a page a ``BlockSpec``).
+  Grid ``(B, H/block_h, S/block_q, P/pages_per_tile)``:
+  the innermost (arbitrary) dimension walks a sequence's logical pages,
+  the scalar-prefetched block table steers each page tile's
+  ``BlockSpec`` index map at the pool (a tile past the context names
+  the last live page again and is neither copied nor attended), scores
+  and weighted sum of a window are MXU dots per head. A K-tile spanning ``pages_per_tile`` pages is realized
+  by passing the pool that many times with per-subtile index maps
+  (table-adjacent pages are not pool-adjacent, so one BlockSpec cannot
+  cover them).
 
-Serving ``prefill`` does not read the pool at all — it routes through
-the existing ``pallas_attention.mha`` flash kernel (``prefill_flash``).
+Serving ``prefill`` does not read the pool at all and is not routed
+here.
 
-Grid: ``(B, H/block_h, S/block_q, P/pages_per_tile)`` — one program
-per (row, head-block, q-block) accumulating over page tiles. The block
-sizes come from ``ops/autotune.py``'s paged tables; a K-tile spanning
-``pages_per_tile`` pages is realized by passing the pool that many
-times with per-subtile index maps (table-adjacent pages are not
-pool-adjacent, so one BlockSpec cannot cover them).
+Masking parity with the pure-JAX reference:
 
-Masking parity with the pure-JAX reference (kept in
-ops/paged_attention.py as the oracle the kernels are tested against):
-
-- trash page / stale table entries: tiles past a row's context load
-  whatever the table points at (often page 0, the trash page) and are
-  masked with -1e30 exactly like the gathered path — except the kernel
-  also *skips* tiles with ``page*page_size >= ctx_len[b]`` via
-  ``pl.when``, which changes nothing for live rows (a fully-masked
-  tile's online-softmax contribution is exp(-1e30 - m) == 0) but means
-  fully-dead rows (ctx 0 / valid all-False) emit zeros where the
-  reference emits a uniform average of garbage. Both are discarded by
-  contract; parity tests compare live rows only.
+- trash page / stale table entries: the grid kernel's tiles past a
+  row's context load whatever the table points at (often page 0, the
+  trash page) and are *skipped* via ``pl.when``; the decode kernel never
+  copies them. Within the last live page, slots past the context are
+  masked with -1e30 exactly like the gathered path. Skipping changes
+  nothing for live rows (a fully-masked tile's online-softmax
+  contribution is exp(-1e30 - m) == 0) but means fully-dead rows (ctx 0
+  / valid all-False) emit zeros where the reference emits a uniform
+  average of garbage. Both are discarded by contract; parity tests
+  compare live rows only.
 
 Quantized pools (``(int8 values, f32 scales)`` tuples — see
-ops/paged_attention.py) dequantize inside the tile load: the int8 page
-tile and its per-(slot, head) scales are fetched through the same block
-table and widened to f32 right before the QK^T dot.
+ops/paged_attention.py) dequantize inside the grid kernel's tile load:
+the int8 page tile and its per-(slot, head) scales are fetched through
+the same block table and widened to f32 right before the QK^T dot.
 
 The kernels compile for the chip on a TPU and run in interpret mode
-on any other backend (``framework.place.on_tpu``), which keeps tier-1
-CPU coverage of every kernel path; tests/test_chip_compile.py compiles
-them for a described v5e at gpt3_1p3b widths.
+on any other backend (``framework.place.on_tpu``) when a caller names
+them (``use_pallas=True``), which keeps tier-1 CPU coverage of every
+kernel path; tests/test_chip_compile.py compiles them for a described
+v5e at gpt3_1p3b and gpt2-medium widths.
 """
 from __future__ import annotations
 
@@ -65,8 +78,7 @@ from ..framework import place as _place
 from .pallas_attention import LANES, NEG_INF, _i0
 from .paged_attention import is_quantized_pool
 
-__all__ = ["paged_attention", "prefill_flash", "supported",
-           "pretune_paged"]
+__all__ = ["paged_attention", "supported", "decode_copies_pages"]
 
 
 def supported(q, k_pool, block_tables, page_size: int, kind: str) -> bool:
@@ -88,6 +100,22 @@ def supported(q, k_pool, block_tables, page_size: int, kind: str) -> bool:
     return values.ndim == 4 and block_tables.ndim == 2
 
 
+def _attend_one_row(q, k, v, live, m, l, acc):
+    """One query row of every head against a tile of positions, folded
+    into a running softmax: at 0.5 FLOP a byte the vector unit carries
+    it in f32 for all heads at once, where the MXU would be fed two
+    matmuls of one row a head. q: [H, D], scaled; k/v: [T, H, D] f32;
+    live: [T, 1, 1] bool; m/l: [H, 1]; acc: [H, D]. Returns the new
+    (m, l, acc)."""
+    s = jnp.sum(k * q[None], axis=-1, keepdims=True)         # [T, H, 1]
+    s = jnp.where(live, s, jnp.float32(NEG_INF))
+    m_new = jnp.maximum(m, jnp.max(s, axis=0))
+    p = jnp.exp(s - m_new[None])
+    alpha = jnp.exp(m - m_new)
+    return (m_new, alpha * l + jnp.sum(p, axis=0),
+            alpha * acc + jnp.sum(p * v, axis=0))
+
+
 def _paged_kernel(tables_ref, ctx_ref, q_ref, pos_ref, val_ref, *refs,
                   page_size, ppt, scale, kind, quantized):
     """Grid program for one (row, head-block, q-block, page-tile).
@@ -97,7 +125,9 @@ def _paged_kernel(tables_ref, ctx_ref, q_ref, pos_ref, val_ref, *refs,
     then ``ppt`` K tiles [page_size, block_h, D] (+ ppt scale tiles
     [page_size, block_h] when quantized), same for V; o_ref like q_ref;
     scratch m/l [block_h, block_q, LANES] and acc [block_h, block_q, D]
-    carry the online softmax across the (sequential) page-tile dim.
+    carry the online softmax across the (sequential) page-tile dim
+    ([block_h, 1] and [block_h, D] for decode over float pools, whose
+    one row is attended for all heads at once).
     """
     o_ref, m_ref, l_ref, acc_ref = refs[-4], refs[-3], refs[-2], refs[-1]
     kv = refs[:-4]
@@ -112,6 +142,7 @@ def _paged_kernel(tables_ref, ctx_ref, q_ref, pos_ref, val_ref, *refs,
     pt = pl.program_id(3)
     npt = pl.num_programs(3)
     block_q, block_h, d = q_ref.shape
+    on_vpu = kind == "decode" and not quantized    # _attend_one_row
 
     @pl.when(pt == 0)
     def _init():
@@ -124,64 +155,195 @@ def _paged_kernel(tables_ref, ctx_ref, q_ref, pos_ref, val_ref, *refs,
     if kind == "chunked":
         pos = pos_ref[...]
         live = val_ref[...]
+    if on_vpu:
+        q_all = q_ref[0].astype(jnp.float32) * jnp.float32(scale)  # [bh, D]
+        t_col = jax.lax.broadcasted_iota(jnp.int32, (page_size, 1, 1), 0)
+
+    def _vpu_page(j, start):
+        m_ref[...], l_ref[...], acc_ref[...] = _attend_one_row(
+            q_all, k_tiles[j][...].astype(jnp.float32),
+            v_tiles[j][...].astype(jnp.float32), start + t_col < ctx_b,
+            m_ref[...], l_ref[...], acc_ref[...])
+
+    def _mxu_page(j, start):
+        t_glob = start + t_page                              # [bq, T]
+        if kind == "decode":
+            mask = t_glob < ctx_b
+        else:
+            mask = (t_glob <= pos) & (live > 0)
+        # static unroll over the head block: rank-2 dots only
+        # (Mosaic's MXU path; no batched dot_general)
+        for i in range(block_h):
+            k_t = k_tiles[j][:, i, :]                        # [T, D]
+            v_t = v_tiles[j][:, i, :]
+            q_i = q_ref[:, i, :]                             # [bq, D]
+            if quantized:
+                k_t = k_t.astype(jnp.float32) \
+                    * k_scales[j][:, i][:, None]
+                v_t = v_t.astype(jnp.float32) \
+                    * v_scales[j][:, i][:, None]
+                q_i = q_i.astype(jnp.float32)
+            s = jax.lax.dot_general(
+                q_i, k_t, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            s = s * jnp.float32(scale)
+            s = jnp.where(mask, s, jnp.float32(NEG_INF))
+            m_prev = m_ref[i, :, 0]
+            l_prev = l_ref[i, :, 0]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+            p = jnp.exp(s - m_new[:, None])
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = alpha * l_prev + jnp.sum(p, axis=1)
+            acc_ref[i] = acc_ref[i] * alpha[:, None] + \
+                jax.lax.dot_general(
+                    p.astype(v_t.dtype), v_t,
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            m_ref[i] = jax.lax.broadcast_in_dim(
+                m_new, (block_q, LANES), (0,))
+            l_ref[i] = jax.lax.broadcast_in_dim(
+                l_new, (block_q, LANES), (0,))
 
     for j in range(ppt):
         # static unroll over the sub-pages of this K-tile; each has its
         # own table-steered BlockSpec (pages are not pool-adjacent)
         start = (pt * ppt + j) * page_size
-
-        def _tile(j=j, start=start):
-            @pl.when(start < ctx_b)   # skip tiles past the context
-            def _update():
-                t_glob = start + t_page                  # [bq, T]
-                if kind == "decode":
-                    mask = t_glob < ctx_b
-                else:
-                    mask = (t_glob <= pos) & (live > 0)
-                # static unroll over the head block: rank-2 dots only
-                # (Mosaic's MXU path; no batched dot_general)
-                for i in range(block_h):
-                    k_t = k_tiles[j][:, i, :]            # [T, D]
-                    v_t = v_tiles[j][:, i, :]
-                    q_i = q_ref[:, i, :]                 # [bq, D]
-                    if quantized:
-                        k_t = k_t.astype(jnp.float32) \
-                            * k_scales[j][:, i][:, None]
-                        v_t = v_t.astype(jnp.float32) \
-                            * v_scales[j][:, i][:, None]
-                        q_i = q_i.astype(jnp.float32)
-                    s = jax.lax.dot_general(
-                        q_i, k_t, (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32)
-                    s = s * jnp.float32(scale)
-                    s = jnp.where(mask, s, jnp.float32(NEG_INF))
-                    m_prev = m_ref[i, :, 0]
-                    l_prev = l_ref[i, :, 0]
-                    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-                    p = jnp.exp(s - m_new[:, None])
-                    alpha = jnp.exp(m_prev - m_new)
-                    l_new = alpha * l_prev + jnp.sum(p, axis=1)
-                    acc_ref[i] = acc_ref[i] * alpha[:, None] + \
-                        jax.lax.dot_general(
-                            p.astype(v_t.dtype), v_t,
-                            (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-                    m_ref[i] = jax.lax.broadcast_in_dim(
-                        m_new, (block_q, LANES), (0,))
-                    l_ref[i] = jax.lax.broadcast_in_dim(
-                        l_new, (block_q, LANES), (0,))
-        _tile()
+        # skip tiles past the context
+        pl.when(start < ctx_b)(functools.partial(
+            _vpu_page if on_vpu else _mxu_page, j, start))
 
     @pl.when(pt == npt - 1)
     def _emit():
+        if on_vpu:
+            l = jnp.maximum(l_ref[...], jnp.float32(1e-30))
+            o_ref[0] = acc_ref[...] / l
+            return
         for i in range(block_h):
             l = jnp.maximum(l_ref[i, :, 0], jnp.float32(1e-30))
             o_ref[:, i, :] = acc_ref[i] / l[:, None]
 
 
+def decode_copies_pages(head_dim: int, quantized: bool) -> bool:
+    """Whether decode takes the kernel that copies a lane's live pages
+    itself (``_decode_kernel``): float pools whose head is whole
+    128-lane rows. Mosaic slices an HBM array along whole lane tiles
+    only, so a page of narrower heads can be copied by a ``BlockSpec``
+    alone, which is the grid kernel's; so is a quantized pool's."""
+    return not quantized and head_dim % LANES == 0
+
+
+def _decode_kernel(tables_ref, ctx_ref, q_ref, k_hbm, v_hbm, o_ref,
+                   kbuf, vbuf, ksem, vsem, *, page_size, chunk, scale):
+    """Grid program for one lane: every live page of its context, and
+    no other, is copied HBM -> VMEM by this program's own DMAs (two
+    slots: chunk c+1 is in flight while chunk c is attended), and the
+    one query row meets it chunk by chunk (``_attend_one_row``). A dead
+    lane starts no copy and emits zeros.
+
+    Scalar prefetch: tables [B, P] i32, ctx [B] i32. q_ref/o_ref:
+    [H, D]; k_hbm/v_hbm: the whole pools [num_pages, page_size, H, D],
+    left in HBM; kbuf/vbuf: [2, chunk*page_size, H, D]; ksem/vsem: a
+    DMA semaphore a slot.
+    """
+    b = pl.program_id(0)
+    ps = page_size
+    ctx = ctx_ref[b]
+    # divisors are explicit i32: under jax_enable_x64 a python int would
+    # reach floor_divide / remainder as i64, which Mosaic cannot lower
+    n_pages = jnp.minimum((ctx + (ps - 1)) // jnp.int32(ps),
+                          tables_ref.shape[1])
+    n_chunks = (n_pages + (chunk - 1)) // jnp.int32(chunk)
+    heads, d = q_ref.shape
+
+    @pl.when(b == 0)
+    def _init():
+        # slots of a part-filled chunk keep what an earlier chunk left
+        # there, which is finite and weighs exp(-1e30 - m) == 0; what
+        # VMEM held before the first copy need not be finite
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    def each_live_page(c, slot, fn):
+        for j in range(chunk):        # static: a chunk is a few pages
+            @pl.when(c * chunk + j < n_pages)
+            def _page(j=j):
+                page = tables_ref[b, c * chunk + j]
+                dst = pl.ds(j * ps, ps)
+                fn(pltpu.make_async_copy(k_hbm.at[page],
+                                         kbuf.at[slot, dst],
+                                         ksem.at[slot]))
+                fn(pltpu.make_async_copy(v_hbm.at[page],
+                                         vbuf.at[slot, dst],
+                                         vsem.at[slot]))
+
+    @pl.when(n_chunks > 0)
+    def _first():
+        each_live_page(_i0(), _i0(), lambda dma: dma.start())
+
+    q = q_ref[...].astype(jnp.float32) * jnp.float32(scale)    # [H, D]
+
+    def _attend(c, carry):
+        slot = c % jnp.int32(2)
+
+        @pl.when(c + 1 < n_chunks)
+        def _next():
+            each_live_page(c + 1, 1 - slot, lambda dma: dma.start())
+
+        each_live_page(c, slot, lambda dma: dma.wait())
+        t = c * (chunk * ps) + jax.lax.broadcasted_iota(
+            jnp.int32, (chunk * ps, 1, 1), 0)
+        return _attend_one_row(q, kbuf[slot].astype(jnp.float32),
+                               vbuf[slot].astype(jnp.float32), t < ctx,
+                               *carry)
+
+    _, l, acc = jax.lax.fori_loop(
+        0, n_chunks, _attend,
+        (jnp.full((heads, 1), NEG_INF, jnp.float32),
+         jnp.zeros((heads, 1), jnp.float32),
+         jnp.zeros((heads, d), jnp.float32)))
+    o_ref[...] = acc / jnp.maximum(l, jnp.float32(1e-30))
+
+
+def _paged_decode(q, k_pool, v_pool, tables, ctx, *, page_size, scale,
+                  pages_per_chunk):
+    """The page-copying decode kernel: q [B, H, D], pools [num_pages,
+    page_size, H, D]. Returns [B, H, D] f32."""
+    from . import autotune
+
+    b, h, d = q.shape
+    chunk = autotune.paged_decode_chunk(
+        page_size, h, d, k_pool.dtype.itemsize, tables.shape[1],
+        override=pages_per_chunk)
+    row_spec = pl.BlockSpec((None, h, d),
+                            lambda bi, ts, cs: (bi, _i0(), _i0()))
+    buf = pltpu.VMEM((2, chunk * page_size, h, d), k_pool.dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(b,),
+        in_specs=[row_spec, pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=row_spec,
+        scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.SemaphoreType.DMA((2,))])
+    kernel = functools.partial(
+        _decode_kernel, page_size=page_size, chunk=chunk,
+        scale=float(scale))
+
+    def _run(tables, ctx, q, k_pool, v_pool):
+        return pl.pallas_call(
+            kernel, grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((b, h, d), jnp.float32),
+            # sequential: the buffers are zeroed by the first program
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=not _place.on_tpu(),
+        )(tables, ctx, q, k_pool, v_pool)
+
+    return _inference_only(_run)(tables, ctx, q, k_pool, v_pool)
+
+
 def paged_attention(q, k_pool, v_pool, block_tables, ctx_len, valid,
                     positions, *, page_size: int, kind: str, scale: float,
-                    block_q=None, block_h=None, pages_per_tile=None):
+                    block_q=None, block_h=None, pages_per_tile=None,
+                    pages_per_chunk=None):
     """Fused read-through-table paged attention (decode/chunked).
 
     q: [B, S, H, D]; pools: [num_pages, page_size, H, D] (or quantized
@@ -191,19 +353,29 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_len, valid,
     positions: [B, S] i32. The caller has already written this step's
     K/V into the pools (write-then-read, same as the reference).
     Returns [B, S, H, D] in q.dtype.
+
+    Decode takes the page-copying kernel where ``decode_copies_pages``
+    holds; chunked windows, the other decode shapes and a call that
+    names grid blocks take the grid kernel.
     """
     from . import autotune
 
     b, s, h, d = q.shape
     p = block_tables.shape[1]
-    quantized = is_quantized_pool(k_pool)
-    bq, bh, ppt = autotune.paged_blocks(
-        kind, s, h, d, page_size, p, dtype=str(q.dtype),
-        quantized=quantized,
-        overrides=(block_q, block_h, pages_per_tile))
-
     tables = block_tables.astype(jnp.int32)
     ctx = ctx_len.astype(jnp.int32)
+    quantized = is_quantized_pool(k_pool)
+    grid_blocks = (block_q, block_h, pages_per_tile)
+    if kind == "decode" and decode_copies_pages(d, quantized) \
+            and grid_blocks == (None,) * 3:
+        out = _paged_decode(
+            q[:, 0], k_pool, v_pool, tables, ctx, page_size=page_size,
+            scale=scale, pages_per_chunk=pages_per_chunk)
+        return out[:, None].astype(q.dtype)
+
+    bq, bh, ppt = autotune.paged_blocks(
+        kind, s, h, d, page_size, p, overrides=grid_blocks)
+    on_vpu = kind == "decode" and not quantized
     # [B, S, 1]: a (block_q, 1) column is a legal tile (the last dim is
     # the whole array's) and broadcasts against the [block_q, T] scores
     pos = positions.astype(jnp.int32)[..., None]
@@ -222,14 +394,22 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_len, valid,
     def row_map(bi, hb, qb, pt, ts, cs):
         return (bi, qb, _i0())
 
+    def page_of(bi, pt, j, ts, cs):
+        # a tile past the row's context names the row's last live page
+        # again: the kernel skips it, and a block whose index did not
+        # change is not copied, so a dead tile moves no bytes
+        last = jnp.maximum(
+            (cs[bi] + (page_size - 1)) // jnp.int32(page_size) - 1, 0)
+        return ts[bi, jnp.minimum(pt * ppt + j, last)]
+
     def kv_map(j):
         def _map(bi, hb, qb, pt, ts, cs):
-            return (ts[bi, pt * ppt + j], _i0(), hb, _i0())
+            return (page_of(bi, pt, j, ts, cs), _i0(), hb, _i0())
         return _map
 
     def sc_map(j):
         def _map(bi, hb, qb, pt, ts, cs):
-            return (ts[bi, pt * ppt + j], _i0(), hb)
+            return (page_of(bi, pt, j, ts, cs), _i0(), hb)
         return _map
 
     q_spec = pl.BlockSpec((None, bq, bh, d), q_map)
@@ -253,6 +433,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_len, valid,
     kernel = functools.partial(
         _paged_kernel, page_size=page_size, ppt=ppt,
         scale=float(scale), kind=kind, quantized=quantized)
+    stat = (bh, 1) if on_vpu else (bh, bq, LANES)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -260,9 +441,9 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_len, valid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((None, bq, bh, d), q_map),
         scratch_shapes=[
-            pltpu.VMEM((bh, bq, LANES), jnp.float32),
-            pltpu.VMEM((bh, bq, LANES), jnp.float32),
-            pltpu.VMEM((bh, bq, d), jnp.float32),
+            pltpu.VMEM(stat, jnp.float32),
+            pltpu.VMEM(stat, jnp.float32),
+            pltpu.VMEM(stat[:-1] + (d,), jnp.float32),
         ])
 
     def _run(tables, ctx, *inputs):
@@ -280,102 +461,20 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_len, valid,
             interpret=not _place.on_tpu(),
         )(tables, ctx, *inputs)
 
-    # pallas_call has no JVP rule, but eager dispatch records ops under
-    # jax.vjp whenever autograd is live — give the kernel an explicit
-    # inference-only vjp so the forward trace succeeds and only an
-    # actual backward() through it fails.
-    call = jax.custom_vjp(_run)
-    call.defvjp(lambda *a: (_run(*a), None), _nondiff_bwd)
-    return call(tables, ctx, *inputs).astype(q.dtype)
+    return _inference_only(_run)(tables, ctx, *inputs).astype(q.dtype)
+
+
+def _inference_only(run):
+    """pallas_call has no JVP rule, but eager dispatch records ops under
+    jax.vjp whenever autograd is live — give the kernel an explicit
+    inference-only vjp so the forward trace succeeds and only an actual
+    backward() through it fails."""
+    call = jax.custom_vjp(run)
+    call.defvjp(lambda *a: (run(*a), None), _nondiff_bwd)
+    return call
 
 
 def _nondiff_bwd(_res, _g):
     raise NotImplementedError(
         "fused paged attention kernels are inference-only (serving "
         "path); train with the pure-JAX reference attention instead")
-
-
-def prefill_flash(q, k, v, scale, use_flash: bool = True):
-    """Serving-prefill routing onto the ``pallas_attention.mha`` flash
-    kernel. Prefill never reads the pool (its K/V are right in the
-    window), so the fused paged kernels add nothing — but the default
-    ``attention_bshd`` gate only *prefers* flash above
-    FLAGS_flash_min_seqlen, a training-tuned crossover that serving
-    windows rarely reach. With FLAGS_decode_pallas_attention the
-    operator asked for kernels, so route any mha-shaped window straight
-    to the kernel: on TPU when ``flash_attention.supported`` holds, and
-    in interpret mode (CPU tier-1) whenever blocks fit, falling back to
-    the dense reference otherwise."""
-    from .flash_attention import (attention_bshd, flash_attention_bshd,
-                                  supported as flash_ok)
-    sq, sk = q.shape[1], k.shape[1]
-    if not _place.on_tpu():
-        # causal mha masks top-left aligned windows only, and its
-        # blocks must be 128-lane multiples — sub-128 bucketed windows
-        # take the dense reference instead
-        if sq == sk and sq % 128 == 0:
-            from .pallas_attention import mha
-            qt = jnp.swapaxes(q, 1, 2)
-            kt = jnp.swapaxes(k, 1, 2)
-            vt = jnp.swapaxes(v, 1, 2)
-            out = mha(qt, kt, vt, causal=True, sm_scale=scale,
-                      block_q=128, block_k=128)
-            return jnp.swapaxes(out, 1, 2)
-    elif flash_ok(q, k, v, None, True):
-        return flash_attention_bshd(q, k, v, causal=True, scale=scale)
-    return attention_bshd(q, k, v, causal=True, scale=scale,
-                          use_flash=use_flash)
-
-
-def pretune_paged(kind, batch, seq, num_heads, head_dim, page_size,
-                  pages_per_seq, dtype="float32", quantized=False):
-    """Eagerly time the paged block-size candidates on the real device
-    and persist the winner where traced serving calls will find it
-    (mirror of flash_attention.pretune). No-op off-TPU / with autotune
-    disabled — interpret mode must never time kernels (the 'interpret
-    skips autotune' guard, self-tested in tests/test_pallas_paged.py).
-    """
-    from . import autotune
-    from .paged_attention import quantize_kv_rows
-
-    if not autotune.enabled():
-        return None
-    cands = autotune.paged_block_candidates(
-        kind, seq, num_heads, head_dim, page_size, pages_per_seq,
-        quantized=quantized)
-    if len(cands) <= 1:
-        return cands[0] if cands else None
-    keys = jax.random.split(jax.random.PRNGKey(0), 3)
-    num_pages = 1 + batch * pages_per_seq
-    q = jax.random.normal(
-        keys[0], (batch, seq, num_heads, head_dim), jnp.float32
-    ).astype(dtype)
-    pool_shape = (num_pages, page_size, num_heads, head_dim)
-    kp = jax.random.normal(keys[1], pool_shape, jnp.float32).astype(dtype)
-    vp = jax.random.normal(keys[2], pool_shape, jnp.float32).astype(dtype)
-    if quantized:
-        kq, ks = quantize_kv_rows(kp.reshape(-1, num_heads, head_dim))
-        vq, vs = quantize_kv_rows(vp.reshape(-1, num_heads, head_dim))
-        kp = (kq.reshape(pool_shape), ks.reshape(pool_shape[:2] + (num_heads,)))
-        vp = (vq.reshape(pool_shape), vs.reshape(pool_shape[:2] + (num_heads,)))
-    tables = (1 + jnp.arange(batch * pages_per_seq, dtype=jnp.int32)
-              ).reshape(batch, pages_per_seq)
-    ctx = jnp.full((batch,), pages_per_seq * page_size, jnp.int32)
-    pos = jnp.broadcast_to(
-        jnp.arange(seq, dtype=jnp.int32), (batch, seq)) + (
-        pages_per_seq * page_size - seq)
-    val = jnp.ones((batch, seq), jnp.int32)
-    sm = 1.0 / (head_dim ** 0.5)
-
-    def make_fn(c):
-        bq, bh, ppt = c
-        return jax.jit(functools.partial(
-            paged_attention, page_size=page_size, kind=kind, scale=sm,
-            block_q=bq, block_h=bh, pages_per_tile=ppt))
-
-    kern = "paged_decode" if kind == "decode" else "paged_chunked"
-    return autotune.pick(
-        kern,
-        (seq, num_heads, head_dim, page_size, pages_per_seq,
-         str(jnp.dtype(dtype)), bool(quantized)),
-        cands, make_fn, (q, kp, vp, tables, ctx, val, pos))

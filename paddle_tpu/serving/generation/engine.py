@@ -600,6 +600,7 @@ class GenerationServer:
                  spec_k: Optional[int] = None,
                  scheduler=None,
                  mesh=None,
+                 use_pallas: Optional[bool] = None,
                  start: bool = True):
         model.eval()
         self.model = model
@@ -625,13 +626,14 @@ class GenerationServer:
         self.eos_token_id = eos_token_id
         self.pad_token_id = int(pad_token_id)
         self.pages_per_seq = -(-self.max_seq_len // self.page_size)
-        # fused-kernel / quantized-pool knobs: read ONCE here and
-        # pinned for the engine's lifetime (they join the decoder's
-        # geometry fingerprint, so warmup manifests and the persistent
-        # compile cache never mix executables across a flag flip)
+        # quantized-pool knob: read ONCE here and pinned for the
+        # engine's lifetime (it joins the decoder's geometry
+        # fingerprint, so warmup manifests and the persistent compile
+        # cache never mix executables across a flag flip). Who attends
+        # (``use_pallas``) is no knob: None lets the decoder take the
+        # fused paged kernels on a TPU and the pure-JAX body elsewhere;
+        # tests name one explicitly
         from ...ops.paged_attention import kv_pool_bytes, resolve_kv_dtype
-        self.use_pallas = bool(_flag("FLAGS_decode_pallas_attention",
-                                     False))
         self.kv_dtype = str(_flag("FLAGS_decode_kv_dtype", "") or "")
         resolve_kv_dtype(self.kv_dtype)   # fail fast on a typo'd dtype
         nh, hd = spec["num_heads"], spec["head_dim"]
@@ -666,8 +668,9 @@ class GenerationServer:
             model, max_batch=self.max_batch, page_size=self.page_size,
             pages_per_seq=self.pages_per_seq, donate=donate,
             max_positions=self.max_seq_len,
-            use_pallas=self.use_pallas, kv_dtype=self.kv_dtype,
+            use_pallas=use_pallas, kv_dtype=self.kv_dtype,
             mesh=self.serving_mesh)
+        self.use_pallas = self.decoder.use_pallas
         self.kv = PagedKVCache(model, num_pages=int(num_pages),
                                page_size=self.page_size,
                                dtype=self.kv_dtype or None,

@@ -95,12 +95,15 @@ class CachedDecoder:
         smesh = mesh if isinstance(mesh, ServingMesh) else ServingMesh(mesh)
         smesh.validate_heads(int(model.kv_cache_spec()["num_heads"]))
         self.serving_mesh = smesh
-        # pinned at construction: a flag flip mid-lifetime must not
-        # silently retrace half the entry points (both join the
-        # geometry fingerprint, so warmup manifests and the persistent
-        # compile cache key on them too)
+        # pinned at construction (both join the geometry fingerprint,
+        # so warmup manifests and the persistent compile cache key on
+        # them too). Who attends is not an operator's choice: None
+        # takes the fused paged kernels on a TPU and the pure-JAX body
+        # elsewhere (ops.paged_attention.kernel_by_default); True /
+        # False name one, for tests, oracles and rehearsals
+        from ...ops.paged_attention import kernel_by_default
         self.use_pallas = bool(
-            flag_value("FLAGS_decode_pallas_attention")
+            kernel_by_default(self.page_size)
             if use_pallas is None else use_pallas)
         self.kv_dtype = str(
             flag_value("FLAGS_decode_kv_dtype")
@@ -302,8 +305,10 @@ class CachedDecoder:
                     # programs are traced from. Bump it with any edit
                     # there that changes the lowered program, metadata
                     # included (v4: named scopes in paged and flash
-                    # attention)
-                    "kv_dtype": self.kv_dtype, "v": 4}
+                    # attention; v5: the decode kernel that loops over
+                    # a lane's live pages, and prefill that keeps
+                    # attention_bshd beside it)
+                    "kv_dtype": self.kv_dtype, "v": 5}
             # mesh axes + weight spec-tree hash join the geometry ONLY
             # when the mesh is live: an inert (None / 1-device) mesh
             # must reuse today's fingerprints byte-for-byte, and a mesh

@@ -3,8 +3,9 @@
 The TPU's compiler is installed here and compiles for a device that is
 described, not attached (``jax.experimental.topologies``): what it
 refuses — a block that is not a legal tile, a slice off the tiling, a
-tile too large for VMEM — it refuses here, at gpt3_1p3b widths, in a
-second or two per kernel, where interpret mode accepts anything.
+tile too large for VMEM — it refuses here, at gpt3_1p3b and gpt2-medium
+widths, in a second or two per kernel, where interpret mode accepts
+anything.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU's library at a time, every
@@ -23,6 +24,8 @@ from jax.sharding import SingleDeviceSharding
 
 # gpt3_1p3b attention geometry and the serving engine's defaults
 H, D, B, PAGE, SLOTS = 16, 128, 8, 16, 2048
+# the benchmark's two serving shapes: (head_dim, lanes, table slots)
+SERVE_SHAPES = {"1p3b": (128, 16, 2048), "medium": (64, 32, 1024)}
 
 
 @pytest.fixture(scope="module")
@@ -102,32 +105,24 @@ def test_mha_compiles(one_chip, compiled_kernels, blocks, backward):
     _compile(fwd_bwd if backward else fwd, x, x, x)
 
 
-def test_prefill_flash_compiles(one_chip, compiled_kernels):
-    """Serving prefill routes a 128-multiple window onto the flash
-    kernel ([B, S, H, D] layout)."""
-    from paddle_tpu.ops.pallas_paged_attention import prefill_flash
-    x = jax.ShapeDtypeStruct((2, 256, H, D), jnp.bfloat16,
-                             sharding=one_chip)
-    _compile(functools.partial(prefill_flash, scale=D ** -0.5), x, x, x)
-
-
 # ------------------------------------------------------ paged attention
-def _paged_args(one_chip, kind, q_dtype, pool, seq):
-    pages = SLOTS // PAGE
-    num_pages = 1 + B * pages
+def _paged_args(one_chip, kind, q_dtype, pool, seq, shape=(D, B, SLOTS)):
+    d, b, slots = shape
+    pages = slots // PAGE
+    num_pages = 1 + b * pages
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     if pool == "int8":
-        kv = (sds((num_pages, PAGE, H, D), jnp.int8),
+        kv = (sds((num_pages, PAGE, H, d), jnp.int8),
               sds((num_pages, PAGE, H), jnp.float32))
     else:
-        kv = sds((num_pages, PAGE, H, D), pool)
+        kv = sds((num_pages, PAGE, H, d), pool)
     s = 1 if kind == "decode" else seq
-    return (sds((B, s, H, D), q_dtype), kv, kv,
-            sds((B, pages), jnp.int32), sds((B,), jnp.int32),
-            sds((B, s), jnp.int32), sds((B, s), jnp.int32))
+    return (sds((b, s, H, d), q_dtype), kv, kv,
+            sds((b, pages), jnp.int32), sds((b,), jnp.int32),
+            sds((b, s), jnp.int32), sds((b, s), jnp.int32))
 
 
 POOLS = [(jnp.float32, jnp.float32), (jnp.bfloat16, jnp.bfloat16),
@@ -141,17 +136,117 @@ POOLS = [(jnp.float32, jnp.float32), (jnp.bfloat16, jnp.bfloat16),
                                       ("chunked", 5)])
 def test_paged_attention_compiles(one_chip, compiled_kernels, kind, seq,
                                   q_dtype, pool):
-    """The fused read-through-table kernel at gpt3_1p3b widths (16
-    heads of 128, batch 8, 2048-slot tables of 16-slot pages): decode,
-    a suffix-prefill window, and a speculative-verify window no
-    8-multiple divides — over f32, bf16 and int8 pools, with the
-    blocks ``autotune.paged_blocks`` picks unaided."""
+    """The fused read-through-table kernels at gpt3_1p3b widths (16
+    heads of 128, batch 8, 2048-slot tables of 16-slot pages): decode
+    (the page-copying kernel over f32 and bf16 pools, the grid kernel
+    over int8 ones), a suffix-prefill window, and a speculative-verify
+    window no 8-multiple divides — over f32, bf16 and int8 pools, with
+    the blocks ``ops/autotune.py`` picks unaided."""
     from paddle_tpu.ops.pallas_paged_attention import (paged_attention,
                                                        supported)
     args = _paged_args(one_chip, kind, q_dtype, pool, seq)
     assert supported(args[0], args[1], args[3], PAGE, kind)
     _compile(functools.partial(paged_attention, page_size=PAGE, kind=kind,
                                scale=D ** -0.5), *args)
+
+
+@pytest.mark.parametrize("shape", sorted(SERVE_SHAPES))
+@pytest.mark.parametrize("kind,seq", [("decode", 1), ("chunked", 64),
+                                      ("chunked", 4)])
+def test_paged_attention_compiles_at_the_serving_shapes(
+        one_chip, compiled_kernels, kind, seq, shape):
+    """Decode, a suffix-prefill window and the speculative-verify
+    window over f32 pools at what the two serve cells run: 16 heads of
+    128 over 128-page tables at 16 lanes (decode: the page-copying
+    kernel), 16 heads of 64 over 64-page tables at 32 lanes (decode:
+    the grid kernel's vector-unit branch), with the block constants
+    ``ops/autotune.py`` holds."""
+    from paddle_tpu.ops.pallas_paged_attention import (
+        decode_copies_pages, paged_attention)
+    d, lanes, slots = SERVE_SHAPES[shape]
+    assert decode_copies_pages(d, False) == (shape == "1p3b")
+    args = _paged_args(one_chip, kind, jnp.float32, jnp.float32, seq,
+                       shape=(d, lanes, slots))
+    _compile(functools.partial(paged_attention, page_size=PAGE, kind=kind,
+                               scale=d ** -0.5), *args)
+
+
+@pytest.mark.parametrize("chunk", [1, 2, 8, 16])
+def test_decode_pages_per_chunk_ladder_compiles(one_chip,
+                                                compiled_kernels, chunk):
+    """The page-copying kernel at the chunk sizes timed on the chip
+    beside the constant (16 pages: 8 MiB of page buffers, the most
+    ``paged_decode_chunk`` lets the constant take)."""
+    from paddle_tpu.ops import autotune
+    from paddle_tpu.ops.pallas_paged_attention import paged_attention
+    assert autotune.paged_decode_chunk(PAGE, H, D, 4, 128) == \
+        autotune.PAGED_DECODE_PAGES_PER_CHUNK
+    assert autotune.paged_decode_chunk(PAGE, H, D, 4, 2) == 2
+    assert autotune.paged_decode_chunk(PAGE, H, 4 * D, 4, 128) <= 4
+    args = _paged_args(one_chip, "decode", jnp.float32, jnp.float32, 1,
+                       shape=SERVE_SHAPES["1p3b"])
+    _compile(functools.partial(paged_attention, page_size=PAGE,
+                               kind="decode", scale=D ** -0.5,
+                               pages_per_chunk=chunk), *args)
+
+
+@pytest.mark.parametrize("heads", [4, 8])
+def test_decode_compiles_on_a_heads_shard(one_chip, compiled_kernels,
+                                          heads):
+    """Under a live ``mp`` mesh each rank runs the kernel on its
+    heads-block of q and the pools (``_sharded_paged_attention``): the
+    page-copying kernel at 16 / 4 and 16 / 2 heads of 128."""
+    from paddle_tpu.ops.pallas_paged_attention import paged_attention
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((1201, PAGE, heads, D), jnp.float32)
+    _compile(functools.partial(paged_attention, page_size=PAGE,
+                               kind="decode", scale=D ** -0.5),
+             sds((16, 1, heads, D), jnp.float32), pool, pool,
+             sds((16, 128), jnp.int32), sds((16,), jnp.int32),
+             sds((16, 1), jnp.int32), sds((16, 1), jnp.int32))
+
+
+@pytest.mark.parametrize("config", ["gpt3_1p3b", "gpt2_medium"])
+def test_default_decode_program_reads_through_the_table(
+        one_chip, compiled_kernels, config):
+    """The decode program a ``CachedDecoder`` built with defaults lowers
+    for the chip (two layers at full width, the cell's lanes and table)
+    holds the Mosaic call, and no array of shape ``[B, P*page_size, H,
+    D]``: the gathered context is gone from the program, not merely
+    unused."""
+    import paddle_tpu as paddle
+    from paddle_tpu import models
+    from paddle_tpu.jit.functional import state_arrays
+    from paddle_tpu.serving.generation.model_fns import CachedDecoder
+    d, lanes, slots = SERVE_SHAPES[
+        "1p3b" if config == "gpt3_1p3b" else "medium"]
+    pages = slots // PAGE
+    paddle.seed(0)
+    model = models.GPTForCausalLM(getattr(models, config)(
+        num_layers=2, vocab_size=1024))
+    model.eval()
+    dec = CachedDecoder(model, max_batch=lanes, page_size=PAGE,
+                        pages_per_seq=pages, donate=True,
+                        max_positions=slots, kv_dtype="")
+    assert dec.use_pallas is True           # nobody asked: the backend
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    params, buffers = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), state_arrays(model))
+    pool = [sds((1 + lanes * 8, PAGE, H, d), jnp.float32)] * 2
+    text = dec._decode_jit.lower(
+        params, buffers, sds((lanes,), jnp.int64), sds((lanes,), jnp.int32),
+        sds((lanes,), jnp.bool_), sds((lanes,), jnp.int32),
+        sds((lanes, pages), jnp.int32), pool, pool).compile().as_text()
+    assert text.count("tpu_custom_call") == 2       # one a layer
+    assert "paged_attention/attend" in text         # and it carries the scope
+    assert f"[{lanes},{slots},{H},{d}]" not in text
+    assert f"[{lanes * slots},{H},{d}]" not in text
 
 
 def test_every_paged_block_candidate_compiles(one_chip, compiled_kernels):

@@ -19,7 +19,7 @@ from paddle_tpu.ops.paged_attention import (
     kv_pool_bytes, paged_attention_update, quantize_kv_rows,
     resolve_kv_dtype)
 from paddle_tpu.ops.pallas_paged_attention import (
-    paged_attention, prefill_flash, supported)
+    decode_copies_pages, paged_attention, supported)
 
 H, D, PS = 4, 16, 8       # heads, head_dim, page_size
 
@@ -200,20 +200,26 @@ def test_update_dispatch_parity_all_kinds():
         np.testing.assert_array_equal(outs[True][2], outs[False][2])
 
 
-def test_prefill_flash_matches_dense():
-    """128-multiple windows route to the mha kernel; others take the
-    dense reference — both must match it."""
-    from paddle_tpu.ops.flash_attention import attention_bshd
-    rng = np.random.RandomState(4)
-    for s in (128, 24):
-        q = jnp.asarray(rng.randn(2, s, H, D), jnp.float32)
-        k = jnp.asarray(rng.randn(2, s, H, D), jnp.float32)
-        v = jnp.asarray(rng.randn(2, s, H, D), jnp.float32)
-        out = prefill_flash(q, k, v, SCALE)
-        ref = attention_bshd(q, k, v, causal=True, scale=SCALE,
-                             use_flash=False)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-4, atol=2e-4)
+@pytest.mark.parametrize("use_pallas", [None, False, True])
+def test_prefill_is_the_same_program_whoever_attends_decode(use_pallas):
+    """Prefill reads no pool: it lowers to the same program (the
+    dense/flash ``attention_bshd`` and the pool write) under every
+    ``use_pallas``, so the decode kernel's arrival moved no prefill."""
+    B, P, S = 2, 2, PS
+    q = jnp.zeros((B, S, H, D), jnp.float32)
+    pool = jnp.zeros((1 + B * P, PS, H, D), jnp.float32)
+    tables = jnp.asarray(
+        np.arange(1, 1 + B * P, dtype=np.int32).reshape(B, P))
+    pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+    args = (q, q, q, pool, pool, tables, jnp.full((B,), S, jnp.int32),
+            jnp.ones((B, S), bool), pos)
+
+    def lowered(up):
+        return jax.jit(lambda *a: paged_attention_update(
+            *a, page_size=PS, kind="prefill", use_pallas=up)
+        ).lower(*args).as_text()
+
+    assert lowered(use_pallas) == lowered(False)
 
 
 def test_supported_gates():
@@ -271,8 +277,10 @@ def test_autotune_interpret_guard():
     got = autotune.pick("paged_test_guard", ("k", 1),
                         [(1, 1, 1), (1, 2, 1)], boom, ())
     assert got == (1, 1, 1)
-    from paddle_tpu.ops.pallas_paged_attention import pretune_paged
-    assert pretune_paged("decode", 2, 1, H, D, PS, 4) is None
+    # the paged kernels' blocks are constants: nothing of theirs can
+    # reach the timer on any backend
+    import paddle_tpu.ops.pallas_paged_attention as ppa
+    assert not hasattr(ppa, "pretune_paged")
 
 
 def test_paged_block_candidates_legal():
@@ -298,7 +306,11 @@ def test_paged_block_candidates_legal():
 
 
 def test_paged_blocks_defaults_and_override_validation():
-    assert autotune.paged_blocks("decode", 1, H, D, PS, 4) == (1, H, 1)
+    # decode: the largest tile up to the constant that divides the table
+    assert autotune.paged_blocks("decode", 1, H, D, PS, 4) == (1, H, 4)
+    assert autotune.paged_blocks("decode", 1, H, D, PS, 128) == (
+        1, H, autotune.PAGED_DECODE_PAGES_PER_TILE)
+    assert autotune.paged_blocks("decode", 1, H, D, PS, 3) == (1, H, 1)
     assert autotune.paged_blocks("chunked", 24, H, D, PS, 4) == (8, H, 1)
     # a window no 8-multiple divides is taken whole
     assert autotune.paged_blocks("chunked", 5, H, D, PS, 4) == (5, H, 1)
@@ -390,10 +402,11 @@ def test_engine_greedy_parity_capacity_and_leaks():
     results = {}
     try:
         for kd, up in [("", False), ("int8", True)]:
-            F.set_flags({"FLAGS_decode_kv_dtype": kd,
-                         "FLAGS_decode_pallas_attention": up})
+            F.set_flags({"FLAGS_decode_kv_dtype": kd})
             srv = GenerationServer(m, max_batch=2, max_seq_len=64,
+                                   use_pallas=up,
                                    name=f"ppq-{kd or 'f32'}")
+            assert srv.use_pallas is up and srv.decoder.use_pallas is up
             try:
                 toks = list(srv.generate([3, 5, 7, 11],
                                          max_new_tokens=8))
@@ -406,8 +419,7 @@ def test_engine_greedy_parity_capacity_and_leaks():
             finally:
                 srv.shutdown()
     finally:
-        F.set_flags({"FLAGS_decode_kv_dtype": "",
-                     "FLAGS_decode_pallas_attention": False})
+        F.set_flags({"FLAGS_decode_kv_dtype": ""})
     f32, i8 = results[""], results["int8"]
     assert i8["toks"] == f32["toks"]
     assert i8["factor"] == 2 and f32["factor"] == 1
@@ -425,10 +437,10 @@ def test_engine_spec_decode_parity_quantized():
     toks = {}
     try:
         for kd, up in [("", False), ("int8", True)]:
-            F.set_flags({"FLAGS_decode_kv_dtype": kd,
-                         "FLAGS_decode_pallas_attention": up})
+            F.set_flags({"FLAGS_decode_kv_dtype": kd})
             srv = GenerationServer(m, max_batch=2, max_seq_len=64,
                                    draft_model=d, spec_k=3,
+                                   use_pallas=up,
                                    name=f"ppsq-{kd or 'f32'}")
             try:
                 toks[kd] = list(srv.generate([3, 5, 7, 11],
@@ -437,6 +449,184 @@ def test_engine_spec_decode_parity_quantized():
             finally:
                 srv.shutdown()
     finally:
-        F.set_flags({"FLAGS_decode_kv_dtype": "",
-                     "FLAGS_decode_pallas_attention": False})
+        F.set_flags({"FLAGS_decode_kv_dtype": ""})
     assert toks["int8"] == toks[""]
+
+
+# ------------------------------------- the decode path a TPU server takes
+
+def test_default_path_is_what_the_backend_says():
+    """No flag decides who attends: off the chip a call that names no
+    path takes the pure body (tier-1 never interprets a kernel it did
+    not ask for), a decoder built with defaults pins that, and the
+    answer joins its fingerprint."""
+    from paddle_tpu.framework import place
+    from paddle_tpu.ops.paged_attention import kernel_by_default
+    from paddle_tpu.serving.generation.model_fns import CachedDecoder
+    assert not place.on_tpu() and not kernel_by_default(16)
+    q, kp, vp, tables, ctx = _decode_case()
+    val = jnp.ones((q.shape[0], 1), bool)
+    pos = jnp.maximum(ctx - 1, 0)[:, None]
+    txt = jax.jit(lambda *a: paged_attention_update(
+        *a, page_size=PS, kind="decode")).lower(
+        q, q, q, kp, vp, tables, ctx, val, pos).as_text()
+    assert "stablehlo.gather" in txt   # gather_pool: the pure body
+    kw = dict(max_batch=2, page_size=16, pages_per_seq=4, donate=False)
+    m = _tiny_model()
+    dec = CachedDecoder(m, **kw)
+    assert dec.use_pallas is False
+    assert dec.fingerprint() == CachedDecoder(
+        m, use_pallas=False, **kw).fingerprint()
+
+
+def test_default_path_on_a_tpu_is_the_kernel(monkeypatch):
+    """What ``kernel_by_default`` answers on a TPU, steered from here:
+    the kernels wherever they can serve the call, the pure body for the
+    one-slot pages they cannot tile — by shape, and without raising."""
+    from paddle_tpu.framework import place
+    from paddle_tpu.ops.paged_attention import kernel_by_default
+    monkeypatch.setattr(place, "on_tpu", lambda: True)
+    assert kernel_by_default(16) and not kernel_by_default(1)
+    q = jnp.zeros((1, 1, 2, 8))
+    pool = jnp.zeros((3, 1, 2, 8))              # one-slot pages
+    txt = jax.jit(lambda *a: paged_attention_update(
+        *a, page_size=1, kind="decode")).lower(
+        q, q, q, pool, pool, jnp.zeros((1, 2), jnp.int32),
+        jnp.ones((1,), jnp.int32), jnp.ones((1, 1), bool),
+        jnp.zeros((1, 1), jnp.int32)).as_text()
+    assert "stablehlo.gather" in txt   # gather_pool: the pure body
+
+
+def test_decode_kernel_selection_is_by_shape():
+    assert decode_copies_pages(128, False)
+    assert decode_copies_pages(256, False)
+    assert not decode_copies_pages(64, False)     # the grid kernel's
+    assert not decode_copies_pages(128, True)     # quantized pools too
+
+
+def _ragged_case(heads, head_dim, seed=0):
+    """Eight lanes over 5-page tables (+ trash page 0): context 1, one
+    short of a page, exactly a page, a page and one, the full table, a
+    dead lane, and two lanes whose table keeps stale entries (another
+    lane's pages, the trash page) past their context."""
+    P, ps = 5, PS
+    ctx = np.array([1, ps - 1, ps, ps + 1, P * ps, 0, 2 * ps + 3, 3],
+                   np.int32)
+    B = len(ctx)
+    rng = np.random.RandomState(seed)
+    shape = (1 + B * P, ps, heads, head_dim)
+    kp = jnp.asarray(rng.randn(*shape), jnp.float32)
+    vp = jnp.asarray(rng.randn(*shape), jnp.float32)
+    # poison the trash page: it must never reach a live output
+    kp, vp = kp.at[0].set(1e4), vp.at[0].set(-1e4)
+    tables = 1 + np.arange(B * P, dtype=np.int32).reshape(B, P)
+    tables[6, 3:] = tables[0, :2]       # stale: another lane's pages
+    tables[7, 1:] = 0                   # stale: the trash page
+    tables[5, :] = 0                    # the dead lane owns nothing
+    return kp, vp, jnp.asarray(tables), jnp.asarray(ctx), rng
+
+
+@pytest.mark.parametrize("chunk", [None, 1, 2, 3])
+@pytest.mark.parametrize("heads,head_dim", [(4, 128), (2, 256), (4, 64),
+                                            (8, 64), (16, 64)])
+def test_decode_parity_over_head_widths_and_ragged_lanes(heads, head_dim,
+                                                         chunk):
+    """Both decode kernels against the pure body: 128-lane heads take
+    the page-copying kernel (at several chunk sizes, the default among
+    them), 64-wide ones the grid kernel's vector-unit branch (at
+    several pages a tile)."""
+    kp, vp, tables, ctx, rng = _ragged_case(heads, head_dim)
+    B = ctx.shape[0]
+    q = jnp.asarray(rng.randn(B, 1, heads, head_dim), jnp.float32)
+    val = jnp.ones((B, 1), jnp.int32)
+    pos = jnp.maximum(ctx - 1, 0)[:, None]
+    scale = 1.0 / np.sqrt(head_dim)
+    copies = decode_copies_pages(head_dim, False)
+    kw = {"pages_per_chunk": chunk} if copies else \
+        {"pages_per_tile": {None: None, 1: 1, 2: 5, 3: 1}[chunk]}
+    out = paged_attention(q, kp, vp, tables, ctx, val, pos, page_size=PS,
+                          kind="decode", scale=scale, **kw)
+    ref = _decode_ref(q, kp, vp, tables, ctx, scale)
+    live = np.asarray(ctx) > 0
+    np.testing.assert_allclose(np.asarray(out)[live],
+                               np.asarray(ref)[live], rtol=2e-5, atol=2e-5)
+    assert np.allclose(np.asarray(out)[~live], 0.0)
+
+
+@pytest.mark.parametrize("pool_dtype", [jnp.bfloat16, "int8"])
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_decode_parity_sub_f32_pools(head_dim, pool_dtype):
+    """bf16 pools through whichever float kernel the width selects,
+    int8 pools through the grid kernel's MXU branch, against the same
+    stored values through the pure body."""
+    kp, vp, tables, ctx, rng = _ragged_case(4, head_dim, seed=3)
+    kp, vp = kp.at[0].set(0.0), vp.at[0].set(0.0)   # int8 absmax range
+    B = ctx.shape[0]
+    q = jnp.asarray(rng.randn(B, 1, 4, head_dim), jnp.float32)
+    val = jnp.ones((B, 1), jnp.int32)
+    pos = jnp.maximum(ctx - 1, 0)[:, None]
+    scale = 1.0 / np.sqrt(head_dim)
+    if pool_dtype == "int8":
+        kq, vq = _quantize_pool(kp), _quantize_pool(vp)
+        kd = dequantize_kv(*kq).reshape(kp.shape)
+        vd = dequantize_kv(*vq).reshape(vp.shape)
+    else:
+        kq, vq = kp.astype(pool_dtype), vp.astype(pool_dtype)
+        kd, vd = kq.astype(jnp.float32), vq.astype(jnp.float32)
+    out = paged_attention(q, kq, vq, tables, ctx, val, pos, page_size=PS,
+                          kind="decode", scale=scale)
+    ref = _decode_ref(q, kd, vd, tables, ctx, scale)
+    live = np.asarray(ctx) > 0
+    np.testing.assert_allclose(np.asarray(out)[live],
+                               np.asarray(ref)[live], rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_chunked_parity_over_head_widths_and_ragged_lanes(head_dim):
+    """The chunked window over the same ragged lanes: each lane's last
+    (up to) four positions are the window, the rest its cached prefix;
+    dead positions and the dead lane are invalid."""
+    heads, S = 4, 4
+    kp, vp, tables, ctx, rng = _ragged_case(heads, head_dim, seed=1)
+    ctx_np = np.asarray(ctx)
+    B = len(ctx_np)
+    seg = np.minimum(ctx_np, S)
+    start = ctx_np - seg
+    offs = np.arange(S, dtype=np.int32)[None, :]
+    pos = jnp.asarray(start[:, None] + offs)
+    val_np = offs < seg[:, None]
+    q = jnp.asarray(rng.randn(B, S, heads, head_dim), jnp.float32)
+    scale = 1.0 / np.sqrt(head_dim)
+    out = paged_attention(q, kp, vp, tables, ctx,
+                          jnp.asarray(val_np.astype(np.int32)), pos,
+                          page_size=PS, kind="chunked", scale=scale)
+    ks = gather_pool(kp, tables, out_dtype=q.dtype)
+    vs = gather_pool(vp, tables, out_dtype=q.dtype)
+    ref = _chunked_attention(q, ks, vs, pos, jnp.asarray(val_np), scale)
+    np.testing.assert_allclose(np.asarray(out)[val_np],
+                               np.asarray(ref)[val_np],
+                               rtol=2e-5, atol=2e-5)
+    assert np.all(np.isfinite(np.asarray(out)))
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_update_decode_parity_through_the_write(head_dim):
+    """``paged_attention_update`` write-then-attend, kernel against the
+    pure body at both head widths: same output, same pools."""
+    heads = 4
+    kp, vp, tables, ctx, rng = _ragged_case(heads, head_dim, seed=2)
+    B = ctx.shape[0]
+    q, k, v = (jnp.asarray(rng.randn(B, 1, heads, head_dim), jnp.float32)
+               for _ in range(3))
+    valid = jnp.asarray(np.asarray(ctx) > 0)[:, None]
+    pos = jnp.maximum(ctx - 1, 0)[:, None]
+    got = {up: paged_attention_update(
+        q, k, v, kp, vp, tables, ctx, valid, pos, page_size=PS,
+        kind="decode", use_pallas=up) for up in (False, True)}
+    live = np.asarray(ctx) > 0
+    np.testing.assert_allclose(np.asarray(got[True][0])[live],
+                               np.asarray(got[False][0])[live],
+                               rtol=2e-5, atol=2e-5)
+    for i in (1, 2):
+        np.testing.assert_array_equal(np.asarray(got[True][i]),
+                                      np.asarray(got[False][i]))

@@ -31,7 +31,7 @@ combined ``decode_prefix_spec`` record (BENCH_PREFIX_r*.json):
   vs the non-speculative engine is asserted, not assumed.
 
 A third mode, ``--kernels``, runs PAIRED serving trials over the
-fused-kernel / quantized-KV matrix (``FLAGS_decode_pallas_attention``
+fused-kernel / quantized-KV matrix (``GenerationServer(use_pallas=)``
 x ``FLAGS_decode_kv_dtype``) on ONE model: decode tok/s, TTFT and p99
 inter-token latency per variant, the int8 page-capacity ratio vs f32
 (the pool-sizing claim: same byte budget, ~2x resident sequences),
@@ -279,16 +279,14 @@ def _bench_kernels(args):
                for _ in range(b)]
 
     variants, streams = {}, {}
-    saved = F.get_flags(["FLAGS_decode_kv_dtype",
-                         "FLAGS_decode_pallas_attention"])
+    saved = F.get_flags(["FLAGS_decode_kv_dtype"])
     try:
         for name, kd, up in _KERNEL_VARIANTS:
-            F.set_flags({"FLAGS_decode_kv_dtype": kd,
-                         "FLAGS_decode_pallas_attention": up})
+            F.set_flags({"FLAGS_decode_kv_dtype": kd})
             srv = GenerationServer(model, max_batch=b,
                                    page_size=args.page_size,
                                    name=f"bench-kern-{name}",
-                                   start=False)
+                                   use_pallas=up, start=False)
             srv.warmup(seq_buckets=[srv.policy.bucket_seq(plen)])
             srv.start()
             ttfts = [_ttft(srv, prompts[0], new)
