@@ -7,6 +7,8 @@ fail before the backend starts.
 """
 from __future__ import annotations
 
+import importlib
+import importlib.util
 import json
 import math
 import os
@@ -140,28 +142,90 @@ def device_record(memory_peak_bytes=None) -> dict:
     return rec
 
 
-def build_model_config(model_section: dict, extra: dict | None = None):
-    """``{"preset": "gpt3_1p3b", "kwargs": {...}}`` -> a GPTConfig of
-    the program's own preset, with the file's keyword arguments and the
-    runner section's (``stacked``, ``recompute``) on top."""
-    from paddle_tpu import models
-    preset = getattr(models, model_section["preset"])
-    kwargs = dict(model_section.get("kwargs", {}))
-    kwargs.update(extra or {})
-    return preset(**kwargs)
+def load_module(path: str, name: str):
+    """The Python file at ``path`` as a module of its own called
+    ``name``: how a metric's reader and a configuration's reference are
+    found by file and not by import path."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
 
 
-def check_model_config(cfg, config_file: dict):
-    """The sizes the file states are the sizes that run: a preset that
-    drifts from the file is an error before any work."""
-    head_dim = cfg.hidden_size // cfg.num_heads
-    got = {"hidden_size": cfg.hidden_size, "num_layers": cfg.num_layers,
-           "num_heads": cfg.num_heads, "head_dim": head_dim,
-           "vocab_size": cfg.vocab_size, "max_seq_len": cfg.max_seq_len,
-           "intermediate_size": cfg.intermediate_size or
-           4 * cfg.hidden_size}
-    for key, want in config_file.get("sizes", {}).items():
-        if key in got and got[key] != want:
+def resolve(spec: str, key: str):
+    """``"<module>:<name>"`` -> the object, for the configuration key
+    ``key``; what cannot be found is an error that names both."""
+    module, _, attr = str(spec).partition(":")
+    if not module or not attr:
+        raise ValueError(f"{key} = {spec!r} is not \"<module>:<name>\"")
+    try:
+        found = importlib.import_module(module)
+    except ImportError as e:
+        raise ImportError(f"{key} = {spec!r}: no module {module!r} "
+                          f"({e})") from e
+    if not hasattr(found, attr):
+        raise ImportError(
+            f"{key} = {spec!r}: module {module!r} has no {attr!r}")
+    return getattr(found, attr)
+
+
+# what a configuration file has to state (a ``train`` or ``serve`` key
+# where it has that section): none of these has a default in the code
+REQUIRED_KEYS = ("reference", "model.class", "model.preset",
+                 "train.criterion", "serve.weights_dtype")
+
+
+def check_config_keys(config: dict, path: str):
+    """A configuration (read from ``path``) that lacks one of
+    ``REQUIRED_KEYS`` is an error before any work, and the message
+    names each."""
+    missing = []
+    for dotted in REQUIRED_KEYS:
+        section, _, key = dotted.rpartition(".")
+        if section in ("train", "serve") and section not in config:
+            continue
+        if key not in (config.get(section, {}) if section else config):
+            missing.append(dotted)
+    if missing:
+        raise ValueError(f"{path} does not state {', '.join(missing)} "
+                         f"(benchmarks/README.md, \"A configuration\")")
+
+
+def build_model(config: dict, section: str):
+    """``(cfg, model)``: ``model.class`` built over what
+    ``model.preset`` returns for the file's ``model.kwargs`` with the
+    section's ``model_kwargs`` (``stacked``, ``recompute``) on top.
+    The caller seeds the program first; a name that cannot be found, or
+    a size that differs from the file's, is an error."""
+    spec = config["model"]
+    cls = resolve(spec["class"], "model.class")
+    preset = resolve(spec["preset"], "model.preset")
+    cfg = preset(**{**spec.get("kwargs", {}),
+                    **config[section].get("model_kwargs", {})})
+    model = cls(cfg)
+    check_model_config(cfg, model, config)
+    return cfg, model
+
+
+def check_model_config(cfg, model, config_file: dict):
+    """The sizes the file states are the sizes that run. Each key of
+    ``sizes`` is compared with what the program reports under that
+    name: an attribute of its config object, else an entry of the
+    model's ``kv_cache_spec()``. A key neither has is an error: a size
+    nobody checks is not stated."""
+    spec = model.kv_cache_spec() if hasattr(model, "kv_cache_spec") else {}
+    for key, want in config_file["sizes"].items():
+        if hasattr(cfg, key):
+            got = getattr(cfg, key)
+        elif key in spec:
+            got = spec[key]
+        else:
+            raise KeyError(
+                f"configuration file states sizes.{key}={want}, and the "
+                f"program reports no {key!r}: neither "
+                f"{type(cfg).__name__} nor {type(model).__name__}"
+                f".kv_cache_spec() has it")
+        if got != want:
             raise ValueError(
-                f"configuration file says {key}={want}, the program's "
-                f"preset gives {got[key]}")
+                f"configuration file says {key}={want}, the program "
+                f"reports {got}")

@@ -64,6 +64,7 @@ class Request:
     max_new: int
     due: Optional[float] = None        # seconds from the window's start
     sent: Optional[float] = None       # host clock, absolute
+    submit_s: Optional[float] = None   # how long the server's submit took
     token_times: List[float] = field(default_factory=list)
     tokens: List[int] = field(default_factory=list)
     finish: Optional[str] = None
@@ -151,7 +152,11 @@ class LoadGenerator:
         with self._lock:
             self.sent.append(req)
         try:
-            return self.submit(req.prompt, req.max_new)
+            stream = self.submit(req.prompt, req.max_new)
+            # a dispatcher that ran late either woke late or stood
+            # here: the two are told apart by this
+            req.submit_s = self.clock() - req.sent
+            return stream
         except Exception as e:  # noqa: BLE001 - refused is an outcome
             req.error = f"{type(e).__name__}: {e}"
             req.finish = "aborted" if self._stop.is_set() else "refused"

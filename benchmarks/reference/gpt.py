@@ -7,7 +7,8 @@ attention, GELU (the tanh form GPT-2 calls ``gelu_new``), a final
 LayerNorm and a head tied to the token embedding.
 
 It is given the model's own parameter arrays (``state_arrays(model)``'s
-first dict), not a copy, in either layout the program has:
+first dict), not a copy and whatever their dtype (every array is cast
+to float32 before it is used), in either layout the program has:
 
 - the module stack: ``gpt.layers.<i>.attn.qkv_proj.weight`` ...
 - the stacked decoder: ``gpt.decoder.qkv_w`` ... with a leading layer
@@ -66,11 +67,12 @@ def _gelu_tanh(x):
         math.sqrt(2.0 / math.pi) * (x + 0.044715 * x ** 3)))
 
 
-@functools.partial(jax.jit, static_argnames=("num_heads", "eps"))
-def block(x, p, *, num_heads: int, eps: float):
-    """One pre-LN decoder block over ``x`` [B, S, H], float32."""
+@functools.partial(jax.jit, static_argnames=("num_heads", "eps", "dtype"))
+def block(x, p, *, num_heads: int, eps: float, dtype=jnp.float32):
+    """One pre-LN decoder block over ``x`` [B, S, H], in ``dtype``
+    (float32; bfloat16 is the control's)."""
     with jax.default_matmul_precision("highest"):
-        p = {k: v.astype(jnp.float32) for k, v in p.items()}
+        p = {k: v.astype(dtype) for k, v in p.items()}
         b, s, hidden = x.shape
         hd = hidden // num_heads
         h = _layer_norm(x, p["ln1_w"], p["ln1_b"], eps)
@@ -88,40 +90,51 @@ def block(x, p, *, num_heads: int, eps: float):
         return x
 
 
-@jax.jit
-def _embed(wte, wpe, ids):
-    return (jnp.take(wte, ids, axis=0)
-            + wpe[:ids.shape[-1]]).astype(jnp.float32)
+@functools.partial(jax.jit, static_argnames=("dtype",))
+def _embed(wte, wpe, ids, *, dtype):
+    return (jnp.take(wte, ids, axis=0).astype(dtype)
+            + wpe[:ids.shape[-1]].astype(dtype))
 
 
-@functools.partial(jax.jit, static_argnames=("eps",))
-def _head(h, ln_w, ln_b, wte, *, eps):
+@functools.partial(jax.jit, static_argnames=("eps", "dtype"))
+def _head(h, ln_w, ln_b, wte, *, eps, dtype):
     with jax.default_matmul_precision("highest"):
-        h = _layer_norm(h, ln_w.astype(jnp.float32),
-                        ln_b.astype(jnp.float32), eps)
-        return h @ wte.astype(jnp.float32).T
+        h = _layer_norm(h, ln_w.astype(dtype), ln_b.astype(dtype), eps)
+        return (h @ wte.astype(dtype).T).astype(jnp.float32)
 
 
-def hidden_states(params: dict, ids, *, num_heads: int, eps: float = 1e-5):
+def hidden_states(params: dict, ids, cfg, dtype=jnp.float32):
     """``ids`` [B, S] -> the last block's output [B, S, H] (before the
     final LayerNorm)."""
     x = _embed(params["gpt.embeddings.word_embeddings.weight"],
                params["gpt.embeddings.position_embeddings"],
-               jnp.asarray(ids))
+               jnp.asarray(ids), dtype=dtype)
     for i in range(num_layers(params)):
-        x = block(x, layer_params(params, i), num_heads=num_heads, eps=eps)
+        x = block(x, layer_params(params, i), num_heads=cfg.num_heads,
+                  eps=cfg.layer_norm_eps, dtype=dtype)
     return x
 
 
-def logits(params: dict, ids, *, num_heads: int, eps: float = 1e-5,
-           positions=None):
+# ------------------------------------------- the protocol (README.md)
+def logits(params: dict, ids, cfg, positions=None, dtype=jnp.float32):
     """Float32 logits [B, S, V], or [B, len(positions), V] for the
-    sequence positions asked for (the head is the largest product)."""
-    h = hidden_states(params, ids, num_heads=num_heads, eps=eps)
+    sequence positions asked for (the head is the largest product).
+    ``cfg`` is the program's config object; read here: ``num_heads``,
+    ``layer_norm_eps``."""
+    h = hidden_states(params, ids, cfg, dtype)
     if positions is not None:
         h = h[:, jnp.asarray(positions)]
     return _head(h, params["gpt.ln_f.weight"], params["gpt.ln_f.bias"],
-                 params["gpt.embeddings.word_embeddings.weight"], eps=eps)
+                 params["gpt.embeddings.word_embeddings.weight"],
+                 eps=cfg.layer_norm_eps, dtype=dtype)
+
+
+def control_logits(params: dict, ids, cfg, positions=None):
+    """The control (``run.py --control``): the same mathematics one
+    precision step below the float32 these configurations state, every
+    array and every activation in bfloat16. Put in the program's place
+    it has to come out as not correct."""
+    return logits(params, ids, cfg, positions, dtype=jnp.bfloat16)
 
 
 @jax.jit
@@ -131,10 +144,8 @@ def _shifted_cross_entropy(lg, labels):
     return -jnp.mean(picked)
 
 
-def causal_lm_loss(params: dict, ids, labels, *, num_heads: int,
-                   eps: float = 1e-5):
+def causal_lm_loss(params: dict, ids, labels, cfg):
     """Mean next-token cross entropy: position t's logits against
     ``labels[t + 1]``."""
-    return _shifted_cross_entropy(
-        logits(params, ids, num_heads=num_heads, eps=eps),
-        jnp.asarray(labels))
+    return _shifted_cross_entropy(logits(params, ids, cfg),
+                                  jnp.asarray(labels))

@@ -5,10 +5,13 @@
         --seconds <s> --trace <0|1>
 
 finds the cell in ``BENCHMARK.json``, its configuration file, its
-traffic file and the reader of each metric by name, runs the cell with
-the runner its traffic ``kind`` names, and prints one JSON object as the
-last line of its output: ``correct``, ``attempted``, ``failed``,
-``metrics``, ``device`` and, with ``--trace 1``, ``breakdown``.
+traffic file, its configuration's reference and the reader of each
+metric by name, runs the cell with the runner its traffic ``kind``
+names, and prints one JSON object as the last line of its output:
+``correct``, ``attempted``, ``failed``, ``metrics``, ``device``, with
+``--trace 1`` ``breakdown``, and last ``compared``: each number that
+decided ``correct`` beside its limit (also the last lines of standard
+error).
 
 With ``--trace 0`` the metrics are the cell's end-to-end metrics and
 the profiler is off; with ``--trace 1`` they are its per-layer metrics
@@ -24,7 +27,7 @@ import time
 T_START = time.perf_counter()      # set-up counts from here
 
 import argparse  # noqa: E402
-import importlib.util  # noqa: E402
+import importlib  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
@@ -56,13 +59,10 @@ def find_file(manifest: dict, sub: str, name: str, suffixes) -> str:
 
 
 def load_reader(manifest: dict, name: str):
-    path = find_file(manifest, "metrics", name, (".py",))
-    spec = importlib.util.spec_from_file_location(
-        "benchmarks.metrics." + name.replace(".", "_").replace("-", "_"),
-        path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
+    from benchmarks import common
+    return common.load_module(
+        find_file(manifest, "metrics", name, (".py",)),
+        "benchmarks.metrics." + name.replace(".", "_").replace("-", "_"))
 
 
 def metrics_of(manifest: dict, cell: str, group: str) -> list:
@@ -80,6 +80,10 @@ def main(argv=None) -> int:
                                                        "BENCHMARK.json"))
     ap.add_argument("--rehearse", action="store_true",
                     help="CPU rehearsal: no TPU needed, no device metric")
+    ap.add_argument("--control", action="store_true",
+                    help="a serve cell's control beside the program's "
+                    "reading: the reference one precision step down, put "
+                    "in the program's place (never in a measured run)")
     args = ap.parse_args(argv)
 
     from benchmarks import common, xplane
@@ -92,8 +96,14 @@ def main(argv=None) -> int:
     config_entry = next(c for c in manifest["configs"]
                         if c["name"] == cell["config"])
     config = common.load_json(os.path.join(ROOT, config_entry["file"]))
+    common.check_config_keys(config, config_entry["file"])
+    reference_path = find_file(manifest, "reference", config["reference"],
+                               (".py",))     # an unknown name fails here
     traffic = common.load_json(find_file(
         manifest, "traffic", cell["traffic"], (".json",)))
+    if args.control and RUNNERS[traffic["kind"]] != "serve_runner":
+        sys.exit(f"--control reads a serve cell's parity; {cell['name']} "
+                 f"is of kind {traffic['kind']!r} (PERF.md section 7)")
 
     import paddle_tpu  # noqa: F401 - a checkout without the program fails here
     if args.rehearse:
@@ -112,9 +122,13 @@ def main(argv=None) -> int:
     t_backend = time.perf_counter()
     runner = importlib.import_module(
         "benchmarks." + RUNNERS[traffic["kind"]])
+    reference = common.load_module(
+        reference_path, "benchmarks.reference." + config["reference"])
     run = runner.run(cell, config, traffic, seed=args.seed,
                      seconds=args.seconds, trace=bool(args.trace),
-                     t_start=T_START, rehearse=args.rehearse)
+                     t_start=T_START, rehearse=args.rehearse,
+                     reference=reference,
+                     **({"control": True} if args.control else {}))
     run["notes"]["backend_s"] = t_backend - T_START
     run["device"] = common.device_record(run["memory_peak_bytes"])
     run["on_chip"] = dev.platform == "tpu"
@@ -152,6 +166,10 @@ def main(argv=None) -> int:
             "device": run["device"]}
     if breakdown is not None:
         line["breakdown"] = breakdown
+    line["compared"] = {name: {"value": float(value), "limit": float(limit)}
+                        for name, (value, limit) in run["compared"].items()}
+    for name, pair in line["compared"].items():
+        log(f"compared {name}: {pair['value']!r} (limit {pair['limit']!r})")
     print(json.dumps(line), flush=True)
     return 0
 

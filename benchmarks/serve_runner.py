@@ -15,7 +15,12 @@ bucket and the decode step, and nothing else); the load's warm-up
 (``warmup_s`` of arrivals, or the first ``open_after_completions``).
 Then the window. Then, outside it: the end of the load, the page
 accounting, and the parity of a seeded sample of completed requests
-against ``reference/gpt.py``.
+against the configuration's plain reference.
+
+Nothing here names an architecture: the configuration file says which
+class to build (``model.class`` over ``model.preset``), in which dtype
+to serve it (``serve.weights_dtype``) and which reference judges it
+(``reference``, handed in by ``run.py``).
 """
 from __future__ import annotations
 
@@ -29,6 +34,17 @@ from . import common, loadgen, xplane
 
 PARITY_TOL = 0.0625   # x the reference logits' std: bf16 passes of
 #                       default-precision f32 matmuls (chip_smoke, PR 21)
+
+
+def parity_tol(serve: dict) -> float:
+    """``PARITY_TOL``, unless the configuration states
+    ``serve.parity_tol`` and, in ``parity_tol_why``, the readings it
+    was set from."""
+    if "parity_tol" not in serve:
+        return PARITY_TOL
+    if not serve.get("parity_tol_why"):
+        raise ValueError("serve.parity_tol needs a serve.parity_tol_why")
+    return float(serve["parity_tol"])
 
 
 def write_manifest(path: str, serve: dict, pages_per_seq: int):
@@ -49,17 +65,22 @@ def write_manifest(path: str, serve: dict, pages_per_seq: int):
     return len(entries)
 
 
-def parity(model, cfg, reqs, pad_to: int, n_pos: int) -> dict:
+def parity(reference, model, cfg, reqs, pad_to: int, n_pos: int, *,
+           control: bool, seed: int) -> dict:
     """Every served token of ``reqs`` against the plain reference's
     logits at its position: how far below the reference's best the
     served token's logit lies, over the logits' std. Sequences are
     padded to one length (a causal model's earlier positions do not see
-    the padding), so the reference compiles once."""
+    the padding), so the reference compiles once. With ``control`` the
+    same gap is also read for two stand-ins for the served token at
+    each of those positions: the token that the reference's
+    ``control_logits`` (one precision step down) puts first, and
+    another token id drawn from ``seed`` (a token altered where it is
+    produced; the least gap any of them reads)."""
     from paddle_tpu.jit.functional import state_arrays
-
-    from .reference import gpt as ref
     params, _ = state_arrays(model)
-    worst, checked = 0.0, 0
+    gaps, control_gaps, altered_gaps = [], [], []   # over the logits' std
+    rng = np.random.default_rng([int(seed), 0xA17E])
     for req in reqs:
         n, served = len(req.prompt), np.asarray(req.tokens, np.int64)
         ids = np.zeros((1, pad_to), np.int64)
@@ -69,33 +90,44 @@ def parity(model, cfg, reqs, pad_to: int, n_pos: int) -> dict:
         positions = np.arange(n - 1, n - 1 + len(served))
         # one fixed count of positions too, for the same reason
         pos_pad = np.resize(positions, max(n_pos, len(positions)))
-        lg = np.asarray(ref.logits(params, ids, num_heads=cfg.num_heads,
-                                   eps=cfg.layer_norm_eps,
-                                   positions=pos_pad))[0][:len(served)]
-        gap = lg.max(-1) - lg[np.arange(len(served)), served]
-        worst = max(worst, float(gap.max() / lg.std()))
-        checked += len(served)
-    return {"worst_gap_over_std": worst, "tokens_checked": checked,
-            "requests_checked": len(reqs)}
+        lg = np.asarray(reference.logits(
+            params, ids, cfg, positions=pos_pad))[0][:len(served)]
+        rows = np.arange(len(served))
+        gaps.extend((lg.max(-1) - lg[rows, served]) / lg.std())
+        if control:
+            low = np.asarray(reference.control_logits(
+                params, ids, cfg, positions=pos_pad))[0][:len(served)]
+            control_gaps.extend(
+                (lg.max(-1) - lg[rows, low.argmax(-1)]) / lg.std())
+            other = (served + rng.integers(1, lg.shape[-1], len(served))
+                     ) % lg.shape[-1]           # never the served token
+            altered_gaps.extend((lg.max(-1) - lg[rows, other]) / lg.std())
+    out = {"worst_gap_over_std": float(max(gaps, default=0.0)),
+           "tokens_checked": len(gaps), "requests_checked": len(reqs)}
+    if control:
+        out["control_gap_over_std"] = float(max(control_gaps, default=0.0))
+        out["altered_token_gap_over_std"] = float(
+            min(altered_gaps, default=0.0))
+    return out
 
 
 def run(cell: dict, config: dict, traffic: dict, *, seed: int,
-        seconds: float, trace: bool, t_start: float, rehearse: bool
-        ) -> dict:
+        seconds: float, trace: bool, t_start: float, rehearse: bool,
+        reference, control: bool = False) -> dict:
     caches = common.place_caches()
     import jax
     counter = common.CompileCounter()
 
     import paddle_tpu as paddle
     from paddle_tpu import compile_cache as cc
-    from paddle_tpu.models import GPTForCausalLM
     from paddle_tpu.serving.generation import GenerationServer
 
     serve = config["serve"]
-    cfg = common.build_model_config(config["model"], serve.get("model_kwargs"))
-    common.check_model_config(cfg, config)
+    tol = parity_tol(serve)
     paddle.seed(int(seed) % (2 ** 31 - 1))
-    model = GPTForCausalLM(cfg)
+    cfg, model = common.build_model(config, "serve")
+    # the program's own cast; the pool follows the model's dtype
+    model.to(dtype=serve["weights_dtype"])
     model.eval()
     t_model = time.perf_counter()
 
@@ -165,8 +197,10 @@ def run(cell: dict, config: dict, traffic: dict, *, seed: int,
     picks = [done[i] for i in sorted(rng.choice(
         len(done), size=min(int(traffic["parity_sample"]), len(done)),
         replace=False))] if done else []
-    par = parity(model, cfg, picks, int(traffic["parity_pad_to"]),
-                 int(traffic["output_len"]["max"]))
+    par = parity(reference, model, cfg, picks,
+                 int(traffic["parity_pad_to"]),
+                 int(traffic["output_len"]["max"]), control=control,
+                 seed=seed)
 
     if traffic["kind"] == "open":
         attempted = len(in_window)
@@ -181,7 +215,7 @@ def run(cell: dict, config: dict, traffic: dict, *, seed: int,
         "no_compile_in_window": compiles["backend_compiles"] == 0
         and snap1["compile_cache"]["misses"] == snap0["compile_cache"]["misses"],
         "no_page_leaked": pages_held == 0,
-        "parity": bool(picks) and par["worst_gap_over_std"] <= PARITY_TOL,
+        "parity": bool(picks) and par["worst_gap_over_std"] <= tol,
         "streams_whole": not short,
         "clients_ended": still_running == 0,
     }
@@ -196,6 +230,16 @@ def run(cell: dict, config: dict, traffic: dict, *, seed: int,
         "attempted": attempted, "failed": failed,
         "correct": all(checks.values()), "checks": checks,
         "parity": par, "memory_peak_bytes": memory_peak,
+        "compared": {
+            "parity_gap_over_std": [par["worst_gap_over_std"], tol],
+            "window_compiles": [compiles["backend_compiles"], 0],
+            "pages_held": [pages_held, 0],
+            "streams_short": [len(short), 0],
+            "clients_running": [still_running, 0]}
+        | ({"control_gap_over_std": [par["control_gap_over_std"], tol],
+            "altered_token_gap_over_std": [
+                par["altered_token_gap_over_std"], tol]}
+           if control else {}),
         "notes": {
             "caches": caches, "model_s": t_model - t_start,
             "warm_s": t_warm - t_model, "warm_signatures": n_warm,
@@ -210,5 +254,10 @@ def run(cell: dict, config: dict, traffic: dict, *, seed: int,
                 and t0 <= r.token_times[-1] < t1) / seconds,
             "rate_per_s": float(os.environ.get(loadgen.RATE_ENV)
                                 or traffic.get("rate_per_s", 0)),
+            "submit_max_ms": 1e3 * max(
+                (r.submit_s or 0.0 for r in gen.sent), default=0.0),
+            "late_max_ms": 1e3 * max(
+                (r.sent - (t0 + r.due) for r in in_window
+                 if r.sent is not None), default=0.0),
             "rehearse": rehearse},
     }

@@ -37,6 +37,27 @@ def test_every_cell_finds_its_files_and_reports_enough(cell):
     assert run.metrics_of(MANIFEST, cell, "per_layer")
 
 
+@pytest.mark.parametrize("entry", MANIFEST["configs"],
+                         ids=lambda c: c["name"])
+def test_every_configuration_names_what_can_be_found(entry):
+    """The keys the harness has no default for, and each name behind
+    them: cheap, no model is built."""
+    config = common.load_json(os.path.join(common.ROOT, entry["file"]))
+    common.check_config_keys(config, entry["file"])
+    assert callable(common.resolve(config["model"]["class"], "model.class"))
+    assert callable(common.resolve(config["model"]["preset"],
+                                   "model.preset"))
+    if "train" in config:
+        assert callable(common.resolve(config["train"]["criterion"],
+                                       "train.criterion"))
+    assert config["serve"]["weights_dtype"] in ("float32", "bfloat16")
+    reference = common.load_module(
+        run.find_file(MANIFEST, "reference", config["reference"], (".py",)),
+        "reference_under_test")
+    assert callable(reference.logits) and callable(reference.causal_lm_loss)
+    assert entry["reduced"] == config["reduced"]
+
+
 @pytest.mark.parametrize("entry", ALL_METRICS, ids=lambda m: m["name"])
 def test_reader_says_what_the_manifest_says(entry):
     reader = run.load_reader(MANIFEST, entry["name"])

@@ -12,6 +12,11 @@ first batch, from the model's own arrays, before the optimizer state
 exists beside them; ``warm_steps`` steps (the first loads or compiles
 the step). Then the window: steps until ``--seconds`` have passed.
 
+Nothing here names an architecture: the configuration file says which
+class to build (``model.class`` over ``model.preset``), which loss
+drives it (``train.criterion``) and which plain reference judges its
+first loss (``reference``, handed in by ``run.py``).
+
 Token ids are drawn from a Zipf-like distribution over the vocabulary,
 so that there is something to learn in a few dozen steps: uniform ids
 start at their entropy and a falling loss would be noise.
@@ -38,8 +43,8 @@ def zipf_ranks(rng, cdf: np.ndarray, shape) -> np.ndarray:
 
 
 def run(cell: dict, config: dict, traffic: dict, *, seed: int,
-        seconds: float, trace: bool, t_start: float, rehearse: bool
-        ) -> dict:
+        seconds: float, trace: bool, t_start: float, rehearse: bool,
+        reference) -> dict:
     caches = common.place_caches()
     import jax
     counter = common.CompileCounter()
@@ -47,16 +52,12 @@ def run(cell: dict, config: dict, traffic: dict, *, seed: int,
     import paddle_tpu as paddle
     from paddle_tpu.jit import TrainStep
     from paddle_tpu.jit.functional import state_arrays
-    from paddle_tpu.models import GPTForCausalLM, GPTPretrainingCriterion
-
-    from .reference import gpt as ref
 
     train = config["train"]
-    cfg = common.build_model_config(config["model"], train.get("model_kwargs"))
-    common.check_model_config(cfg, config)
+    criterion = common.resolve(train["criterion"], "train.criterion")
     batch, seq = int(traffic["batch"]), int(traffic["seq"])
     paddle.seed(int(seed) % (2 ** 31 - 1))
-    model = GPTForCausalLM(cfg)
+    cfg, model = common.build_model(config, "train")
     t_model = time.perf_counter()
 
     rng = np.random.default_rng([int(seed), 0x7A1])
@@ -69,12 +70,11 @@ def run(cell: dict, config: dict, traffic: dict, *, seed: int,
         return perm[zipf_ranks(rng, cdf, (batch, seq))]
 
     first = next_batch()
-    ref_loss = float(ref.causal_lm_loss(
-        state_arrays(model)[0], first, first, num_heads=cfg.num_heads,
-        eps=cfg.layer_norm_eps))
+    ref_loss = float(reference.causal_lm_loss(
+        state_arrays(model)[0], first, first, cfg))
     t_ref = time.perf_counter()
 
-    crit = GPTPretrainingCriterion()
+    crit = criterion()
     opt = paddle.optimizer.AdamW(
         learning_rate=float(train["learning_rate"]),
         parameters=model.parameters(), moment_dtype=train["moment_dtype"])
@@ -156,6 +156,12 @@ def run(cell: dict, config: dict, traffic: dict, *, seed: int,
         "failed": int(sum(not np.isfinite(x) for x in window_losses)),
         "correct": all(checks.values()), "checks": checks,
         "memory_peak_bytes": common.device_record()["memory_peak_bytes"],
+        "compared": {
+            "first_loss_rel_gap": [abs(losses[0] - ref_loss)
+                                   / abs(ref_loss), tol],
+            "window_compiles": [compiles["backend_compiles"], 0],
+            "losses_not_finite": [
+                int(sum(not np.isfinite(x) for x in losses)), 0]},
         "notes": {
             "caches": caches, "model_s": t_model - t_start,
             "reference_s": t_ref - t_model, "reference_loss": ref_loss,
