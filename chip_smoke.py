@@ -22,6 +22,15 @@ layers, 16 heads, vocab 50304) with random weights made from a seed:
   warm-up manifest from the cache (hits, no misses) and serves from
   the loaded executables.
 
+``--phase smallthinker`` runs instead the one comparison the
+benchmark's token parity cannot give (PERF.md section 7): the 8-layer
+cut of ``smallthinker_21ba3b`` in bfloat16 at its published widths
+prefills a 6,000-token prompt and decodes 64 tokens through
+``CachedDecoder`` and both kinds of KV pool, and each step's **logits**
+are held to the plain float32 reference's full forward
+(``benchmarks/reference/smallthinker.py``), with the reference's own
+float8 control beside them, which has to fail.
+
 ``--chips 4`` runs instead the paths that exist only across chips, and
 what they are compared with: one TrainStep over a dp x mp mesh against
 the single-device step, and an mp=4 ``ServingMesh`` server against the
@@ -356,6 +365,100 @@ def logit_parity(srv, model, prompt, new_tokens, seq_bucket) -> tuple:
             float(ref[n - 1:n + 1].std()))
 
 
+# how far the logits of prefill + decode through the cache may lie from
+# the float32 reference's full forward, as the root mean square of the
+# difference over the reference logits' standard deviation, for a
+# configuration that states bfloat16 weights, activations and pool.
+# Set between two readings on the chip (my chip run, PR 29; PERF.md
+# section 6): the program's own, 0.0141 (its worst single row 0.056;
+# 0.0167 while its logits left the device as bfloat16), and the
+# reference's control with every activation rounded to float8
+# e4m3, 1.07, which has to lie above it: 6 x room below, 10 x above.
+LOGIT_RMS_TOL = 0.1
+
+
+def phase_cached_logits(cfg, *, prompt_len, new_tokens, seq_bucket,
+                        page_size=16, seed=0, tol=LOGIT_RMS_TOL) -> dict:
+    """Prefill then decode through ``CachedDecoder`` and the cache
+    manager's own pools and table row, one lane, greedy; every step's
+    logits against the full forward of the configuration's plain
+    reference over prompt + served tokens, and the reference's control
+    (one precision step down) against the same. Raises unless the
+    program lies inside ``tol`` and the control outside it."""
+    import os
+
+    import paddle_tpu as paddle
+    from benchmarks import common
+    from paddle_tpu.jit.functional import state_arrays
+    from paddle_tpu.models import GPTForCausalLM
+    from paddle_tpu.serving.generation.kv_cache import PagedKVCache
+    from paddle_tpu.serving.generation.model_fns import CachedDecoder
+
+    reference = common.load_module(os.path.join(
+        common.HERE, "reference", "smallthinker.py"), "smallthinker_ref")
+    paddle.seed(seed)
+    model = GPTForCausalLM(cfg)
+    model.eval()
+    total = prompt_len + new_tokens
+    kv = PagedKVCache(model, num_pages=2 + -(-total // page_size),
+                      page_size=page_size, max_batch=1)
+    width = kv.table_width(total)
+    dec = CachedDecoder(model, max_batch=1, page_size=page_size,
+                        pages_per_seq=width, max_positions=total)
+    log(f"[cached-logits] {model.num_params() / 1e9:.3f} B parameters, "
+        f"{'the fused paged kernels' if dec.use_pallas else 'the pure-JAX body'}"
+        f"; pools {fmt(kv.pool_bytes())}; tables {width} wide "
+        f"(ring {kv.ring_pages})")
+    rng = np.random.default_rng(seed)
+    prompt = rng.integers(0, cfg.vocab_size, prompt_len)
+    tables = np.zeros((1, width), np.int32)
+    kv.fill_row(tables[0], kv.alloc(kv.pages_for(total)),
+                kv.alloc_window(total))
+    ids = np.zeros((1, seq_bucket), np.int64)
+    ids[0, :prompt_len] = prompt
+    last, kv.k, kv.v, _ = dec.prefill(
+        ids, np.array([prompt_len], np.int32), tables, kv.k, kv.v)
+    rows = [np.asarray(last, np.float32)[0]]
+    served = []
+    for step in range(new_tokens):
+        served.append(int(rows[-1].argmax()))
+        ctx = prompt_len + step
+        out, kv.k, kv.v, _ = dec.decode(
+            np.array([served[-1]], np.int64), np.array([ctx], np.int32),
+            np.array([True]), np.array([ctx + 1], np.int32), tables,
+            kv.k, kv.v)
+        rows.append(np.asarray(out, np.float32)[0])
+    got = np.stack(rows)                       # positions n-1 .. n+new-1
+    # the full forward over prompt + served tokens, padded to whole
+    # query blocks (a causal model's earlier positions do not see it)
+    padded = -(-total // reference.QUERY_BLOCK) * reference.QUERY_BLOCK
+    full = np.zeros((1, padded), np.int64)
+    full[0, :prompt_len] = prompt
+    full[0, prompt_len:total] = served
+    at = np.arange(prompt_len - 1, total)
+    params = state_arrays(model)[0]
+    want = np.asarray(reference.logits(params, full, cfg, positions=at))[0]
+    low = np.asarray(reference.control_logits(params, full, cfg,
+                                              positions=at))[0]
+
+    def rms_over_std(x):
+        return float(np.sqrt(np.mean(np.square(x - want))) / want.std())
+
+    out = {"program": rms_over_std(got), "control": rms_over_std(low),
+           "program_worst_row": float(max(
+               np.sqrt(np.mean(np.square(g - w))) for g, w in
+               zip(got, want)) / want.std()),
+           "same_greedy_token": int(np.sum(
+               got.argmax(-1) == want.argmax(-1))), "rows": len(got),
+           "tol": tol}
+    log(f"[cached-logits] {out}")
+    if not out["program"] <= tol < out["control"]:
+        raise AssertionError(
+            f"logits of prefill + decode through the cache against the "
+            f"reference's full forward: {out}")
+    return out
+
+
 def drive(srv, traffic, vocab_size) -> dict:
     """The smoke's traffic through ``submit_generate`` on a server that
     has not started: every stream read to its end, checked against what
@@ -671,6 +774,9 @@ def main(argv=None) -> int:
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4),
                     help="4 runs only the cross-chip phase and what it "
                          "is compared with")
+    ap.add_argument("--phase", default="gpt", choices=("gpt", "smallthinker"),
+                    help="smallthinker: only the cached-logits comparison "
+                         "of the expert configuration's 8-layer cut")
     args = ap.parse_args(argv)
 
     from paddle_tpu.compile_cache import aot_cache_dir, place_jax_cache
@@ -705,7 +811,12 @@ def main(argv=None) -> int:
             f"{counter.since(snap)}")
         return out
 
-    if args.chips == 1:
+    if args.phase == "smallthinker":
+        from paddle_tpu.models import smallthinker_21ba3b
+        timed("cached-logits", phase_cached_logits,
+              cfg=smallthinker_21ba3b(num_layers=8, dtype="bfloat16"),
+              prompt_len=6000, new_tokens=64, seq_bucket=8192)
+    elif args.chips == 1:
         timed("train", phase_train,
               cfg=gpt3_1p3b(stacked=True, recompute="full"),
               batch=2, seq=2048, steps=4, expect_kernel=True)

@@ -4,7 +4,7 @@ SURVEY §3.3 / BASELINE GPT-3 1.3B config)."""
 from .gpt import (  # noqa: F401
     GPTConfig, GPTKVCache, GPTModel, GPTForCausalLM,
     GPTPretrainingCriterion, gpt2_medium,
-    gpt_tiny, gpt2_small, gpt2_large, gpt3_1p3b,
+    gpt_tiny, gpt2_small, gpt2_large, gpt3_1p3b, smallthinker_21ba3b,
 )
 from .bert import (  # noqa: F401
     BertConfig, BertModel, BertForPretraining,

@@ -42,6 +42,7 @@ __all__ = [
     "GPTConfig", "GPTModel", "GPTForCausalLM", "GPTPretrainingCriterion",
     "GPTKVCache",
     "gpt_tiny", "gpt2_small", "gpt2_medium", "gpt3_1p3b",
+    "smallthinker_21ba3b",
 ]
 
 
@@ -71,15 +72,126 @@ class GPTConfig:
     #             extra matmul FLOPs, bounded memory)
     #   "none"  — save everything XLA wants (max memory, max speed)
     recompute: str = "full"
+    # ---- what other decoder families need of the one block. Every
+    # default is the GPT-2/GPT-3 block, whose modules and programs are
+    # then exactly what they were; the module stack alone takes the
+    # fields below (``stacked`` refuses each by name).
+    num_kv_heads: int = 0            # 0 -> num_heads; K/V head g serves
+    #                                  query heads g*G .. g*G+G-1
+    head_dim: int = 0                # 0 -> hidden_size // num_heads
+    norm: str = "layernorm"          # or "rmsnorm" (no bias, f32 inside)
+    bias: bool = True                # biases on projections
+    position: str = "learned"        # or "rope": rotate-half RoPE on the
+    #                                  layers rope_layout marks, nothing
+    #                                  added on the others (NoPE)
+    rope_theta: float = 10000.0
+    rope_layout: tuple = ()          # per layer 0/1; () -> every layer
+    sliding_window: int = 0          # positions a token attends, itself
+    #                                  among them, on the layers
+    sliding_window_layout: tuple = ()  # marks (per layer 0/1; () -> all)
+    moe_num_experts: int = 0         # 0 -> the dense GELU MLP
+    moe_top_k: int = 0
+    moe_intermediate_size: int = 0   # width of one ReGLU expert
+    # what the router reads: the MLP's normed input, or the
+    # attention's ("router placed before attention")
+    moe_router_input: str = "mlp_input"
+    dtype: str = ""                  # parameters are created in it
+    #                                  ('' -> float32)
+
+    NEW_FIELDS = ("num_kv_heads", "head_dim", "norm", "bias", "position",
+                  "sliding_window", "moe_num_experts", "dtype")
 
     def __post_init__(self):
         if self.intermediate_size == 0:
             self.intermediate_size = 4 * self.hidden_size
-        assert self.hidden_size % self.num_heads == 0
+        fresh = GPTConfig.__dataclass_fields__
+        changed = [f for f in self.NEW_FIELDS
+                   if getattr(self, f) != fresh[f].default]
+        if self.head_dim == 0:
+            assert self.hidden_size % self.num_heads == 0
+            self.head_dim = self.hidden_size // self.num_heads
+        if self.num_kv_heads == 0:
+            self.num_kv_heads = self.num_heads
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError(
+                f"num_heads={self.num_heads} is no multiple of "
+                f"num_kv_heads={self.num_kv_heads}")
+        if self.stacked and changed:
+            raise ValueError(
+                f"the stacked scan decoder is the GPT-2/GPT-3 block; it "
+                f"does not take {', '.join(changed)} (module stack only)")
+        if self.norm not in ("layernorm", "rmsnorm"):
+            raise ValueError(f"norm must be 'layernorm' or 'rmsnorm', "
+                             f"got {self.norm!r}")
+        if self.position not in ("learned", "rope"):
+            raise ValueError(f"position must be 'learned' or 'rope', "
+                             f"got {self.position!r}")
+        if self.moe_router_input not in ("mlp_input", "attention_input"):
+            raise ValueError(
+                f"moe_router_input must be 'mlp_input' or "
+                f"'attention_input', got {self.moe_router_input!r}")
+        for name in ("rope_layout", "sliding_window_layout"):
+            layout = tuple(int(v) for v in getattr(self, name))
+            if layout and len(layout) != self.num_layers:
+                raise ValueError(f"{name} has {len(layout)} entries for "
+                                 f"{self.num_layers} layers")
+            setattr(self, name, layout)
+        if self.moe_num_experts and not (
+                0 < self.moe_top_k <= self.moe_num_experts
+                and self.moe_intermediate_size > 0):
+            raise ValueError("experts need moe_top_k and "
+                             "moe_intermediate_size")
         if self.recompute not in ("full", "dots", "attn", "none"):
             raise ValueError(
                 f"recompute must be 'full', 'dots', 'attn' or 'none', "
                 f"got {self.recompute!r}")
+
+    # ---- what a layer is, read off the fields
+    @property
+    def classic_attention(self) -> bool:
+        """The GPT-2/GPT-3 attention module (fused head-major qkv with
+        biases, learned positions, every head its own K/V, the whole
+        context)."""
+        return (self.num_kv_heads == self.num_heads
+                and self.head_dim * self.num_heads == self.hidden_size
+                and self.bias and self.position == "learned"
+                and not self.sliding_window)
+
+    def layer_rope(self, layer: int) -> bool:
+        return self.position == "rope" and bool(
+            self.rope_layout[layer] if self.rope_layout else 1)
+
+    def layer_window(self, layer: int):
+        """Positions layer ``layer`` attends, or None for all."""
+        if not self.sliding_window:
+            return None
+        marked = self.sliding_window_layout[layer] \
+            if self.sliding_window_layout else 1
+        return int(self.sliding_window) if marked else None
+
+    def num_params(self) -> int:
+        """Parameters of the model these fields describe, reckoned
+        without building it."""
+        h, v = self.hidden_size, self.vocab_size
+        qd, kvd = self.num_heads * self.head_dim, \
+            self.num_kv_heads * self.head_dim
+        norm = h * (2 if self.norm == "layernorm" else 1)
+        attn = h * (qd + 2 * kvd) + qd * h
+        if self.bias:
+            attn += qd + 2 * kvd + h
+        if self.moe_num_experts:
+            mlp = h * self.moe_num_experts + self.moe_num_experts * 3 \
+                * h * self.moe_intermediate_size
+        else:
+            mlp = 2 * h * self.intermediate_size
+            if self.bias:
+                mlp += self.intermediate_size + h
+        total = v * h + self.num_layers * (2 * norm + attn + mlp) + norm
+        if self.position == "learned":
+            total += self.max_seq_len * h
+        if not self.tie_word_embeddings:
+            total += v * h
+        return int(total)
 
 
 def gpt_tiny(**kw) -> GPTConfig:
@@ -113,6 +225,28 @@ def gpt2_large(**kw) -> GPTConfig:
 def gpt3_1p3b(**kw) -> GPTConfig:
     d = dict(vocab_size=50304, hidden_size=2048, num_layers=24, num_heads=16,
              max_seq_len=2048)
+    d.update(kw)
+    return GPTConfig(**d)
+
+
+def smallthinker_21ba3b(**kw) -> GPTConfig:
+    """SmallThinker-21BA3B-Instruct (PowerInfer, config.json): 52 layers
+    of 28 query heads over 4 K/V heads of 128, RMSNorm, no biases,
+    untied head; every fourth layer attends the whole context with no
+    positions at all, the others the last 4096 with RoPE (theta 1.5e6);
+    64 ReGLU experts of width 768, 6 a token, routed from the
+    attention's normed input. ``num_layers`` cuts whole periods off the
+    end (the layouts are cut with it); ``dtype`` is the parameters'."""
+    layers = int(kw.get("num_layers", 52))
+    pattern = tuple(0 if i % 4 == 0 else 1 for i in range(layers))
+    d = dict(vocab_size=151936, hidden_size=2560, num_layers=layers,
+             num_heads=28, num_kv_heads=4, head_dim=128, max_seq_len=16384,
+             norm="rmsnorm", layer_norm_eps=1e-6, bias=False,
+             position="rope", rope_theta=1.5e6, rope_layout=pattern,
+             sliding_window=4096, sliding_window_layout=pattern,
+             moe_num_experts=64, moe_top_k=6, moe_intermediate_size=768,
+             moe_router_input="attention_input", tie_word_embeddings=False,
+             use_flash_attention=True)
     d.update(kw)
     return GPTConfig(**d)
 
@@ -161,6 +295,14 @@ class GPTKVCache:
     structure-agnostic — pools are opaque pytrees whose leaves get
     wrapped/unwrapped at the boundaries.
 
+    ``logits_at`` ([B] int32, or None for every position): the one
+    position a row whose logits the caller wants; the head is then
+    applied to that position's hidden state alone and the logits come
+    back ``[B, 1, vocab]`` (a prefill's last real position: no
+    ``[B, S, vocab]`` array exists). ``aux`` is a dict the forward
+    fills with whole-number arrays beside the logits (an expert
+    layer's ``moe`` counters, one entry a layer), or None.
+
     ``use_pallas`` names who attends (the fused kernels of
     ops/pallas_paged_attention.py or the pure-JAX body) for every
     layer of this forward; None leaves it to
@@ -170,10 +312,12 @@ class GPTKVCache:
     """
 
     __slots__ = ("kind", "page_size", "k", "v", "block_tables",
-                 "ctx_len", "valid", "positions", "use_pallas", "mesh")
+                 "ctx_len", "valid", "positions", "use_pallas", "mesh",
+                 "logits_at", "aux")
 
     def __init__(self, kind, page_size, k, v, block_tables, ctx_len,
-                 valid, positions, use_pallas=None, mesh=None):
+                 valid, positions, use_pallas=None, mesh=None,
+                 logits_at=None, aux=None):
         if kind not in ("prefill", "decode", "chunked"):
             raise ValueError(f"kind must be 'prefill', 'decode' or "
                              f"'chunked', got {kind!r}")
@@ -192,6 +336,17 @@ class GPTKVCache:
         # Pallas shard_map dispatch consumes it; the pure-JAX path
         # relies on GSPMD propagating the operands' heads sharding.
         self.mesh = mesh
+        self.logits_at = logits_at
+        self.aux = aux
+
+    def layer_view(self, k, v, block_tables=None):
+        """This cache as one layer sees it: its own pools and, for a
+        layer kind with a table of its own, that table."""
+        return GPTKVCache(
+            self.kind, self.page_size, k, v,
+            self.block_tables if block_tables is None else block_tables,
+            self.ctx_len, self.valid, self.positions,
+            use_pallas=self.use_pallas, mesh=self.mesh, aux=self.aux)
 
 
 class GPTEmbeddings(Layer):
@@ -200,14 +355,18 @@ class GPTEmbeddings(Layer):
         self.word_embeddings = VocabParallelEmbedding(
             config.vocab_size, config.hidden_size)
         init = I.Normal(std=config.initializer_range)
+        # rotary layers place positions inside attention (or nowhere)
         self.position_embeddings = create_parameter_with_attr(
             [config.max_seq_len, config.hidden_size], self._dtype, None,
-            False, default_initializer=init)
+            False, default_initializer=init) \
+            if config.position == "learned" else None
         self.dropout = Dropout(config.dropout)
 
     def forward(self, input_ids, positions=None):
         seq_len = input_ids.shape[-1]
         h = self.word_embeddings(input_ids)
+        if self.position_embeddings is None:
+            return _seq_constraint(self.dropout(h))
         if positions is not None:
             # decode path: each row sits at its own absolute position
             import jax.numpy as jnp
@@ -290,6 +449,205 @@ class GPTAttention(Layer):
         return self.dropout(self.out_proj(out))
 
 
+def _rms_norm(x, w, eps):
+    import jax.numpy as jnp
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1,
+                                     keepdims=True) + jnp.float32(eps))
+    return (y * w.astype(jnp.float32)).astype(x.dtype)
+
+
+class GPTRMSNorm(Layer):
+    """``x * rsqrt(mean(x^2) + eps) * w``, computed in float32."""
+
+    def __init__(self, size: int, eps: float, dtype: str):
+        super().__init__()
+        self.eps = float(eps)
+        self.weight = create_parameter_with_attr(
+            [size], dtype, None, False, default_initializer=I.Constant(1.0))
+
+    def forward(self, x):
+        return apply_op("rms_norm", _rms_norm, x, self.weight, eps=self.eps)
+
+
+def _norm(config: GPTConfig):
+    if config.norm == "rmsnorm":
+        return GPTRMSNorm(config.hidden_size, config.layer_norm_eps,
+                          config.dtype or "float32")
+    return LayerNorm(config.hidden_size, epsilon=config.layer_norm_eps)
+
+
+def _rope(x, positions, theta):
+    """Rotate-half RoPE over the whole head: pairs ``(i, i + D/2)``
+    turn by ``positions * theta^(-2i/D)``. x: [B, S, H, D]; positions:
+    [B, S]. Angles and rotation in float32."""
+    import jax.numpy as jnp
+    half = x.shape[-1] // 2
+    inv = jnp.float32(theta) ** (
+        -jnp.arange(half, dtype=jnp.float32) / jnp.float32(half))
+    ang = positions.astype(jnp.float32)[..., None] * inv      # [B, S, D/2]
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[:, :, None, :]
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[:, :, None, :]
+    x32 = x.astype(jnp.float32)
+    turned = jnp.concatenate([-x32[..., half:], x32[..., :half]], -1)
+    return (x32 * cos + turned * sin).astype(x.dtype)
+
+
+class GPTGroupedAttention(Layer):
+    """Attention for what ``GPTAttention`` cannot express: fewer K/V
+    heads than query heads, a head size of its own, rotary or no
+    positions, a sliding window, no biases. Separate q/k/v projections
+    (each splits over 'mp' by whole heads)."""
+
+    def __init__(self, config: GPTConfig, layer: int):
+        super().__init__()
+        self.num_heads = config.num_heads
+        self.num_kv_heads = config.num_kv_heads
+        self.head_dim = config.head_dim
+        self.use_flash = config.use_flash_attention
+        self.rope_theta = config.rope_theta if config.layer_rope(layer) \
+            else None
+        self.window = config.layer_window(layer)
+        dt = config.dtype or "float32"
+        init = I.Normal(std=config.initializer_range)
+        qd = self.num_heads * self.head_dim
+        kvd = self.num_kv_heads * self.head_dim
+
+        def mk(shape, spec, bias=False):
+            p = create_parameter_with_attr(
+                shape, dt, None, bias, default_initializer=I.Constant(0.0)
+                if bias else init)
+            p.dist_spec = spec
+            return p
+
+        h = config.hidden_size
+        self.q_w = mk([h, qd], (None, "mp"))
+        self.k_w = mk([h, kvd], (None, "mp"))
+        self.v_w = mk([h, kvd], (None, "mp"))
+        self.out_w = mk([qd, h], ("mp", None))
+        self.has_bias = bool(config.bias)
+        if self.has_bias:
+            self.q_b = mk([qd], ("mp",), True)
+            self.k_b = mk([kvd], ("mp",), True)
+            self.v_b = mk([kvd], ("mp",), True)
+            self.out_b = mk([h], (None,), True)
+
+    def _biases(self):
+        return [self.q_b, self.k_b, self.v_b, self.out_b] \
+            if self.has_bias else []
+
+    def forward(self, x, kv_cache=None):
+        import jax.numpy as jnp
+        nh, nkv, hd = self.num_heads, self.num_kv_heads, self.head_dim
+        theta, window, use_flash = self.rope_theta, self.window, \
+            self.use_flash
+        has_bias = self.has_bias
+
+        def qkv(x, positions, q_w, k_w, v_w, biases):
+            b, s, _ = x.shape
+            q, k, v = x @ q_w, x @ k_w, x @ v_w
+            if has_bias:
+                q, k, v = q + biases[0], k + biases[1], v + biases[2]
+            q = q.reshape(b, s, nh, hd)
+            k = k.reshape(b, s, nkv, hd)
+            v = v.reshape(b, s, nkv, hd)
+            if theta is not None:
+                if positions is None:
+                    positions = jnp.broadcast_to(
+                        jnp.arange(s, dtype=jnp.int32), (b, s))
+                q, k = _rope(q, positions, theta), _rope(k, positions, theta)
+            return q, k, v
+
+        def out(a, out_w, biases):
+            b, s = a.shape[:2]
+            o = a.reshape(b, s, nh * hd) @ out_w
+            return o + biases[3] if has_bias else o
+
+        if kv_cache is None:
+            def fn(x, q_w, k_w, v_w, out_w, *biases):
+                from ..ops.flash_attention import attention_bshd
+                q, k, v = qkv(x, None, q_w, k_w, v_w, biases)
+                a = attention_bshd(q, k, v, causal=True,
+                                   scale=1.0 / math.sqrt(hd),
+                                   use_flash=use_flash, window=window)
+                return out(a, out_w, biases)
+            return apply_op("grouped_attention", fn, x, self.q_w, self.k_w,
+                            self.v_w, self.out_w, *self._biases())
+
+        from ..ops.paged_attention import paged_attention_update
+        k_leaves, pool_def = jax.tree_util.tree_flatten(kv_cache.k)
+        v_leaves, _ = jax.tree_util.tree_flatten(kv_cache.v)
+        nk, nb = len(k_leaves), len(self._biases())
+
+        def fn(x, tables, ctx, valid, positions, q_w, k_w, v_w, out_w,
+               *rest, **kw):
+            biases, pool_leaves = rest[:nb], rest[nb:]
+            kp = jax.tree_util.tree_unflatten(pool_def, pool_leaves[:nk])
+            vp = jax.tree_util.tree_unflatten(pool_def, pool_leaves[nk:])
+            q, k, v = qkv(x, positions, q_w, k_w, v_w, biases)
+            a, kp2, vp2 = paged_attention_update(
+                q, k, v, kp, vp, tables, ctx, valid, positions,
+                window=window, **kw)
+            return (out(a, out_w, biases),
+                    *jax.tree_util.tree_leaves(kp2),
+                    *jax.tree_util.tree_leaves(vp2))
+
+        res = apply_op(
+            "paged_attention", fn, x, kv_cache.block_tables,
+            kv_cache.ctx_len, kv_cache.valid, kv_cache.positions,
+            self.q_w, self.k_w, self.v_w, self.out_w, *self._biases(),
+            *k_leaves, *v_leaves, page_size=kv_cache.page_size,
+            kind=kv_cache.kind, use_flash=use_flash,
+            use_pallas=kv_cache.use_pallas, mesh=kv_cache.mesh)
+        k_pool = jax.tree_util.tree_unflatten(pool_def, res[1:1 + nk])
+        v_pool = jax.tree_util.tree_unflatten(pool_def, res[1 + nk:])
+        return res[0], k_pool, v_pool
+
+
+class GPTExpertMLP(Layer):
+    """Dropless top-k ReGLU experts (``ops.moe.dropless_moe``): the
+    weights of one projection of all experts are one stacked array."""
+
+    def __init__(self, config: GPTConfig):
+        super().__init__()
+        self.top_k = config.moe_top_k
+        dt = config.dtype or "float32"
+        init = I.Normal(std=config.initializer_range)
+        h, e, i = (config.hidden_size, config.moe_num_experts,
+                   config.moe_intermediate_size)
+
+        def mk(shape):
+            return create_parameter_with_attr(
+                shape, dt, None, False, default_initializer=init)
+
+        self.router_w = mk([h, e])
+        self.gate_w = mk([e, h, i])
+        self.up_w = mk([e, h, i])
+        self.down_w = mk([e, i, h])
+
+    def forward(self, x, router_in, valid=None):
+        """x, router_in: [B, S, H]; valid: [B, S] bool or None. Returns
+        ``(out [B, S, H], stats int32 [3])``: assignments, experts
+        touched, the fullest expert's rows."""
+        import jax.numpy as jnp
+
+        from ..ops.moe import dropless_moe
+        top_k = self.top_k
+
+        def fn(x, router_in, valid, *weights):
+            b, s, h = x.shape
+            out, stats = dropless_moe(
+                x.reshape(b * s, h), router_in.reshape(b * s, h), *weights,
+                top_k=top_k,
+                valid=None if valid is None else valid.reshape(b * s))
+            return out.reshape(b, s, h), jnp.stack(
+                [stats["assignments"], stats["experts_touched"],
+                 stats["max_expert_load"]]).astype(jnp.int32)
+
+        return apply_op("moe", fn, x, router_in, valid, self.router_w,
+                        self.gate_w, self.up_w, self.down_w)
+
+
 class GPTMLP(Layer):
     def __init__(self, config: GPTConfig):
         super().__init__()
@@ -311,23 +669,44 @@ class GPTMLP(Layer):
 class GPTDecoderLayer(Layer):
     """Pre-LN decoder block (the MFU-critical fused pattern the reference
     implements as fused_attention/fused_feedforward CUDA ops —
-    /root/reference/paddle/fluid/operators/fused/; here XLA fuses)."""
+    /root/reference/paddle/fluid/operators/fused/; here XLA fuses).
+    The config's fields say which modules it is made of: the defaults
+    give the GPT-2/GPT-3 block."""
 
-    def __init__(self, config: GPTConfig):
+    def __init__(self, config: GPTConfig, layer: int = 0):
         super().__init__()
-        self.ln_1 = LayerNorm(config.hidden_size, epsilon=config.layer_norm_eps)
-        self.attn = GPTAttention(config)
-        self.ln_2 = LayerNorm(config.hidden_size, epsilon=config.layer_norm_eps)
-        self.mlp = GPTMLP(config)
+        self.ln_1 = _norm(config)
+        self.attn = GPTAttention(config) if config.classic_attention \
+            else GPTGroupedAttention(config, layer)
+        self.ln_2 = _norm(config)
+        self.experts = bool(config.moe_num_experts)
+        self.mlp = GPTExpertMLP(config) if self.experts else GPTMLP(config)
+        self.router_reads_attention_input = \
+            config.moe_router_input == "attention_input"
+        if config.dtype:
+            # the modules shared with the GPT presets are born float32
+            self.to(dtype=config.dtype)
+
+    def _mlp(self, x, h, kv_cache=None):
+        """``x + mlp(ln_2(x))``; ``h`` is the attention's normed input,
+        which a router "placed before attention" reads."""
+        m = self.ln_2(x)
+        if not self.experts:
+            return x + self.mlp(m)
+        y, stats = self.mlp(
+            m, h if self.router_reads_attention_input else m,
+            valid=None if kv_cache is None else kv_cache.valid)
+        if kv_cache is not None and kv_cache.aux is not None:
+            kv_cache.aux.setdefault("moe", []).append(stats._data)
+        return x + y
 
     def forward(self, x, kv_cache=None):
+        h = self.ln_1(x)
         if kv_cache is not None:
-            a, k_pool, v_pool = self.attn(self.ln_1(x), kv_cache=kv_cache)
-            x = x + a
-            x = x + self.mlp(self.ln_2(x))
+            a, k_pool, v_pool = self.attn(h, kv_cache=kv_cache)
+            x = self._mlp(x + a, h, kv_cache)
             return _seq_constraint(x), k_pool, v_pool
-        x = x + self.attn(self.ln_1(x))
-        x = x + self.mlp(self.ln_2(x))
+        x = self._mlp(x + self.attn(h), h)
         return _seq_constraint(x)
 
 
@@ -644,9 +1023,12 @@ class GPTModel(Layer):
             self.decoder = GPTStackedTransformer(config)
             self.layers = LayerList([])
         else:
-            self.layers = LayerList([GPTDecoderLayer(config)
-                                     for _ in range(config.num_layers)])
-        self.ln_f = LayerNorm(config.hidden_size, epsilon=config.layer_norm_eps)
+            self.layers = LayerList([GPTDecoderLayer(config, i)
+                                     for i in range(config.num_layers)])
+        self.ln_f = _norm(config)
+        if config.dtype:
+            self.embeddings.to(dtype=config.dtype)
+            self.ln_f.to(dtype=config.dtype)
 
     def forward(self, input_ids, cache=None):
         if cache is not None:
@@ -667,16 +1049,35 @@ class GPTModel(Layer):
             h, k_new, v_new = self.decoder(h, cache=cache)
         else:
             k_new, v_new = [], []
+            tables = self._tables_by_layer(cache)
             for i, layer in enumerate(self.layers):
-                view = GPTKVCache(
-                    cache.kind, cache.page_size, cache.k[i], cache.v[i],
-                    cache.block_tables, cache.ctx_len, cache.valid,
-                    cache.positions, use_pallas=cache.use_pallas,
-                    mesh=cache.mesh)
-                h, k_i, v_i = layer(h, kv_cache=view)
+                h, k_i, v_i = layer(h, kv_cache=cache.layer_view(
+                    cache.k[i], cache.v[i], tables[i]))
                 k_new.append(k_i)
                 v_new.append(v_i)
         return self.ln_f(h), (k_new, v_new)
+
+    def _tables_by_layer(self, cache: GPTKVCache):
+        """Each layer's block table. Layers that attend the whole
+        context share the one the cache brings. Where some attend a
+        window, the cache's table is both kinds' side by side (the
+        engine feeds one array): the last ``ring_pages(window,
+        page_size)`` columns are the window layers' ring, the columns
+        before them the full-context layers' table."""
+        cfg = self.config
+        windows = [cfg.layer_window(i) for i in range(cfg.num_layers)]
+        if not any(windows):
+            return [None] * cfg.num_layers
+        from ..ops.paged_attention import ring_pages
+        ring = ring_pages(cfg.sliding_window, cache.page_size)
+        width = cache.block_tables.shape[1]
+        if width <= ring:
+            raise ValueError(
+                f"block tables of {width} columns leave none beside the "
+                f"window layers' ring of {ring}")
+        full = cache.block_tables[:, :width - ring]
+        window = cache.block_tables[:, width - ring:]
+        return [window if w else full for w in windows]
 
     # -- pipeline segmentation hook (pp_layers.LayerDesc consumers) --
     def pipeline_stages(self):
@@ -689,17 +1090,40 @@ class GPTForCausalLM(Layer):
         self.gpt = GPTModel(config)
         self.config = config
         if not config.tie_word_embeddings:
-            self.lm_head = Linear(config.hidden_size, config.vocab_size,
-                                  bias_attr=False)
+            self.lm_head = Linear(
+                config.hidden_size, config.vocab_size, bias_attr=False,
+                weight_attr=None if config.classic_attention
+                else I.Normal(std=config.initializer_range))
+            if config.dtype:
+                self.lm_head.to(dtype=config.dtype)
 
     def forward(self, input_ids, cache=None):
         if cache is not None:
             h, pools = self.gpt(input_ids, cache=cache)
+            if cache.logits_at is not None:
+                # the head meets one position a row, not the window
+                import jax.numpy as jnp
+                h = apply_op(
+                    "take_positions",
+                    lambda h, at: jnp.take_along_axis(
+                        h, at.astype(jnp.int32)[:, None, None], axis=1),
+                    h, cache.logits_at)
         else:
             h = self.gpt(input_ids)
-        if self.config.tie_word_embeddings:
+        tied = self.config.tie_word_embeddings
+        w = self.gpt.embeddings.word_embeddings.weight if tied \
+            else self.lm_head.weight
+        if str(w._data.dtype) != "float32":
+            # weights below float32: the logits leave the product's
+            # float32 accumulator unrounded (a bfloat16 logit has 8 bits
+            # and ties its neighbours; the host's sampler reads float32)
+            import jax.numpy as jnp
+            logits = apply_op(
+                "lm_head", lambda h, w: jnp.einsum(
+                    "bsh,vh->bsv" if tied else "bsh,hv->bsv", h, w,
+                    preferred_element_type=jnp.float32), h, w)
+        elif tied:
             from ..tensor import linalg
-            w = self.gpt.embeddings.word_embeddings.weight
             logits = linalg.matmul(h, w, transpose_y=True)
         else:
             logits = self.lm_head(h)
@@ -708,53 +1132,74 @@ class GPTForCausalLM(Layer):
         return logits
 
     # ---- paged KV-cache plumbing (serving.generation engine) ----
-    def init_kv_pools(self, num_pages: int, page_size: int, dtype=None):
+    def init_kv_pools(self, num_pages: int, page_size: int, dtype=None,
+                      window_pages=None):
         """Zeroed paged K/V pools shaped for this model: a list of
-        per-layer ``[num_pages, page_size, heads, head_dim]`` arrays
+        per-layer ``[num_pages, page_size, kv_heads, head_dim]`` arrays
         (module stack) or one stacked ``[L, ...]`` pair (stacked
         decoder). Page 0 is the trash page and is never allocated.
+        A layer that attends a window only has ``window_pages`` pages
+        (its sequences hold a ring of ``ring_pages`` each, however long
+        they grow; ``PagedKVCache`` sizes it from the lanes), or
+        ``num_pages`` where the caller names none.
         ``dtype`` may also be the string ``"int8"``: pools then become
         ``(int8 values, f32 per-slot-per-head scales)`` tuples (see
         ops.paged_attention for the quantized-pool contract). Returns
         raw jax arrays ``(k, v)`` — engine plumbing, not Tensors."""
         import jax.numpy as jnp
         cfg = self.config
-        nh, hd = cfg.num_heads, cfg.hidden_size // cfg.num_heads
-        shape = (int(num_pages), int(page_size), nh, hd)
-        if isinstance(dtype, str) and dtype == "int8":
-            sshape = shape[:-1]
+        nh, hd = cfg.num_kv_heads, cfg.head_dim
+        pages = [int(window_pages or num_pages) if cfg.layer_window(i)
+                 else int(num_pages) for i in range(cfg.num_layers)]
 
-            def mk(lead=()):
-                return (jnp.zeros(lead + shape, jnp.int8),
-                        jnp.zeros(lead + sshape, jnp.float32))
+        def shape_of(n):
+            return (n, int(page_size), nh, hd)
+
+        if isinstance(dtype, str) and dtype == "int8":
+            def mk(shape):
+                return (jnp.zeros(shape, jnp.int8),
+                        jnp.zeros(shape[:-1], jnp.float32))
 
             if cfg.stacked:
-                return mk((cfg.num_layers,)), mk((cfg.num_layers,))
-            return ([mk() for _ in range(cfg.num_layers)],
-                    [mk() for _ in range(cfg.num_layers)])
+                lead = (cfg.num_layers,) + shape_of(int(num_pages))
+                return mk(lead), mk(lead)
+            return ([mk(shape_of(n)) for n in pages],
+                    [mk(shape_of(n)) for n in pages])
         dt = dtype or self.gpt.embeddings.word_embeddings.weight._data.dtype
         if cfg.stacked:
-            k = jnp.zeros((cfg.num_layers,) + shape, dt)
-            return k, jnp.zeros((cfg.num_layers,) + shape, dt)
-        return ([jnp.zeros(shape, dt) for _ in range(cfg.num_layers)],
-                [jnp.zeros(shape, dt) for _ in range(cfg.num_layers)])
+            lead = (cfg.num_layers,) + shape_of(int(num_pages))
+            return jnp.zeros(lead, dt), jnp.zeros(lead, dt)
+        return ([jnp.zeros(shape_of(n), dt) for n in pages],
+                [jnp.zeros(shape_of(n), dt) for n in pages])
 
     def kv_cache_spec(self, kv_dtype: str = "") -> dict:
         """Geometry the decode engine sizes its cache from.
         ``kv_dtype`` ('' = model dtype) adds per-token byte accounting
-        so sizing and shardcheck agree on pool cost."""
+        so sizing and shardcheck agree on pool cost. ``kinds`` says
+        what each kind of layer keeps: ``full`` the whole context,
+        ``window`` the last ``window`` positions (absent where no layer
+        is of that kind)."""
         from ..ops.paged_attention import kv_pool_bytes
         cfg = self.config
-        nh, hd = cfg.num_heads, cfg.hidden_size // cfg.num_heads
+        nh, hd = cfg.num_kv_heads, cfg.head_dim
         per_token = cfg.num_layers * 2 * kv_pool_bytes(
             1, 1, nh, hd, kv_dtype or None)
+        windows = [cfg.layer_window(i) for i in range(cfg.num_layers)]
+        kinds = {"full": {"layers": [i for i, w in enumerate(windows)
+                                     if not w], "window": None}}
+        if any(windows):
+            kinds["window"] = {
+                "layers": [i for i, w in enumerate(windows) if w],
+                "window": int(cfg.sliding_window)}
         return {"num_layers": cfg.num_layers,
-                "num_heads": nh,
+                "num_heads": cfg.num_heads,
+                "num_kv_heads": nh,
                 "head_dim": hd,
                 "max_seq_len": cfg.max_seq_len,
                 "stacked": bool(cfg.stacked),
                 "kv_dtype": kv_dtype or "",
-                "kv_bytes_per_token": int(per_token)}
+                "kv_bytes_per_token": int(per_token),
+                "kinds": kinds}
 
     def num_params(self) -> int:
         return sum(int(np.prod(p.shape)) for p in self.parameters())

@@ -129,12 +129,28 @@ def pretune(batch, num_heads, seq_len, head_dim, dtype="bfloat16",
         cands, make_fn, (qt, kt, vt))
 
 
-def flash_attention_bshd(q, k, v, causal=False, scale=None):
+def flash_attention_bshd(q, k, v, causal=False, scale=None, window=None):
     """[B,S,H,D] layout wrapper over the BHSD pallas kernel; block sizes
     are autotuned per shape on the first real-device call
-    (ops/autotune.py — the reference's phi/kernels/autotune analog)."""
+    (ops/autotune.py — the reference's phi/kernels/autotune analog).
+    Fewer K/V heads than query heads, or a ``window``, take the
+    forward-only kernel (``pallas_attention.mha_forward``) at the
+    static blocks."""
     from . import autotune
     from .pallas_attention import mha
+    if window is not None or k.shape[2] != q.shape[2]:
+        from .pallas_attention import mha_forward
+        if not causal:
+            raise ValueError("grouped heads and windows are causal")
+        sq = q.shape[1]
+        bq, bk = clip_blocks(*_default_blocks(sq, sq), sq, sq)
+        with jax.named_scope("flash_attention"):
+            out = mha_forward(
+                jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
+                jnp.swapaxes(v, 1, 2), sm_scale=scale if scale is not None
+                else 1.0 / math.sqrt(q.shape[-1]), block_q=bq, block_k=bk,
+                window=window)
+        return jnp.swapaxes(out, 1, 2)
 
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
@@ -178,15 +194,22 @@ def flash_attention_bshd(q, k, v, causal=False, scale=None):
     return jnp.swapaxes(out, 1, 2)
 
 
-def attention_bshd(q, k, v, causal=False, scale=None, use_flash=True):
+def attention_bshd(q, k, v, causal=False, scale=None, use_flash=True,
+                   window=None):
     """THE flash-or-dense selection point for maskless attention in
     [B,S,H,D] layout: Pallas kernel when ``use_flash`` and preferred()
     (supported shapes AND seq >= FLAGS_flash_min_seqlen — the measured
     win/loss boundary, PERF.md), else the XLA softmax reference. Both
     the module attention path and the stacked SPMD decoder route here
-    so the gating can never diverge between them."""
+    so the gating can never diverge between them. ``k``/``v`` may hold
+    fewer heads than ``q`` (K/V head ``g`` serves query heads ``g*G ..
+    g*G+G-1``); ``window`` (causal only) lets a row see its last
+    ``window`` positions, itself among them."""
     if use_flash and preferred(q, k, v, None, causal):
-        return flash_attention_bshd(q, k, v, causal=causal, scale=scale)
+        return flash_attention_bshd(q, k, v, causal=causal, scale=scale,
+                                    window=window)
+    if window is not None or k.shape[2] != q.shape[2]:
+        return _dense_grouped(q, k, v, causal, scale, window)
     # dense path: matmuls stay in the INPUT dtype (bf16 under AMP — the
     # MXU fast path; _mha_reference is the f32-matmul test oracle and
     # routing production traffic through it cost 24% of the train step),
@@ -205,3 +228,26 @@ def attention_bshd(q, k, v, causal=False, scale=None, use_flash=True):
                            axis=-1).astype(qt.dtype)
     out = jnp.einsum("bhqk,bhkd->bhqd", probs, vt)
     return jnp.swapaxes(out, 1, 2)
+
+
+def _dense_grouped(q, k, v, causal, scale, window):
+    """The dense path for grouped K/V heads and sliding windows:
+    matmuls in the input dtype, scores and softmax in f32."""
+    if window is not None and not causal:
+        raise ValueError("a window is causal")
+    b, sq, h, d = q.shape
+    sk, hk = k.shape[1], k.shape[2]
+    s = scale if scale is not None else 1.0 / math.sqrt(d)
+    qg = q.reshape(b, sq, hk, h // hk, d)
+    logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
+                        preferred_element_type=jnp.float32) * jnp.float32(s)
+    if causal:
+        rows = jnp.arange(sq)[:, None] + (sk - sq)
+        cols = jnp.arange(sk)[None, :]
+        seen = rows >= cols
+        if window is not None:
+            seen = seen & (rows - cols < window)
+        logits = jnp.where(seen, logits, jnp.float32(-1e30))
+    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v)
+    return out.reshape(b, sq, h, d)

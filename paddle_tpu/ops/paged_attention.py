@@ -107,15 +107,20 @@ def kv_pool_bytes(num_pages, page_size, num_heads, head_dim,
     return slots * num_heads * head_dim * dt.itemsize
 
 
-def flat_slots(block_tables, positions, valid, page_size: int):
+def flat_slots(block_tables, positions, valid, page_size: int,
+               ring: bool = False):
     """Flat pool-slot index for each (row, position): ``page * page_size
     + offset`` through the block table, or a trash-page slot (< page_size)
-    where ``valid`` is False.
+    where ``valid`` is False. With ``ring`` the table is a ring of its
+    ``P`` pages: logical page ``n`` lives in entry ``n % P`` (a window
+    layer's table, ``ring_positions``).
 
     block_tables: [B, P] int32; positions: [B, S] int32; valid: [B, S]
     bool. Returns [B, S] int32.
     """
     page_idx = positions // page_size
+    if ring:
+        page_idx = page_idx % block_tables.shape[1]
     offset = positions % page_size
     # clip so dead lanes with positions past the table read page 0, not
     # out of bounds (jax clamps gathers, but be explicit)
@@ -174,6 +179,48 @@ def gather_pool(pool, block_tables, out_dtype=None):
         sg = _gather_flat(scales, block_tables)
         return dequantize_kv(vg, sg, out_dtype or jnp.float32)
     return _gather_flat(pool, block_tables)
+
+
+def ring_pages(window: int, page_size: int) -> int:
+    """Pages a window layer keeps a sequence: the ``window`` positions a
+    token attends (itself among them) span at most this many pages,
+    wherever in a page the newest one falls."""
+    return -(-int(window) // int(page_size)) + 1
+
+
+def ring_positions(ctx_len, pages: int, page_size: int):
+    """The logical position held by each slot a ring table addresses,
+    in the order ``gather_pool`` returns them: entry ``r`` of a lane
+    whose newest page is ``last = (ctx - 1) // page_size`` holds logical
+    page ``last - (last - r) % pages``, the newest one congruent to
+    ``r``. Negative where that entry was never written. ctx_len: [B]
+    (the written positions included). Returns [B, pages * page_size]."""
+    last = (ctx_len.astype(jnp.int32) - 1) // page_size          # [B]
+    r = jnp.arange(pages, dtype=jnp.int32)[None, :]
+    page = last[:, None] - (last[:, None] - r) % pages            # [B, P]
+    offs = jnp.arange(page_size, dtype=jnp.int32)
+    return (page[:, :, None] * page_size + offs).reshape(
+        ctx_len.shape[0], pages * page_size)
+
+
+def _masked_attention(q, ks, vs, mask, scale):
+    """Attention of a window of queries against gathered context under
+    an explicit mask, K/V heads shared by groups of query heads.
+
+    q: [B, S, Hq, D]; ks/vs: [B, T, Hkv, D] with Hq a multiple of Hkv
+    (KV head ``g`` serves query heads ``g*G .. g*G+G-1``); mask:
+    [B, S, T]. Scores and softmax in f32. Masked slots get -1e30 (an
+    all-dead row must stay finite; its output is discarded)."""
+    b, s, hq, d = q.shape
+    hkv = ks.shape[2]
+    qg = q.reshape(b, s, hkv, hq // hkv, d)
+    logits = jnp.einsum("bqhgd,bthd->bhgqt", qg, ks,
+                        preferred_element_type=jnp.float32) \
+        * jnp.float32(scale)
+    logits = jnp.where(mask[:, None, None], logits, jnp.float32(-1e30))
+    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    out = jnp.einsum("bhgqt,bthd->bqhgd", probs, vs)
+    return out.reshape(b, s, hq, d)
 
 
 def _decode_attention(q, ks, vs, ctx_len, scale):
@@ -241,7 +288,7 @@ def _mesh_mp(mesh):
 
 def _sharded_paged_attention(mesh, q, k_pool, v_pool, block_tables,
                              ctx_len, valid, positions, *, page_size,
-                             kind, scale):
+                             kind, scale, window=None):
     """Per-shard Pallas dispatch under a live mp mesh: every rank runs
     the fused kernel on ITS heads-axis block of q and the pools
     (attention is embarrassingly parallel over heads — no collective in
@@ -257,7 +304,7 @@ def _sharded_paged_attention(mesh, q, k_pool, v_pool, block_tables,
     def body(q_loc, kp_loc, vp_loc, tables, ctx, val, pos):
         return ppa.paged_attention(
             q_loc, kp_loc, vp_loc, tables, ctx, val, pos,
-            page_size=page_size, kind=kind, scale=scale)
+            page_size=page_size, kind=kind, scale=scale, window=window)
 
     qspec = P(None, None, "mp", None)
     in_specs = (qspec, _pool_shard_spec(k_pool), _pool_shard_spec(v_pool),
@@ -269,15 +316,28 @@ def _sharded_paged_attention(mesh, q, k_pool, v_pool, block_tables,
 def paged_attention_update(q, k, v, k_pool, v_pool, block_tables,
                            ctx_len, valid, positions, *, page_size: int,
                            kind: str, use_flash: bool = True,
-                           use_pallas=None, mesh=None):
+                           use_pallas=None, mesh=None, window=None):
     """One layer's cache-aware attention: write this call's K/V into the
     paged pool, then attend.
 
-    q/k/v: [B, S, H, D] (S = prompt window for prefill, 1 for decode);
-    k_pool/v_pool: [num_pages, page_size, H, D]; block_tables: [B, P];
+    q: [B, S, Hq, D], k/v: [B, S, H, D] (S = prompt window for prefill,
+    1 for decode; Hq a multiple of H: KV head ``g`` serves query heads
+    ``g*G .. g*G+G-1``); k_pool/v_pool: [num_pages, page_size, H, D];
+    block_tables: [B, P];
     ctx_len: [B] visible length including the positions written here;
     valid: [B, S] which fed positions are real; positions: [B, S]
     absolute positions being written.
+
+    ``window`` (None: the whole context) is how many positions a token
+    attends, itself among them: position ``i`` sees ``j`` with
+    ``0 <= i - j < window``. The block table of such a layer is a
+    *ring* of its ``P`` pages (``P >= ring_pages(window, page_size)``):
+    logical page ``n`` lives in entry ``n % P``, so a sequence holds
+    ``P`` pages however long it grows, and only positions a later step
+    can still see are written (a prefill keeps its last ``window``).
+    A decode or chunked call over a ring may span at most
+    ``(P - 1) * page_size - window + 2`` positions: a longer one could
+    overwrite what its own first token still attends.
 
     kind="prefill": K/V of the window are right here, so attention is
     ordinary causal attention over the window (bit-identical to the
@@ -331,7 +391,8 @@ def paged_attention_update(q, k, v, k_pool, v_pool, block_tables,
         return _paged_attention_update(
             q, k, v, k_pool, v_pool, block_tables, ctx_len, valid,
             positions, page_size=page_size, kind=kind,
-            use_flash=use_flash, use_pallas=use_pallas, mesh=mesh)
+            use_flash=use_flash, use_pallas=use_pallas, mesh=mesh,
+            window=None if window is None else int(window))
 
 
 def kernel_by_default(page_size: int) -> bool:
@@ -344,18 +405,43 @@ def kernel_by_default(page_size: int) -> bool:
 
 def _paged_attention_update(q, k, v, k_pool, v_pool, block_tables,
                             ctx_len, valid, positions, *, page_size,
-                            kind, use_flash, use_pallas, mesh):
+                            kind, use_flash, use_pallas, mesh, window):
     from . import pallas_paged_attention as ppa
     if use_pallas is None:
         use_pallas = kernel_by_default(page_size) and ppa.supported(
             q, k_pool, block_tables, page_size, kind)
     mp = _mesh_mp(mesh)
     heads = q.shape[2]
-    sharded = use_pallas and mp > 0 and heads % mp == 0
+    kv_heads = k.shape[2]
+    if heads % kv_heads:
+        raise ValueError(f"{heads} query heads over {kv_heads} K/V heads")
+    sharded = use_pallas and mp > 0 and kv_heads % mp == 0
+    ring = window is not None
     b, s = q.shape[0], q.shape[1]
+    pages = block_tables.shape[1]
+    if ring:
+        if pages < ring_pages(window, page_size):
+            raise ValueError(
+                f"a window of {window} positions needs a ring of "
+                f"{ring_pages(window, page_size)} pages of {page_size} "
+                f"slots, the table has {pages}")
+        # the newest token's page may not be the one the oldest token
+        # of the same call still reads, wherever in a page they fall
+        if kind != "prefill" and \
+                s > pages * page_size - window - page_size + 2:
+            raise ValueError(
+                f"{s} positions a call over a ring of {pages} x "
+                f"{page_size} slots would overwrite what the first of "
+                f"them attends (window {window}): at most "
+                f"{pages * page_size - window - page_size + 2}")
     scale = 1.0 / math.sqrt(q.shape[-1])
     with jax.named_scope("kv_write"):
-        slots = flat_slots(block_tables, positions, valid, page_size)
+        keep = valid
+        if ring:
+            # only what a later step can still see is kept
+            keep = valid & (positions >= ctx_len[:, None] - window)
+        slots = flat_slots(block_tables, positions, keep, page_size,
+                           ring=ring)
         slots_flat = slots.reshape(b * s)
         # the pool scatter stays OUTSIDE shard_map: the flat
         # [P*page, H, D] reshape keeps the heads dim intact, so GSPMD
@@ -368,7 +454,7 @@ def _paged_attention_update(q, k, v, k_pool, v_pool, block_tables,
         with jax.named_scope("attend"):
             from .flash_attention import attention_bshd
             out = attention_bshd(q, k, v, causal=True, scale=scale,
-                                 use_flash=use_flash)
+                                 use_flash=use_flash, window=window)
         return out, k_pool, v_pool
     if use_pallas:
         if not ppa.supported(q, k_pool, block_tables, page_size, kind):
@@ -385,18 +471,35 @@ def _paged_attention_update(q, k, v, k_pool, v_pool, block_tables,
                 out = _sharded_paged_attention(
                     mesh, q, k_pool, v_pool, block_tables, ctx_len,
                     valid, positions, page_size=page_size, kind=kind,
-                    scale=scale)
+                    scale=scale, window=window)
             else:
                 out = ppa.paged_attention(
                     q, k_pool, v_pool, block_tables, ctx_len, valid,
                     positions, page_size=page_size, kind=kind,
-                    scale=scale)
+                    scale=scale, window=window)
         return out, k_pool, v_pool
     with jax.named_scope("kv_gather"):
         ks = gather_pool(k_pool, block_tables, out_dtype=q.dtype)
         vs = gather_pool(v_pool, block_tables, out_dtype=q.dtype)
     with jax.named_scope("attend"):
-        if kind == "decode":
+        if ring or kv_heads != heads:
+            # where each gathered slot lies in the sequence: table
+            # order, or the ring's
+            at = ring_positions(ctx_len, pages, page_size) if ring else \
+                jnp.broadcast_to(jnp.arange(ks.shape[1], dtype=jnp.int32),
+                                 (b, ks.shape[1]))
+            if kind == "decode":
+                seen = (at < ctx_len[:, None])[:, None, :]
+                newest = ctx_len[:, None, None] - 1
+            else:
+                seen = (at[:, None, :] <= positions[:, :, None]) \
+                    & valid[:, :, None]
+                newest = positions[:, :, None]
+            seen = seen & (at >= 0)[:, None, :]
+            if ring:
+                seen = seen & (newest - at[:, None, :] < window)
+            out = _masked_attention(q, ks, vs, seen, scale)
+        elif kind == "decode":
             out = _decode_attention(q, ks, vs, ctx_len, scale)
         else:
             out = _chunked_attention(q, ks, vs, positions, valid, scale)

@@ -49,7 +49,7 @@ def _i0():
 # ---------------------------------------------------------------- forward
 
 def _mha_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal,
-                    block_k, kv_len):
+                    block_k, kv_len, window=None):
     # q_ref: [block_q, d]; k_ref/v_ref: [kv_len, d]; o_ref: [block_q, d]
     # lse_ref: [block_q, LANES] (row logsumexp replicated across lanes)
     block_q = q_ref.shape[0]
@@ -82,7 +82,10 @@ def _mha_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal,
                 jnp.int32, (block_q, block_k), 0)
             k_pos = kb * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, jnp.float32(NEG_INF))
+            seen = q_pos >= k_pos
+            if window is not None:
+                seen = seen & (q_pos - k_pos < jnp.int32(window))
+            s = jnp.where(seen, s, jnp.float32(NEG_INF))
         m_cur = jnp.max(s, axis=1)
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp(s - m_new[:, None])
@@ -102,7 +105,13 @@ def _mha_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal,
     else:
         last_kb = jnp.int32(num_kb)
 
-    m, l, acc = jax.lax.fori_loop(jnp.int32(0), last_kb, body,
+    first_kb = jnp.int32(0)
+    if window is not None:
+        # blocks wholly before the first row's window are skipped
+        first_kb = jax.lax.div(
+            jnp.maximum(q_idx * block_q - jnp.int32(window - 1), 0),
+            jnp.int32(block_k))
+    m, l, acc = jax.lax.fori_loop(first_kb, last_kb, body,
                                   (m_init, l_init, acc_init))
     l = jnp.maximum(l, jnp.float32(1e-30))
     o_ref[:] = (acc / l[:, None]).astype(o_ref.dtype)
@@ -110,23 +119,35 @@ def _mha_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal,
     lse_ref[:] = jax.lax.broadcast_in_dim(lse, (block_q, LANES), (0,))
 
 
-def _mha_fwd(q, k, v, causal, sm_scale, block_q, block_k):
-    """Returns (out [b,h,sq,d], lse [b*h, sq, LANES] f32)."""
+def _mha_fwd(q, k, v, causal, sm_scale, block_q, block_k, window=None):
+    """Returns (out [b,h,sq,d], lse [b*h, sq, LANES] f32). ``k``/``v``
+    may hold fewer heads than ``q`` (a multiple): K/V head ``g`` then
+    serves query heads ``g*G .. g*G+G-1``, and is copied once for all of
+    them (consecutive grid steps name the same block). ``window``
+    (causal only): a row sees its last ``window`` positions, itself
+    among them."""
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    hk, sk = k.shape[1], k.shape[2]
     qr = q.reshape(b * h, sq, d)
-    kr = k.reshape(b * h, sk, d)
-    vr = v.reshape(b * h, sk, d)
+    kr = k.reshape(b * hk, sk, d)
+    vr = v.reshape(b * hk, sk, d)
+    if hk == h:
+        def kv_map(bh, i):
+            return (bh, _i0(), _i0())
+    else:
+        def kv_map(bh, i):
+            return (jax.lax.div(bh, jnp.int32(h // hk)), _i0(), _i0())
 
-    kernel = functools.partial(_mha_fwd_kernel, sm_scale=sm_scale,
-                               causal=causal, block_k=block_k, kv_len=sk)
+    kernel = functools.partial(
+        _mha_fwd_kernel, sm_scale=sm_scale, causal=causal, block_k=block_k,
+        kv_len=sk, **({} if window is None else {"window": int(window)}))
     out, lse = pl.pallas_call(
         kernel,
         grid=(b * h, sq // block_q),
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda bh, i: (bh, i, _i0())),
-            pl.BlockSpec((None, sk, d), lambda bh, i: (bh, _i0(), _i0())),
-            pl.BlockSpec((None, sk, d), lambda bh, i: (bh, _i0(), _i0())),
+            pl.BlockSpec((None, sk, d), kv_map),
+            pl.BlockSpec((None, sk, d), kv_map),
         ],
         out_specs=[
             pl.BlockSpec((None, block_q, d), lambda bh, i: (bh, i, _i0())),
@@ -403,3 +424,27 @@ def _mha_vjp_bwd(causal, sm_scale, block_q, block_k, res, g):
 
 
 mha.defvjp(_mha_vjp_fwd, _mha_vjp_bwd)
+
+
+def mha_forward(q, k, v, *, sm_scale, block_q, block_k, window=None):
+    """Causal forward alone, for serving: grouped K/V heads (``k``/``v``
+    [B, Hkv, S, D] under ``q`` [B, Hq, S, D]) and a sliding ``window``,
+    neither of which the backward kernels know."""
+    _check_mha_args(q, k, True, block_q, block_k)
+    if q.shape[1] % k.shape[1]:
+        raise ValueError(f"{q.shape[1]} query heads over {k.shape[1]} "
+                         f"K/V heads")
+
+    def run(q, k, v):
+        return _mha_fwd(q, k, v, True, sm_scale, block_q, block_k,
+                        window=window)[0]
+
+    call = jax.custom_vjp(run)
+    call.defvjp(lambda *a: (run(*a), None), _forward_only_bwd)
+    return call(q, k, v)
+
+
+def _forward_only_bwd(_res, _g):
+    raise NotImplementedError(
+        "mha_forward (grouped K/V heads, sliding window) is the serving "
+        "prefill's kernel and has no backward")

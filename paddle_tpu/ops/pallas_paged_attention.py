@@ -117,7 +117,8 @@ def _attend_one_row(q, k, v, live, m, l, acc):
 
 
 def _paged_kernel(tables_ref, ctx_ref, q_ref, pos_ref, val_ref, *refs,
-                  page_size, ppt, scale, kind, quantized):
+                  page_size, ppt, scale, kind, quantized, group=1,
+                  window=None):
     """Grid program for one (row, head-block, q-block, page-tile).
 
     Scalar prefetch: tables [B, P] i32 (also feeds the K/V index maps),
@@ -127,7 +128,11 @@ def _paged_kernel(tables_ref, ctx_ref, q_ref, pos_ref, val_ref, *refs,
     scratch m/l [block_h, block_q, LANES] and acc [block_h, block_q, D]
     carry the online softmax across the (sequential) page-tile dim
     ([block_h, 1] and [block_h, D] for decode over float pools, whose
-    one row is attended for all heads at once).
+    one row is attended for all heads at once). With ``group`` > 1 a
+    K/V tile holds ``block_h / group`` heads, each serving ``group``
+    consecutive query heads. With ``window`` the table is a ring: tile
+    entry ``r`` holds the newest logical page congruent to it, and a
+    query sees its last ``window`` positions only.
     """
     o_ref, m_ref, l_ref, acc_ref = refs[-4], refs[-3], refs[-2], refs[-1]
     kv = refs[:-4]
@@ -142,7 +147,8 @@ def _paged_kernel(tables_ref, ctx_ref, q_ref, pos_ref, val_ref, *refs,
     pt = pl.program_id(3)
     npt = pl.num_programs(3)
     block_q, block_h, d = q_ref.shape
-    on_vpu = kind == "decode" and not quantized    # _attend_one_row
+    # _attend_one_row
+    on_vpu = kind == "decode" and not quantized and group == 1
 
     @pl.when(pt == 0)
     def _init():
@@ -169,19 +175,23 @@ def _paged_kernel(tables_ref, ctx_ref, q_ref, pos_ref, val_ref, *refs,
         t_glob = start + t_page                              # [bq, T]
         if kind == "decode":
             mask = t_glob < ctx_b
+            if window is not None:
+                mask = mask & (t_glob >= ctx_b - jnp.int32(window))
         else:
             mask = (t_glob <= pos) & (live > 0)
+            if window is not None:
+                mask = mask & (pos - t_glob < jnp.int32(window))
         # static unroll over the head block: rank-2 dots only
         # (Mosaic's MXU path; no batched dot_general)
         for i in range(block_h):
-            k_t = k_tiles[j][:, i, :]                        # [T, D]
-            v_t = v_tiles[j][:, i, :]
+            k_t = k_tiles[j][:, i // group, :]               # [T, D]
+            v_t = v_tiles[j][:, i // group, :]
             q_i = q_ref[:, i, :]                             # [bq, D]
             if quantized:
                 k_t = k_t.astype(jnp.float32) \
-                    * k_scales[j][:, i][:, None]
+                    * k_scales[j][:, i // group][:, None]
                 v_t = v_t.astype(jnp.float32) \
-                    * v_scales[j][:, i][:, None]
+                    * v_scales[j][:, i // group][:, None]
                 q_i = q_i.astype(jnp.float32)
             s = jax.lax.dot_general(
                 q_i, k_t, (((1,), (1,)), ((), ())),
@@ -207,9 +217,19 @@ def _paged_kernel(tables_ref, ctx_ref, q_ref, pos_ref, val_ref, *refs,
     for j in range(ppt):
         # static unroll over the sub-pages of this K-tile; each has its
         # own table-steered BlockSpec (pages are not pool-adjacent)
-        start = (pt * ppt + j) * page_size
-        # skip tiles past the context
-        pl.when(start < ctx_b)(functools.partial(
+        if window is None:
+            start = (pt * ppt + j) * page_size
+            live_tile = start < ctx_b          # skip tiles past the context
+        else:
+            # ring entry -> the newest logical page congruent to it;
+            # negative where the lane never wrote that entry
+            last = (ctx_b - 1) // jnp.int32(page_size)
+            entry = pt * ppt + j
+            start = (last - (last - entry) % jnp.int32(
+                tables_ref.shape[1])) * page_size
+            live_tile = (start >= 0) & (start + page_size
+                                        > ctx_b - jnp.int32(window))
+        pl.when(live_tile)(functools.partial(
             _vpu_page if on_vpu else _mxu_page, j, start))
 
     @pl.when(pt == npt - 1)
@@ -232,28 +252,59 @@ def decode_copies_pages(head_dim: int, quantized: bool) -> bool:
     return not quantized and head_dim % LANES == 0
 
 
+def _attend_group(q, k, v, live, m, l, acc, scale):
+    """The ``G`` query rows one K/V head serves against a tile of
+    positions, folded into a running softmax: two MXU dots (``G`` rows
+    against the tile, the weights against V) with f32 accumulation.
+    q: [G, D] and k/v: [T, D], both in the pool's type; live: [1, T];
+    m/l: [G, 1]; acc: [G, D]. Returns the new (m, l, acc)."""
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)   # [G, T]
+    s = jnp.where(live, s * jnp.float32(scale), jnp.float32(NEG_INF))
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m - m_new)
+    return (m_new, alpha * l + jnp.sum(p, axis=1, keepdims=True),
+            alpha * acc + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+
+
 def _decode_kernel(tables_ref, ctx_ref, q_ref, k_hbm, v_hbm, o_ref,
-                   kbuf, vbuf, ksem, vsem, *, page_size, chunk, scale):
+                   kbuf, vbuf, ksem, vsem, *, page_size, chunk, scale,
+                   window=None):
     """Grid program for one lane: every live page of its context, and
     no other, is copied HBM -> VMEM by this program's own DMAs (two
     slots: chunk c+1 is in flight while chunk c is attended), and the
-    one query row meets it chunk by chunk (``_attend_one_row``). A dead
-    lane starts no copy and emits zeros.
+    one query row meets it chunk by chunk (``_attend_one_row``; where
+    a K/V head serves a group of query heads, ``_attend_group`` a
+    head). A dead lane starts no copy and emits zeros. With ``window``
+    the live pages are those that hold the lane's last ``window``
+    positions, and the table is a ring (logical page ``n`` in entry
+    ``n % P``).
 
     Scalar prefetch: tables [B, P] i32, ctx [B] i32. q_ref/o_ref:
-    [H, D]; k_hbm/v_hbm: the whole pools [num_pages, page_size, H, D],
-    left in HBM; kbuf/vbuf: [2, chunk*page_size, H, D]; ksem/vsem: a
-    DMA semaphore a slot.
+    [H, D], or [H, G, D] for groups of G query heads a K/V head;
+    k_hbm/v_hbm: the whole pools [num_pages, page_size, H, D], left in
+    HBM; kbuf/vbuf: [2, chunk*page_size, H, D]; ksem/vsem: a DMA
+    semaphore a slot.
     """
     b = pl.program_id(0)
     ps = page_size
     ctx = ctx_ref[b]
+    width = tables_ref.shape[1]
     # divisors are explicit i32: under jax_enable_x64 a python int would
     # reach floor_divide / remainder as i64, which Mosaic cannot lower
-    n_pages = jnp.minimum((ctx + (ps - 1)) // jnp.int32(ps),
-                          tables_ref.shape[1])
+    end_page = (ctx + (ps - 1)) // jnp.int32(ps)
+    if window is None:
+        first_page = None
+        n_pages = jnp.minimum(end_page, width)
+    else:
+        first_page = jnp.maximum(ctx - jnp.int32(window), 0) // jnp.int32(ps)
+        n_pages = jnp.minimum(end_page - first_page, width)
     n_chunks = (n_pages + (chunk - 1)) // jnp.int32(chunk)
-    heads, d = q_ref.shape
+    grouped = len(q_ref.shape) == 3
+    heads, d = q_ref.shape[0], q_ref.shape[-1]
 
     @pl.when(b == 0)
     def _init():
@@ -261,12 +312,18 @@ def _decode_kernel(tables_ref, ctx_ref, q_ref, k_hbm, v_hbm, o_ref,
         # there, which is finite and weighs exp(-1e30 - m) == 0; what
         # VMEM held before the first copy need not be finite
         vbuf[...] = jnp.zeros_like(vbuf)
+        if grouped:
+            kbuf[...] = jnp.zeros_like(kbuf)   # it meets the MXU
 
     def each_live_page(c, slot, fn):
         for j in range(chunk):        # static: a chunk is a few pages
             @pl.when(c * chunk + j < n_pages)
             def _page(j=j):
-                page = tables_ref[b, c * chunk + j]
+                if window is None:
+                    page = tables_ref[b, c * chunk + j]
+                else:
+                    page = tables_ref[
+                        b, (first_page + c * chunk + j) % jnp.int32(width)]
                 dst = pl.ds(j * ps, ps)
                 fn(pltpu.make_async_copy(k_hbm.at[page],
                                          kbuf.at[slot, dst],
@@ -279,7 +336,10 @@ def _decode_kernel(tables_ref, ctx_ref, q_ref, k_hbm, v_hbm, o_ref,
     def _first():
         each_live_page(_i0(), _i0(), lambda dma: dma.start())
 
-    q = q_ref[...].astype(jnp.float32) * jnp.float32(scale)    # [H, D]
+    if grouped:
+        q = q_ref[...].astype(kbuf.dtype)
+    else:
+        q = q_ref[...].astype(jnp.float32) * jnp.float32(scale)
 
     def _attend(c, carry):
         slot = c % jnp.int32(2)
@@ -289,32 +349,60 @@ def _decode_kernel(tables_ref, ctx_ref, q_ref, k_hbm, v_hbm, o_ref,
             each_live_page(c + 1, 1 - slot, lambda dma: dma.start())
 
         each_live_page(c, slot, lambda dma: dma.wait())
-        t = c * (chunk * ps) + jax.lax.broadcasted_iota(
-            jnp.int32, (chunk * ps, 1, 1), 0)
-        return _attend_one_row(q, kbuf[slot].astype(jnp.float32),
-                               vbuf[slot].astype(jnp.float32), t < ctx,
-                               *carry)
+        t0 = c * (chunk * ps)
+        if window is not None:
+            t0 = t0 + first_page * ps
+        if not grouped:
+            t = t0 + jax.lax.broadcasted_iota(
+                jnp.int32, (chunk * ps, 1, 1), 0)
+            live = t < ctx
+            if window is not None:
+                live = live & (t >= ctx - jnp.int32(window))
+            return _attend_one_row(q, kbuf[slot].astype(jnp.float32),
+                                   vbuf[slot].astype(jnp.float32), live,
+                                   *carry)
+        t = t0 + jax.lax.broadcasted_iota(jnp.int32, (1, chunk * ps), 1)
+        live = t < ctx
+        if window is not None:
+            live = live & (t >= ctx - jnp.int32(window))
+        return tuple(
+            _attend_group(q[h], kbuf[slot, :, h, :], vbuf[slot, :, h, :],
+                          live, *carry[h], scale) for h in range(heads))
 
-    _, l, acc = jax.lax.fori_loop(
-        0, n_chunks, _attend,
-        (jnp.full((heads, 1), NEG_INF, jnp.float32),
-         jnp.zeros((heads, 1), jnp.float32),
-         jnp.zeros((heads, d), jnp.float32)))
-    o_ref[...] = acc / jnp.maximum(l, jnp.float32(1e-30))
+    rows = q_ref.shape[1:-1] if grouped else (heads,)
+    init = (jnp.full(rows + (1,), NEG_INF, jnp.float32),
+            jnp.zeros(rows + (1,), jnp.float32),
+            jnp.zeros(rows + (d,), jnp.float32))
+    if not grouped:
+        _, l, acc = jax.lax.fori_loop(0, n_chunks, _attend, init)
+        o_ref[...] = acc / jnp.maximum(l, jnp.float32(1e-30))
+        return
+    out = jax.lax.fori_loop(0, n_chunks, _attend, (init,) * heads)
+    for h, (_, l, acc) in enumerate(out):
+        o_ref[h] = acc / jnp.maximum(l, jnp.float32(1e-30))
 
 
 def _paged_decode(q, k_pool, v_pool, tables, ctx, *, page_size, scale,
-                  pages_per_chunk):
-    """The page-copying decode kernel: q [B, H, D], pools [num_pages,
-    page_size, H, D]. Returns [B, H, D] f32."""
+                  pages_per_chunk, window=None):
+    """The page-copying decode kernel: q [B, Hq, D], pools [num_pages,
+    page_size, H, D], Hq a multiple of H. Returns [B, Hq, D] f32."""
     from . import autotune
 
-    b, h, d = q.shape
+    b, hq, d = q.shape
+    h = k_pool.shape[2]
     chunk = autotune.paged_decode_chunk(
         page_size, h, d, k_pool.dtype.itemsize, tables.shape[1],
         override=pages_per_chunk)
-    row_spec = pl.BlockSpec((None, h, d),
-                            lambda bi, ts, cs: (bi, _i0(), _i0()))
+    if hq == h:
+        row_spec = pl.BlockSpec((None, h, d),
+                                lambda bi, ts, cs: (bi, _i0(), _i0()))
+    else:
+        # a K/V head's group of query heads is a leading index in the
+        # kernel, never a slice of sublanes
+        q = q.reshape(b, h, hq // h, d)
+        row_spec = pl.BlockSpec(
+            (None, h, hq // h, d),
+            lambda bi, ts, cs: (bi, _i0(), _i0(), _i0()))
     buf = pltpu.VMEM((2, chunk * page_size, h, d), k_pool.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2, grid=(b,),
@@ -325,29 +413,33 @@ def _paged_decode(q, k_pool, v_pool, tables, ctx, *, page_size, scale,
                         pltpu.SemaphoreType.DMA((2,))])
     kernel = functools.partial(
         _decode_kernel, page_size=page_size, chunk=chunk,
-        scale=float(scale))
+        scale=float(scale), **({} if window is None
+                               else {"window": int(window)}))
 
     def _run(tables, ctx, q, k_pool, v_pool):
         return pl.pallas_call(
             kernel, grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, h, d), jnp.float32),
+            out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
             # sequential: the buffers are zeroed by the first program
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=not _place.on_tpu(),
         )(tables, ctx, q, k_pool, v_pool)
 
-    return _inference_only(_run)(tables, ctx, q, k_pool, v_pool)
+    return _inference_only(_run)(tables, ctx, q, k_pool, v_pool).reshape(
+        b, hq, d)
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, ctx_len, valid,
                     positions, *, page_size: int, kind: str, scale: float,
                     block_q=None, block_h=None, pages_per_tile=None,
-                    pages_per_chunk=None):
+                    pages_per_chunk=None, window=None):
     """Fused read-through-table paged attention (decode/chunked).
 
-    q: [B, S, H, D]; pools: [num_pages, page_size, H, D] (or quantized
-    tuples); block_tables: [B, P] i32 (entries must be valid pool page
+    q: [B, S, Hq, D]; pools: [num_pages, page_size, H, D] (or quantized
+    tuples), Hq a multiple of H (a K/V head serves a group of
+    consecutive query heads); ``window`` as in
+    ``paged_attention_update`` (the table is then a ring); block_tables: [B, P] i32 (entries must be valid pool page
     ids — the engine guarantees this; the trash page is maskable but an
     id >= num_pages is not); ctx_len: [B]; valid: [B, S] bool;
     positions: [B, S] i32. The caller has already written this step's
@@ -365,17 +457,21 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_len, valid,
     tables = block_tables.astype(jnp.int32)
     ctx = ctx_len.astype(jnp.int32)
     quantized = is_quantized_pool(k_pool)
+    group = h // (k_pool[0] if quantized else k_pool).shape[2]
     grid_blocks = (block_q, block_h, pages_per_tile)
     if kind == "decode" and decode_copies_pages(d, quantized) \
             and grid_blocks == (None,) * 3:
         out = _paged_decode(
             q[:, 0], k_pool, v_pool, tables, ctx, page_size=page_size,
-            scale=scale, pages_per_chunk=pages_per_chunk)
+            scale=scale, pages_per_chunk=pages_per_chunk, window=window)
         return out[:, None].astype(q.dtype)
 
     bq, bh, ppt = autotune.paged_blocks(
         kind, s, h, d, page_size, p, overrides=grid_blocks)
-    on_vpu = kind == "decode" and not quantized
+    if bh % group:
+        raise ValueError(f"a head block of {bh} splits a group of "
+                         f"{group} query heads")
+    on_vpu = kind == "decode" and not quantized and group == 1
     # [B, S, 1]: a (block_q, 1) column is a legal tile (the last dim is
     # the whole array's) and broadcasts against the [block_q, T] scores
     pos = positions.astype(jnp.int32)[..., None]
@@ -395,6 +491,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_len, valid,
         return (bi, qb, _i0())
 
     def page_of(bi, pt, j, ts, cs):
+        if window is not None:
+            return ts[bi, pt * ppt + j]      # a ring: every entry
         # a tile past the row's context names the row's last live page
         # again: the kernel skips it, and a block whose index did not
         # change is not copied, so a dead tile moves no bytes
@@ -414,8 +512,10 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_len, valid,
 
     q_spec = pl.BlockSpec((None, bq, bh, d), q_map)
     row_spec = pl.BlockSpec((None, bq, 1), row_map)
-    tile_spec = lambda j: pl.BlockSpec((None, page_size, bh, d), kv_map(j))  # noqa: E731
-    scale_spec = lambda j: pl.BlockSpec((None, page_size, bh), sc_map(j))  # noqa: E731
+    tile_spec = lambda j: pl.BlockSpec(  # noqa: E731
+        (None, page_size, bh // group, d), kv_map(j))
+    scale_spec = lambda j: pl.BlockSpec(  # noqa: E731
+        (None, page_size, bh // group), sc_map(j))
 
     in_specs = [q_spec, row_spec, row_spec]
     inputs = [q, pos, val]
@@ -432,7 +532,9 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_len, valid,
 
     kernel = functools.partial(
         _paged_kernel, page_size=page_size, ppt=ppt,
-        scale=float(scale), kind=kind, quantized=quantized)
+        scale=float(scale), kind=kind, quantized=quantized,
+        **({} if group == 1 else {"group": group}),
+        **({} if window is None else {"window": int(window)}))
     stat = (bh, 1) if on_vpu else (bh, bq, LANES)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(

@@ -264,15 +264,18 @@ class _Request:
 class _ActiveSeq:
     """One live lane of the in-flight decode batch."""
 
-    __slots__ = ("req", "slot", "pages", "ctx", "max_total",
-                 "last_token", "n_generated", "last_emit_t",
+    __slots__ = ("req", "slot", "pages", "window_pages", "ctx",
+                 "max_total", "last_token", "n_generated", "last_emit_t",
                  "prefix_len", "history", "draft_ctx", "published")
 
     def __init__(self, req: _Request, slot: int, pages: List[int],
-                 max_total: int, prefix_len: int = 0):
+                 max_total: int, prefix_len: int = 0,
+                 window_pages: Sequence[int] = ()):
         self.req = req
         self.slot = slot
         self.pages = pages              # prefix pages first, private after
+        # the ring of the window layers (kv_cache.py): its own, unshared
+        self.window_pages = list(window_pages)
         self.ctx = len(req.prompt)      # tokens whose K/V is cached
         self.max_total = max_total      # prompt + generation budget
         self.last_token = -1
@@ -294,6 +297,11 @@ _EVENTS = ("submitted", "completed", "rejected", "timed_out",
 # RecordEvent and a cumulative sum in DecodeMetrics
 PHASES = ("admit", "prefill", "decode_feeds", "decode_call",
           "sample_emit", "bookkeeping", "wait")
+
+# tokens (rows x sequence bucket) one prefill dispatch may hold: the
+# smallest power of two that leaves the benchmark's GPT servers'
+# dispatches as they were (they reach 16 x 1024 and 32 x 768)
+PREFILL_TOKEN_BUDGET = 32768
 
 
 def _tail_buckets_ms(lo: float = 0.05, hi: float = 60e3,
@@ -427,9 +435,17 @@ class DecodeMetrics:
         self._loop_s = dict.fromkeys(PHASES, 0.0)
         self._phase: Optional[str] = None     # the open phase
         self._phase_t0 = 0.0                  # perf_counter at its start
-        self._prefill = {"prompt_tokens": 0, "padded_tokens": 0}
+        self._prefill = {"prompt_tokens": 0, "padded_tokens": 0,
+                         "split_groups": 0}
         self._prefill_by_shape: Dict[str, int] = {}
         self._prefill_call_s_by_shape: Dict[str, float] = {}
+        # an expert layer's counters (model_fns._aux_out), cumulative;
+        # None until a program of a model with experts has run
+        self._moe: Optional[Dict[str, int]] = None
+        # the cache manager's numbers by kind of layer
+        # (``PagedKVCache.by_kind``), as of the last admission, release
+        # or decode step
+        self._kv_by_kind: Dict[str, dict] = {}
 
     def switch_phase(self, phase: Optional[str], now: float):
         """The loop thread leaves its open phase at ``now`` (a
@@ -456,6 +472,28 @@ class DecodeMetrics:
                 self._prefill_call_s_by_shape.get(shape, 0.0) \
                 + call_ms / 1e3
 
+    def observe_prefill_split(self):
+        """A prefill group held more rows than one dispatch may."""
+        with self._lock:
+            self._prefill["split_groups"] += 1
+
+    def observe_moe(self, aux: dict):
+        """What one prefill or decode program's expert layers counted
+        (host whole numbers, each summed over its layers), added to
+        the cumulative ``engine.moe``: assignments, experts that got a
+        row, and the rows of each layer's fullest expert."""
+        with self._lock:
+            m = self._moe
+            if m is None:
+                m = self._moe = {"assignments": 0, "experts_touched": 0,
+                                 "max_expert_load": 0}
+            for key in m:
+                m[key] += int(aux["moe_" + key])
+
+    def set_kv_by_kind(self, by_kind: dict):
+        with self._lock:
+            self._kv_by_kind = by_kind
+
     def observe_queue_wait(self, waits_s: Sequence[float]):
         self._h_qwait.observe_many([w * 1e3 for w in waits_s])
 
@@ -475,13 +513,17 @@ class DecodeMetrics:
             # the open phase so far: the phases then sum to the loop
             # thread's wall time at this very moment
             loop_s[self._phase] += time.perf_counter() - self._phase_t0
-        return {"loop_s": loop_s,
-                "prefill": dict(self._prefill,
-                                by_shape=dict(self._prefill_by_shape),
-                                call_s_by_shape=dict(
-                                    self._prefill_call_s_by_shape)),
-                "stream_stall_ms": self._cumulative(self._h_stall),
-                "queue_wait_ms": self._cumulative(self._h_qwait)}
+        out = {"loop_s": loop_s,
+               "prefill": dict(self._prefill,
+                               by_shape=dict(self._prefill_by_shape),
+                               call_s_by_shape=dict(
+                                   self._prefill_call_s_by_shape)),
+               "kv": self._kv_by_kind,
+               "stream_stall_ms": self._cumulative(self._h_stall),
+               "queue_wait_ms": self._cumulative(self._h_qwait)}
+        if self._moe is not None:
+            out["moe"] = dict(self._moe)
+        return out
 
     def count(self, event: str, n: int = 1):
         self._events[event].inc(n)
@@ -601,6 +643,7 @@ class GenerationServer:
                  scheduler=None,
                  mesh=None,
                  use_pallas: Optional[bool] = None,
+                 prefill_token_budget: Optional[int] = None,
                  start: bool = True):
         model.eval()
         self.model = model
@@ -616,7 +659,9 @@ class GenerationServer:
         else:
             self.serving_mesh = mesh if isinstance(mesh, ServingMesh) \
                 else ServingMesh(mesh)
-        self.serving_mesh.validate_heads(int(spec["num_heads"]))
+        # the pools shard by K/V heads, which a model may have fewer of
+        nh = int(spec.get("num_kv_heads", spec["num_heads"]))
+        self.serving_mesh.validate_heads(nh)
         self.max_batch = int(max_batch if max_batch is not None
                              else _flag("FLAGS_decode_max_batch", 8))
         self.page_size = int(page_size if page_size is not None
@@ -625,7 +670,6 @@ class GenerationServer:
                                else spec["max_seq_len"])
         self.eos_token_id = eos_token_id
         self.pad_token_id = int(pad_token_id)
-        self.pages_per_seq = -(-self.max_seq_len // self.page_size)
         # quantized-pool knob: read ONCE here and pinned for the
         # engine's lifetime (it joins the decoder's geometry
         # fingerprint, so warmup manifests and the persistent compile
@@ -636,7 +680,7 @@ class GenerationServer:
         from ...ops.paged_attention import kv_pool_bytes, resolve_kv_dtype
         self.kv_dtype = str(_flag("FLAGS_decode_kv_dtype", "") or "")
         resolve_kv_dtype(self.kv_dtype)   # fail fast on a typo'd dtype
-        nh, hd = spec["num_heads"], spec["head_dim"]
+        hd = spec["head_dim"]
         f32_tok = kv_pool_bytes(1, 1, nh, hd, None)
         cur_tok = kv_pool_bytes(1, 1, nh, hd, self.kv_dtype or None)
         # sub-f32 pools grant extra resident sequences for the SAME
@@ -647,7 +691,8 @@ class GenerationServer:
         if num_pages is None:
             num_pages = int(_flag("FLAGS_decode_kv_pages", 0))
         if not num_pages:
-            num_pages = 1 + (self.max_batch * self.pages_per_seq
+            num_pages = 1 + (self.max_batch
+                             * -(-self.max_seq_len // self.page_size)
                              * self.kv_capacity_factor)
         self.default_timeout_ms = default_timeout_ms \
             if default_timeout_ms is not None \
@@ -664,6 +709,23 @@ class GenerationServer:
         self.policy = ShapeBucketPolicy(
             max_batch_size=self.max_batch, pad_batch=True,
             seq_buckets=seq_buckets, seq_axis=1)
+        # a prefill dispatch holds a bounded number of tokens: a group
+        # of more rows than the budget buys at the largest bucket is
+        # split (one cap for every bucket, so the programs a server
+        # can dispatch are rows <= cap x the buckets)
+        self.prefill_token_budget = int(
+            prefill_token_budget if prefill_token_budget is not None
+            else PREFILL_TOKEN_BUDGET)
+        self.prefill_rows_cap = max(
+            1, self.prefill_token_budget // max(self.policy.seq_buckets))
+        self.kv = PagedKVCache(model, num_pages=int(num_pages),
+                               page_size=self.page_size,
+                               dtype=self.kv_dtype or None,
+                               mesh=self.serving_mesh,
+                               max_batch=self.max_batch)
+        # columns of a sequence's block-table row: the cache manager's
+        # to say (the full layers' table, then the window layers' ring)
+        self.pages_per_seq = self.kv.table_width(self.max_seq_len)
         self.decoder = CachedDecoder(
             model, max_batch=self.max_batch, page_size=self.page_size,
             pages_per_seq=self.pages_per_seq, donate=donate,
@@ -671,13 +733,15 @@ class GenerationServer:
             use_pallas=use_pallas, kv_dtype=self.kv_dtype,
             mesh=self.serving_mesh)
         self.use_pallas = self.decoder.use_pallas
-        self.kv = PagedKVCache(model, num_pages=int(num_pages),
-                               page_size=self.page_size,
-                               dtype=self.kv_dtype or None,
-                               mesh=self.serving_mesh)
         # ---- shared-prefix KV reuse (radix index over full pages)
+        if prefix_cache and self.kv.window is not None:
+            raise ValueError(
+                "prefix_cache=True with a model whose window layers "
+                "keep a ring a sequence: a ring cannot be shared (what "
+                "it held of the prefix is overwritten)")
         if prefix_cache is None:
-            prefix_cache = bool(_flag("FLAGS_decode_prefix_cache", True))
+            prefix_cache = self.kv.window is None and bool(
+                _flag("FLAGS_decode_prefix_cache", True))
         self.prefix = PrefixCache(self.kv) if prefix_cache else None
         # ---- speculative decoding (draft proposes, target verifies)
         self.spec_k = int(spec_k if spec_k is not None
@@ -717,6 +781,7 @@ class GenerationServer:
         self.metrics = DecodeMetrics(name, self.max_batch,
                                      self.kv.capacity)
         self.metrics.set_kv_pages(0, self.kv.capacity)
+        self.metrics.set_kv_by_kind(self.kv.by_kind())
         self.metrics.set_kv_pool_bytes(self.kv.pool_bytes(),
                                        self.kv_dtype)
         # ---- multi-tenant admission (scheduling subsystem): an
@@ -1201,20 +1266,44 @@ class GenerationServer:
             self._span.begin()
         return now
 
+    def _note_kv_pages(self):
+        """The page gauges, after the cache manager's books moved."""
+        self.metrics.set_kv_pages(self.kv.used_pages, self.kv.free_pages)
+        self.metrics.set_kv_by_kind(self.kv.by_kind())
+
+    def _fetch_aux(self) -> dict:
+        """What the decoder's last program counted beside its logits,
+        as host numbers, into the cumulative ``engine.moe`` counters
+        ({} for a model that counts nothing). Called once the logits
+        are here, so the scalars are too."""
+        aux = self.decoder.last_aux
+        if not aux:
+            return {}
+        import jax
+        aux = jax.device_get(aux)
+        self.metrics.observe_moe(aux)
+        return aux
+
     def _enter_decode_call(self, active: List[_ActiveSeq],
-                           context_tokens: int,
+                           ctx_after: np.ndarray,
                            stall_t0: Optional[float]):
         """``engine::decode_call`` opens, and says on the span what
         the step's attention has to read: ``context_tokens`` cached
         positions over ``active`` live lanes (the work behind
-        ``paged_attn_roofline``, on the trace's own clock).
+        ``paged_attn_roofline``, on the trace's own clock), and for a
+        model with window layers ``window_context_tokens``, the part of
+        each lane's context such a layer reads (``min(ctx, window)``).
         ``stall_t0`` is when this loop iteration began, if it began
         with a live stream (the end of the last iteration's
         sample_emit): what every running stream has waited since, the
         admissions and prefill groups of this iteration, is the
         stream stall."""
-        now = self._enter_phase("decode_call", active=len(active),
-                                context_tokens=int(context_tokens))
+        args = {"active": len(active),
+                "context_tokens": int(ctx_after.sum())}
+        if self.kv.window is not None:
+            args["window_context_tokens"] = int(
+                np.minimum(ctx_after, self.kv.window).sum())
+        now = self._enter_phase("decode_call", **args)
         if stall_t0 is not None:
             self.metrics.observe_stream_stall((now - stall_t0) * 1e3)
 
@@ -1415,6 +1504,16 @@ class GenerationServer:
                         pages = self.kv.alloc(need)
                 if pages is None:
                     break       # head-of-line until pages free up
+                # the window layers' ring: sized for every lane, so it
+                # is there whenever a slot is
+                try:
+                    ring = self.kv.alloc_window(max_total)
+                except BaseException:
+                    self.kv.release(pages)
+                    raise
+                if ring is None:
+                    self.kv.release(pages)
+                    break
                 # exception barrier (pdlint RP001): between taking the
                 # reservation and publishing it into self._slots no
                 # failure may keep the references — a leaked page never
@@ -1424,6 +1523,7 @@ class GenerationServer:
                     self.kv.retain(shared)
                 except BaseException:
                     self.kv.release(pages)
+                    self.kv.release_window(ring)
                     raise
                 try:
                     if self.prefix is not None:
@@ -1433,17 +1533,20 @@ class GenerationServer:
                     del self._queue[idx]
                     slot = free_slots.pop(0)
                     seq = _ActiveSeq(req, slot, shared + pages,
-                                     max_total, prefix_len=matched)
+                                     max_total, prefix_len=matched,
+                                     window_pages=ring)
                     self._slots[slot] = seq
                 except BaseException:
                     self.kv.release(shared + pages)
+                    self.kv.release_window(ring)
                     raise
-                self._tables[slot, :] = 0
-                self._tables[slot, :len(seq.pages)] = seq.pages
+                self.kv.fill_row(self._tables[slot], seq.pages,
+                                 seq.window_pages)
+                # its prompt's pages past the ring are never written
+                self.kv.note_positions(0, len(req.prompt))
                 admitted.append(seq)
             if admitted:
-                self.metrics.set_kv_pages(self.kv.used_pages,
-                                          self.kv.free_pages)
+                self._note_kv_pages()
         if not admitted:
             return
         # submit to slot: the clock is read after the admission loop
@@ -1482,10 +1585,14 @@ class GenerationServer:
                 bucket = min(self.policy.bucket_seq(n_suffix),
                              self.max_seq_len)
                 cold.setdefault(bucket, []).append(seq)
-        for bucket, seqs in cold.items():
-            self._prefill_group(seqs, bucket)
-        for bucket, seqs in hot.items():
-            self._prefill_chunked_group(seqs, bucket)
+        cap = self.prefill_rows_cap
+        for groups, prefill in ((cold, self._prefill_group),
+                                (hot, self._prefill_chunked_group)):
+            for bucket, seqs in groups.items():
+                if len(seqs) > cap:
+                    self.metrics.observe_prefill_split()
+                for i in range(0, len(seqs), cap):
+                    prefill(seqs[i:i + cap], bucket)
 
     def _prefill_group(self, seqs: List[_ActiveSeq], seq_bucket: int):
         rows = len(seqs)
@@ -1507,6 +1614,7 @@ class GenerationServer:
                 ids, lens, tables, self.kv.k, self.kv.v)
             logits = np.asarray(last)
             self.kv.k, self.kv.v = k2, v2
+            self._fetch_aux()
             if self.draft is not None:
                 dlast, dk, dv, dfresh = self.draft.prefill(
                     ids, lens, tables, self._draft_k, self._draft_v)
@@ -1662,7 +1770,7 @@ class GenerationServer:
             ctx_after[seq.slot] = seq.ctx + 1
         # the context this step's attention reads: each live lane's
         # cached positions, the one it writes among them
-        self._enter_decode_call(active, int(ctx_after.sum()), stall_t0)
+        self._enter_decode_call(active, ctx_after, stall_t0)
         t_wall = time.time_ns()
         t0 = time.perf_counter()
         try:
@@ -1670,6 +1778,11 @@ class GenerationServer:
                 tokens, positions, mask, ctx_after, self._tables,
                 self.kv.k, self.kv.v)
             logits = np.asarray(logits)
+            aux = self._fetch_aux()
+            if aux:
+                # what this step's expert layers read, on its own span
+                self._span.set_arg("experts_touched",
+                                   int(aux["moe_experts_touched"]))
         except Exception as e:  # noqa: BLE001 - fault barrier: a model
             # error fails the in-flight sequences, not the engine
             with self._lock:
@@ -1715,7 +1828,10 @@ class GenerationServer:
             ((self.max_batch,), "bool"), ((self.max_batch,), "int32"),
             (self._tables.shape, "int32")])
         for seq in active:
+            self.kv.note_positions(seq.ctx, seq.ctx + 1)
             seq.ctx += 1
+        if self.kv.window is not None:
+            self._note_kv_pages()
         self._enter_phase("sample_emit")
         self._sample_and_emit(active,
                               logits[[s.slot for s in active]])
@@ -1738,7 +1854,8 @@ class GenerationServer:
         # (the verify window reads each lane's context and its k + 1
         # new positions)
         self._enter_decode_call(
-            active, sum(s.ctx + k + 1 for s in active), stall_t0)
+            active, np.asarray([s.ctx + k + 1 for s in active], np.int64),
+            stall_t0)
         t_wall = time.time_ns()
         t0 = time.perf_counter()
         try:
@@ -2005,8 +2122,9 @@ class GenerationServer:
         self._slots[seq.slot] = None
         self._tables[seq.slot, :] = 0
         freed = self.kv.release(seq.pages)
+        self.kv.release_window(seq.window_pages)
+        seq.window_pages = []
         self.metrics.observe_evictions(freed)
         self.metrics.count(event)
-        self.metrics.set_kv_pages(self.kv.used_pages,
-                                  self.kv.free_pages)
+        self._note_kv_pages()
         self._lock.notify_all()

@@ -5,8 +5,10 @@ then exposes exactly two device entry points:
 
 - ``prefill(ids, prompt_lens, tables, pools)`` — one forward over a
   padded prompt window that writes the prompt's K/V into the paged
-  pools and returns only the last real position's logits ``[B, vocab]``
-  (the full ``[B, S, vocab]`` tensor never crosses to the host);
+  pools and returns only the last real position's logits ``[B, vocab]``:
+  the head is applied to that position's hidden state alone
+  (``GPTKVCache.logits_at``), so no ``[B, S, vocab]`` array exists in
+  the program;
 - ``decode(tokens, positions, active, ctx, tables, pools)`` — the
   fixed-shape ``[max_batch, 1]`` decode step: append one position per
   live lane, attend through the block tables, return ``[B, vocab]``;
@@ -93,7 +95,10 @@ class CachedDecoder:
         # single-shard path byte-for-byte — fingerprints, cache keys,
         # placement (regression-tested).
         smesh = mesh if isinstance(mesh, ServingMesh) else ServingMesh(mesh)
-        smesh.validate_heads(int(model.kv_cache_spec()["num_heads"]))
+        spec = model.kv_cache_spec()
+        # the pools shard by K/V heads, which a model may have fewer of
+        smesh.validate_heads(int(spec.get("num_kv_heads",
+                                          spec["num_heads"])))
         self.serving_mesh = smesh
         # pinned at construction (both join the geometry fingerprint,
         # so warmup manifests and the persistent compile cache key on
@@ -121,6 +126,9 @@ class CachedDecoder:
         self._donate = bool(donate) if donate is not None \
             else jax.default_backend() != "cpu"
         self._fp: Optional[str] = None
+        # what the last prefill or decode counted beside its logits
+        # (``_aux_out``: device scalars, {} for a model without experts)
+        self.last_aux: dict = {}
         # per-signature AOT memo; False marks "tried, unavailable"
         self._aot: Dict[tuple, object] = {}
         self.compiled_signatures = set()    # (site, shape-sig) seen
@@ -149,6 +157,31 @@ class CachedDecoder:
 
         from ...distributed.shard import constrain_batch
 
+        def _one_position(logits, idx):
+            """``[B, vocab]`` from a model that honoured
+            ``cache.logits_at`` (``[B, 1, vocab]``), or from one that
+            computed every position (``[B, S, vocab]``: a model of
+            another family whose forward ignores the field)."""
+            if logits.shape[1] == 1:
+                return logits[:, 0]
+            at = jnp.broadcast_to(idx[:, None, None],
+                                  (logits.shape[0], 1, logits.shape[-1]))
+            return jnp.take_along_axis(logits, at, axis=1)[:, 0]
+
+        def _aux_out(aux):
+            """What the forward counted beside the logits, as whole
+            numbers fetched with them, each summed over the expert
+            layers: assignments, experts that got a row, and the rows
+            of each layer's fullest expert (over assignments / experts
+            it says how uneven the routing is). Empty for a model that
+            counts nothing: the program then has no such output."""
+            if not aux or "moe" not in aux:
+                return {}
+            per_layer = jnp.sum(jnp.stack(aux["moe"]), axis=0)   # [3]
+            return {"moe_assignments": per_layer[0],
+                    "moe_experts_touched": per_layer[1],
+                    "moe_max_expert_load": per_layer[2]}
+
         def _make_fns(use_pallas):
             # One closure set per kernel path. The real jits below bind
             # the pinned ``self.use_pallas``; the shadow-verification
@@ -171,25 +204,23 @@ class CachedDecoder:
                 positions = jnp.broadcast_to(
                     jnp.arange(s, dtype=jnp.int32), (b, s))
                 valid = positions < prompt_lens[:, None]
+                # only the last REAL position's logits are computed
+                idx = jnp.clip(prompt_lens.astype(jnp.int32) - 1, 0,
+                               s - 1)
                 cache = GPTKVCache(
                     "prefill", page,
                     jax.tree_util.tree_map(_wrap, k),
                     jax.tree_util.tree_map(_wrap, v),
                     _wrap(tables), _wrap(prompt_lens), _wrap(valid),
                     _wrap(positions), use_pallas=use_pallas,
-                    mesh=live_mesh)
+                    mesh=live_mesh, logits_at=_wrap(idx), aux={})
                 logits, (k2, v2) = functional_call(
                     model, params, buffers, ids, cache=cache,
                     training=False)
-                # only the last REAL position's logits leave the device
-                idx = jnp.clip(prompt_lens.astype(jnp.int32) - 1, 0,
-                               s - 1)
-                idx = jnp.broadcast_to(idx[:, None, None],
-                                       (b, 1, logits.shape[-1]))
-                last = jnp.take_along_axis(logits, idx, axis=1)[:, 0]
                 # tied lm_head leaves logits vocab-sharded under mp:
                 # gather ONCE inside the executable, not on the host
-                return smesh.replicate(last), k2, v2
+                return (smesh.replicate(_one_position(logits, idx)), k2,
+                        v2, _aux_out(cache.aux))
 
             def _decode(params, buffers, tokens, positions, active,
                         ctx, tables, k, v):
@@ -204,18 +235,20 @@ class CachedDecoder:
                     jax.tree_util.tree_map(_wrap, v),
                     _wrap(tables), _wrap(ctx), _wrap(active[:, None]),
                     _wrap(positions[:, None].astype(jnp.int32)),
-                    use_pallas=use_pallas, mesh=live_mesh)
+                    use_pallas=use_pallas, mesh=live_mesh, aux={})
                 logits, (k2, v2) = functional_call(
                     model, params, buffers, ids, cache=cache,
                     training=False)
-                return smesh.replicate(logits[:, 0]), k2, v2
+                return (smesh.replicate(logits[:, 0]), k2, v2,
+                        _aux_out(cache.aux))
 
             def _chunked(params, buffers, ids, start, seg_lens, tables,
-                         k, v):
+                         k, v, logits_at=None):
                 # suffix prefill / speculative verify window: per-row
                 # starting positions; attention reaches the cached
                 # prefix through the block tables (kind="chunked").
-                # Returns ALL window logits [B, S, vocab].
+                # Returns ALL window logits [B, S, vocab], or those of
+                # the one position a row that ``logits_at`` names.
                 ids = constrain_batch(ids)
                 k = smesh.constrain_pools(k)
                 v = smesh.constrain_pools(v)
@@ -234,7 +267,8 @@ class CachedDecoder:
                     jax.tree_util.tree_map(_wrap, v),
                     _wrap(tables), _wrap(ctx), _wrap(valid),
                     _wrap(positions), use_pallas=use_pallas,
-                    mesh=live_mesh)
+                    mesh=live_mesh, logits_at=None if logits_at is None
+                    else _wrap(logits_at))
                 logits, (k2, v2) = functional_call(
                     model, params, buffers, ids, cache=cache,
                     training=False)
@@ -242,14 +276,12 @@ class CachedDecoder:
 
             def _prefill_chunked(params, buffers, ids, start, seg_lens,
                                  tables, k, v):
+                idx = jnp.clip(seg_lens.astype(jnp.int32) - 1, 0,
+                               ids.shape[1] - 1)
                 logits, k2, v2 = _chunked(params, buffers, ids, start,
-                                          seg_lens, tables, k, v)
-                b, s = ids.shape
-                idx = jnp.clip(seg_lens.astype(jnp.int32) - 1, 0, s - 1)
-                idx = jnp.broadcast_to(idx[:, None, None],
-                                       (b, 1, logits.shape[-1]))
-                last = jnp.take_along_axis(logits, idx, axis=1)[:, 0]
-                return last, k2, v2
+                                          seg_lens, tables, k, v,
+                                          logits_at=idx)
+                return _one_position(logits, idx), k2, v2
 
             return {"prefill": _prefill, "decode": _decode,
                     "chunked": _prefill_chunked, "verify": _chunked}
@@ -307,8 +339,10 @@ class CachedDecoder:
                     # included (v4: named scopes in paged and flash
                     # attention; v5: the decode kernel that loops over
                     # a lane's live pages, and prefill that keeps
-                    # attention_bshd beside it)
-                    "kv_dtype": self.kv_dtype, "v": 5}
+                    # attention_bshd beside it; v6: the head on one
+                    # position a row in prefill, counters beside the
+                    # logits, windows and grouped heads in the ops)
+                    "kv_dtype": self.kv_dtype, "v": 6}
             # mesh axes + weight spec-tree hash join the geometry ONLY
             # when the mesh is live: an inert (None / 1-device) mesh
             # must reuse today's fingerprints byte-for-byte, and a mesh
@@ -506,8 +540,9 @@ class CachedDecoder:
                 np.ascontiguousarray(ids, np.int64),
                 np.ascontiguousarray(prompt_lens, np.int32),
                 np.ascontiguousarray(tables, np.int32), k, v)
-        (last, k2, v2), fresh = self._dispatch(
+        (last, k2, v2, aux), fresh = self._dispatch(
             "generate_prefill", self._prefill_jit, args)
+        self.last_aux = aux
         return last, k2, v2, fresh
 
     def prefill_chunked(self, ids: np.ndarray, start: np.ndarray,
@@ -558,6 +593,7 @@ class CachedDecoder:
                 np.ascontiguousarray(active, bool),
                 np.ascontiguousarray(ctx, np.int32),
                 np.ascontiguousarray(tables, np.int32), k, v)
-        (logits, k2, v2), fresh = self._dispatch(
+        (logits, k2, v2, aux), fresh = self._dispatch(
             "generate_decode", self._decode_jit, args)
+        self.last_aux = aux
         return logits, k2, v2, fresh

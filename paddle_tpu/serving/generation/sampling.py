@@ -28,14 +28,18 @@ def sample_next_tokens(logits: np.ndarray,
     RandomState so interleaved batches stay per-request deterministic),
     else from ``rng``. Returns ``[B]`` int64.
     """
-    logits = np.asarray(logits, dtype=np.float64)
+    logits = np.asarray(logits)
     b = logits.shape[0]
     temps = np.broadcast_to(np.asarray(temperature, np.float64),
                             (b,)).copy()
-    out = logits.argmax(-1).astype(np.int64)
     sampled = temps > 0.0
     if not sampled.any():
-        return out
+        # all greedy: the argmax of the logits as they came (widening a
+        # [lanes, vocab] array to float64 first changes no comparison
+        # and costs more than the selection)
+        return logits.argmax(-1).astype(np.int64)
+    logits = logits.astype(np.float64)
+    out = logits.argmax(-1).astype(np.int64)
     if uniforms is None:
         if rng is None:
             rng = np.random.RandomState(0)

@@ -277,3 +277,86 @@ def test_illegal_head_block_is_refused_by_name(one_chip, compiled_kernels):
         jax.jit(functools.partial(
             paged_attention, page_size=PAGE, kind="decode",
             scale=D ** -0.5, block_h=1)).lower(*args)
+
+
+# ------------- grouped K/V heads, sliding windows, experts (PR 29) ------
+# the expert configuration's serving geometry: 28 query heads over 4 K/V
+# heads of 128, bf16 pools, 32 lanes; a full layer's 576-page tables over
+# 18,433 pages, a window layer's ring of 257 over 1 + 32 x 257
+GQA_HEADS, GQA_KV_HEADS, GQA_LANES, WINDOW = 28, 4, 32, 4096
+GQA_KINDS = {"full": (None, 576, 18433), "window": (WINDOW, 257, 8225)}
+
+
+@pytest.mark.parametrize("kind", sorted(GQA_KINDS))
+def test_grouped_decode_compiles_for_both_kinds_of_layer(
+        one_chip, compiled_kernels, kind):
+    """``paged_attention_update`` as the expert configuration's decode
+    step calls it: the page-copying kernel with a group of 7 query
+    heads a K/V head on the MXU, over the whole context and over a
+    ring of the last 4096 positions; the bf16 pool reaches the kernel
+    as it lies (no copy of it in the program)."""
+    from paddle_tpu.ops.paged_attention import paged_attention_update
+    window, width, pages = GQA_KINDS[kind]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((pages, PAGE, GQA_KV_HEADS, D), jnp.bfloat16)
+    kv = sds((GQA_LANES, 1, GQA_KV_HEADS, D), jnp.bfloat16)
+    # the pools donated, as the decoder's entry points donate them: an
+    # undonated pool is copied once before the in-place write
+    text = jax.jit(
+        functools.partial(paged_attention_update, page_size=PAGE,
+                          kind="decode", window=window),
+        donate_argnums=(3, 4)).lower(
+        sds((GQA_LANES, 1, GQA_HEADS, D), jnp.bfloat16), kv, kv, pool, pool,
+        sds((GQA_LANES, width), jnp.int32), sds((GQA_LANES,), jnp.int32),
+        sds((GQA_LANES, 1), jnp.bool_), sds((GQA_LANES, 1), jnp.int32)
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert not [line for line in text.splitlines()
+                if f"bf16[{pages},{PAGE},{GQA_KV_HEADS},{D}]" in line
+                and " copy(" in line]
+
+
+@pytest.mark.parametrize("window", [None, WINDOW], ids=["full", "window"])
+@pytest.mark.parametrize("rows,seq", [(4, 8192), (1, 2048)])
+def test_grouped_prefill_compiles_for_both_kinds_of_layer(
+        one_chip, compiled_kernels, window, rows, seq):
+    """The prefill side: ``attention_bshd`` takes the forward-only flash
+    kernel (a K/V head copied once for its 7 query heads; blocks wholly
+    before a row's window skipped) at the largest and the smallest
+    shape the kernel serves."""
+    from paddle_tpu.ops.flash_attention import attention_bshd
+
+    def sds(heads):
+        return jax.ShapeDtypeStruct((rows, seq, heads, D), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    _compile(functools.partial(attention_bshd, causal=True,
+                               scale=D ** -0.5, window=window),
+             sds(GQA_HEADS), sds(GQA_KV_HEADS), sds(GQA_KV_HEADS))
+
+
+@pytest.mark.parametrize("tokens", [32, 4 * 8192],
+                         ids=["decode", "prefill"])
+def test_expert_layer_compiles_to_grouped_matmuls(one_chip, tokens):
+    """``ops.moe.dropless_moe`` at the published widths (64 experts of
+    2560 x 768, 6 a token): each of the three ``ragged_dot`` products
+    becomes XLA's grouped-matmul kernel, which walks the sorted rows
+    and reads the experts that own some: no dense product over all 64
+    experts is in the program."""
+    from paddle_tpu.ops.moe import dropless_moe
+    hidden, experts, inter = 2560, 64, 768
+
+    def sds(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(functools.partial(dropless_moe, top_k=6)).lower(
+        sds(tokens, hidden), sds(tokens, hidden), sds(hidden, experts),
+        sds(experts, hidden, inter), sds(experts, hidden, inter),
+        sds(experts, inter, hidden), valid=sds(tokens, dtype=jnp.bool_)
+    ).compile()
+    text = compiled.as_text()
+    assert text.count('op_name="ragged-dot-none"') == 3
+    assert f"[{tokens * 6},{experts}," not in text

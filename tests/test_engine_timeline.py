@@ -128,10 +128,15 @@ def test_queue_wait_counts_from_submit_to_slot(lockstep_run):
 
 def test_only_read_counters_are_kept(lockstep_run):
     eng = lockstep_run["snap1"]["engine"]
-    assert set(eng) == {"loop_s", "prefill", "stream_stall_ms",
+    # "moe" joins them for a model with expert layers (PR 29)
+    assert set(eng) == {"loop_s", "prefill", "kv", "stream_stall_ms",
                         "queue_wait_ms"}
     assert set(eng["prefill"]) == {"prompt_tokens", "padded_tokens",
-                                   "by_shape", "call_s_by_shape"}
+                                   "split_groups", "by_shape",
+                                   "call_s_by_shape"}
+    assert set(eng["kv"]) == {"pages_in_use", "capacity",
+                              "window_pages_recycled"}
+    assert set(eng["kv"]["capacity"]) == {"full"}
     assert set(eng["queue_wait_ms"]) == {"le", "counts"}
 
 
