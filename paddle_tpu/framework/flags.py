@@ -234,7 +234,7 @@ define_flag("FLAGS_serving_mesh_mp", 1,
             "tensor-parallel degree of ONE serving replica: the "
             "replica spans a {'mp': N} device mesh, weights shard by "
             "the shard.py rule tables, paged KV pools shard along the "
-            "heads axis ([pages, page_size, heads/mp, head_dim]), and "
+            "heads ([pages, page_size, heads/mp * head_dim]), and "
             "the prefill/chunked/verify/decode entry points run GSPMD-"
             "partitioned across all N chips (serving/mesh.py). <=1 = "
             "single-shard (today's exact behavior: same fingerprints, "

@@ -267,9 +267,10 @@ class GPTKVCache:
     Tensors (under jit via ``jit.functional.functional_call``):
 
     - ``k``/``v``: per-layer pools — a list of ``[num_pages, page_size,
-      heads, head_dim]`` Tensors for the module stack, or ONE stacked
-      ``[num_layers, num_pages, page_size, heads, head_dim]`` Tensor
-      for ``GPTStackedTransformer``. Page 0 is the trash page
+      heads * head_dim]`` Tensors for the module stack, or ONE stacked
+      ``[num_layers, num_pages, page_size, heads * head_dim]`` Tensor
+      for ``GPTStackedTransformer`` (the layout is
+      ``ops.paged_attention.kv_pool_shape``'s). Page 0 is the trash page
       (ops/paged_attention.py).
     - ``block_tables``: [B, P] int32 logical-page → pool-page map.
     - ``ctx_len``: [B] int32 visible context length INCLUDING the
@@ -1135,9 +1136,11 @@ class GPTForCausalLM(Layer):
     def init_kv_pools(self, num_pages: int, page_size: int, dtype=None,
                       window_pages=None):
         """Zeroed paged K/V pools shaped for this model: a list of
-        per-layer ``[num_pages, page_size, kv_heads, head_dim]`` arrays
-        (module stack) or one stacked ``[L, ...]`` pair (stacked
-        decoder). Page 0 is the trash page and is never allocated.
+        per-layer arrays in the pool's resident layout
+        (``ops.paged_attention.kv_pool_shape``: heads folded into the
+        lanes) for the module stack, or one stacked ``[L, ...]`` pair
+        (stacked decoder). Page 0 is the trash page and is never
+        allocated.
         A layer that attends a window only has ``window_pages`` pages
         (its sequences hold a ring of ``ring_pages`` each, however long
         they grow; ``PagedKVCache`` sizes it from the lanes), or
@@ -1146,31 +1149,21 @@ class GPTForCausalLM(Layer):
         ``(int8 values, f32 per-slot-per-head scales)`` tuples (see
         ops.paged_attention for the quantized-pool contract). Returns
         raw jax arrays ``(k, v)`` — engine plumbing, not Tensors."""
-        import jax.numpy as jnp
+        from ..ops.paged_attention import new_kv_pool
         cfg = self.config
-        nh, hd = cfg.num_kv_heads, cfg.head_dim
+        dtype = dtype or \
+            self.gpt.embeddings.word_embeddings.weight._data.dtype
+
+        def mk(n, lead=()):
+            return new_kv_pool(n, page_size, cfg.num_kv_heads,
+                               cfg.head_dim, dtype, lead=lead)
+
+        if cfg.stacked:
+            lead = (cfg.num_layers,)
+            return mk(num_pages, lead), mk(num_pages, lead)
         pages = [int(window_pages or num_pages) if cfg.layer_window(i)
                  else int(num_pages) for i in range(cfg.num_layers)]
-
-        def shape_of(n):
-            return (n, int(page_size), nh, hd)
-
-        if isinstance(dtype, str) and dtype == "int8":
-            def mk(shape):
-                return (jnp.zeros(shape, jnp.int8),
-                        jnp.zeros(shape[:-1], jnp.float32))
-
-            if cfg.stacked:
-                lead = (cfg.num_layers,) + shape_of(int(num_pages))
-                return mk(lead), mk(lead)
-            return ([mk(shape_of(n)) for n in pages],
-                    [mk(shape_of(n)) for n in pages])
-        dt = dtype or self.gpt.embeddings.word_embeddings.weight._data.dtype
-        if cfg.stacked:
-            lead = (cfg.num_layers,) + shape_of(int(num_pages))
-            return jnp.zeros(lead, dt), jnp.zeros(lead, dt)
-        return ([jnp.zeros(shape_of(n), dt) for n in pages],
-                [jnp.zeros(shape_of(n), dt) for n in pages])
+        return [mk(n) for n in pages], [mk(n) for n in pages]
 
     def kv_cache_spec(self, kv_dtype: str = "") -> dict:
         """Geometry the decode engine sizes its cache from.

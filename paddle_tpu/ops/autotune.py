@@ -121,11 +121,14 @@ def pick(kernel: str, key: Sequence, candidates: List[Tuple],
 #
 # Their blocks are constants: a serving call sits inside a trace, where
 # nothing can be timed, and a server does not time kernels when it
-# starts. What is here was found on a v5e at the benchmark's two serving
-# shapes, 24 layers a call, f32 pools of 16-slot pages (PERF.md section
-# 6, PR 26): 16 heads of 128 over 128-page tables, 7 of 16 lanes live
-# with 2,815 positions; 16 heads of 64 over 64-page tables, 32 lanes
-# with 12,893.
+# starts. What is here was found on a v5e at the benchmark's serving
+# shapes, 16-slot pages, one call of one layer over pools laid out
+# ``[pages, page_size, kv_heads * head_dim]`` (PERF.md section 6, PR 30;
+# PR 26 for the pools that had a heads axis): 16 heads of 128 in f32
+# over 128-page tables, 7 of 16 lanes live with 2,697 positions; 16
+# heads of 64 in f32 over 64-page tables, 32 lanes with 12,810; 28 query
+# heads over 4 K/V heads of 128 in bf16, 32 lanes with 125,150 positions
+# (576-page tables) or the last 4,096 of each (a ring of 257).
 
 # VMEM the decode kernel's page buffers may take (K and V, two slots
 # each): half the 16 MiB a v5e program gets unasked.
@@ -133,37 +136,51 @@ PAGED_DECODE_VMEM_BYTES = 8 * 1024 * 1024
 # Pages a decode grid program copies and attends at a time. More pages
 # amortize the loop and the running softmax's rescale over more
 # positions; fewer waste less of a lane's last, part-filled chunk.
-# 128-wide heads, ms a call: 1 page 2.27, 2 1.84, 4 1.83, 8 1.90, 16 2.10
-# (the gather: 63.4).
+# One row for all heads on the vector unit, ms a call: 64-wide heads
+# 2 pages 0.232, 4 0.191, 8 0.204, 16 0.212 (the grid kernel 0.485);
+# 128-wide heads 4 0.074, 8 0.076 (the grid kernel 0.380).
 PAGED_DECODE_PAGES_PER_CHUNK = 4
+# The same where a K/V head serves a group of query heads and the MXU
+# carries a chunk in two products: a longer chunk is a better-filled
+# product. ms a call, whole context / ring: 4 pages 1.040 / 0.944,
+# 8 0.728 / 0.687, 16 0.596 / 0.576, 32 0.531 / 0.529, 64 0.515 /
+# 0.524; the kernel's page copies are unrolled, so it compiles in 1.8,
+# 2.7, 4.4, 7.6-14.3 and 15-27 s: 16 has nine tenths of the gain.
+PAGED_DECODE_PAGES_PER_CHUNK_MXU = 16
 
 
-def paged_decode_chunk(page_size: int, rows: int, lanes: int,
-                       itemsize: int, pages_per_seq: int,
+def paged_decode_chunk(page_size: int, lanes: int, itemsize: int,
+                       pages_per_seq: int, on_mxu: bool = False,
                        override=None) -> int:
     """Pages per chunk of the decode kernel for a pool whose page is
-    ``[page_size, rows, lanes]``: the constant, cut to what the table
-    holds and to what fits the kernel's VMEM (a buffer row pads to the
-    native tile: 8 sublanes of 32 bits, 128 lanes)."""
+    ``[page_size, lanes]`` (``paged_attention.kv_pool_shape``): the
+    constant of whoever attends a chunk (the vector unit, or the MXU
+    where there are groups of query heads), cut to what the table holds
+    and to what fits the kernel's VMEM (a buffer pads to the native
+    tile: 8 sublanes of 32 bits, 128 lanes)."""
     if override is not None:
         if override < 1:
             raise ValueError(f"pages_per_chunk must be >= 1, got "
                              f"{override}")
         return int(override)
     sublanes = 8 * max(1, 4 // itemsize)
-    page_bytes = (page_size * -(-rows // sublanes) * sublanes
+    page_bytes = (-(-page_size // sublanes) * sublanes
                   * -(-lanes // 128) * 128 * itemsize)
     fit = PAGED_DECODE_VMEM_BYTES // (4 * page_bytes)
-    return int(max(1, min(PAGED_DECODE_PAGES_PER_CHUNK, pages_per_seq,
-                          fit)))
+    chunk = PAGED_DECODE_PAGES_PER_CHUNK_MXU if on_mxu \
+        else PAGED_DECODE_PAGES_PER_CHUNK
+    return int(max(1, min(chunk, pages_per_seq, fit)))
 
 
 # Pages a grid-kernel program attends for decode (a tile of that many
 # table-steered blocks): fewer grid steps a lane, more blocks a step to
-# steer. 64-wide heads, ms a call: 1 page 17.6, 2 15.0, 4 14.0, 8 14.3,
-# 16 14.9 (the gather: 63.3). At 128-wide heads the same kernel takes
-# 8.7 at 4 pages, the page-copying kernel 1.83: every table slot, live
-# or dead, costs this kernel some 0.18 us of block steering.
+# steer. 64-wide heads, ms a call of 24 layers (PR 26): 1 page 17.6, 2
+# 15.0, 4 14.0, 8 14.3, 16 14.9 (the gather: 63.3). At 128-wide heads
+# the same kernel takes 8.7 at 4 pages, the page-copying kernel 1.83:
+# every table slot, live or dead, costs this kernel some 0.18 us of
+# block steering. Over the folded pool (PR 30) it reads the same: 0.485
+# and 0.380 ms a layer, so it serves quantized pools, windows of
+# queries and rows narrower than a lane tile, and no float decode.
 PAGED_DECODE_PAGES_PER_TILE = 4
 
 
@@ -176,10 +193,12 @@ def paged_block_candidates(kind: str, seq: int, num_heads: int,
 
     Legal means what the TPU lowering takes: the last two dims of a
     block are the whole array's or multiples of the native (8, 128)
-    tile. Heads is the second-minor dim of the q and pool blocks
-    ``[.., block_h, D]`` and the minor dim of a quantized pool's scale
-    blocks ``[page_size, block_h]``; block_q is the second-minor dim
-    of the ``[block_q, 1]`` position/valid columns.
+    tile. Heads is the second-minor dim of the q blocks
+    ``[.., block_h, D]``, the major factor of the minor dim of the pool
+    blocks ``[page_size, block_h * D]`` and the minor dim of a
+    quantized pool's scale blocks ``[page_size, block_h]``; block_q is
+    the second-minor dim of the ``[block_q, 1]`` position/valid
+    columns.
 
     - block_q tiles the query window (decode is structurally S == 1;
       chunked windows tile at multiples of 8 up to the 128-row register

@@ -7,7 +7,14 @@ tokens and a sequence's pages can be scattered anywhere in the pool.
 A per-sequence int32 *block table* maps logical position ``p`` to pool
 page ``table[p // page_size]`` at offset ``p % page_size``.
 
-Pool layout is ``[num_pages, page_size, num_heads, head_dim]``.
+Pool layout is ``[num_pages, page_size, kv_heads * head_dim]``: the heads
+are folded into the lane axis, head ``h`` in lanes ``h * head_dim ..
+(h + 1) * head_dim - 1`` (``kv_pool_shape``; this module alone knows
+it). A row is whole 128-lane tiles for every configuration served, so
+the TPU's compiler keeps the pool row-major and no program relayouts it
+at its boundary; with the heads an axis of their own it made the *page*
+axis minor for 64-wide heads and every scatter and every kernel paid a
+transposing copy of the whole pool (PERF.md section 6, PR 30).
 **Page 0 is the trash page**: the allocator never hands it out, and
 every masked write (padding positions, dead batch lanes) is redirected
 to a slot inside it, so scatter shapes stay fixed — the XLA-friendly
@@ -20,7 +27,7 @@ These are pure jax functions; the model layer threads them through
 ``serving.generation.model_fns``.
 
 Quantized pools (``FLAGS_decode_kv_dtype=int8``): a pool is then the
-2-tuple ``(values int8 [num_pages, page_size, H, D], scales f32
+2-tuple ``(values int8 [num_pages, page_size, H * D], scales f32
 [num_pages, page_size, H])`` — symmetric absmax quantization over
 head_dim, one scale per written (slot, head). Scales are per-slot
 rather than per-whole-page because pages fill incrementally (one token
@@ -43,10 +50,44 @@ import jax.numpy as jnp
 __all__ = ["flat_slots", "write_pool", "gather_pool",
            "paged_attention_update", "kernel_by_default", "is_quantized_pool",
            "quantize_kv_rows", "dequantize_kv", "kv_pool_bytes",
-           "resolve_kv_dtype"]
+           "resolve_kv_dtype", "kv_pool_shape", "kv_pool_heads_spec",
+           "new_kv_pool"]
 
 KINDS = ("prefill", "decode", "chunked")
 KV_DTYPES = ("", "float32", "bfloat16", "int8")
+
+
+# ------------------------------------------------------- the pool layout
+
+def kv_pool_shape(num_pages, page_size, kv_heads, head_dim=None):
+    """Shape of one resident K or V pool: ``[num_pages, page_size,
+    kv_heads * head_dim]``, head ``h`` in lanes ``h * head_dim .. (h +
+    1) * head_dim - 1``. Without ``head_dim``: a quantized pool's scale
+    plane, ``[num_pages, page_size, kv_heads]``."""
+    return (int(num_pages), int(page_size),
+            int(kv_heads) * int(1 if head_dim is None else head_dim))
+
+
+def kv_pool_heads_spec(ndim: int, axis: str = "mp") -> tuple:
+    """Partition spec of a pool leaf of ``ndim`` dimensions (leading
+    layer axis or not, values or scale plane) with its heads split over
+    ``axis``: heads are the major factor of the last dimension of both,
+    so equal contiguous blocks of it are whole heads (``kv_heads %
+    mp == 0``)."""
+    return (None,) * (ndim - 1) + (axis,)
+
+
+def new_kv_pool(num_pages, page_size, kv_heads, head_dim, dtype, lead=()):
+    """One zeroed pool (K or V) in the resident layout, under ``lead``
+    leading axes (a stacked decoder's layers). ``dtype`` "int8" gives
+    the quantized ``(values, scales)`` pair."""
+    shape = tuple(lead) + kv_pool_shape(num_pages, page_size, kv_heads,
+                                        head_dim)
+    if isinstance(dtype, str) and dtype == "int8":
+        return (jnp.zeros(shape, jnp.int8),
+                jnp.zeros(tuple(lead) + kv_pool_shape(
+                    num_pages, page_size, kv_heads), jnp.float32))
+    return jnp.zeros(shape, dtype)
 
 
 # ------------------------------------------------------- quantized pools
@@ -100,11 +141,13 @@ def kv_pool_bytes(num_pages, page_size, num_heads, head_dim,
     including the per-slot-per-head f32 scales when quantized. The
     shardcheck KV-bytes projection and the engine's pool gauges both
     size from here so they can never disagree."""
-    slots = int(num_pages) * int(page_size)
+    values = math.prod(kv_pool_shape(num_pages, page_size, num_heads,
+                                     head_dim))
     if (kv_dtype or "") == "int8":
-        return slots * num_heads * (head_dim * 1 + 4)
+        return values + 4 * math.prod(
+            kv_pool_shape(num_pages, page_size, num_heads))
     dt = jnp.dtype(kv_dtype) if kv_dtype else jnp.dtype(jnp.float32)
-    return slots * num_heads * head_dim * dt.itemsize
+    return values * dt.itemsize
 
 
 def flat_slots(block_tables, positions, valid, page_size: int,
@@ -142,17 +185,19 @@ def _scatter_flat(arr, slots, rows):
 def write_pool(pool, slots, kv):
     """Scatter ``kv`` rows into the flattened pool at ``slots``.
 
-    pool: [num_pages, page_size, H, D] (or the quantized (values,
-    scales) tuple — this is the quantize-on-write point); slots: [N]
-    int32 flat slot ids; kv: [N, H, D]. Duplicate trash-slot writes are
-    unordered — the trash page holds garbage by contract.
+    pool: [num_pages, page_size, H * D] (or the quantized (values,
+    scales) tuple — this is the quantize-on-write point, a head at a
+    time, before the heads are folded); slots: [N] int32 flat slot ids;
+    kv: [N, H, D]. Duplicate trash-slot writes are unordered — the
+    trash page holds garbage by contract.
     """
+    n = kv.shape[0]
     if is_quantized_pool(pool):
         values, scales = pool
         qrows, srows = quantize_kv_rows(kv)
-        return (_scatter_flat(values, slots, qrows),
+        return (_scatter_flat(values, slots, qrows.reshape(n, -1)),
                 _scatter_flat(scales, slots, srows))
-    return _scatter_flat(pool, slots, kv)
+    return _scatter_flat(pool, slots, kv.reshape(n, -1))
 
 
 def _gather_flat(arr, block_tables):
@@ -164,21 +209,23 @@ def _gather_flat(arr, block_tables):
     return flat[slots.reshape(b, -1)]
 
 
-def gather_pool(pool, block_tables, out_dtype=None):
-    """Gather every slot a block table can address, in logical order.
+def gather_pool(pool, block_tables, kv_heads: int, out_dtype=None):
+    """Gather every slot a block table can address, in logical order,
+    the heads unfolded again.
 
-    pool: [num_pages, page_size, H, D] (or the quantized tuple — this
+    pool: [num_pages, page_size, H * D] (or the quantized tuple — this
     is the pure-JAX dequantize-on-read point); block_tables: [B, P]
-    int32. Returns [B, P * page_size, H, D] where gathered row ``t``
-    holds logical position ``t`` of each sequence (pages are
+    int32; kv_heads: H. Returns [B, P * page_size, H, D] where gathered
+    row ``t`` holds logical position ``t`` of each sequence (pages are
     table-ordered).
     """
-    if is_quantized_pool(pool):
-        values, scales = pool
-        vg = _gather_flat(values, block_tables)
-        sg = _gather_flat(scales, block_tables)
-        return dequantize_kv(vg, sg, out_dtype or jnp.float32)
-    return _gather_flat(pool, block_tables)
+    quantized = is_quantized_pool(pool)
+    vg = _gather_flat(pool[0] if quantized else pool, block_tables)
+    vg = vg.reshape(*vg.shape[:2], kv_heads, vg.shape[2] // kv_heads)
+    if quantized:
+        return dequantize_kv(vg, _gather_flat(pool[1], block_tables),
+                             out_dtype or jnp.float32)
+    return vg
 
 
 def ring_pages(window: int, page_size: int) -> int:
@@ -268,13 +315,11 @@ def _chunked_attention(q, ks, vs, positions, valid, scale):
 
 
 def _pool_shard_spec(pool):
-    """shard_map PartitionSpecs for one pool pytree, heads axis on
-    'mp': values [..., P, page, H, D] → P(None, None, 'mp', None),
-    quantized scales [..., P, page, H] → P(None, None, 'mp')."""
+    """shard_map PartitionSpecs for one pool pytree, its heads on
+    'mp' (``kv_pool_heads_spec``), values and quantized scales alike."""
     from jax.sharding import PartitionSpec as P
-    if is_quantized_pool(pool):
-        return (P(None, None, "mp", None), P(None, None, "mp"))
-    return P(None, None, "mp", None)
+    return jax.tree_util.tree_map(
+        lambda a: P(*kv_pool_heads_spec(a.ndim)), pool)
 
 
 def _mesh_mp(mesh):
@@ -290,12 +335,13 @@ def _sharded_paged_attention(mesh, q, k_pool, v_pool, block_tables,
                              ctx_len, valid, positions, *, page_size,
                              kind, scale, window=None):
     """Per-shard Pallas dispatch under a live mp mesh: every rank runs
-    the fused kernel on ITS heads-axis block of q and the pools
-    (attention is embarrassingly parallel over heads — no collective in
-    the body). GSPMD cannot partition a pallas_call itself, so this
-    shard_map wrapper is what keeps the fused path available under
-    tensor parallelism; the kernel sees local shapes, so the autotune
-    block table picks tile sizes for H/mp heads."""
+    the fused kernel on ITS heads: a block of q's heads axis, the same
+    heads' lanes of the pools' rows (attention is embarrassingly
+    parallel over heads — no collective in the body). GSPMD cannot
+    partition a pallas_call itself, so this shard_map wrapper is what
+    keeps the fused path available under tensor parallelism; the
+    kernel sees local shapes, so the autotune block table picks tile
+    sizes for H/mp heads."""
     from jax.sharding import PartitionSpec as P
 
     from ..distributed.mesh_utils import manual_shard_map
@@ -322,8 +368,8 @@ def paged_attention_update(q, k, v, k_pool, v_pool, block_tables,
 
     q: [B, S, Hq, D], k/v: [B, S, H, D] (S = prompt window for prefill,
     1 for decode; Hq a multiple of H: KV head ``g`` serves query heads
-    ``g*G .. g*G+G-1``); k_pool/v_pool: [num_pages, page_size, H, D];
-    block_tables: [B, P];
+    ``g*G .. g*G+G-1``); k_pool/v_pool: [num_pages, page_size, H * D]
+    (``kv_pool_shape``); block_tables: [B, P];
     ctx_len: [B] visible length including the positions written here;
     valid: [B, S] which fed positions are real; positions: [B, S]
     absolute positions being written.
@@ -443,8 +489,8 @@ def _paged_attention_update(q, k, v, k_pool, v_pool, block_tables,
         slots = flat_slots(block_tables, positions, keep, page_size,
                            ring=ring)
         slots_flat = slots.reshape(b * s)
-        # the pool scatter stays OUTSIDE shard_map: the flat
-        # [P*page, H, D] reshape keeps the heads dim intact, so GSPMD
+        # the pool scatter stays OUTSIDE shard_map: heads are the
+        # major factor of the flat [P*page, H*D] rows' lanes, so GSPMD
         # partitions the write from the pool's committed sharding
         k_pool = write_pool(k_pool, slots_flat,
                             k.reshape(b * s, *k.shape[2:]))
@@ -479,8 +525,8 @@ def _paged_attention_update(q, k, v, k_pool, v_pool, block_tables,
                     scale=scale, window=window)
         return out, k_pool, v_pool
     with jax.named_scope("kv_gather"):
-        ks = gather_pool(k_pool, block_tables, out_dtype=q.dtype)
-        vs = gather_pool(v_pool, block_tables, out_dtype=q.dtype)
+        ks = gather_pool(k_pool, block_tables, kv_heads, out_dtype=q.dtype)
+        vs = gather_pool(v_pool, block_tables, kv_heads, out_dtype=q.dtype)
     with jax.named_scope("attend"):
         if ring or kv_heads != heads:
             # where each gathered slot lies in the sequence: table

@@ -21,19 +21,26 @@ Two kernels:
   (``memory_space=pl.ANY``). A dead page costs nothing, a dead lane one
   empty grid step. One query row against a page is 0.5 FLOP a byte, so
   the scores and the weighted sum run on the vector unit in f32: no
-  M = 1 matmul. It takes heads of whole 128-lane rows
-  (``decode_copies_pages``): Mosaic slices an HBM array along whole lane
-  tiles only.
+  M = 1 matmul. A page is a dense ``[page_size, kv_heads * head_dim]``
+  tile of the pool (``paged_attention.kv_pool_shape``), so heads of any
+  width whose row is whole 128-lane tiles take it
+  (``decode_copies_pages``); a head is never sliced out of a page: the
+  arithmetic keeps every head's numbers in that head's lanes
+  (``_head_sums``; for a group of query heads a K/V head, one
+  block-diagonal product a chunk, ``_attend_group``).
 - ``_paged_kernel`` — ``chunked`` windows (shared-prefix suffix prefill
   and the spec-decode verify window, mask ``t <= positions[b, s] &
-  valid[b, s]``), and decode over quantized pools or narrower heads
-  (float pools on the vector unit as above, a page a ``BlockSpec``).
+  valid[b, s]``), and decode over quantized pools, rows that are not
+  whole lane tiles, or named grid blocks (float pools on the vector
+  unit as above, a page a ``BlockSpec``).
   Grid ``(B, H/block_h, S/block_q, P/pages_per_tile)``:
   the innermost (arbitrary) dimension walks a sequence's logical pages,
   the scalar-prefetched block table steers each page tile's
   ``BlockSpec`` index map at the pool (a tile past the context names
   the last live page again and is neither copied nor attended), scores
-  and weighted sum of a window are MXU dots per head. A K-tile spanning ``pages_per_tile`` pages is realized
+  and weighted sum of a window are MXU dots per head, a head's
+  columns a lane slice of the page tile. A K-tile spanning
+  ``pages_per_tile`` pages is realized
   by passing the pool that many times with per-subtile index maps
   (table-adjacent pages are not pool-adjacent, so one BlockSpec cannot
   cover them).
@@ -97,38 +104,72 @@ def supported(q, k_pool, block_tables, page_size: int, kind: str) -> bool:
     if q.ndim != 4 or page_size < 2:
         return False
     values = k_pool[0] if is_quantized_pool(k_pool) else k_pool
-    return values.ndim == 4 and block_tables.ndim == 2
+    return values.ndim == 3 and block_tables.ndim == 2
 
 
-def _attend_one_row(q, k, v, live, m, l, acc):
+def _head_sums(x, head_dim):
+    """``x`` [T, H * head_dim], heads folded into the lanes: in every
+    lane the sum over its head's ``head_dim`` lanes. Lanes are taken a
+    group at a time, the narrowest run of whole lane tiles that is
+    whole heads (one head of 128 or 256, two of 64), so no head is cut
+    out of its tile: a group is summed over its lanes, under a mask for
+    each head that shares it."""
+    lanes = x.shape[1]
+    if head_dim % LANES == 0:
+        width = head_dim
+    elif LANES % head_dim == 0 and lanes % LANES == 0:
+        width = LANES
+    else:
+        width = lanes
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    groups = []
+    for lo in range(0, lanes, width):
+        g = x[:, lo:lo + width]
+        if width == head_dim:
+            groups.append(jnp.broadcast_to(
+                jnp.sum(g, axis=1, keepdims=True), g.shape))
+            continue
+        out = jnp.zeros_like(g)
+        for h in range(width // head_dim):
+            mine = (lane >= h * head_dim) & (lane < (h + 1) * head_dim)
+            out = jnp.where(mine, jnp.sum(jnp.where(mine, g, 0.0), axis=1,
+                                          keepdims=True), out)
+        groups.append(out)
+    return groups[0] if len(groups) == 1 else \
+        jnp.concatenate(groups, axis=1)
+
+
+def _attend_one_row(q, k, v, live, m, l, acc, head_dim):
     """One query row of every head against a tile of positions, folded
     into a running softmax: at 0.5 FLOP a byte the vector unit carries
     it in f32 for all heads at once, where the MXU would be fed two
-    matmuls of one row a head. q: [H, D], scaled; k/v: [T, H, D] f32;
-    live: [T, 1, 1] bool; m/l: [H, 1]; acc: [H, D]. Returns the new
-    (m, l, acc)."""
-    s = jnp.sum(k * q[None], axis=-1, keepdims=True)         # [T, H, 1]
-    s = jnp.where(live, s, jnp.float32(NEG_INF))
-    m_new = jnp.maximum(m, jnp.max(s, axis=0))
-    p = jnp.exp(s - m_new[None])
+    matmuls of one row a head. Everything is laid out as a pool row is,
+    a head's numbers in (and repeated over) that head's lanes. q:
+    [1, H*D], scaled; k/v: [T, H*D] f32; live: [T, 1] bool; m/l/acc:
+    [1, H*D]. Returns the new (m, l, acc)."""
+    s = jnp.where(live, _head_sums(k * q, head_dim), jnp.float32(NEG_INF))
+    m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+    p = jnp.exp(s - m_new)
     alpha = jnp.exp(m - m_new)
-    return (m_new, alpha * l + jnp.sum(p, axis=0),
-            alpha * acc + jnp.sum(p * v, axis=0))
+    return (m_new, alpha * l + jnp.sum(p, axis=0, keepdims=True),
+            alpha * acc + jnp.sum(p * v, axis=0, keepdims=True))
 
 
 def _paged_kernel(tables_ref, ctx_ref, q_ref, pos_ref, val_ref, *refs,
-                  page_size, ppt, scale, kind, quantized, group=1,
-                  window=None):
+                  page_size, ppt, scale, kind, quantized, head_dim,
+                  group=1, window=None):
     """Grid program for one (row, head-block, q-block, page-tile).
 
     Scalar prefetch: tables [B, P] i32 (also feeds the K/V index maps),
     ctx [B] i32. q_ref: [block_q, block_h, D]; pos/val: [block_q] i32;
-    then ``ppt`` K tiles [page_size, block_h, D] (+ ppt scale tiles
-    [page_size, block_h] when quantized), same for V; o_ref like q_ref;
-    scratch m/l [block_h, block_q, LANES] and acc [block_h, block_q, D]
-    carry the online softmax across the (sequential) page-tile dim
-    ([block_h, 1] and [block_h, D] for decode over float pools, whose
-    one row is attended for all heads at once). With ``group`` > 1 a
+    then ``ppt`` K tiles [page_size, block_h / group * D], a K/V head's
+    columns a lane slice (+ ppt scale tiles [page_size, block_h / group]
+    when quantized), same for V; o_ref like q_ref; scratch m/l
+    [block_h, block_q, LANES] and acc [block_h, block_q, D] carry the
+    online softmax across the (sequential) page-tile dim. Decode over
+    float pools attends its one row for all heads at once
+    (``_attend_one_row``): q_ref, o_ref and the scratch are then
+    [1, block_h * D], laid out as a pool row. With ``group`` > 1 a
     K/V tile holds ``block_h / group`` heads, each serving ``group``
     consecutive query heads. With ``window`` the table is a ring: tile
     entry ``r`` holds the newest logical page congruent to it, and a
@@ -146,7 +187,7 @@ def _paged_kernel(tables_ref, ctx_ref, q_ref, pos_ref, val_ref, *refs,
     b = pl.program_id(0)
     pt = pl.program_id(3)
     npt = pl.num_programs(3)
-    block_q, block_h, d = q_ref.shape
+    d = head_dim
     # _attend_one_row
     on_vpu = kind == "decode" and not quantized and group == 1
 
@@ -157,19 +198,22 @@ def _paged_kernel(tables_ref, ctx_ref, q_ref, pos_ref, val_ref, *refs,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     ctx_b = ctx_ref[b]
-    t_page = jax.lax.broadcasted_iota(jnp.int32, (block_q, page_size), 1)
+    if on_vpu:
+        q_all = q_ref[...].astype(jnp.float32) * jnp.float32(scale)
+        t_col = jax.lax.broadcasted_iota(jnp.int32, (page_size, 1), 0)
+    else:
+        block_q, block_h = q_ref.shape[0], q_ref.shape[1]
+        t_page = jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, page_size), 1)
     if kind == "chunked":
         pos = pos_ref[...]
         live = val_ref[...]
-    if on_vpu:
-        q_all = q_ref[0].astype(jnp.float32) * jnp.float32(scale)  # [bh, D]
-        t_col = jax.lax.broadcasted_iota(jnp.int32, (page_size, 1, 1), 0)
 
     def _vpu_page(j, start):
         m_ref[...], l_ref[...], acc_ref[...] = _attend_one_row(
             q_all, k_tiles[j][...].astype(jnp.float32),
             v_tiles[j][...].astype(jnp.float32), start + t_col < ctx_b,
-            m_ref[...], l_ref[...], acc_ref[...])
+            m_ref[...], l_ref[...], acc_ref[...], d)
 
     def _mxu_page(j, start):
         t_glob = start + t_page                              # [bq, T]
@@ -184,14 +228,13 @@ def _paged_kernel(tables_ref, ctx_ref, q_ref, pos_ref, val_ref, *refs,
         # static unroll over the head block: rank-2 dots only
         # (Mosaic's MXU path; no batched dot_general)
         for i in range(block_h):
-            k_t = k_tiles[j][:, i // group, :]               # [T, D]
-            v_t = v_tiles[j][:, i // group, :]
+            g = i // group
+            k_t = k_tiles[j][:, g * d:(g + 1) * d]           # [T, D]
+            v_t = v_tiles[j][:, g * d:(g + 1) * d]
             q_i = q_ref[:, i, :]                             # [bq, D]
             if quantized:
-                k_t = k_t.astype(jnp.float32) \
-                    * k_scales[j][:, i // group][:, None]
-                v_t = v_t.astype(jnp.float32) \
-                    * v_scales[j][:, i // group][:, None]
+                k_t = k_t.astype(jnp.float32) * k_scales[j][:, g][:, None]
+                v_t = v_t.astype(jnp.float32) * v_scales[j][:, g][:, None]
                 q_i = q_i.astype(jnp.float32)
             s = jax.lax.dot_general(
                 q_i, k_t, (((1,), (1,)), ((), ())),
@@ -236,30 +279,35 @@ def _paged_kernel(tables_ref, ctx_ref, q_ref, pos_ref, val_ref, *refs,
     def _emit():
         if on_vpu:
             l = jnp.maximum(l_ref[...], jnp.float32(1e-30))
-            o_ref[0] = acc_ref[...] / l
+            o_ref[...] = acc_ref[...] / l
             return
         for i in range(block_h):
             l = jnp.maximum(l_ref[i, :, 0], jnp.float32(1e-30))
             o_ref[:, i, :] = acc_ref[i] / l[:, None]
 
 
-def decode_copies_pages(head_dim: int, quantized: bool) -> bool:
+def decode_copies_pages(row_lanes: int, quantized: bool) -> bool:
     """Whether decode takes the kernel that copies a lane's live pages
-    itself (``_decode_kernel``): float pools whose head is whole
-    128-lane rows. Mosaic slices an HBM array along whole lane tiles
-    only, so a page of narrower heads can be copied by a ``BlockSpec``
-    alone, which is the grid kernel's; so is a quantized pool's."""
-    return not quantized and head_dim % LANES == 0
+    itself (``_decode_kernel``): float pools whose row (``kv_heads *
+    head_dim`` lanes) is whole 128-lane tiles, so that a page lands in
+    its buffer as dense tiles. A narrower row can be copied by a
+    ``BlockSpec`` alone, which is the grid kernel's; so is a quantized
+    pool's."""
+    return not quantized and row_lanes % LANES == 0
 
 
 def _attend_group(q, k, v, live, m, l, acc, scale):
-    """The ``G`` query rows one K/V head serves against a tile of
-    positions, folded into a running softmax: two MXU dots (``G`` rows
-    against the tile, the weights against V) with f32 accumulation.
-    q: [G, D] and k/v: [T, D], both in the pool's type; live: [1, T];
-    m/l: [G, 1]; acc: [G, D]. Returns the new (m, l, acc)."""
+    """Every query row against a tile of positions where a K/V head
+    serves a group of query heads, folded into a running softmax: two
+    MXU dots with f32 accumulation. A query row is laid out as a pool
+    row, its numbers in its K/V head's lanes and zeros in the others
+    (block-diagonal), so the product over the whole row is the product
+    with that head and nothing is sliced out of a page; likewise only
+    its K/V head's lanes of its ``acc`` row mean anything. q: [Hq, H*D]
+    and k/v: [T, H*D], both in the pool's type; live: [1, T]; m/l:
+    [Hq, 1]; acc: [Hq, H*D]. Returns the new (m, l, acc)."""
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)   # [G, T]
+                            preferred_element_type=jnp.float32)  # [Hq, T]
     s = jnp.where(live, s * jnp.float32(scale), jnp.float32(NEG_INF))
     m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
     p = jnp.exp(s - m_new)
@@ -272,21 +320,22 @@ def _attend_group(q, k, v, live, m, l, acc, scale):
 
 def _decode_kernel(tables_ref, ctx_ref, q_ref, k_hbm, v_hbm, o_ref,
                    kbuf, vbuf, ksem, vsem, *, page_size, chunk, scale,
-                   window=None):
+                   head_dim, window=None):
     """Grid program for one lane: every live page of its context, and
     no other, is copied HBM -> VMEM by this program's own DMAs (two
     slots: chunk c+1 is in flight while chunk c is attended), and the
     one query row meets it chunk by chunk (``_attend_one_row``; where
-    a K/V head serves a group of query heads, ``_attend_group`` a
-    head). A dead lane starts no copy and emits zeros. With ``window``
+    a K/V head serves a group of query heads, ``_attend_group``). A
+    dead lane starts no copy and emits zeros. With ``window``
     the live pages are those that hold the lane's last ``window``
     positions, and the table is a ring (logical page ``n`` in entry
     ``n % P``).
 
     Scalar prefetch: tables [B, P] i32, ctx [B] i32. q_ref/o_ref:
-    [H, D], or [H, G, D] for groups of G query heads a K/V head;
-    k_hbm/v_hbm: the whole pools [num_pages, page_size, H, D], left in
-    HBM; kbuf/vbuf: [2, chunk*page_size, H, D]; ksem/vsem: a DMA
+    [rows, H*D], laid out as a pool row: one row for all heads, or a
+    block-diagonal row a query head where there are groups;
+    k_hbm/v_hbm: the whole pools [num_pages, page_size, H*D], left in
+    HBM; kbuf/vbuf: [2, chunk*page_size, H*D]; ksem/vsem: a DMA
     semaphore a slot.
     """
     b = pl.program_id(0)
@@ -303,8 +352,7 @@ def _decode_kernel(tables_ref, ctx_ref, q_ref, k_hbm, v_hbm, o_ref,
         first_page = jnp.maximum(ctx - jnp.int32(window), 0) // jnp.int32(ps)
         n_pages = jnp.minimum(end_page - first_page, width)
     n_chunks = (n_pages + (chunk - 1)) // jnp.int32(chunk)
-    grouped = len(q_ref.shape) == 3
-    heads, d = q_ref.shape[0], q_ref.shape[-1]
+    grouped = q_ref.shape[0] > 1
 
     @pl.when(b == 0)
     def _init():
@@ -352,58 +400,73 @@ def _decode_kernel(tables_ref, ctx_ref, q_ref, k_hbm, v_hbm, o_ref,
         t0 = c * (chunk * ps)
         if window is not None:
             t0 = t0 + first_page * ps
-        if not grouped:
-            t = t0 + jax.lax.broadcasted_iota(
-                jnp.int32, (chunk * ps, 1, 1), 0)
-            live = t < ctx
-            if window is not None:
-                live = live & (t >= ctx - jnp.int32(window))
-            return _attend_one_row(q, kbuf[slot].astype(jnp.float32),
-                                   vbuf[slot].astype(jnp.float32), live,
-                                   *carry)
-        t = t0 + jax.lax.broadcasted_iota(jnp.int32, (1, chunk * ps), 1)
+        # positions down the sublanes beside a row of heads, along the
+        # lanes beside the MXU's [Hq, T] scores
+        t = t0 + jax.lax.broadcasted_iota(
+            jnp.int32, (1, chunk * ps) if grouped else (chunk * ps, 1),
+            1 if grouped else 0)
         live = t < ctx
         if window is not None:
             live = live & (t >= ctx - jnp.int32(window))
-        return tuple(
-            _attend_group(q[h], kbuf[slot, :, h, :], vbuf[slot, :, h, :],
-                          live, *carry[h], scale) for h in range(heads))
+        if grouped:
+            return _attend_group(q, kbuf[slot], vbuf[slot], live, *carry,
+                                 scale)
+        return _attend_one_row(q, kbuf[slot].astype(jnp.float32),
+                               vbuf[slot].astype(jnp.float32), live,
+                               *carry, head_dim)
 
-    rows = q_ref.shape[1:-1] if grouped else (heads,)
-    init = (jnp.full(rows + (1,), NEG_INF, jnp.float32),
-            jnp.zeros(rows + (1,), jnp.float32),
-            jnp.zeros(rows + (d,), jnp.float32))
-    if not grouped:
-        _, l, acc = jax.lax.fori_loop(0, n_chunks, _attend, init)
-        o_ref[...] = acc / jnp.maximum(l, jnp.float32(1e-30))
-        return
-    out = jax.lax.fori_loop(0, n_chunks, _attend, (init,) * heads)
-    for h, (_, l, acc) in enumerate(out):
-        o_ref[h] = acc / jnp.maximum(l, jnp.float32(1e-30))
+    rows, lanes = q_ref.shape
+    stat = (rows, 1) if grouped else (1, lanes)
+    _, l, acc = jax.lax.fori_loop(
+        0, n_chunks, _attend,
+        (jnp.full(stat, NEG_INF, jnp.float32),
+         jnp.zeros(stat, jnp.float32),
+         jnp.zeros((rows, lanes), jnp.float32)))
+    o_ref[...] = acc / jnp.maximum(l, jnp.float32(1e-30))
+
+
+def _fold_queries(q, kv_heads):
+    """q [B, Hq, D] laid out as pool rows. Hq == kv_heads: one row of
+    all heads, [B, 1, Hq*D]. A group of query heads a K/V head: a row a
+    query head, its numbers in its K/V head's lanes and zeros in the
+    others (block-diagonal), [B, Hq, kv_heads*D]."""
+    b, hq, d = q.shape
+    if hq == kv_heads:
+        return q.reshape(b, 1, hq * d)
+    own = jnp.eye(kv_heads, dtype=q.dtype)[:, None, :, None]
+    return (q.reshape(b, kv_heads, hq // kv_heads, 1, d) * own).reshape(
+        b, hq, kv_heads * d)
+
+
+def _unfold_outputs(out, hq, kv_heads):
+    """The inverse for what the kernel emits: [B, 1, Hq*D] or, with
+    groups, [B, Hq, kv_heads*D] of which a row's own K/V head's lanes
+    are its output. Returns [B, Hq, D]."""
+    b, lanes = out.shape[0], out.shape[2]
+    if hq == kv_heads:
+        return out.reshape(b, hq, lanes // hq)
+    d = lanes // kv_heads
+    return jnp.einsum(
+        "bhgkd,hk->bhgd",
+        out.reshape(b, kv_heads, hq // kv_heads, kv_heads, d),
+        jnp.eye(kv_heads, dtype=out.dtype)).reshape(b, hq, d)
 
 
 def _paged_decode(q, k_pool, v_pool, tables, ctx, *, page_size, scale,
                   pages_per_chunk, window=None):
     """The page-copying decode kernel: q [B, Hq, D], pools [num_pages,
-    page_size, H, D], Hq a multiple of H. Returns [B, Hq, D] f32."""
+    page_size, H*D], Hq a multiple of H. Returns [B, Hq, D] f32."""
     from . import autotune
 
     b, hq, d = q.shape
-    h = k_pool.shape[2]
+    lanes = k_pool.shape[2]
     chunk = autotune.paged_decode_chunk(
-        page_size, h, d, k_pool.dtype.itemsize, tables.shape[1],
-        override=pages_per_chunk)
-    if hq == h:
-        row_spec = pl.BlockSpec((None, h, d),
-                                lambda bi, ts, cs: (bi, _i0(), _i0()))
-    else:
-        # a K/V head's group of query heads is a leading index in the
-        # kernel, never a slice of sublanes
-        q = q.reshape(b, h, hq // h, d)
-        row_spec = pl.BlockSpec(
-            (None, h, hq // h, d),
-            lambda bi, ts, cs: (bi, _i0(), _i0(), _i0()))
-    buf = pltpu.VMEM((2, chunk * page_size, h, d), k_pool.dtype)
+        page_size, lanes, k_pool.dtype.itemsize, tables.shape[1],
+        on_mxu=hq * d != lanes, override=pages_per_chunk)
+    q = _fold_queries(q, lanes // d)
+    row_spec = pl.BlockSpec((None,) + q.shape[1:],
+                            lambda bi, ts, cs: (bi, _i0(), _i0()))
+    buf = pltpu.VMEM((2, chunk * page_size, lanes), k_pool.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2, grid=(b,),
         in_specs=[row_spec, pl.BlockSpec(memory_space=pl.ANY),
@@ -413,8 +476,8 @@ def _paged_decode(q, k_pool, v_pool, tables, ctx, *, page_size, scale,
                         pltpu.SemaphoreType.DMA((2,))])
     kernel = functools.partial(
         _decode_kernel, page_size=page_size, chunk=chunk,
-        scale=float(scale), **({} if window is None
-                               else {"window": int(window)}))
+        scale=float(scale), head_dim=d,
+        **({} if window is None else {"window": int(window)}))
 
     def _run(tables, ctx, q, k_pool, v_pool):
         return pl.pallas_call(
@@ -426,8 +489,9 @@ def _paged_decode(q, k_pool, v_pool, tables, ctx, *, page_size, scale,
             interpret=not _place.on_tpu(),
         )(tables, ctx, q, k_pool, v_pool)
 
-    return _inference_only(_run)(tables, ctx, q, k_pool, v_pool).reshape(
-        b, hq, d)
+    return _unfold_outputs(
+        _inference_only(_run)(tables, ctx, q, k_pool, v_pool), hq,
+        lanes // d)
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, ctx_len, valid,
@@ -436,7 +500,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_len, valid,
                     pages_per_chunk=None, window=None):
     """Fused read-through-table paged attention (decode/chunked).
 
-    q: [B, S, Hq, D]; pools: [num_pages, page_size, H, D] (or quantized
+    q: [B, S, Hq, D]; pools: [num_pages, page_size, H*D] (or quantized
     tuples), Hq a multiple of H (a K/V head serves a group of
     consecutive query heads); ``window`` as in
     ``paged_attention_update`` (the table is then a ring); block_tables: [B, P] i32 (entries must be valid pool page
@@ -457,9 +521,14 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_len, valid,
     tables = block_tables.astype(jnp.int32)
     ctx = ctx_len.astype(jnp.int32)
     quantized = is_quantized_pool(k_pool)
-    group = h // (k_pool[0] if quantized else k_pool).shape[2]
+    if quantized:
+        k_vals, k_sc = k_pool
+        v_vals, v_sc = v_pool
+    else:
+        k_vals, v_vals = k_pool, v_pool
+    group = h * d // k_vals.shape[2]
     grid_blocks = (block_q, block_h, pages_per_tile)
-    if kind == "decode" and decode_copies_pages(d, quantized) \
+    if kind == "decode" and decode_copies_pages(k_vals.shape[2], quantized) \
             and grid_blocks == (None,) * 3:
         out = _paged_decode(
             q[:, 0], k_pool, v_pool, tables, ctx, page_size=page_size,
@@ -476,12 +545,6 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_len, valid,
     # the whole array's) and broadcasts against the [block_q, T] scores
     pos = positions.astype(jnp.int32)[..., None]
     val = valid.astype(jnp.int32)[..., None]
-
-    if quantized:
-        k_vals, k_sc = k_pool
-        v_vals, v_sc = v_pool
-    else:
-        k_vals, v_vals = k_pool, v_pool
 
     # index maps (scalar-prefetch refs ride after the grid indices)
     def q_map(bi, hb, qb, pt, ts, cs):
@@ -501,21 +564,24 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_len, valid,
         return ts[bi, jnp.minimum(pt * ppt + j, last)]
 
     def kv_map(j):
-        def _map(bi, hb, qb, pt, ts, cs):
-            return (page_of(bi, pt, j, ts, cs), _i0(), hb, _i0())
-        return _map
-
-    def sc_map(j):
+        # pool values and scale planes alike: a page, whole, and the
+        # head block's share of its lanes
         def _map(bi, hb, qb, pt, ts, cs):
             return (page_of(bi, pt, j, ts, cs), _i0(), hb)
         return _map
 
-    q_spec = pl.BlockSpec((None, bq, bh, d), q_map)
+    if on_vpu:
+        # the one row of all the block's heads, laid out as a pool row
+        q = q.reshape(b, s, h * d)
+        q_spec = pl.BlockSpec((None, 1, bh * d),
+                              lambda bi, hb, qb, pt, ts, cs: (bi, qb, hb))
+    else:
+        q_spec = pl.BlockSpec((None, bq, bh, d), q_map)
     row_spec = pl.BlockSpec((None, bq, 1), row_map)
     tile_spec = lambda j: pl.BlockSpec(  # noqa: E731
-        (None, page_size, bh // group, d), kv_map(j))
+        (None, page_size, bh // group * d), kv_map(j))
     scale_spec = lambda j: pl.BlockSpec(  # noqa: E731
-        (None, page_size, bh // group), sc_map(j))
+        (None, page_size, bh // group), kv_map(j))
 
     in_specs = [q_spec, row_spec, row_spec]
     inputs = [q, pos, val]
@@ -532,21 +598,19 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_len, valid,
 
     kernel = functools.partial(
         _paged_kernel, page_size=page_size, ppt=ppt,
-        scale=float(scale), kind=kind, quantized=quantized,
+        scale=float(scale), kind=kind, quantized=quantized, head_dim=d,
         **({} if group == 1 else {"group": group}),
         **({} if window is None else {"window": int(window)}))
-    stat = (bh, 1) if on_vpu else (bh, bq, LANES)
+    if on_vpu:
+        scratch = [pltpu.VMEM((1, bh * d), jnp.float32)] * 3
+    else:
+        scratch = [pltpu.VMEM((bh, bq, LANES), jnp.float32)] * 2 \
+            + [pltpu.VMEM((bh, bq, d), jnp.float32)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, h // bh, s // bq, p // ppt),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, bq, bh, d), q_map),
-        scratch_shapes=[
-            pltpu.VMEM(stat, jnp.float32),
-            pltpu.VMEM(stat, jnp.float32),
-            pltpu.VMEM(stat[:-1] + (d,), jnp.float32),
-        ])
+        in_specs=in_specs, out_specs=q_spec, scratch_shapes=scratch)
 
     def _run(tables, ctx, *inputs):
         return pl.pallas_call(
@@ -555,7 +619,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_len, valid,
             # f32 out, cast by the caller below: Mosaic has no layout
             # for a 16-bit [block_q, D] row store when D is not a
             # whole 128-lane tile
-            out_shape=jax.ShapeDtypeStruct((b, s, h, d), jnp.float32),
+            out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "parallel",
                                      "arbitrary"),
@@ -563,7 +627,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_len, valid,
             interpret=not _place.on_tpu(),
         )(tables, ctx, *inputs)
 
-    return _inference_only(_run)(tables, ctx, *inputs).astype(q.dtype)
+    return _inference_only(_run)(tables, ctx, *inputs).reshape(
+        b, s, h, d).astype(q.dtype)
 
 
 def _inference_only(run):

@@ -19,7 +19,7 @@ Pieces:
   KV pools donated where the backend supports it, persistent-compile-
   cache AOT tier first.
 - ``PagedKVCache`` (kv_cache.py): preallocated per-layer
-  ``[num_pages, page_size, heads, head_dim]`` pools + the host page
+  ``[num_pages, page_size, heads * head_dim]`` pools + the host page
   allocator (page 0 reserved as the trash page for masked writes),
   REFCOUNTED so full pages can be shared across sequences.
 - ``PrefixCache`` (prefix_cache.py): radix index over immutable full

@@ -1,7 +1,7 @@
 """Paged KV cache: device pools + the host-side page allocator.
 
 The device side is a per-layer pool pytree (``model.init_kv_pools``)
-shaped ``[num_pages, page_size, heads, head_dim]`` whose contents the
+shaped ``[num_pages, page_size, heads * head_dim]`` whose contents the
 jitted prefill/decode steps update functionally (ops/paged_attention);
 the host side here owns which pages belong to whom: a free list, the
 per-slot page assignments, and the occupancy/eviction accounting. Page

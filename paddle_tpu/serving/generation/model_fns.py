@@ -341,8 +341,9 @@ class CachedDecoder:
                     # a lane's live pages, and prefill that keeps
                     # attention_bshd beside it; v6: the head on one
                     # position a row in prefill, counters beside the
-                    # logits, windows and grouped heads in the ops)
-                    "kv_dtype": self.kv_dtype, "v": 6}
+                    # logits, windows and grouped heads in the ops;
+                    # v7: the pools' heads folded into their lanes)
+                    "kv_dtype": self.kv_dtype, "v": 7}
             # mesh axes + weight spec-tree hash join the geometry ONLY
             # when the mesh is live: an inert (None / 1-device) mesh
             # must reuse today's fingerprints byte-for-byte, and a mesh

@@ -8,8 +8,8 @@ and from then on
   (``spec_tree`` — the same inference the training path uses), placed
   once with committed ``NamedSharding``s so GSPMD partitions every jit
   entry point from the operand layouts;
-- **paged KV pools** shard along the heads axis: each chip holds
-  ``[num_pages, page_size, heads/mp, head_dim]`` of every pool (the
+- **paged KV pools** shard along the heads: each chip holds
+  ``[num_pages, page_size, heads/mp * head_dim]`` of every pool (the
   host-side prefix-cache radix index, refcounts and block tables are
   layout-agnostic and ride unchanged — only device placement changes);
 - **activations** are constrained inside the prefill/chunked/verify/
@@ -122,31 +122,19 @@ class ServingMesh:
         return placed, bufs
 
     # ------------------------------------------------------- pool side
-    def _pool_leaf_spec(self, leaf) -> tuple:
-        """Heads-axis spec for one pool leaf. Value leaves end in
-        ``[..., heads, head_dim]``; a quantized pool's f32 scale planes
-        end in ``[..., heads]`` and are the only non-int8 leaves of an
-        int8 pool — classified per-leaf by dtype so stacked/per-layer
-        and quantized/plain pools all resolve without structure
-        knowledge."""
-        import numpy as np
-        if np.dtype(leaf.dtype) == np.int8 or not self._pool_quantized:
-            return (None,) * (leaf.ndim - 2) + ("mp", None)
-        return (None,) * (leaf.ndim - 1) + ("mp",)
-
     def pool_specs(self, pools):
-        """Matching pytree of specs for a pool pytree (normalized, so a
-        heads dim mp doesn't divide degrades to replication — but see
-        ``validate_heads``, which the engine calls first)."""
+        """Matching pytree of specs for a pool pytree: every leaf's
+        heads over 'mp' (``ops.paged_attention.kv_pool_heads_spec``
+        owns the layout: values and a quantized pool's scale planes,
+        stacked or a layer each), normalized, so a heads dim mp doesn't
+        divide degrades to replication — but see ``validate_heads``,
+        which the engine calls first."""
         import jax
         from ..distributed.shard import normalize_spec
-        leaves = jax.tree_util.tree_leaves(pools)
-        import numpy as np
-        self._pool_quantized = any(
-            np.dtype(a.dtype) == np.int8 for a in leaves)
+        from ..ops.paged_attention import kv_pool_heads_spec
         return jax.tree_util.tree_map(
-            lambda a: normalize_spec(self._pool_leaf_spec(a), self.mesh,
-                                     tuple(a.shape)),
+            lambda a: normalize_spec(kv_pool_heads_spec(a.ndim),
+                                     self.mesh, tuple(a.shape)),
             pools)
 
     def place_pools(self, k, v):

@@ -16,11 +16,14 @@ sees this process's CPU; the ``compiled_kernels`` fixture steers it
 from here.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.ops.paged_attention import kv_pool_shape
 
 # gpt3_1p3b attention geometry and the serving engine's defaults
 H, D, B, PAGE, SLOTS = 16, 128, 8, 16, 2048
@@ -115,10 +118,10 @@ def _paged_args(one_chip, kind, q_dtype, pool, seq, shape=(D, B, SLOTS)):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     if pool == "int8":
-        kv = (sds((num_pages, PAGE, H, d), jnp.int8),
-              sds((num_pages, PAGE, H), jnp.float32))
+        kv = (sds(kv_pool_shape(num_pages, PAGE, H, d), jnp.int8),
+              sds(kv_pool_shape(num_pages, PAGE, H), jnp.float32))
     else:
-        kv = sds((num_pages, PAGE, H, d), pool)
+        kv = sds(kv_pool_shape(num_pages, PAGE, H, d), pool)
     s = 1 if kind == "decode" else seq
     return (sds((b, s, H, d), q_dtype), kv, kv,
             sds((b, pages), jnp.int32), sds((b,), jnp.int32),
@@ -157,14 +160,14 @@ def test_paged_attention_compiles_at_the_serving_shapes(
         one_chip, compiled_kernels, kind, seq, shape):
     """Decode, a suffix-prefill window and the speculative-verify
     window over f32 pools at what the two serve cells run: 16 heads of
-    128 over 128-page tables at 16 lanes (decode: the page-copying
-    kernel), 16 heads of 64 over 64-page tables at 32 lanes (decode:
-    the grid kernel's vector-unit branch), with the block constants
-    ``ops/autotune.py`` holds."""
+    128 over 128-page tables at 16 lanes, 16 heads of 64 over 64-page
+    tables at 32 lanes (decode: the page-copying kernel for both, a
+    page a dense [16, 2048] or [16, 1024] tile), with the block
+    constants ``ops/autotune.py`` holds."""
     from paddle_tpu.ops.pallas_paged_attention import (
         decode_copies_pages, paged_attention)
     d, lanes, slots = SERVE_SHAPES[shape]
-    assert decode_copies_pages(d, False) == (shape == "1p3b")
+    assert decode_copies_pages(H * d, False)
     args = _paged_args(one_chip, kind, jnp.float32, jnp.float32, seq,
                        shape=(d, lanes, slots))
     _compile(functools.partial(paged_attention, page_size=PAGE, kind=kind,
@@ -179,10 +182,10 @@ def test_decode_pages_per_chunk_ladder_compiles(one_chip,
     ``paged_decode_chunk`` lets the constant take)."""
     from paddle_tpu.ops import autotune
     from paddle_tpu.ops.pallas_paged_attention import paged_attention
-    assert autotune.paged_decode_chunk(PAGE, H, D, 4, 128) == \
+    assert autotune.paged_decode_chunk(PAGE, H * D, 4, 128) == \
         autotune.PAGED_DECODE_PAGES_PER_CHUNK
-    assert autotune.paged_decode_chunk(PAGE, H, D, 4, 2) == 2
-    assert autotune.paged_decode_chunk(PAGE, H, 4 * D, 4, 128) <= 4
+    assert autotune.paged_decode_chunk(PAGE, H * D, 4, 2) == 2
+    assert autotune.paged_decode_chunk(PAGE, H * 8 * D, 4, 128) == 2
     args = _paged_args(one_chip, "decode", jnp.float32, jnp.float32, 1,
                        shape=SERVE_SHAPES["1p3b"])
     _compile(functools.partial(paged_attention, page_size=PAGE,
@@ -201,7 +204,7 @@ def test_decode_compiles_on_a_heads_shard(one_chip, compiled_kernels,
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    pool = sds((1201, PAGE, heads, D), jnp.float32)
+    pool = sds(kv_pool_shape(1201, PAGE, heads, D), jnp.float32)
     _compile(functools.partial(paged_attention, page_size=PAGE,
                                kind="decode", scale=D ** -0.5),
              sds((16, 1, heads, D), jnp.float32), pool, pool,
@@ -238,7 +241,7 @@ def test_default_decode_program_reads_through_the_table(
 
     params, buffers = jax.tree_util.tree_map(
         lambda a: sds(a.shape, a.dtype), state_arrays(model))
-    pool = [sds((1 + lanes * 8, PAGE, H, d), jnp.float32)] * 2
+    pool = [sds(kv_pool_shape(1 + lanes * 8, PAGE, H, d), jnp.float32)] * 2
     text = dec._decode_jit.lower(
         params, buffers, sds((lanes,), jnp.int64), sds((lanes,), jnp.int32),
         sds((lanes,), jnp.bool_), sds((lanes,), jnp.int32),
@@ -247,6 +250,80 @@ def test_default_decode_program_reads_through_the_table(
     assert "paged_attention/attend" in text         # and it carries the scope
     assert f"[{lanes},{slots},{H},{d}]" not in text
     assert f"[{lanes * slots},{H},{d}]" not in text
+
+
+# the three serving configurations as their cells run them, two layers
+# deep (a whole-context and a window layer where there are both kinds),
+# nothing cut that a pool's shape or a program's use of it depends on:
+# preset, cuts, lanes, table slots, pool pages (full, window), a prefill
+POOL_PROGRAMS = {
+    "gpt2_medium": ({"vocab_size": 1024}, 32, 1024, (2100, None), (1, 128)),
+    "gpt3_1p3b": ({"vocab_size": 1024}, 16, 2048, (1200, None), (1, 256)),
+    "smallthinker_21ba3b": (
+        {"vocab_size": 1024, "moe_num_experts": 8, "dtype": "bfloat16",
+         "max_seq_len": 9216}, 32, 9216, (18433, 8225), (1, 1024)),
+}
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("config", sorted(POOL_PROGRAMS))
+def test_no_program_copies_a_pool_at_its_boundary(
+        one_chip, compiled_kernels, config, program):
+    """The decode program and a prefill program of a ``CachedDecoder``
+    built with defaults, compiled for the chip over donated pools of
+    the cells' sizes (gpt2-medium f32, 1024 lanes a row; gpt3-1p3b f32,
+    2048; SmallThinker bf16, 512, a whole-context and a window pool):
+    every pool-shaped array in the program is row-major, the arguments
+    among them, and no ``copy`` makes one. With the heads an axis of
+    the pool the compiler made the page axis minor for 64-wide heads
+    and each layer's scatter and kernel paid four transposing copies of
+    a whole pool: 96 a gpt2-medium decode step (PERF.md, PR 30)."""
+    import paddle_tpu as paddle
+    from paddle_tpu import models
+    from paddle_tpu.jit.functional import state_arrays
+    from paddle_tpu.ops.paged_attention import ring_pages
+    from paddle_tpu.serving.generation.model_fns import CachedDecoder
+    cuts, lanes, slots, (pages, window_pages), (rows, seq) = \
+        POOL_PROGRAMS[config]
+    paddle.seed(0)
+    model = models.GPTForCausalLM(getattr(models, config)(
+        num_layers=2, **cuts))
+    model.eval()
+    width = slots // PAGE
+    if window_pages:
+        width += ring_pages(model.config.sliding_window, PAGE)
+    dec = CachedDecoder(model, max_batch=lanes, page_size=PAGE,
+                        pages_per_seq=width, donate=True,
+                        max_positions=slots, kv_dtype="")
+    assert dec.use_pallas is True
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    def described(tree):
+        return jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), tree)
+
+    params, buffers = described(state_arrays(model))
+    k, v = described(jax.eval_shape(lambda: model.init_kv_pools(
+        pages, PAGE, window_pages=window_pages)))
+    if program == "decode":
+        lowered = dec._decode_jit.lower(
+            params, buffers, sds((lanes,), jnp.int64),
+            sds((lanes,), jnp.int32), sds((lanes,), jnp.bool_),
+            sds((lanes,), jnp.int32), sds((lanes, width), jnp.int32), k, v)
+    else:
+        lowered = dec._prefill_jit.lower(
+            params, buffers, sds((rows, seq), jnp.int64),
+            sds((rows,), jnp.int32), sds((rows, width), jnp.int32), k, v)
+    text = lowered.compile().as_text()
+    spec = model.kv_cache_spec()
+    for n in {pages, window_pages or pages}:
+        shape = ",".join(map(str, kv_pool_shape(
+            n, PAGE, spec["num_kv_heads"], spec["head_dim"])))
+        pool = r"(?:f32|bf16)\[" + shape + r"\]"
+        layouts = set(re.findall(pool + r"\{([\d,]+)", text))
+        assert layouts == {"2,1,0"}, layouts
+        assert not re.findall(pool + r"\S* copy\(", text)
 
 
 def test_every_paged_block_candidate_compiles(one_chip, compiled_kernels):
@@ -270,13 +347,19 @@ def test_every_paged_block_candidate_compiles(one_chip, compiled_kernels):
 
 def test_illegal_head_block_is_refused_by_name(one_chip, compiled_kernels):
     """A tile the lowering does not take raises the compiler's own
-    message on the chip — there is no interpret mode to fall back to."""
+    message on the chip — there is no interpret mode to fall back to.
+    (A window of queries: its q block is ``[block_q, block_h, D]``. For
+    float decode one head a block is legal since the heads lie in the
+    pool's lanes: a head of 128 is a whole lane tile.)"""
     from paddle_tpu.ops.pallas_paged_attention import paged_attention
-    args = _paged_args(one_chip, "decode", *POOLS[1], 1)
+    args = _paged_args(one_chip, "chunked", *POOLS[1], 64)
     with pytest.raises(ValueError, match="divisible by 8 and 128"):
         jax.jit(functools.partial(
-            paged_attention, page_size=PAGE, kind="decode",
+            paged_attention, page_size=PAGE, kind="chunked",
             scale=D ** -0.5, block_h=1)).lower(*args)
+    _compile(functools.partial(
+        paged_attention, page_size=PAGE, kind="decode", scale=D ** -0.5,
+        block_h=1), *_paged_args(one_chip, "decode", *POOLS[1], 1))
 
 
 # ------------- grouped K/V heads, sliding windows, experts (PR 29) ------
@@ -301,7 +384,7 @@ def test_grouped_decode_compiles_for_both_kinds_of_layer(
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    pool = sds((pages, PAGE, GQA_KV_HEADS, D), jnp.bfloat16)
+    pool = sds(kv_pool_shape(pages, PAGE, GQA_KV_HEADS, D), jnp.bfloat16)
     kv = sds((GQA_LANES, 1, GQA_KV_HEADS, D), jnp.bfloat16)
     # the pools donated, as the decoder's entry points donate them: an
     # undonated pool is copied once before the in-place write
@@ -315,7 +398,7 @@ def test_grouped_decode_compiles_for_both_kinds_of_layer(
     ).compile().as_text()
     assert text.count("tpu_custom_call") == 1
     assert not [line for line in text.splitlines()
-                if f"bf16[{pages},{PAGE},{GQA_KV_HEADS},{D}]" in line
+                if f"bf16[{pages},{PAGE},{GQA_KV_HEADS * D}]" in line
                 and " copy(" in line]
 
 

@@ -225,9 +225,10 @@ class TestNoChip:
         the gather path does not answer under the kernel's name."""
         import jax.numpy as jnp
 
-        from paddle_tpu.ops.paged_attention import paged_attention_update
+        from paddle_tpu.ops.paged_attention import (
+            kv_pool_shape, paged_attention_update)
         q = jnp.zeros((1, 1, 2, 8))
-        pool = jnp.zeros((3, 1, 2, 8))         # one-slot pages
+        pool = jnp.zeros(kv_pool_shape(3, 1, 2, 8))   # one-slot pages
         args = (q, q, q, pool, pool, jnp.zeros((1, 2), jnp.int32),
                 jnp.ones((1,), jnp.int32), jnp.ones((1, 1), bool),
                 jnp.zeros((1, 1), jnp.int32))

@@ -35,7 +35,7 @@ class TestPagedOps:
         import jax.numpy as jnp
 
         from paddle_tpu.ops import paged_attention as pa
-        pool = jnp.zeros((5, 4, 2, 3))
+        pool = jnp.zeros(pa.kv_pool_shape(5, 4, 2, 3))
         tables = np.array([[2, 4], [1, 3]], np.int32)
         kv = np.arange(2 * 8 * 2 * 3, dtype=np.float32).reshape(2, 8, 2, 3)
         positions = np.broadcast_to(np.arange(8, dtype=np.int32), (2, 8))
@@ -44,14 +44,18 @@ class TestPagedOps:
                               jnp.asarray(valid), 4)
         pool = pa.write_pool(pool, np.asarray(slots).reshape(-1),
                              kv.reshape(-1, 2, 3))
-        out = np.asarray(pa.gather_pool(pool, jnp.asarray(tables)))
+        out = np.asarray(pa.gather_pool(pool, jnp.asarray(tables), 2))
         np.testing.assert_array_equal(out, kv)
+        # a page's row is its heads side by side: head h in lanes
+        # h * head_dim .. (h + 1) * head_dim - 1
+        np.testing.assert_array_equal(np.asarray(pool[2, 1]),
+                                      kv[0, 1].reshape(-1))
 
     def test_invalid_positions_hit_trash_page_only(self):
         import jax.numpy as jnp
 
         from paddle_tpu.ops import paged_attention as pa
-        pool = jnp.full((3, 4, 1, 2), -7.0)
+        pool = jnp.full(pa.kv_pool_shape(3, 4, 1, 2), -7.0)
         tables = np.array([[1, 2]], np.int32)
         positions = np.broadcast_to(np.arange(8, dtype=np.int32), (1, 8))
         valid = np.zeros((1, 8), bool)     # everything masked

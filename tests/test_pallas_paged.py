@@ -16,8 +16,8 @@ import pytest
 from paddle_tpu.ops import autotune
 from paddle_tpu.ops.paged_attention import (
     _chunked_attention, _decode_attention, dequantize_kv, gather_pool,
-    kv_pool_bytes, paged_attention_update, quantize_kv_rows,
-    resolve_kv_dtype)
+    kv_pool_bytes, kv_pool_shape, paged_attention_update,
+    quantize_kv_rows, resolve_kv_dtype)
 from paddle_tpu.ops.pallas_paged_attention import (
     decode_copies_pages, paged_attention, supported)
 
@@ -26,15 +26,25 @@ H, D, PS = 4, 16, 8       # heads, head_dim, page_size
 
 def _pools(num_pages, seed, scale=1.0):
     rng = np.random.RandomState(seed)
-    shape = (num_pages, PS, H, D)
+    shape = kv_pool_shape(num_pages, PS, H, D)
     return (jnp.asarray(rng.randn(*shape) * scale, jnp.float32),
             jnp.asarray(rng.randn(*shape) * scale, jnp.float32))
 
 
-def _quantize_pool(pool):
-    p, ps, h, d = pool.shape
-    vals, scales = quantize_kv_rows(pool.reshape(p * ps, h, d))
-    return (vals.reshape(p, ps, h, d), scales.reshape(p, ps, h))
+def _quantize_pool(pool, heads=H):
+    """A float pool's values as the quantized pair, a head at a time."""
+    p, ps, lanes = pool.shape
+    vals, scales = quantize_kv_rows(
+        pool.reshape(p * ps, heads, lanes // heads))
+    return (vals.reshape(pool.shape),
+            scales.reshape(kv_pool_shape(p, ps, heads)))
+
+
+def _dequantize_pool(pool):
+    """The quantized pair's values as a float32 pool."""
+    vals, scales = pool
+    return dequantize_kv(vals.reshape(*scales.shape, -1),
+                         scales).reshape(vals.shape)
 
 
 def _decode_case(seed=0, trash=0.0):
@@ -56,8 +66,8 @@ def _decode_case(seed=0, trash=0.0):
 
 
 def _decode_ref(q, kp, vp, tables, ctx, scale):
-    ks = gather_pool(kp, tables, out_dtype=q.dtype)
-    vs = gather_pool(vp, tables, out_dtype=q.dtype)
+    ks = gather_pool(kp, tables, q.shape[2], out_dtype=q.dtype)
+    vs = gather_pool(vp, tables, q.shape[2], out_dtype=q.dtype)
     return _decode_attention(q, ks, vs, ctx, scale)
 
 
@@ -114,8 +124,8 @@ def test_chunked_parity_cow_shared_tables():
     tables = jnp.asarray(tables)
     out = paged_attention(q, kp, vp, tables, ctx, val, pos,
                           page_size=PS, kind="chunked", scale=SCALE)
-    ks = gather_pool(kp, tables, out_dtype=q.dtype)
-    vs = gather_pool(vp, tables, out_dtype=q.dtype)
+    ks = gather_pool(kp, tables, q.shape[2], out_dtype=q.dtype)
+    vs = gather_pool(vp, tables, q.shape[2], out_dtype=q.dtype)
     ref = _chunked_attention(q, ks, vs, pos, np.asarray(val) > 0, SCALE)
     liv = np.asarray(val) > 0
     np.testing.assert_allclose(np.asarray(out)[liv], np.asarray(ref)[liv],
@@ -154,8 +164,7 @@ def test_quantized_decode_matches_dequantized_reference():
     out = paged_attention(q, kq, vq, tables, ctx, val, pos,
                           page_size=PS, kind="decode", scale=SCALE)
     # oracle: the SAME int8 data dequantized, through the pure path
-    kd = dequantize_kv(*kq).reshape(kp.shape)
-    vd = dequantize_kv(*vq).reshape(vp.shape)
+    kd, vd = _dequantize_pool(kq), _dequantize_pool(vq)
     ref = _decode_ref(q, kd, vd, tables, ctx, SCALE)
     live = np.asarray(ctx) > 0
     np.testing.assert_allclose(np.asarray(out)[live],
@@ -170,8 +179,8 @@ def test_update_dispatch_parity_all_kinds():
     rng = np.random.RandomState(2)
 
     def pools():
-        return (jnp.zeros((1 + B * P, PS, H, D), jnp.float32),
-                jnp.zeros((1 + B * P, PS, H, D), jnp.float32))
+        shape = kv_pool_shape(1 + B * P, PS, H, D)
+        return jnp.zeros(shape, jnp.float32), jnp.zeros(shape, jnp.float32)
 
     tables = jnp.asarray(
         np.arange(1, 1 + B * P, dtype=np.int32).reshape(B, P))
@@ -207,7 +216,7 @@ def test_prefill_is_the_same_program_whoever_attends_decode(use_pallas):
     ``use_pallas``, so the decode kernel's arrival moved no prefill."""
     B, P, S = 2, 2, PS
     q = jnp.zeros((B, S, H, D), jnp.float32)
-    pool = jnp.zeros((1 + B * P, PS, H, D), jnp.float32)
+    pool = jnp.zeros(kv_pool_shape(1 + B * P, PS, H, D), jnp.float32)
     tables = jnp.asarray(
         np.arange(1, 1 + B * P, dtype=np.int32).reshape(B, P))
     pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
@@ -228,8 +237,9 @@ def test_supported_gates():
     q = jnp.zeros((2, 1, H, D))
     assert supported(q, kp, t, PS, "decode")
     assert not supported(q, kp, t, 1, "decode")
-    assert supported(q, (jnp.zeros((3, PS, H, D), jnp.int8),
-                         jnp.zeros((3, PS, H))), t, PS, "chunked")
+    assert supported(q, (jnp.zeros(kv_pool_shape(3, PS, H, D), jnp.int8),
+                         jnp.zeros(kv_pool_shape(3, PS, H))), t, PS,
+                     "chunked")
     assert not supported(q, kp, t, PS, "prefill")
     assert not supported(q[0], kp, t, PS, "decode")
 
@@ -488,7 +498,7 @@ def test_default_path_on_a_tpu_is_the_kernel(monkeypatch):
     monkeypatch.setattr(place, "on_tpu", lambda: True)
     assert kernel_by_default(16) and not kernel_by_default(1)
     q = jnp.zeros((1, 1, 2, 8))
-    pool = jnp.zeros((3, 1, 2, 8))              # one-slot pages
+    pool = jnp.zeros(kv_pool_shape(3, 1, 2, 8))  # one-slot pages
     txt = jax.jit(lambda *a: paged_attention_update(
         *a, page_size=1, kind="decode")).lower(
         q, q, q, pool, pool, jnp.zeros((1, 2), jnp.int32),
@@ -498,10 +508,13 @@ def test_default_path_on_a_tpu_is_the_kernel(monkeypatch):
 
 
 def test_decode_kernel_selection_is_by_shape():
-    assert decode_copies_pages(128, False)
-    assert decode_copies_pages(256, False)
-    assert not decode_copies_pages(64, False)     # the grid kernel's
-    assert not decode_copies_pages(128, True)     # quantized pools too
+    """By the row a page is copied in (``kv_heads * head_dim`` lanes),
+    not by the head: 64-wide heads take the page-copying kernel too."""
+    assert decode_copies_pages(16 * 128, False)   # gpt3-1p3b
+    assert decode_copies_pages(16 * 64, False)    # gpt2-medium
+    assert decode_copies_pages(4 * 128, False)    # SmallThinker's K/V heads
+    assert not decode_copies_pages(4 * 16, False)  # half a lane tile
+    assert not decode_copies_pages(16 * 128, True)  # quantized pools too
 
 
 def _ragged_case(heads, head_dim, seed=0):
@@ -514,7 +527,7 @@ def _ragged_case(heads, head_dim, seed=0):
                    np.int32)
     B = len(ctx)
     rng = np.random.RandomState(seed)
-    shape = (1 + B * P, ps, heads, head_dim)
+    shape = kv_pool_shape(1 + B * P, ps, heads, head_dim)
     kp = jnp.asarray(rng.randn(*shape), jnp.float32)
     vp = jnp.asarray(rng.randn(*shape), jnp.float32)
     # poison the trash page: it must never reach a live output
@@ -529,24 +542,53 @@ def _ragged_case(heads, head_dim, seed=0):
 @pytest.mark.parametrize("chunk", [None, 1, 2, 3])
 @pytest.mark.parametrize("heads,head_dim", [(4, 128), (2, 256), (4, 64),
                                             (8, 64), (16, 64)])
-def test_decode_parity_over_head_widths_and_ragged_lanes(heads, head_dim,
-                                                         chunk):
-    """Both decode kernels against the pure body: 128-lane heads take
-    the page-copying kernel (at several chunk sizes, the default among
-    them), 64-wide ones the grid kernel's vector-unit branch (at
-    several pages a tile)."""
+@pytest.mark.parametrize("kernel", ["pages", "grid"])
+def test_decode_parity_over_head_widths_and_ragged_lanes(kernel, heads,
+                                                         head_dim, chunk):
+    """Both decode kernels against the pure body, at heads of one, two
+    and half a lane tile: the page-copying kernel (at several chunk
+    sizes, the default among them), and the grid kernel's vector-unit
+    branch, which a call that names grid blocks takes (at several pages
+    a tile)."""
     kp, vp, tables, ctx, rng = _ragged_case(heads, head_dim)
     B = ctx.shape[0]
     q = jnp.asarray(rng.randn(B, 1, heads, head_dim), jnp.float32)
     val = jnp.ones((B, 1), jnp.int32)
     pos = jnp.maximum(ctx - 1, 0)[:, None]
     scale = 1.0 / np.sqrt(head_dim)
-    copies = decode_copies_pages(head_dim, False)
-    kw = {"pages_per_chunk": chunk} if copies else \
-        {"pages_per_tile": {None: None, 1: 1, 2: 5, 3: 1}[chunk]}
+    assert decode_copies_pages(heads * head_dim, False)
+    kw = {"pages_per_chunk": chunk} if kernel == "pages" else \
+        {"block_h": heads,
+         "pages_per_tile": {None: None, 1: 1, 2: 5, 3: 1}[chunk]}
     out = paged_attention(q, kp, vp, tables, ctx, val, pos, page_size=PS,
                           kind="decode", scale=scale, **kw)
     ref = _decode_ref(q, kp, vp, tables, ctx, scale)
+    live = np.asarray(ctx) > 0
+    np.testing.assert_allclose(np.asarray(out)[live],
+                               np.asarray(ref)[live], rtol=2e-5, atol=2e-5)
+    assert np.allclose(np.asarray(out)[~live], 0.0)
+
+
+@pytest.mark.parametrize("chunk", [None, 3])
+@pytest.mark.parametrize("pool_dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("heads", [2, 16])
+def test_page_copying_kernel_takes_64_wide_heads(heads, pool_dtype, chunk):
+    """What the kernel could not take while a head was an axis of the
+    pool: 64-wide heads, ungrouped, two to a lane tile (one tile a row,
+    and gpt2-medium's eight), over lanes whose last page is part
+    filled, a dead lane, stale table entries and a poisoned trash
+    page."""
+    kp, vp, tables, ctx, rng = _ragged_case(heads, 64, seed=4)
+    kp, vp = kp.astype(pool_dtype), vp.astype(pool_dtype)
+    B = ctx.shape[0]
+    q = jnp.asarray(rng.randn(B, 1, heads, 64), jnp.float32)
+    val = jnp.ones((B, 1), jnp.int32)
+    pos = jnp.maximum(ctx - 1, 0)[:, None]
+    out = paged_attention(q, kp, vp, tables, ctx, val, pos, page_size=PS,
+                          kind="decode", scale=0.125,
+                          pages_per_chunk=chunk)
+    ref = _decode_ref(q, kp.astype(jnp.float32), vp.astype(jnp.float32),
+                      tables, ctx, 0.125)
     live = np.asarray(ctx) > 0
     np.testing.assert_allclose(np.asarray(out)[live],
                                np.asarray(ref)[live], rtol=2e-5, atol=2e-5)
@@ -568,8 +610,7 @@ def test_decode_parity_sub_f32_pools(head_dim, pool_dtype):
     scale = 1.0 / np.sqrt(head_dim)
     if pool_dtype == "int8":
         kq, vq = _quantize_pool(kp), _quantize_pool(vp)
-        kd = dequantize_kv(*kq).reshape(kp.shape)
-        vd = dequantize_kv(*vq).reshape(vp.shape)
+        kd, vd = _dequantize_pool(kq), _dequantize_pool(vq)
     else:
         kq, vq = kp.astype(pool_dtype), vp.astype(pool_dtype)
         kd, vd = kq.astype(jnp.float32), vq.astype(jnp.float32)
@@ -600,8 +641,8 @@ def test_chunked_parity_over_head_widths_and_ragged_lanes(head_dim):
     out = paged_attention(q, kp, vp, tables, ctx,
                           jnp.asarray(val_np.astype(np.int32)), pos,
                           page_size=PS, kind="chunked", scale=scale)
-    ks = gather_pool(kp, tables, out_dtype=q.dtype)
-    vs = gather_pool(vp, tables, out_dtype=q.dtype)
+    ks = gather_pool(kp, tables, q.shape[2], out_dtype=q.dtype)
+    vs = gather_pool(vp, tables, q.shape[2], out_dtype=q.dtype)
     ref = _chunked_attention(q, ks, vs, pos, jnp.asarray(val_np), scale)
     np.testing.assert_allclose(np.asarray(out)[val_np],
                                np.asarray(ref)[val_np],

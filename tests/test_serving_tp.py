@@ -12,6 +12,7 @@ import paddle_tpu as paddle
 from paddle_tpu.distributed.mesh_utils import (build_mesh, get_global_mesh,
                                                set_global_mesh)
 from paddle_tpu.models import GPTForCausalLM, gpt_tiny
+from paddle_tpu.ops.paged_attention import kv_pool_shape
 from paddle_tpu.serving.generation import GenerationServer
 from paddle_tpu.serving.generation.model_fns import CachedDecoder
 from paddle_tpu.serving.mesh import ServingMesh, serving_mesh_from_flags
@@ -109,12 +110,18 @@ class TestShardedParity:
         smesh = ServingMesh(build_mesh({"mp": 8}))
         k, v = m.init_kv_pools(9, 8, None)
         k, v = smesh.place_pools(k, v)
+        spec = m.kv_cache_spec()
         for leaf in jax.tree_util.tree_leaves((k, v)):
             full = tuple(leaf.shape)
+            assert full[-3:] == kv_pool_shape(
+                9, 8, spec["num_kv_heads"], spec["head_dim"])
             local = tuple(leaf.addressable_shards[0].data.shape)
-            assert local[-2] == full[-2] // 8, \
-                f"heads axis not sharded: {local} vs {full}"
-            assert local[:-2] + local[-1:] == full[:-2] + full[-1:]
+            # heads are folded into the last axis, a chip's share of it
+            # is whole heads
+            assert local[-1] == full[-1] // 8 \
+                == spec["num_kv_heads"] // 8 * spec["head_dim"], \
+                f"heads not sharded: {local} vs {full}"
+            assert local[:-1] == full[:-1]
 
     def test_int8_pool_scales_shard_with_values(self):
         import jax
@@ -124,10 +131,9 @@ class TestShardedParity:
         k, v = smesh.place_pools(k, v)
         for leaf in jax.tree_util.tree_leaves((k, v)):
             local = tuple(leaf.addressable_shards[0].data.shape)
-            if leaf.dtype == np.int8:       # values [..., H, D]
-                assert local[-2] == leaf.shape[-2] // 8
-            else:                           # scale planes [..., H]
-                assert local[-1] == leaf.shape[-1] // 8
+            # values [..., H*D] and scale planes [..., H] alike
+            assert local[-1] == leaf.shape[-1] // 8
+            assert local[:-1] == tuple(leaf.shape[:-1])
 
 
 # ------------------------------------------------------------- guards

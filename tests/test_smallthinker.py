@@ -20,6 +20,7 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu import models
 from paddle_tpu.jit.functional import state_arrays
+from paddle_tpu.ops.paged_attention import kv_pool_shape
 from paddle_tpu.serving.generation import GenerationServer
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -252,7 +253,8 @@ def test_parameters_are_born_in_the_configs_dtype():
     assert {str(a.dtype) for a in state_arrays(m)[0].values()} == \
         {"bfloat16"}
     k, _ = m.init_kv_pools(8, PAGE)
-    assert k[0].dtype == jnp.bfloat16 and k[0].shape == (8, PAGE, 2, 16)
+    assert k[0].dtype == jnp.bfloat16 \
+        and k[0].shape == kv_pool_shape(8, PAGE, 2, 16)
     assert m.kv_cache_spec()["kinds"]["window"] == {
         "layers": [1, 2, 3, 5, 6, 7], "window": WINDOW}
 
