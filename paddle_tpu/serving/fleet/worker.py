@@ -555,11 +555,19 @@ class StubBackend:
         """Wedge-aware device wait: threads queued behind a hung
         dispatch fail with ``ReplicaWedgedError`` the moment the
         watchdog declares the wedge, instead of blocking forever."""
-        while not self._device.acquire(timeout=0.05):
+        while True:
+            got = self._device.acquire(timeout=0.05)
             if self._wedged.is_set():
+                # also when the wait was won: the hung dispatch lets
+                # go of the device on its way out, and whoever takes
+                # it then has still not executed
+                if got:
+                    self._device.release()
                 raise ReplicaWedgedError(
                     "device wedged: dispatch queued behind a hung "
                     "step, replica restarting")
+            if got:
+                return
             with self._lock:
                 if not self._alive:
                     raise ServerClosedError("stub backend crashed")

@@ -46,7 +46,9 @@ import threading
 import time
 import weakref
 from collections import deque
-from typing import Dict, List, Optional, Sequence
+from types import SimpleNamespace
+from typing import (Callable, Dict, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import numpy as np
 
@@ -58,6 +60,7 @@ from ..request import (DeadlineExceededError, QueueFullError,
 from .kv_cache import PagedKVCache
 from .model_fns import CachedDecoder, supports_cached_decode
 from .prefix_cache import PrefixCache
+from .runner import SITES, ProgramRunner
 from .sampling import sample_next_tokens
 from .spec_decode import accept_tokens, softmax
 
@@ -287,6 +290,14 @@ class _ActiveSeq:
         self.history: List[int] = [int(t) for t in req.prompt]
         self.draft_ctx = len(req.prompt)    # draft-pool cached tokens
         self.published = False              # prompt pages in the index
+
+
+class _Ran(NamedTuple):
+    """What ``GenerationServer._dispatch`` hands back."""
+    out: np.ndarray         # the first runner's first output, fetched
+    ms: float               # host time of the calls and their fetches
+    t_wall: int             # time_ns at their start
+    fresh: List[bool]       # per runner: a signature seen first now
 
 
 _EVENTS = ("submitted", "completed", "rejected", "timed_out",
@@ -733,6 +744,10 @@ class GenerationServer:
             use_pallas=use_pallas, kv_dtype=self.kv_dtype,
             mesh=self.serving_mesh)
         self.use_pallas = self.decoder.use_pallas
+        # every program runs through a runner (runner.py): the target's
+        # pools stay on ``self.kv``, where callers read them
+        self._runners: Tuple[ProgramRunner, ...] = (
+            ProgramRunner(self.decoder, self.kv),)
         # ---- shared-prefix KV reuse (radix index over full pages)
         if prefix_cache and self.kv.window is not None:
             raise ValueError(
@@ -749,7 +764,6 @@ class GenerationServer:
         if draft_model is None:
             self.spec_k = 0
         self.draft: Optional[CachedDecoder] = None
-        self._draft_k = self._draft_v = None
         if self.spec_k:
             if not supports_cached_decode(draft_model):
                 raise TypeError("draft_model must support KV-cached "
@@ -772,12 +786,14 @@ class GenerationServer:
                 max_positions=self.max_seq_len,
                 use_pallas=self.use_pallas, kv_dtype=self.kv_dtype,
                 mesh=self.serving_mesh)
-            self._draft_k, self._draft_v = draft_model.init_kv_pools(
-                self.kv.num_pages, self.page_size,
-                self.kv_dtype or None)
-            self._draft_k, self._draft_v = \
-                self.serving_mesh.place_pools(self._draft_k,
-                                              self._draft_v)
+            dk, dv = self.serving_mesh.place_pools(
+                *draft_model.init_kv_pools(
+                    self.kv.num_pages, self.page_size,
+                    self.kv_dtype or None))
+            # the second runner owns the draft's pools: a prefill is
+            # mirrored through it, a proposal step runs on it alone
+            self._runners += (ProgramRunner(
+                self.draft, SimpleNamespace(k=dk, v=dv)),)
         self.metrics = DecodeMetrics(name, self.max_batch,
                                      self.kv.capacity)
         self.metrics.set_kv_pages(0, self.kv.capacity)
@@ -1099,9 +1115,9 @@ class GenerationServer:
         on, the chunked (suffix-prefill) lattice is warmed alongside,
         and a draft model's mirror signatures ride every warm. Returns
         the number of fresh signatures."""
-        fresh = self._warm_decode()
+        fresh = self._warm("decode", self.max_batch)
         if self.spec_k:
-            fresh += self._warm_verify()
+            fresh += self._warm("verify", self.max_batch, self.spec_k + 1)
         seqs = list(seq_buckets if seq_buckets is not None
                     else (self.policy.seq_buckets or []))
         if batch_buckets is None:
@@ -1112,97 +1128,32 @@ class GenerationServer:
             batch_buckets.append(self.max_batch)
         for s in seqs:
             for r in batch_buckets:
-                fresh += self._warm_prefill(int(r), int(s))
+                fresh += self._warm("prefill", int(r), int(s))
                 if self.prefix is not None:
-                    fresh += self._warm_chunked(int(r), int(s))
+                    fresh += self._warm("prefill_chunked", int(r), int(s))
         self._warmed.set()
         return fresh
 
-    def _warm_decode(self) -> int:
-        args = (np.zeros(self.max_batch, np.int64),
-                np.zeros(self.max_batch, np.int32),
-                np.zeros(self.max_batch, bool),
-                np.zeros(self.max_batch, np.int32),
-                np.zeros_like(self._tables))
-        logits, k2, v2, fresh = self.decoder.decode(
-            *args, self.kv.k, self.kv.v)
-        np.asarray(logits)
-        self.kv.k, self.kv.v = k2, v2
-        self._note_dispatch("generate_decode", fresh, [
-            ((self.max_batch,), "int64"), ((self.max_batch,), "int32"),
-            ((self.max_batch,), "bool"), ((self.max_batch,), "int32"),
-            (self._tables.shape, "int32")], record=False)
-        fresh = int(fresh)
-        if self.draft is not None:
-            dlogits, dk, dv, dfresh = self.draft.decode(
-                *args, self._draft_k, self._draft_v)
-            np.asarray(dlogits)
-            self._draft_k, self._draft_v = dk, dv
-            self.metrics.observe_compile(hit=not dfresh)
-            fresh += int(dfresh)
-        return fresh
-
-    def _warm_prefill(self, rows: int, seq: int) -> int:
-        ids = np.zeros((rows, seq), np.int64)
-        lens = np.zeros(rows, np.int32)
+    def _warm(self, kind: str, rows: int, seq: int = 0) -> int:
+        """Zeros of ``kind``'s shapes (``rows`` lanes; ``seq`` token
+        columns, for every kind but decode) through the runners traffic
+        of that kind takes, unrecorded: the site stays tagged in the
+        manifest by traffic alone, so a restarted engine replays what
+        was observed. Returns the number of fresh signatures. A verify
+        step warms the target alone: a draft never verifies."""
+        def vec(dtype):
+            return np.zeros(rows, dtype)
         tables = np.zeros((rows, self.pages_per_seq), np.int32)
-        last, k2, v2, fresh = self.decoder.prefill(
-            ids, lens, tables, self.kv.k, self.kv.v)
-        np.asarray(last)
-        self.kv.k, self.kv.v = k2, v2
-        self._note_dispatch("generate_prefill", fresh, [
-            (ids.shape, "int64"), (lens.shape, "int32"),
-            (tables.shape, "int32")], record=False)
-        fresh = int(fresh)
-        if self.draft is not None:
-            dlast, dk, dv, dfresh = self.draft.prefill(
-                ids, lens, tables, self._draft_k, self._draft_v)
-            np.asarray(dlast)
-            self._draft_k, self._draft_v = dk, dv
-            self.metrics.observe_compile(hit=not dfresh)
-            fresh += int(dfresh)
-        return fresh
-
-    def _warm_chunked(self, rows: int, seq: int) -> int:
-        ids = np.zeros((rows, seq), np.int64)
-        start = np.zeros(rows, np.int32)
-        seg = np.zeros(rows, np.int32)
-        tables = np.zeros((rows, self.pages_per_seq), np.int32)
-        last, k2, v2, fresh = self.decoder.prefill_chunked(
-            ids, start, seg, tables, self.kv.k, self.kv.v)
-        np.asarray(last)
-        self.kv.k, self.kv.v = k2, v2
-        self._note_dispatch("generate_chunked", fresh, [
-            (ids.shape, "int64"), (start.shape, "int32"),
-            (seg.shape, "int32"), (tables.shape, "int32")],
-            record=False)
-        fresh = int(fresh)
-        if self.draft is not None:
-            dlast, dk, dv, dfresh = self.draft.prefill_chunked(
-                ids, start, seg, tables, self._draft_k, self._draft_v)
-            np.asarray(dlast)
-            self._draft_k, self._draft_v = dk, dv
-            self.metrics.observe_compile(hit=not dfresh)
-            fresh += int(dfresh)
-        return fresh
-
-    def _warm_verify(self) -> int:
-        """The ONE [max_batch, spec_k + 1] verify signature (site-
-        tagged in the manifest so a restarted engine replays it)."""
-        width = self.spec_k + 1
-        ids = np.zeros((self.max_batch, width), np.int64)
-        start = np.zeros(self.max_batch, np.int32)
-        seg = np.zeros(self.max_batch, np.int32)
-        tables = np.zeros_like(self._tables)
-        logits, k2, v2, fresh = self.decoder.verify(
-            ids, start, seg, tables, self.kv.k, self.kv.v)
-        np.asarray(logits)
-        self.kv.k, self.kv.v = k2, v2
-        self._note_dispatch("generate_verify", fresh, [
-            (ids.shape, "int64"), (start.shape, "int32"),
-            (seg.shape, "int32"), (tables.shape, "int32")],
-            record=False)
-        return int(fresh)
+        if kind == "decode":
+            feeds = (vec(np.int64), vec(np.int32), vec(bool),
+                     vec(np.int32), tables)
+        else:
+            ids = np.zeros((rows, seq), np.int64)
+            feeds = (ids, vec(np.int32), tables) if kind == "prefill" \
+                else (ids, vec(np.int32), vec(np.int32), tables)
+        runners = self._runners[:1] if kind == "verify" else self._runners
+        return sum(self._dispatch(kind, feeds, (), runners,
+                                  record=False).fresh)
 
     def warmup_from_manifest(self, path: Optional[str] = None) -> int:
         """Replay the persisted decode/prefill signatures a previous
@@ -1217,22 +1168,18 @@ class GenerationServer:
         if manifest is None:
             return 0
         fresh = 0
-        for spec in manifest.specs(site="generate_prefill"):
-            (rows, seq) = spec["feeds"][0][0]
-            if rows > self.max_batch or seq > self.max_seq_len:
-                continue
-            fresh += self._warm_prefill(int(rows), int(seq))
-        for spec in manifest.specs(site="generate_chunked"):
-            (rows, seq) = spec["feeds"][0][0]
-            if rows > self.max_batch or seq > self.max_seq_len:
-                continue
-            fresh += self._warm_chunked(int(rows), int(seq))
-        if manifest.specs(site="generate_decode"):
-            fresh += self._warm_decode()
+        for kind in ("prefill", "prefill_chunked"):
+            for spec in manifest.specs(site=SITES[kind]):
+                (rows, seq) = spec["feeds"][0][0]
+                if rows > self.max_batch or seq > self.max_seq_len:
+                    continue
+                fresh += self._warm(kind, int(rows), int(seq))
+        if manifest.specs(site=SITES["decode"]):
+            fresh += self._warm("decode", self.max_batch)
         if self.spec_k and any(
                 spec["feeds"][0][0] == (self.max_batch, self.spec_k + 1)
-                for spec in manifest.specs(site="generate_verify")):
-            fresh += self._warm_verify()
+                for spec in manifest.specs(site=SITES["verify"])):
+            fresh += self._warm("verify", self.max_batch, self.spec_k + 1)
         self._warmed.set()
         return fresh
 
@@ -1577,169 +1524,152 @@ class GenerationServer:
         hot: Dict[int, List[_ActiveSeq]] = {}
         for seq in admitted:
             n_suffix = len(seq.req.prompt) - seq.prefix_len
-            if seq.prefix_len:
-                bucket = min(self.policy.bucket_seq(n_suffix),
-                             self.max_seq_len)
-                hot.setdefault(bucket, []).append(seq)
-            else:
-                bucket = min(self.policy.bucket_seq(n_suffix),
-                             self.max_seq_len)
-                cold.setdefault(bucket, []).append(seq)
+            bucket = min(self.policy.bucket_seq(n_suffix),
+                         self.max_seq_len)
+            (hot if seq.prefix_len else cold).setdefault(
+                bucket, []).append(seq)
         cap = self.prefill_rows_cap
-        for groups, prefill in ((cold, self._prefill_group),
-                                (hot, self._prefill_chunked_group)):
+        for groups, kind in ((cold, "prefill"), (hot, "prefill_chunked")):
             for bucket, seqs in groups.items():
                 if len(seqs) > cap:
                     self.metrics.observe_prefill_split()
                 for i in range(0, len(seqs), cap):
-                    prefill(seqs[i:i + cap], bucket)
+                    self._prefill_group(seqs[i:i + cap], bucket, kind)
 
-    def _prefill_group(self, seqs: List[_ActiveSeq], seq_bucket: int):
-        rows = len(seqs)
-        padded = min(self.policy.bucket_batch(rows), self.max_batch)
-        prompt_tokens = sum(len(seq.req.prompt) for seq in seqs)
-        self._enter_phase("prefill")
-        ids = np.full((padded, seq_bucket), self.pad_token_id, np.int64)
-        lens = np.zeros(padded, np.int32)
-        tables = np.zeros((padded, self.pages_per_seq), np.int32)
-        for i, seq in enumerate(seqs):
-            p = seq.req.prompt
-            ids[i, :len(p)] = p
-            lens[i] = len(p)
-            tables[i] = self._tables[seq.slot]
-        t_wall = time.time_ns()
-        t0 = time.perf_counter()
+    # ---- the one way a decoder program runs ----
+    def _dispatch(self, kind: str, feeds: tuple,
+                  seqs: Sequence[_ActiveSeq],
+                  runners: Sequence[ProgramRunner], *,
+                  stage: Optional[str] = None, record: bool = True,
+                  since: Optional[Tuple[int, float]] = None
+                  ) -> Optional[_Ran]:
+        """Run ``kind``'s program over ``feeds`` on each of ``runners``
+        in turn (``self._runners[:1]`` is the target, ``[1:]`` the
+        draft, all of it a prefill with its draft mirror) and time the
+        lot, from ``since`` (a ``(time_ns, perf_counter)`` pair read
+        earlier: a verify step counts the proposal before it) or from
+        now, to the last fetch.
+
+        The fault barrier: a failure fails the futures of ``seqs``,
+        the sequences in the call, and theirs alone, returns their
+        pages and lanes, and returns None; the worker survives. With
+        ``stage``, ``engine::bookkeeping`` opens at the second clock
+        reading and the time goes to ``step_ms[stage]``. ``record=False``
+        is a warmup: it has no request to fail (the error is the
+        caller's), enters no manifest and counts no expert."""
+        t_wall, t0 = since or (time.time_ns(), time.perf_counter())
+        target, ran = self._runners[0], []
         try:
-            last, k2, v2, fresh = self.decoder.prefill(
-                ids, lens, tables, self.kv.k, self.kv.v)
-            logits = np.asarray(last)
-            self.kv.k, self.kv.v = k2, v2
-            self._fetch_aux()
-            if self.draft is not None:
-                dlast, dk, dv, dfresh = self.draft.prefill(
-                    ids, lens, tables, self._draft_k, self._draft_v)
-                np.asarray(dlast)
-                self._draft_k, self._draft_v = dk, dv
-                self.metrics.observe_compile(hit=not dfresh)
-        except Exception as e:  # noqa: BLE001 - fault barrier: fail
-            # only THIS group's requests; the worker survives
+            for runner in runners:
+                ran.append(runner.run(kind, feeds))
+                if record and runner is target \
+                        and kind in ("prefill", "decode"):
+                    aux = self._fetch_aux()
+                    if aux and kind == "decode":
+                        # what this step's expert layers read, on its
+                        # own span
+                        self._span.set_arg(
+                            "experts_touched",
+                            int(aux["moe_experts_touched"]))
+        except Exception as e:  # noqa: BLE001 - the fault barrier
+            if not record:
+                raise
             with self._lock:
                 for seq in seqs:
                     seq.req.future._fail(e)
                     self._release(seq, "failed")
             self._trace_finish(seqs, "error",
                                error=f"{type(e).__name__}: {e}")
-            return
+            return None
         ms = (time.perf_counter() - t0) * 1e3
-        self._enter_phase("bookkeeping")
-        self.metrics.observe_step("prefill", ms)
-        self.metrics.observe_prefill_dispatch(padded, seq_bucket,
-                                              prompt_tokens, ms)
+        if stage is not None:
+            self._enter_phase("bookkeeping")
+            self.metrics.observe_step(stage, ms)
+        for runner, (_, fresh, signature) in zip(runners, ran):
+            # the manifest is the target's lattice: a draft's programs
+            # are counted, never recorded
+            self._note_dispatch(SITES[kind], fresh, signature,
+                                record=record and runner is target)
+        return _Ran(ran[0][0], ms, t_wall, [fresh for _, fresh, _ in ran])
+
+    def _account(self, ran: _Ran, seqs: Sequence[_ActiveSeq],
+                 envelopes: Sequence[dict], span: str,
+                 attrs_of: Callable[[_ActiveSeq], dict]):
+        """What a traffic dispatch leaves for the observers: its
+        stepprof ``envelopes`` (``record_step``'s keywords, one per
+        step kind the dispatch stands for; a straggler becomes an error
+        span in /tracez) and a ``generate::<span>`` request span over
+        the dispatch for every traced sequence of ``seqs``, with
+        ``attrs_of(seq)``."""
         try:
-            # stepprof envelope per prefill group: joins with the
-            # generate_prefill executable for paddle_mfu{kind=prefill}.
-            # ms is the host's time of the call (dispatch, execution
-            # and the logits' fetch), so it goes under host_ms: nothing
-            # here knows the device's own time
             from ...observability.stepprof import default_profiler
-            default_profiler().record_step(
-                ms, kind="prefill", step=self._steps,
-                host_ms=ms, occupancy=rows,
-                kv_pages_used=self.kv.used_pages)
-        except Exception:  # noqa: BLE001 - profiling is garnish
-            pass
+            for envelope in envelopes:
+                default_profiler().record_step(step=self._steps,
+                                               **envelope)
+        except Exception:  # noqa: BLE001 - profiling is garnish on the
+            pass           # hot path
         for seq in seqs:
             if seq.req.trace is not None:
+                # long streams are bounded by the flight recorder's
+                # per-trace cap, not here
                 tracing.record_span(
-                    seq.req.trace, "generate::prefill",
-                    stage="prefill", start_unix_ns=t_wall,
-                    duration_ms=ms,
+                    seq.req.trace, "generate::" + span, stage=span,
+                    start_unix_ns=ran.t_wall, duration_ms=ran.ms,
                     attrs={"server": self.metrics.name,
-                           "rows": rows, "seq_bucket": seq_bucket,
-                           "prefix_hit": False, "tokens_reused": 0,
-                           "compile_miss": bool(fresh)})
-        self._note_dispatch("generate_prefill", fresh, [
-            (ids.shape, "int64"), (lens.shape, "int32"),
-            (tables.shape, "int32")])
-        self._publish_prompts(seqs)
-        self._enter_phase("sample_emit")
-        self._sample_and_emit(seqs, logits[:rows])
+                           **attrs_of(seq)})
 
-    def _prefill_chunked_group(self, seqs: List[_ActiveSeq],
-                               seq_bucket: int):
-        """Suffix prefill for prefix-cache hits: the window holds only
-        each prompt's unmatched tail; attention reaches the shared
-        prefix pages through the block tables (kind="chunked")."""
+    def _decode_envelope(self, ms: float, n_active: int, **attrs) -> dict:
+        """The stepprof envelope of one decode iteration (occupancy and
+        KV pressure ride along)."""
+        return dict(
+            wall_ms=ms, kind="decode", occupancy=n_active,
+            kv_pages_used=self.kv.used_pages,
+            attrs=dict(attrs, prefix_tokens_reused=self.prefix.tokens_reused
+                       if self.prefix is not None else 0))
+
+    def _prefill_group(self, seqs: List[_ActiveSeq], seq_bucket: int,
+                       kind: str):
+        """One prefill dispatch. ``kind`` "prefill" runs whole prompts;
+        "prefill_chunked" runs prefix-cache hits: the window holds only
+        each prompt's unmatched tail, and attention reaches the shared
+        prefix pages through the block tables. Both join the
+        generate_<kind> executable for paddle_mfu{kind=prefill}: one
+        MFU stream per step kind."""
         rows = len(seqs)
         padded = min(self.policy.bucket_batch(rows), self.max_batch)
-        # the tokens this dispatch computes: the unmatched tails
-        prompt_tokens = sum(len(seq.req.prompt) - seq.prefix_len
-                            for seq in seqs)
         self._enter_phase("prefill")
         ids = np.full((padded, seq_bucket), self.pad_token_id, np.int64)
         start = np.zeros(padded, np.int32)
-        seg = np.zeros(padded, np.int32)
+        lens = np.zeros(padded, np.int32)
         tables = np.zeros((padded, self.pages_per_seq), np.int32)
         for i, seq in enumerate(seqs):
-            suffix = seq.req.prompt[seq.prefix_len:]
-            ids[i, :len(suffix)] = suffix
+            # nothing of a cold prompt is cached: its tail is all of it
+            tail = seq.req.prompt[seq.prefix_len:]
+            ids[i, :len(tail)] = tail
             start[i] = seq.prefix_len
-            seg[i] = len(suffix)
+            lens[i] = len(tail)
             tables[i] = self._tables[seq.slot]
-        t_wall = time.time_ns()
-        t0 = time.perf_counter()
-        try:
-            last, k2, v2, fresh = self.decoder.prefill_chunked(
-                ids, start, seg, tables, self.kv.k, self.kv.v)
-            logits = np.asarray(last)
-            self.kv.k, self.kv.v = k2, v2
-            if self.draft is not None:
-                dlast, dk, dv, dfresh = self.draft.prefill_chunked(
-                    ids, start, seg, tables,
-                    self._draft_k, self._draft_v)
-                np.asarray(dlast)
-                self._draft_k, self._draft_v = dk, dv
-                self.metrics.observe_compile(hit=not dfresh)
-        except Exception as e:  # noqa: BLE001 - fault barrier, as above
-            with self._lock:
-                for seq in seqs:
-                    seq.req.future._fail(e)
-                    self._release(seq, "failed")
-            self._trace_finish(seqs, "error",
-                               error=f"{type(e).__name__}: {e}")
+        feeds = (ids, lens, tables) if kind == "prefill" \
+            else (ids, start, lens, tables)
+        ran = self._dispatch(kind, feeds, seqs, self._runners,
+                             stage="prefill")
+        if ran is None:
             return
-        ms = (time.perf_counter() - t0) * 1e3
-        self._enter_phase("bookkeeping")
-        self.metrics.observe_step("prefill", ms)
+        # the tokens this dispatch computed: the unmatched tails
         self.metrics.observe_prefill_dispatch(padded, seq_bucket,
-                                              prompt_tokens, ms)
-        try:
-            # envelope for the suffix-prefill step (same prefill kind
-            # as the cold path: one MFU stream per step kind)
-            from ...observability.stepprof import default_profiler
-            default_profiler().record_step(
-                ms, kind="prefill", step=self._steps,
-                host_ms=ms, occupancy=rows,
-                kv_pages_used=self.kv.used_pages)
-        except Exception:  # noqa: BLE001 - profiling is garnish
-            pass
-        for seq in seqs:
-            if seq.req.trace is not None:
-                tracing.record_span(
-                    seq.req.trace, "generate::prefill",
-                    stage="prefill", start_unix_ns=t_wall,
-                    duration_ms=ms,
-                    attrs={"server": self.metrics.name,
-                           "rows": rows, "seq_bucket": seq_bucket,
-                           "prefix_hit": True,
-                           "tokens_reused": seq.prefix_len,
-                           "compile_miss": bool(fresh)})
-        self._note_dispatch("generate_chunked", fresh, [
-            (ids.shape, "int64"), (start.shape, "int32"),
-            (seg.shape, "int32"), (tables.shape, "int32")])
+                                              int(lens.sum()), ran.ms)
+        self._account(
+            ran, seqs,
+            [dict(wall_ms=ran.ms, kind="prefill", occupancy=rows,
+                  kv_pages_used=self.kv.used_pages)],
+            "prefill",
+            lambda seq: {"rows": rows, "seq_bucket": seq_bucket,
+                         "prefix_hit": bool(seq.prefix_len),
+                         "tokens_reused": seq.prefix_len,
+                         "compile_miss": ran.fresh[0]})
         self._publish_prompts(seqs)
         self._enter_phase("sample_emit")
-        self._sample_and_emit(seqs, logits[:rows])
+        self._sample_and_emit(seqs, ran.out[:rows])
 
     def _publish_prompts(self, seqs: List[_ActiveSeq]):
         """Index each prefilled prompt's FULL pages so later admissions
@@ -1756,77 +1686,47 @@ class GenerationServer:
                 seq.published = True
 
     # ---- one decode iteration ----
+    def _decode_feeds(self, seqs: List[_ActiveSeq], tokens: Sequence[int],
+                      positions: Sequence[int]) -> tuple:
+        """The decode program's feeds: the lane of each of ``seqs`` is
+        fed its entry of ``tokens`` at its entry of ``positions`` (the
+        slot it writes; the context it reads ends one past it); every
+        other lane is masked dead."""
+        b = self.max_batch
+        toks = np.zeros(b, np.int64)
+        pos = np.zeros(b, np.int32)
+        mask = np.zeros(b, bool)
+        ctx_after = np.zeros(b, np.int32)
+        # scalar writes: at 32 lanes they cost half of what four
+        # fancy-index assignments from lists do (PERF.md §6, PR 31)
+        for seq, token, position in zip(seqs, tokens, positions):
+            slot = seq.slot
+            toks[slot] = token
+            pos[slot] = position
+            mask[slot] = True
+            ctx_after[slot] = position + 1
+        return toks, pos, mask, ctx_after, self._tables
+
     def _decode_iteration(self, active: List[_ActiveSeq],
                           stall_t0: Optional[float] = None):
         self._enter_phase("decode_feeds")
-        tokens = np.zeros(self.max_batch, np.int64)
-        positions = np.zeros(self.max_batch, np.int32)
-        mask = np.zeros(self.max_batch, bool)
-        ctx_after = np.zeros(self.max_batch, np.int32)
-        for seq in active:
-            tokens[seq.slot] = seq.last_token
-            positions[seq.slot] = seq.ctx
-            mask[seq.slot] = True
-            ctx_after[seq.slot] = seq.ctx + 1
+        feeds = self._decode_feeds(active,
+                                   [s.last_token for s in active],
+                                   [s.ctx for s in active])
         # the context this step's attention reads: each live lane's
         # cached positions, the one it writes among them
-        self._enter_decode_call(active, ctx_after, stall_t0)
-        t_wall = time.time_ns()
-        t0 = time.perf_counter()
-        try:
-            logits, k2, v2, fresh = self.decoder.decode(
-                tokens, positions, mask, ctx_after, self._tables,
-                self.kv.k, self.kv.v)
-            logits = np.asarray(logits)
-            aux = self._fetch_aux()
-            if aux:
-                # what this step's expert layers read, on its own span
-                self._span.set_arg("experts_touched",
-                                   int(aux["moe_experts_touched"]))
-        except Exception as e:  # noqa: BLE001 - fault barrier: a model
-            # error fails the in-flight sequences, not the engine
-            with self._lock:
-                for seq in active:
-                    seq.req.future._fail(e)
-                    self._release(seq, "failed")
-            self._trace_finish(active, "error",
-                               error=f"{type(e).__name__}: {e}")
+        self._enter_decode_call(active, feeds[3], stall_t0)
+        ran = self._dispatch("decode", feeds, active, self._runners[:1],
+                             stage="decode")
+        if ran is None:
             return
-        self.kv.k, self.kv.v = k2, v2
-        ms = (time.perf_counter() - t0) * 1e3
-        self._enter_phase("bookkeeping")
         self._steps += 1
-        self.metrics.observe_step("decode", ms)
         self.metrics.observe_occupancy(len(active))
-        try:
-            # continuous step profiler: one envelope per decode
-            # iteration (occupancy + KV pressure ride along); a
-            # straggler iteration becomes an error span in /tracez
-            from ...observability.stepprof import default_profiler
-            default_profiler().record_step(
-                ms, kind="decode", step=self._steps,
-                host_ms=ms, occupancy=len(active),
-                kv_pages_used=self.kv.used_pages,
-                attrs={"prefix_tokens_reused":
-                       self.prefix.tokens_reused
-                       if self.prefix is not None else 0})
-        except Exception:  # noqa: BLE001 - profiling is garnish on the
-            pass           # decode hot path
-        for seq in active:
-            if seq.req.trace is not None:
-                # per-iteration span; long streams are bounded by the
-                # flight recorder's per-trace cap, not here
-                tracing.record_span(
-                    seq.req.trace, "generate::decode_step",
-                    stage="decode_step", start_unix_ns=t_wall,
-                    duration_ms=ms,
-                    attrs={"server": self.metrics.name,
-                           "step": seq.n_generated,
-                           "occupancy": len(active)})
-        self._note_dispatch("generate_decode", fresh, [
-            ((self.max_batch,), "int64"), ((self.max_batch,), "int32"),
-            ((self.max_batch,), "bool"), ((self.max_batch,), "int32"),
-            (self._tables.shape, "int32")])
+        self._account(
+            ran, active, [self._decode_envelope(ran.ms, len(active))],
+            "decode_step",
+            lambda seq: {"step": seq.n_generated,
+                         "occupancy": len(active)})
         for seq in active:
             self.kv.note_positions(seq.ctx, seq.ctx + 1)
             seq.ctx += 1
@@ -1834,7 +1734,7 @@ class GenerationServer:
             self._note_kv_pages()
         self._enter_phase("sample_emit")
         self._sample_and_emit(active,
-                              logits[[s.slot for s in active]])
+                              ran.out[[s.slot for s in active]])
 
     # ---- one speculative iteration: draft proposes, target verifies
     def _spec_iteration(self, active: List[_ActiveSeq],
@@ -1856,51 +1756,38 @@ class GenerationServer:
         self._enter_decode_call(
             active, np.asarray([s.ctx + k + 1 for s in active], np.int64),
             stall_t0)
-        t_wall = time.time_ns()
-        t0 = time.perf_counter()
-        try:
-            draft_toks, draft_probs = self._draft_propose(active, k)
-            draft_ms = (time.perf_counter() - t0) * 1e3
-            # ---- verify: one chunked window per lane
-            ids = np.zeros((b, k + 1), np.int64)
-            start = np.zeros(b, np.int32)
-            seg = np.zeros(b, np.int32)
-            for s in active:
-                ids[s.slot, 0] = s.last_token
-                ids[s.slot, 1:] = draft_toks[s.slot]
-                start[s.slot] = s.ctx
-                seg[s.slot] = k + 1
-            vlogits, k2, v2, fresh = self.decoder.verify(
-                ids, start, seg, self._tables, self.kv.k, self.kv.v)
-            vlogits = np.asarray(vlogits)
-        except Exception as e:  # noqa: BLE001 - fault barrier: a model
-            # error fails the in-flight sequences, not the engine
-            with self._lock:
-                for seq in active:
-                    seq.req.future._fail(e)
-                    self._release(seq, "failed")
-            self._trace_finish(active, "error",
-                               error=f"{type(e).__name__}: {e}")
+        since = (time.time_ns(), time.perf_counter())
+        proposal = self._draft_propose(active, k)
+        if proposal is None:
+            return      # a draft step failed, and the lanes with it
+        draft_toks, draft_probs = proposal
+        draft_ms = (time.perf_counter() - since[1]) * 1e3
+        # ---- verify: one chunked window per lane
+        ids = np.zeros((b, k + 1), np.int64)
+        start = np.zeros(b, np.int32)
+        seg = np.zeros(b, np.int32)
+        for s in active:
+            ids[s.slot, 0] = s.last_token
+            ids[s.slot, 1:] = draft_toks[s.slot]
+            start[s.slot] = s.ctx
+            seg[s.slot] = k + 1
+        ran = self._dispatch("verify", (ids, start, seg, self._tables),
+                             active, self._runners[:1], stage="decode",
+                             since=since)
+        if ran is None:
             return
-        self.kv.k, self.kv.v = k2, v2
-        ms = (time.perf_counter() - t0) * 1e3
-        self._enter_phase("bookkeeping")
         self._steps += 1
-        self.metrics.observe_step("decode", ms)
         self.metrics.observe_occupancy(len(active))
-        self._note_dispatch("generate_verify", fresh, [
-            (ids.shape, "int64"), (start.shape, "int32"),
-            (seg.shape, "int32"), (self._tables.shape, "int32")])
         # ---- accept-and-resample per lane (host)
         self._enter_phase("sample_emit")
         toks_lists: List[List[int]] = []
-        accs: List[int] = []
+        spans: Dict[int, dict] = {}
         n_accepted = 0
         for s in active:
             remaining = min(s.req.max_new - s.n_generated,
                             s.max_total - s.ctx)
             emitted, acc = accept_tokens(
-                vlogits[s.slot], draft_toks[s.slot],
+                ran.out[s.slot], draft_toks[s.slot],
                 draft_probs.get(s.slot), s.req.temperature, s.req.rng,
                 max_emit=remaining,
                 eos_token_id=self.eos_token_id)
@@ -1912,39 +1799,23 @@ class GenerationServer:
             # ctx masks them and the next write overwrites in place
             s.draft_ctx = min(s.draft_ctx, s.ctx)
             toks_lists.append(emitted)
-            accs.append(acc)
+            spans[s.slot] = {"proposed": k, "accepted": acc,
+                             "emitted": len(emitted),
+                             "draft_ms": round(draft_ms, 3),
+                             "occupancy": len(active)}
         self._enter_phase("bookkeeping")
-        try:
-            from ...observability.stepprof import default_profiler
-            default_profiler().record_step(
-                ms, kind="decode", step=self._steps,
-                host_ms=ms, occupancy=len(active),
-                kv_pages_used=self.kv.used_pages,
-                attrs={"spec_proposed": k * len(active),
-                       "spec_accepted": n_accepted,
-                       "prefix_tokens_reused":
-                       self.prefix.tokens_reused
-                       if self.prefix is not None else 0})
-            # the verify window alone (iteration minus draft proposal)
-            # as its own kind: joins with the generate_verify
-            # executable for paddle_mfu{kind=verify}
-            default_profiler().record_step(
-                max(ms - draft_ms, 0.0), kind="verify",
-                step=self._steps, occupancy=len(active),
-                attrs={"draft_ms": round(draft_ms, 4)})
-        except Exception:  # noqa: BLE001 - profiling is garnish
-            pass
-        for seq, toks, acc in zip(active, toks_lists, accs):
-            if seq.req.trace is not None:
-                tracing.record_span(
-                    seq.req.trace, "generate::verify",
-                    stage="verify", start_unix_ns=t_wall,
-                    duration_ms=ms,
-                    attrs={"server": self.metrics.name,
-                           "proposed": k, "accepted": acc,
-                           "emitted": len(toks),
-                           "draft_ms": round(draft_ms, 3),
-                           "occupancy": len(active)})
+        self._account(
+            ran, active,
+            [self._decode_envelope(ran.ms, len(active),
+                                   spec_proposed=k * len(active),
+                                   spec_accepted=n_accepted),
+             # the verify window alone (iteration minus draft proposal)
+             # as its own kind: joins with the generate_verify
+             # executable for paddle_mfu{kind=verify}
+             dict(wall_ms=max(ran.ms - draft_ms, 0.0), kind="verify",
+                  occupancy=len(active),
+                  attrs={"draft_ms": round(draft_ms, 4)})],
+            "verify", lambda seq: spans[seq.slot])
         self._enter_phase("sample_emit")
         self._emit_batch(active, toks_lists)
 
@@ -1955,49 +1826,33 @@ class GenerationServer:
         RNG. Lanes whose draft pool lags the target context (one
         position, after a fully-accepted round) catch up first with
         masked feed steps. Returns ``(draft_toks [B, k] int64,
-        {slot: draft_probs [k, vocab]} for sampled lanes)``."""
-        b = self.max_batch
+        {slot: draft_probs [k, vocab]} for sampled lanes)``, or None
+        when a draft step failed (the barrier of ``_dispatch`` has
+        failed every lane of ``active`` then: none can be verified)."""
+        draft = self._runners[1:]
         while True:
             lag = [s for s in active if s.draft_ctx < s.ctx]
             if not lag:
                 break
-            tokens = np.zeros(b, np.int64)
-            positions = np.zeros(b, np.int32)
-            mask = np.zeros(b, bool)
-            ctx_after = np.zeros(b, np.int32)
-            for s in lag:
-                tokens[s.slot] = s.history[s.draft_ctx]
-                positions[s.slot] = s.draft_ctx
-                mask[s.slot] = True
-                ctx_after[s.slot] = s.draft_ctx + 1
-            _, dk, dv, dfresh = self.draft.decode(
-                tokens, positions, mask, ctx_after, self._tables,
-                self._draft_k, self._draft_v)
-            self._draft_k, self._draft_v = dk, dv
-            self.metrics.observe_compile(hit=not dfresh)
+            feeds = self._decode_feeds(
+                lag, [s.history[s.draft_ctx] for s in lag],
+                [s.draft_ctx for s in lag])
+            if self._dispatch("decode", feeds, active, draft) is None:
+                return None
             for s in lag:
                 s.draft_ctx += 1
-        draft_toks = np.zeros((b, k), np.int64)
+        draft_toks = np.zeros((self.max_batch, k), np.int64)
         draft_probs: Dict[int, np.ndarray] = {}
-        feed = np.zeros(b, np.int64)
-        for s in active:
-            feed[s.slot] = s.last_token
+        feed = [s.last_token for s in active]
         for j in range(k):
-            positions = np.zeros(b, np.int32)
-            mask = np.zeros(b, bool)
-            ctx_after = np.zeros(b, np.int32)
-            for s in active:
-                positions[s.slot] = s.draft_ctx
-                mask[s.slot] = True
-                ctx_after[s.slot] = s.draft_ctx + 1
-            logits, dk, dv, dfresh = self.draft.decode(
-                feed, positions, mask, ctx_after, self._tables,
-                self._draft_k, self._draft_v)
-            logits = np.asarray(logits)
-            self._draft_k, self._draft_v = dk, dv
-            self.metrics.observe_compile(hit=not dfresh)
-            for s in active:
-                row = logits[s.slot]
+            ran = self._dispatch(
+                "decode", self._decode_feeds(
+                    active, feed, [s.draft_ctx for s in active]),
+                active, draft)
+            if ran is None:
+                return None
+            for i, s in enumerate(active):
+                row = ran.out[s.slot]
                 if s.req.temperature > 0.0:
                     p = softmax(row, s.req.temperature)
                     probs = draft_probs.setdefault(
@@ -2012,7 +1867,7 @@ class GenerationServer:
                 else:
                     tok = int(row.argmax())
                 draft_toks[s.slot, j] = tok
-                feed[s.slot] = tok
+                feed[i] = tok
                 s.draft_ctx += 1
         return draft_toks, draft_probs
 

@@ -404,54 +404,71 @@ class TestGenerationServer:
         assert srv.metrics_snapshot()["counters"]["rejected"] == 1
         srv.shutdown()   # inline drain resolves the two queued streams
 
-    def test_fault_barrier_decode(self):
-        """A model error mid-decode fails the in-flight streams only;
-        the worker survives and serves the next request."""
+    @pytest.mark.parametrize("kind", [
+        "prefill", "prefill_chunked", "decode", "verify", "draft_decode"])
+    def test_fault_barrier(self, kind):
+        """A model error in one program, of whatever kind, fails the
+        sequences that were in that call, typed, and no other: a stream
+        already decoding outlives a failed prefill, a queued request a
+        failed decode step. Their pages return, the pools are live
+        arrays, and the worker serves the next request."""
+        import jax
         m, cfg = make_model()
-        with GenerationServer(m, max_batch=2, page_size=8,
-                              name="fault") as srv:
-            real = srv.decoder.decode
-            state = {"bombs": 1}
+        spec = kind in ("verify", "draft_decode")
+        pre = list(np.random.RandomState(3).randint(0, cfg.vocab_size, 16))
+        bystander = pre + [1]
+        # two full pages of the bystander's prompt are shared: the
+        # victim's prefill is then the chunked one
+        victim = pre + [2] if kind == "prefill_chunked" else [5, 7, 9]
+        srv = GenerationServer(
+            m, max_batch=2, page_size=8, name=f"fault-{kind}", start=False,
+            **(dict(draft_model=m, spec_k=3) if spec else {}))
+        with srv:
+            decoder, entry = (srv.draft, "decode") \
+                if kind == "draft_decode" else (srv.decoder, kind)
+            real, armed = getattr(decoder, entry), []
 
             def bomb(*a, **kw):
-                if state["bombs"]:
-                    state["bombs"] -= 1
-                    raise RuntimeError("injected decode fault")
+                if armed:
+                    armed.pop()
+                    raise RuntimeError(f"injected {kind} fault")
                 return real(*a, **kw)
 
-            srv.decoder.decode = bomb
-            fut = srv.submit_generate([5, 7, 9], max_new_tokens=6)
-            with pytest.raises(RuntimeError, match="injected"):
-                fut.result(timeout=30)
-            assert fut.finish_reason == "error"
+            setattr(decoder, entry, bomb)
+            if kind.startswith("prefill"):
+                srv.start()
+                ok = srv.submit_generate(bystander, max_new_tokens=24)
+                deadline = time.monotonic() + 60
+                while not ok.tokens() and time.monotonic() < deadline:
+                    time.sleep(0.002)
+                assert ok.tokens()      # prefilled and decoding
+                armed.append(kind)
+                bad = [srv.submit_generate(victim, max_new_tokens=4)]
+            else:
+                armed.append(kind)
+                bad = [srv.submit_generate(p, max_new_tokens=6)
+                       for p in (victim, [1, 2, 3])]
+                # both lanes are taken: this one waits in the queue
+                ok = srv.submit_generate(bystander, max_new_tokens=24)
+                srv.start()
+            for fut in bad:
+                with pytest.raises(RuntimeError, match=f"injected {kind}"):
+                    fut.result(timeout=60)
+                assert fut.finish_reason == "error"
+            assert not armed
+            assert ok.result(timeout=60) == \
+                self._reference(m, cfg, bystander, 24)
+            assert srv.generate(victim, max_new_tokens=6,
+                                timeout_ms=None) == \
+                self._reference(m, cfg, victim, 6)
+            counters = srv.metrics_snapshot()["counters"]
+            assert counters["failed"] == len(bad)
+            assert counters["completed"] == 2
+            srv.clear_prefix_cache()
+            srv.kv.assert_no_leaks()
             assert srv.kv.free_pages == srv.kv.capacity
-            got = srv.generate([5, 7, 9], max_new_tokens=6,
-                               timeout_ms=None)
-            assert got == self._reference(m, cfg, [5, 7, 9], 6)
-            snap = srv.metrics_snapshot()
-            assert snap["counters"]["failed"] == 1
-            assert snap["counters"]["completed"] == 1
-
-    def test_fault_barrier_prefill(self):
-        m, cfg = make_model()
-        with GenerationServer(m, max_batch=2, page_size=8,
-                              name="pfault") as srv:
-            real = srv.decoder.prefill
-            state = {"bombs": 1}
-
-            def bomb(*a, **kw):
-                if state["bombs"]:
-                    state["bombs"] -= 1
-                    raise RuntimeError("injected prefill fault")
-                return real(*a, **kw)
-
-            srv.decoder.prefill = bomb
-            fut = srv.submit_generate([5, 7], max_new_tokens=2)
-            with pytest.raises(RuntimeError, match="injected"):
-                fut.result(timeout=30)
-            assert srv.kv.free_pages == srv.kv.capacity
-            assert srv.generate([5, 7], max_new_tokens=2) == \
-                self._reference(m, cfg, [5, 7], 2)
+            assert all(not a.is_deleted() for a in
+                       jax.tree_util.tree_leaves((srv.kv.k, srv.kv.v)))
 
     def test_shutdown_no_drain_fails_queued(self):
         from paddle_tpu.serving import ServerClosedError

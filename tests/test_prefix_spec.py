@@ -507,6 +507,56 @@ class TestWarmupManifestSites:
         assert set(srv2.decoder.compiled_signatures) == sigs
         srv2.shutdown()
 
+    @pytest.mark.parametrize("site", [
+        "generate_prefill", "generate_chunked", "generate_decode",
+        "generate_verify"])
+    def test_manifest_feeds_are_the_compiled_signatures(self, cache_dir,
+                                                        site):
+        """The manifest's feeds are derived from the arrays a program
+        was given: for every site traffic took, its specs name exactly
+        the shapes and dtypes ``CachedDecoder.compiled_signatures``
+        recorded there, and a second server warmed from the manifest
+        compiles nothing new under the same traffic."""
+        m, cfg = make_model()
+        pre = list(np.random.RandomState(7).randint(
+            0, cfg.vocab_size, 16))
+
+        def server(model, name, **kw):
+            if site == "generate_verify":    # only speculation verifies
+                kw.update(draft_model=model, spec_k=3)
+            return GenerationServer(model, max_batch=2, page_size=8,
+                                    name=name, **kw)
+
+        def traffic(srv):
+            srv.generate(pre + [1], max_new_tokens=6)    # cold prefill
+            srv.generate(pre + [2], max_new_tokens=6)    # chunked hit
+            srv.generate([5, 7, 9], max_new_tokens=3)    # another bucket
+
+        with server(m, f"sig-{site}") as srv:
+            traffic(srv)
+            man = srv.warmup_manifest
+            recorded = sorted(
+                [(tuple(shape), dtype) for shape, dtype in e["feeds"]]
+                for e in man.specs(site=site))
+            assert recorded
+            # a signature is (site, feeds..., the pools' leaves)
+            n = len(recorded[0])
+            compiled = sorted(list(sig[1:1 + n]) for sig
+                              in srv.decoder.compiled_signatures
+                              if sig[0] == site)
+            assert recorded == compiled
+            path = man.path
+        srv2 = server(make_model()[0], f"sig2-{site}", start=False)
+        srv2.warmup_from_manifest(path)
+        warmed = set(srv2.decoder.compiled_signatures)
+        assert {sig for sig in warmed if sig[0] == site} == {
+            sig for sig in srv.decoder.compiled_signatures
+            if sig[0] == site}
+        srv2.start()
+        traffic(srv2)
+        assert set(srv2.decoder.compiled_signatures) == warmed
+        srv2.shutdown()
+
 
 # ------------------------------------------------- tracing hookup
 class TestTracingHookup:

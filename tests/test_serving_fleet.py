@@ -948,7 +948,11 @@ class TestWedgeWatchdog:
 
             t = threading.Thread(target=_poison, daemon=True)
             t.start()
-            time.sleep(0.05)    # poison reaches the device first
+            # the poison holds the device before the waiter is sent
+            # (however long a busy machine takes to get it there)
+            assert _wait(lambda: be._hang.is_set()
+                         and be._device.locked(), timeout=30,
+                         interval=0.005)
             # the waiter queued behind the wedge fails TYPED once the
             # watchdog fires — never blocks past the bound
             req = urllib.request.Request(

@@ -6,7 +6,8 @@ in-flight batches (an error in batch N must not poison batch N+1 or
 kill the completion thread), >=2 shape buckets in flight, the
 staging-buffer pool, warmup exclusion from traffic metrics, the
 host_ms/device_ms stage split in metrics_json, and a fast-tier smoke
-that pipelined throughput is not below the serial-batched executor.
+that the pipelined executor overlaps the batches the serial-batched one
+runs one after another.
 """
 import json
 import time
@@ -169,10 +170,12 @@ class TestPipelineRobustness:
         """Two shape buckets' worth of traffic interleaved: the batcher
         dispatches a FULL bucket even while an older, still-open window
         is gathering a different signature, and the pipeline keeps both
-        in flight without cross-talk."""
+        apart. Asserted by order and counts, not by a duration: the
+        older window cannot close by itself within this test."""
         rng = np.random.RandomState(6)
         srv = serving.InferenceServer(seq_predictor, max_batch_size=2,
-                                      max_wait_ms=200, pipeline_depth=2,
+                                      max_wait_ms=120_000,
+                                      pipeline_depth=2,
                                       seq_buckets=[4, 8], seq_axis=1,
                                       name="t_pl_2bkt", start=False)
         # one request in the seq=4 bucket opens a LONG window...
@@ -180,16 +183,21 @@ class TestPipelineRobustness:
         # ...then a FULL seq=8 bucket arrives behind it
         fast = srv.submit_many(
             [[rng.randn(1, 7, 8).astype("float32")] for _ in range(2)])
-        t0 = time.monotonic()
         srv.start()
         for f in fast:
-            f.result(timeout=60)
-        fast_done = time.monotonic() - t0
-        # the full bucket did not wait out the 200ms window of the
-        # older, incompatible head-of-line request
-        assert fast_done < 0.15
-        slow.result(timeout=60)
-        assert srv.metrics.snapshot()["counters"]["batches"] == 2
+            assert f.result(timeout=60)[0].shape == (1, 7, 4)
+        # the full bucket went through, and did not wait out (or
+        # close) the window of the older, incompatible head-of-line
+        # request, which is still gathering
+        assert not slow.done()
+        assert srv.metrics.snapshot()["counters"]["batches"] == 1
+        # a second seq=4 request fills that window's bucket: the two
+        # leave at once, as one batch
+        filler = srv.submit([rng.randn(1, 2, 8).astype("float32")])
+        assert slow.result(timeout=60)[0].shape == (1, 3, 4)
+        assert filler.result(timeout=60)[0].shape == (1, 2, 4)
+        counters = srv.metrics.snapshot()["counters"]
+        assert counters["batches"] == 2 and counters["completed"] == 4
         srv.shutdown()
 
     def test_drain_completes_inflight(self, predictor):
@@ -287,10 +295,15 @@ class TestPipelineMetrics:
 
 class TestPipelineThroughputSmoke:
     def test_pipelined_not_slower_than_sync_batched(self, tmp_path):
-        """Fast-tier smoke for the perf claim: pipelined throughput >=
-        the serial-batched executor's on the same traffic (a generous
-        0.85 tolerance absorbs CI timing noise; the real gauge is
-        tools/bench_serving.py --pipeline)."""
+        """Fast-tier smoke for the perf claim, as far as a shared CPU
+        can carry it: on the same traffic the pipelined executor forms
+        the same batches and answers the same as the serial-batched
+        one, and it OVERLAPS them (batch N + 1 is dispatched while
+        batch N is not yet completed, never more than the depth
+        allows), which the serial one never does. That overlap is
+        where the throughput comes from; how much is a chip's to say
+        (tools/bench_serving.py --pipeline), not a wall clock's
+        here."""
         pred = _export(tmp_path, [None, 8], "m_smoke", width=256)
         rng = np.random.RandomState(12)
         reqs = [[rng.randn(1, 8).astype("float32")] for _ in range(96)]
@@ -301,15 +314,33 @@ class TestPipelineThroughputSmoke:
                 pipeline_depth=depth, queue_capacity=len(reqs) + 1,
                 name=name, start=False)
             srv.warmup()
-            t0 = time.perf_counter()
+            inflight, complete = [], srv._complete
+
+            def spy(inf):
+                # the first batch is held back until the loop thread
+                # has dispatched the second (it must not need the
+                # first's completion for that)
+                deadline = time.monotonic() + 60
+                while depth and not inflight \
+                        and srv.inflight_batches < 2 \
+                        and time.monotonic() < deadline:
+                    time.sleep(0.001)
+                inflight.append(srv.inflight_batches)
+                return complete(inf)
+
+            srv._complete = spy
             futs = srv.submit_many(reqs)
             srv.start()
-            for f in futs:
-                f.result(timeout=120)
-            dt = time.perf_counter() - t0
+            outs = [f.result(timeout=120)[0] for f in futs]
+            batches = srv.metrics.snapshot()["counters"]["batches"]
             srv.shutdown()
-            return len(reqs) / dt
+            return outs, batches, inflight
 
-        sync_rps = run(0, "t_pl_smoke_sync")
-        pipe_rps = run(2, "t_pl_smoke_pipe")
-        assert pipe_rps >= 0.85 * sync_rps, (pipe_rps, sync_rps)
+        sync_outs, sync_batches, sync_inflight = run(0, "t_pl_smoke_sync")
+        pipe_outs, pipe_batches, pipe_inflight = run(2, "t_pl_smoke_pipe")
+        for a, b in zip(sync_outs, pipe_outs):
+            np.testing.assert_array_equal(a, b)
+        assert sync_batches == pipe_batches == len(reqs) // 8
+        assert len(sync_inflight) == len(pipe_inflight) == sync_batches
+        assert max(sync_inflight) == 0       # nothing is ever in flight
+        assert 2 <= max(pipe_inflight) <= 2 + 1, pipe_inflight
