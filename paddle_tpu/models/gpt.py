@@ -663,8 +663,20 @@ class GPTMLP(Layer):
         # tanh-approximate gelu: GPT-2's canonical "gelu_new", and the
         # same form the stacked decoder uses (keeps the two paths
         # numerically consistent)
-        return self.dropout(self.fc_out(F.gelu(self.fc_in(x),
-                                               approximate=True)))
+        h = self.fc_in(x)
+        if x.shape[-2] > 1:
+            # More than one position a row (a prefill, a chunk, a verify
+            # window): the barrier makes the up-projection an array of
+            # the program. Without it the TPU's compiler may put fc_in's
+            # product inside fc_out's fusion as a producer and compute
+            # it again for every output tile (1.3B prefill[1,256] took
+            # 108 ms for it where [1,512] takes 16: PERF.md, PR 32). The
+            # GELU stays free to fuse into fc_out. A decode step's
+            # activation is a single tile whichever way it is fused, and
+            # the barrier would cost it one fusion boundary a layer.
+            h = apply_op("optimization_barrier",
+                         jax.lax.optimization_barrier, h)
+        return self.dropout(self.fc_out(F.gelu(h, approximate=True)))
 
 
 class GPTDecoderLayer(Layer):

@@ -16,6 +16,7 @@ sees this process's CPU; the ``compiled_kernels`` fixture steers it
 from here.
 """
 import functools
+import math
 import re
 
 import jax
@@ -265,26 +266,17 @@ POOL_PROGRAMS = {
 }
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill"])
-@pytest.mark.parametrize("config", sorted(POOL_PROGRAMS))
-def test_no_program_copies_a_pool_at_its_boundary(
-        one_chip, compiled_kernels, config, program):
-    """The decode program and a prefill program of a ``CachedDecoder``
-    built with defaults, compiled for the chip over donated pools of
-    the cells' sizes (gpt2-medium f32, 1024 lanes a row; gpt3-1p3b f32,
-    2048; SmallThinker bf16, 512, a whole-context and a window pool):
-    every pool-shaped array in the program is row-major, the arguments
-    among them, and no ``copy`` makes one. With the heads an axis of
-    the pool the compiler made the page axis minor for 64-wide heads
-    and each layer's scatter and kernel paid four transposing copies of
-    a whole pool: 96 a gpt2-medium decode step (PERF.md, PR 30)."""
+def _lower_serve_program(one_chip, config, program):
+    """``(model, lanes, lowered)``: ``program`` (``"decode"`` or a
+    prefill's ``(rows, seq)``) of a ``CachedDecoder`` built with
+    defaults over ``POOL_PROGRAMS[config]``'s model, lanes, table and
+    donated pools, lowered for the described chip."""
     import paddle_tpu as paddle
     from paddle_tpu import models
     from paddle_tpu.jit.functional import state_arrays
     from paddle_tpu.ops.paged_attention import ring_pages
     from paddle_tpu.serving.generation.model_fns import CachedDecoder
-    cuts, lanes, slots, (pages, window_pages), (rows, seq) = \
-        POOL_PROGRAMS[config]
+    cuts, lanes, slots, (pages, window_pages), _ = POOL_PROGRAMS[config]
     paddle.seed(0)
     model = models.GPTForCausalLM(getattr(models, config)(
         num_layers=2, **cuts))
@@ -312,9 +304,30 @@ def test_no_program_copies_a_pool_at_its_boundary(
             sds((lanes,), jnp.int32), sds((lanes,), jnp.bool_),
             sds((lanes,), jnp.int32), sds((lanes, width), jnp.int32), k, v)
     else:
+        rows, seq = program
         lowered = dec._prefill_jit.lower(
             params, buffers, sds((rows, seq), jnp.int64),
             sds((rows,), jnp.int32), sds((rows, width), jnp.int32), k, v)
+    return model, lanes, lowered
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("config", sorted(POOL_PROGRAMS))
+def test_no_program_copies_a_pool_at_its_boundary(
+        one_chip, compiled_kernels, config, program):
+    """The decode program and a prefill program of a ``CachedDecoder``
+    built with defaults, compiled for the chip over donated pools of
+    the cells' sizes (gpt2-medium f32, 1024 lanes a row; gpt3-1p3b f32,
+    2048; SmallThinker bf16, 512, a whole-context and a window pool):
+    every pool-shaped array in the program is row-major, the arguments
+    among them, and no ``copy`` makes one. With the heads an axis of
+    the pool the compiler made the page axis minor for 64-wide heads
+    and each layer's scatter and kernel paid four transposing copies of
+    a whole pool: 96 a gpt2-medium decode step (PERF.md, PR 30)."""
+    pages, window_pages = POOL_PROGRAMS[config][3]
+    model, _, lowered = _lower_serve_program(
+        one_chip, config,
+        program if program == "decode" else POOL_PROGRAMS[config][4])
     text = lowered.compile().as_text()
     spec = model.kv_cache_spec()
     for n in {pages, window_pages or pages}:
@@ -324,6 +337,91 @@ def test_no_program_copies_a_pool_at_its_boundary(
         layouts = set(re.findall(pool + r"\{([\d,]+)", text))
         assert layouts == {"2,1,0"}, layouts
         assert not re.findall(pool + r"\S* copy\(", text)
+
+
+# the single-row prefill of every bucket a GPT cell warms, a two-row
+# prefill and the decode step: where the compiler's choice of what to
+# fuse into the MLP's down-projection turns on the activation's size
+MLP_PROGRAMS = [
+    (config, program)
+    for config, buckets in (("gpt2_medium", (128, 256, 512, 768)),
+                            ("gpt3_1p3b", (128, 256, 512, 1024)))
+    for program in ("decode", *((1, seq) for seq in buckets), (2, 128))]
+
+
+def _computations(text):
+    """``{name: instruction lines}`` of a compiled module's HLO text,
+    and the entry computation's name."""
+    comps, entry, name = {}, None, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            name = head.group(2)
+            comps[name] = []
+            if head.group(1):
+                entry = name
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    return comps, entry
+
+
+@pytest.mark.parametrize(
+    "config,program", MLP_PROGRAMS,
+    ids=[f"{c}-{p if p == 'decode' else 'prefill[%d,%d]' % p}"
+         for c, p in MLP_PROGRAMS])
+def test_no_matmul_is_computed_inside_another_matmuls_fusion(
+        one_chip, compiled_kernels, config, program):
+    """In no prefill program a GPT serve cell warms (two layers at full
+    width, the cells' lanes, tables and pools, compiled for the chip)
+    does a computation that holds a ``convolution`` call a fusion whose
+    computation holds one: a product that is a *producer* inside
+    another product's fusion is computed again for every output tile of
+    it. Without the barrier in ``GPTMLP.forward`` (the parent of PR
+    32, where these ten cases fail) the compiler nests ``fc_in``'s
+    product and GELU inside ``fc_out``'s fusion in six of them
+    (gpt3_1p3b ``prefill[1,128]``, ``[1,256]``; gpt2_medium
+    ``[1,128]``, ``[1,256]``, ``[1,512]``, ``[2,128]``), and 1.3B's
+    ``[1,256]`` took 108 ms for it where ``[1,512]`` takes 16 (PERF.md,
+    PR 32). With it the up-projection is an array of the entry
+    computation, written once by one fusion a layer. The decode step,
+    one position a row, is left to the compiler (the barrier costs it a
+    fusion boundary a layer: 2.4% of gpt2-medium's step): it lowers
+    without a barrier, and what the compiler nests there is a product
+    of ``lanes`` rows, a single tile."""
+    model, lanes, lowered = _lower_serve_program(one_chip, config, program)
+    barriers = lowered.as_text().count("optimization_barrier")
+    comps, entry = _computations(lowered.compile().as_text())
+    calls = {name: re.findall(r" fusion\(.*calls=%?([\w.\-]+)",
+                              "\n".join(lines))
+             for name, lines in comps.items()}
+    # rows of each product a computation holds: its result's dimensions
+    # but the last, multiplied
+    products = {name: [math.prod(map(int, dims.split(",")[:-1]))
+                       for dims in re.findall(
+                           r"= \w+\[([\d,]+)\]\S* convolution\(",
+                           "\n".join(lines))]
+                for name, lines in comps.items()}
+    assert sum(map(bool, products.values())) >= 8   # qkv, out, fc_in, fc_out
+
+    def rows_inside(name):
+        return products[name] + [
+            r for inner in calls[name] for r in rows_inside(inner)]
+
+    nested = [rows for outer in sorted(comps) if products[outer]
+              for inner in calls[outer] if (rows := rows_inside(inner))]
+    if program == "decode":
+        assert barriers == 0
+        assert all(rows == [lanes] for rows in nested), nested
+        return
+    assert not nested, nested
+    assert barriers == 2                        # one a layer
+    if (config, program) == ("gpt3_1p3b", (1, 256)):
+        inter = model.config.intermediate_size
+        written = [line for line in comps[entry] if re.search(
+            rf"= f32\[1,256,{inter}\]\S* fusion\(", line)]
+        assert len(written) == 2, written       # one a layer
 
 
 def test_every_paged_block_candidate_compiles(one_chip, compiled_kernels):

@@ -106,3 +106,71 @@ class TestGPTTrain:
         n = m.num_params()
         # embedding 256*64 + pos 128*64 + 2 blocks + ln_f
         assert n > 256 * 64
+
+
+class TestMLPBarrier:
+    """Over more than one position a row ``GPTMLP.forward`` holds
+    ``fc_in``'s output behind an ``optimization_barrier`` (PERF.md, PR
+    32): it changes what the TPU's compiler may fuse, and nothing else.
+    Loss and every parameter's gradient through the module stack are
+    bitwise those of the plain expression ``fc_out(gelu(fc_in(x)))`` in
+    its place."""
+
+    @staticmethod
+    def _plain(self, x):
+        from paddle_tpu.nn import functional as F
+        return self.dropout(self.fc_out(F.gelu(self.fc_in(x),
+                                               approximate=True)))
+
+    @staticmethod
+    def _eager(m, ids):
+        crit = GPTPretrainingCriterion()
+        m.clear_gradients()
+        loss = crit(m(ids), ids)
+        loss.backward()
+        return loss.numpy(), {n: p.grad.numpy()
+                              for n, p in m.named_parameters()}
+
+    @staticmethod
+    def _jitted(m, ids):
+        import jax
+        from paddle_tpu.jit.functional import functional_call, state_arrays
+        crit = GPTPretrainingCriterion()
+        params, buffers = state_arrays(m)
+
+        def loss_fn(params):
+            logits = functional_call(m, params, buffers, ids)
+            return crit(logits, ids)._data
+        loss, grads = jax.jit(jax.value_and_grad(loss_fn))(params)
+        return np.asarray(loss), {n: np.asarray(g) for n, g in grads.items()}
+
+    @staticmethod
+    def _barriers(m, ids):
+        import jax
+        from paddle_tpu.jit.functional import functional_call, state_arrays
+        params, buffers = state_arrays(m)
+        return str(jax.make_jaxpr(lambda p: functional_call(
+            m, p, buffers, ids))(params)).count("optimization_barrier")
+
+    @pytest.mark.parametrize("what", ["loss", "grads"])
+    @pytest.mark.parametrize("mode", ["eager", "jit"])
+    def test_equals_the_unbarriered_expression(self, monkeypatch, mode,
+                                               what):
+        from paddle_tpu.models.gpt import GPTMLP
+        m, cfg, ids = make()
+        m.eval()
+        run = self._eager if mode == "eager" else self._jitted
+        loss, grads = run(m, ids)
+        assert self._barriers(m, ids) == cfg.num_layers    # one a block
+        assert self._barriers(m, ids[:, :1]) == 0   # a decode step's shape
+        monkeypatch.setattr(GPTMLP, "forward", self._plain)
+        assert self._barriers(m, ids) == 0
+        plain_loss, plain_grads = run(m, ids)
+        if what == "loss":
+            assert np.isfinite(loss) and loss.tobytes() == \
+                plain_loss.tobytes()
+            return
+        assert len(grads) == len(plain_grads) > 10
+        for name, g in grads.items():
+            assert np.abs(g).max() > 0 or "bias" in name, name
+            assert g.tobytes() == plain_grads[name].tobytes(), name
