@@ -31,6 +31,12 @@ are held to the plain float32 reference's full forward
 (``benchmarks/reference/smallthinker.py``), with the reference's own
 float8 control beside them, which has to fail.
 
+``--phase kexaone`` is the same comparison for the share of
+``k_exaone_236b_a23b`` the benchmark serves (5 layers, experts 0-15 of
+128, 19,200 vocabulary rows): a 1,500-token prompt through
+``prefill[1,2048]``, 64 decode steps, against
+``benchmarks/reference/kexaone.py``.
+
 ``--chips 4`` runs instead the paths that exist only across chips, and
 what they are compared with: one TrainStep over a dp x mp mesh against
 the single-device step, and an mp=4 ``ServingMesh`` server against the
@@ -375,10 +381,34 @@ def logit_parity(srv, model, prompt, new_tokens, seq_bucket) -> tuple:
 # reference's control with every activation rounded to float8
 # e4m3, 1.07, which has to lie above it: 6 x room below, 10 x above.
 LOGIT_RMS_TOL = 0.1
+# the same for --phase kexaone (5 layers, 16 of 128 experts held, 1/8 of
+# the vocabulary; my chip runs, PR 33): the program 0.0229 (its worst
+# single row 0.090), the float8 control 0.310 (weights, activations and
+# the residual stream in float8; activations alone read 0.162: an eighth
+# of the routed experts is in the sum, so a token sent elsewhere by a
+# rounded router moves one term in eight, beside a shared expert and a
+# dense layer that do not route). 0.06: 2.6 x room below, 5.2 x above,
+# and under the control of activations alone too.
+KEXAONE_LOGIT_RMS_TOL = 0.06
+
+
+# --phase -> the preset in paddle_tpu.models, the cut it is built with and
+# what phase_cached_logits is told
+CACHED_LOGITS_PHASES = {
+    "smallthinker": ("smallthinker_21ba3b",
+                     dict(num_layers=8, dtype="bfloat16"),
+                     dict(prompt_len=6000, new_tokens=64, seq_bucket=8192)),
+    "kexaone": ("k_exaone_236b_a23b",
+                dict(num_layers=5, moe_num_experts=16, vocab_size=19200,
+                     dtype="bfloat16"),
+                dict(prompt_len=1500, new_tokens=64, seq_bucket=2048,
+                     reference="kexaone", tol=KEXAONE_LOGIT_RMS_TOL)),
+}
 
 
 def phase_cached_logits(cfg, *, prompt_len, new_tokens, seq_bucket,
-                        page_size=16, seed=0, tol=LOGIT_RMS_TOL) -> dict:
+                        page_size=16, seed=0, tol=LOGIT_RMS_TOL,
+                        reference="smallthinker") -> dict:
     """Prefill then decode through ``CachedDecoder`` and the cache
     manager's own pools and table row, one lane, greedy; every step's
     logits against the full forward of the configuration's plain
@@ -395,7 +425,7 @@ def phase_cached_logits(cfg, *, prompt_len, new_tokens, seq_bucket,
     from paddle_tpu.serving.generation.model_fns import CachedDecoder
 
     reference = common.load_module(os.path.join(
-        common.HERE, "reference", "smallthinker.py"), "smallthinker_ref")
+        common.HERE, "reference", reference + ".py"), reference + "_ref")
     paddle.seed(seed)
     model = GPTForCausalLM(cfg)
     model.eval()
@@ -774,9 +804,10 @@ def main(argv=None) -> int:
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4),
                     help="4 runs only the cross-chip phase and what it "
                          "is compared with")
-    ap.add_argument("--phase", default="gpt", choices=("gpt", "smallthinker"),
-                    help="smallthinker: only the cached-logits comparison "
-                         "of the expert configuration's 8-layer cut")
+    ap.add_argument("--phase", default="gpt",
+                    choices=("gpt", *CACHED_LOGITS_PHASES),
+                    help="smallthinker, kexaone: only the cached-logits "
+                         "comparison of that expert configuration's cut")
     args = ap.parse_args(argv)
 
     from paddle_tpu.compile_cache import aot_cache_dir, place_jax_cache
@@ -811,11 +842,11 @@ def main(argv=None) -> int:
             f"{counter.since(snap)}")
         return out
 
-    if args.phase == "smallthinker":
-        from paddle_tpu.models import smallthinker_21ba3b
+    if args.phase in CACHED_LOGITS_PHASES:
+        from paddle_tpu import models
+        preset, cut, told = CACHED_LOGITS_PHASES[args.phase]
         timed("cached-logits", phase_cached_logits,
-              cfg=smallthinker_21ba3b(num_layers=8, dtype="bfloat16"),
-              prompt_len=6000, new_tokens=64, seq_bucket=8192)
+              cfg=getattr(models, preset)(**cut), **told)
     elif args.chips == 1:
         timed("train", phase_train,
               cfg=gpt3_1p3b(stacked=True, recompute="full"),

@@ -5,6 +5,7 @@ from .gpt import (  # noqa: F401
     GPTConfig, GPTKVCache, GPTModel, GPTForCausalLM,
     GPTPretrainingCriterion, gpt2_medium,
     gpt_tiny, gpt2_small, gpt2_large, gpt3_1p3b, smallthinker_21ba3b,
+    k_exaone_236b_a23b,
 )
 from .bert import (  # noqa: F401
     BertConfig, BertModel, BertForPretraining,

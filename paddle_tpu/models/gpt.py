@@ -42,7 +42,7 @@ __all__ = [
     "GPTConfig", "GPTModel", "GPTForCausalLM", "GPTPretrainingCriterion",
     "GPTKVCache",
     "gpt_tiny", "gpt2_small", "gpt2_medium", "gpt3_1p3b",
-    "smallthinker_21ba3b",
+    "smallthinker_21ba3b", "k_exaone_236b_a23b",
 ]
 
 
@@ -89,17 +89,44 @@ class GPTConfig:
     sliding_window: int = 0          # positions a token attends, itself
     #                                  among them, on the layers
     sliding_window_layout: tuple = ()  # marks (per layer 0/1; () -> all)
-    moe_num_experts: int = 0         # 0 -> the dense GELU MLP
+    moe_num_experts: int = 0         # experts held here; 0 -> the dense
+    #                                  MLP on every layer
     moe_top_k: int = 0
-    moe_intermediate_size: int = 0   # width of one ReGLU expert
+    moe_intermediate_size: int = 0   # width of one gated expert
     # what the router reads: the MLP's normed input, or the
     # attention's ("router placed before attention")
     moe_router_input: str = "mlp_input"
     dtype: str = ""                  # parameters are created in it
     #                                  ('' -> float32)
+    qk_norm: bool = False            # RMSNorm over head_dim on q and k
+    #                                  (one weight vector a layer each),
+    #                                  before RoPE
+    mlp_kind: str = "gelu"           # the dense MLP: fc_in-GELU-fc_out,
+    #                                  or "swiglu": (silu(x Wg) * x Wu) Wd,
+    #                                  no biases
+    moe_layout: tuple = ()           # per layer 0/1: which layers have
+    #                                  experts; () -> every layer
+    # one chip's share of an expert-parallel layer: the router has
+    # moe_router_experts outputs (0 -> moe_num_experts: all held) and
+    # the weights hold experts moe_expert_offset .. + moe_num_experts-1
+    moe_router_experts: int = 0
+    moe_expert_offset: int = 0
+    moe_scoring: str = "softmax_top_k"  # or "sigmoid_norm": sigmoid
+    #                                  scores, normalised over the chosen,
+    moe_routed_scale: float = 1.0    # times this
+    moe_activation: str = "relu"     # the experts' gate: or "silu"
+    moe_shared_intermediate_size: int = 0  # width of the expert every
+    #                                  token goes through; 0 -> none
+    moe_token_block: int = 0         # rows an expert layer computes at a
+    #                                  time (bounds what a long prefill
+    #                                  keeps); 0 -> all at once
 
     NEW_FIELDS = ("num_kv_heads", "head_dim", "norm", "bias", "position",
-                  "sliding_window", "moe_num_experts", "dtype")
+                  "sliding_window", "moe_num_experts", "dtype", "qk_norm",
+                  "mlp_kind", "moe_layout", "moe_router_experts",
+                  "moe_expert_offset", "moe_scoring", "moe_routed_scale",
+                  "moe_activation", "moe_shared_intermediate_size",
+                  "moe_token_block")
 
     def __post_init__(self):
         if self.intermediate_size == 0:
@@ -130,17 +157,36 @@ class GPTConfig:
             raise ValueError(
                 f"moe_router_input must be 'mlp_input' or "
                 f"'attention_input', got {self.moe_router_input!r}")
-        for name in ("rope_layout", "sliding_window_layout"):
+        for name in ("rope_layout", "sliding_window_layout", "moe_layout"):
             layout = tuple(int(v) for v in getattr(self, name))
             if layout and len(layout) != self.num_layers:
                 raise ValueError(f"{name} has {len(layout)} entries for "
                                  f"{self.num_layers} layers")
             setattr(self, name, layout)
+        if self.moe_num_experts and self.moe_router_experts == 0:
+            self.moe_router_experts = self.moe_num_experts
         if self.moe_num_experts and not (
-                0 < self.moe_top_k <= self.moe_num_experts
+                0 < self.moe_top_k <= self.moe_router_experts
                 and self.moe_intermediate_size > 0):
             raise ValueError("experts need moe_top_k and "
                              "moe_intermediate_size")
+        if self.mlp_kind not in ("gelu", "swiglu"):
+            raise ValueError(f"mlp_kind must be 'gelu' or 'swiglu', "
+                             f"got {self.mlp_kind!r}")
+        if self.moe_scoring not in ("softmax_top_k", "sigmoid_norm"):
+            raise ValueError(
+                f"moe_scoring must be 'softmax_top_k' or 'sigmoid_norm', "
+                f"got {self.moe_scoring!r}")
+        if self.moe_activation not in ("relu", "silu"):
+            raise ValueError(f"moe_activation must be 'relu' or 'silu', "
+                             f"got {self.moe_activation!r}")
+        if self.moe_num_experts and not (
+                0 <= self.moe_expert_offset
+                <= self.moe_router_experts - self.moe_num_experts):
+            raise ValueError(
+                f"experts {self.moe_expert_offset}.."
+                f"{self.moe_expert_offset + self.moe_num_experts - 1} "
+                f"are not among the router's {self.moe_router_experts}")
         if self.recompute not in ("full", "dots", "attn", "none"):
             raise ValueError(
                 f"recompute must be 'full', 'dots', 'attn' or 'none', "
@@ -169,6 +215,11 @@ class GPTConfig:
             if self.sliding_window_layout else 1
         return int(self.sliding_window) if marked else None
 
+    def layer_experts(self, layer: int) -> bool:
+        """Whether layer ``layer``'s MLP is the expert layer."""
+        return bool(self.moe_num_experts) and bool(
+            self.moe_layout[layer] if self.moe_layout else 1)
+
     def num_params(self) -> int:
         """Parameters of the model these fields describe, reckoned
         without building it."""
@@ -179,14 +230,21 @@ class GPTConfig:
         attn = h * (qd + 2 * kvd) + qd * h
         if self.bias:
             attn += qd + 2 * kvd + h
-        if self.moe_num_experts:
-            mlp = h * self.moe_num_experts + self.moe_num_experts * 3 \
-                * h * self.moe_intermediate_size
+        if self.qk_norm:
+            attn += 2 * self.head_dim
+        sparse = h * self.moe_router_experts + 3 * h * (
+            self.moe_num_experts * self.moe_intermediate_size
+            + self.moe_shared_intermediate_size)
+        if self.mlp_kind == "swiglu":
+            dense = 3 * h * self.intermediate_size
         else:
-            mlp = 2 * h * self.intermediate_size
+            dense = 2 * h * self.intermediate_size
             if self.bias:
-                mlp += self.intermediate_size + h
-        total = v * h + self.num_layers * (2 * norm + attn + mlp) + norm
+                dense += self.intermediate_size + h
+        n_sparse = sum(self.layer_experts(i)
+                       for i in range(self.num_layers))
+        total = v * h + self.num_layers * (2 * norm + attn) \
+            + n_sparse * sparse + (self.num_layers - n_sparse) * dense + norm
         if self.position == "learned":
             total += self.max_seq_len * h
         if not self.tie_word_embeddings:
@@ -247,6 +305,38 @@ def smallthinker_21ba3b(**kw) -> GPTConfig:
              moe_num_experts=64, moe_top_k=6, moe_intermediate_size=768,
              moe_router_input="attention_input", tie_word_embeddings=False,
              use_flash_attention=True)
+    d.update(kw)
+    return GPTConfig(**d)
+
+
+def k_exaone_236b_a23b(**kw) -> GPTConfig:
+    """K-EXAONE-236B-A23B (LG AI Research, config.json, ``exaone_moe``):
+    48 layers of 64 query heads over 8 K/V heads of 128 with RMSNorm on
+    q and k, RMSNorm (eps 1e-5), no biases, untied head; every fourth
+    layer (3, 7, ...) attends the whole context with no positions at
+    all, the others the last 128 with RoPE (theta 1e6); layer 0 a SwiGLU
+    MLP of width 18,432, every other layer 128 SwiGLU experts of width
+    2,048, 8 a token by sigmoid scores normalised over the chosen and
+    scaled 2.5, beside one shared expert of the same width. The
+    multi-token-prediction module is not built. ``num_layers`` keeps
+    the leading layers under their published kinds; ``moe_num_experts``
+    (with ``moe_expert_offset``) is the share of the 128 experts held,
+    ``vocab_size`` the rows of the vocabulary held; ``dtype`` is the
+    parameters'."""
+    layers = int(kw.get("num_layers", 48))
+    window = tuple(0 if i % 4 == 3 else 1 for i in range(layers))
+    d = dict(vocab_size=153600, hidden_size=6144, num_layers=layers,
+             num_heads=64, num_kv_heads=8, head_dim=128,
+             max_seq_len=262144, intermediate_size=18432, norm="rmsnorm",
+             layer_norm_eps=1e-5, bias=False, position="rope",
+             rope_theta=1e6, rope_layout=window, sliding_window=128,
+             sliding_window_layout=window, qk_norm=True, mlp_kind="swiglu",
+             moe_layout=tuple(int(i >= 1) for i in range(layers)),
+             moe_num_experts=128, moe_router_experts=128, moe_top_k=8,
+             moe_intermediate_size=2048, moe_shared_intermediate_size=2048,
+             moe_scoring="sigmoid_norm", moe_routed_scale=2.5,
+             moe_activation="silu", moe_token_block=4096,
+             tie_word_embeddings=False, use_flash_attention=True)
     d.update(kw)
     return GPTConfig(**d)
 
@@ -497,8 +587,9 @@ def _rope(x, positions, theta):
 class GPTGroupedAttention(Layer):
     """Attention for what ``GPTAttention`` cannot express: fewer K/V
     heads than query heads, a head size of its own, rotary or no
-    positions, a sliding window, no biases. Separate q/k/v projections
-    (each splits over 'mp' by whole heads)."""
+    positions, a sliding window, no biases, RMSNorm on q and k (over a
+    head, before the positions). Separate q/k/v projections (each
+    splits over 'mp' by whole heads)."""
 
     def __init__(self, config: GPTConfig, layer: int):
         super().__init__()
@@ -532,10 +623,22 @@ class GPTGroupedAttention(Layer):
             self.k_b = mk([kvd], ("mp",), True)
             self.v_b = mk([kvd], ("mp",), True)
             self.out_b = mk([h], (None,), True)
+        self.qk_norm_eps = float(config.layer_norm_eps) \
+            if config.qk_norm else None
+        if config.qk_norm:
+            ones = I.Constant(1.0)
+            self.q_norm_w = create_parameter_with_attr(
+                [self.head_dim], dt, None, False, default_initializer=ones)
+            self.k_norm_w = create_parameter_with_attr(
+                [self.head_dim], dt, None, False, default_initializer=ones)
 
     def _biases(self):
         return [self.q_b, self.k_b, self.v_b, self.out_b] \
             if self.has_bias else []
+
+    def _norms(self):
+        return [self.q_norm_w, self.k_norm_w] \
+            if self.qk_norm_eps is not None else []
 
     def forward(self, x, kv_cache=None):
         import jax.numpy as jnp
@@ -543,8 +646,12 @@ class GPTGroupedAttention(Layer):
         theta, window, use_flash = self.rope_theta, self.window, \
             self.use_flash
         has_bias = self.has_bias
+        norm_eps = self.qk_norm_eps
+        nb = len(self._biases())
 
-        def qkv(x, positions, q_w, k_w, v_w, biases):
+        def qkv(x, positions, q_w, k_w, v_w, extra):
+            # extra: the biases, then the q and k norms' weights
+            biases, norms = extra[:nb], extra[nb:]
             b, s, _ = x.shape
             q, k, v = x @ q_w, x @ k_w, x @ v_w
             if has_bias:
@@ -552,6 +659,9 @@ class GPTGroupedAttention(Layer):
             q = q.reshape(b, s, nh, hd)
             k = k.reshape(b, s, nkv, hd)
             v = v.reshape(b, s, nkv, hd)
+            if norm_eps is not None:
+                q = _rms_norm(q, norms[0], norm_eps)
+                k = _rms_norm(k, norms[1], norm_eps)
             if theta is not None:
                 if positions is None:
                     positions = jnp.broadcast_to(
@@ -564,39 +674,40 @@ class GPTGroupedAttention(Layer):
             o = a.reshape(b, s, nh * hd) @ out_w
             return o + biases[3] if has_bias else o
 
+        extra = self._biases() + self._norms()
         if kv_cache is None:
-            def fn(x, q_w, k_w, v_w, out_w, *biases):
+            def fn(x, q_w, k_w, v_w, out_w, *extra):
                 from ..ops.flash_attention import attention_bshd
-                q, k, v = qkv(x, None, q_w, k_w, v_w, biases)
+                q, k, v = qkv(x, None, q_w, k_w, v_w, extra)
                 a = attention_bshd(q, k, v, causal=True,
                                    scale=1.0 / math.sqrt(hd),
                                    use_flash=use_flash, window=window)
-                return out(a, out_w, biases)
+                return out(a, out_w, extra)
             return apply_op("grouped_attention", fn, x, self.q_w, self.k_w,
-                            self.v_w, self.out_w, *self._biases())
+                            self.v_w, self.out_w, *extra)
 
         from ..ops.paged_attention import paged_attention_update
         k_leaves, pool_def = jax.tree_util.tree_flatten(kv_cache.k)
         v_leaves, _ = jax.tree_util.tree_flatten(kv_cache.v)
-        nk, nb = len(k_leaves), len(self._biases())
+        nk, ne = len(k_leaves), len(extra)
 
         def fn(x, tables, ctx, valid, positions, q_w, k_w, v_w, out_w,
                *rest, **kw):
-            biases, pool_leaves = rest[:nb], rest[nb:]
+            extra, pool_leaves = rest[:ne], rest[ne:]
             kp = jax.tree_util.tree_unflatten(pool_def, pool_leaves[:nk])
             vp = jax.tree_util.tree_unflatten(pool_def, pool_leaves[nk:])
-            q, k, v = qkv(x, positions, q_w, k_w, v_w, biases)
+            q, k, v = qkv(x, positions, q_w, k_w, v_w, extra)
             a, kp2, vp2 = paged_attention_update(
                 q, k, v, kp, vp, tables, ctx, valid, positions,
                 window=window, **kw)
-            return (out(a, out_w, biases),
+            return (out(a, out_w, extra),
                     *jax.tree_util.tree_leaves(kp2),
                     *jax.tree_util.tree_leaves(vp2))
 
         res = apply_op(
             "paged_attention", fn, x, kv_cache.block_tables,
             kv_cache.ctx_len, kv_cache.valid, kv_cache.positions,
-            self.q_w, self.k_w, self.v_w, self.out_w, *self._biases(),
+            self.q_w, self.k_w, self.v_w, self.out_w, *extra,
             *k_leaves, *v_leaves, page_size=kv_cache.page_size,
             kind=kv_cache.kind, use_flash=use_flash,
             use_pallas=kv_cache.use_pallas, mesh=kv_cache.mesh)
@@ -606,47 +717,101 @@ class GPTGroupedAttention(Layer):
 
 
 class GPTExpertMLP(Layer):
-    """Dropless top-k ReGLU experts (``ops.moe.dropless_moe``): the
-    weights of one projection of all experts are one stacked array."""
+    """Dropless top-k gated experts (``ops.moe.dropless_moe``): the
+    weights of one projection of the experts held here are one stacked
+    array, the router is as wide as the model has experts, and a shared
+    expert (every token's) has three plain weights of its own."""
 
     def __init__(self, config: GPTConfig):
         super().__init__()
-        self.top_k = config.moe_top_k
         dt = config.dtype or "float32"
         init = I.Normal(std=config.initializer_range)
         h, e, i = (config.hidden_size, config.moe_num_experts,
                    config.moe_intermediate_size)
+        self.share = e != config.moe_router_experts
+        # what dropless_moe is told beyond the weights (the fields'
+        # defaults are the op's own: SmallThinker's call is what it was)
+        self.options = dict(
+            top_k=config.moe_top_k, scoring=config.moe_scoring,
+            scale=config.moe_routed_scale, activation=config.moe_activation,
+            offset=config.moe_expert_offset,
+            token_block=config.moe_token_block)
 
         def mk(shape):
             return create_parameter_with_attr(
                 shape, dt, None, False, default_initializer=init)
 
-        self.router_w = mk([h, e])
+        self.router_w = mk([h, config.moe_router_experts])
         self.gate_w = mk([e, h, i])
         self.up_w = mk([e, h, i])
         self.down_w = mk([e, i, h])
+        si = config.moe_shared_intermediate_size
+        self.has_shared = bool(si)
+        if si:
+            self.shared_gate_w = mk([h, si])
+            self.shared_up_w = mk([h, si])
+            self.shared_down_w = mk([si, h])
 
     def forward(self, x, router_in, valid=None):
         """x, router_in: [B, S, H]; valid: [B, S] bool or None. Returns
-        ``(out [B, S, H], stats int32 [3])``: assignments, experts
-        touched, the fullest expert's rows."""
+        ``(out [B, S, H], stats int32 [3 or 4])``: assignments, held
+        experts touched, the fullest held expert's rows and, where this
+        layer holds a share of the experts, the assignments its own
+        experts computed (all of them otherwise)."""
         import jax.numpy as jnp
 
         from ..ops.moe import dropless_moe
-        top_k = self.top_k
+        options, share, has_shared = self.options, self.share, \
+            self.has_shared
 
         def fn(x, router_in, valid, *weights):
             b, s, h = x.shape
+            extra = {"shared": weights[4:]} if has_shared else {}
             out, stats = dropless_moe(
-                x.reshape(b * s, h), router_in.reshape(b * s, h), *weights,
-                top_k=top_k,
+                x.reshape(b * s, h), router_in.reshape(b * s, h),
+                *weights[:4], **options, **extra,
                 valid=None if valid is None else valid.reshape(b * s))
-            return out.reshape(b, s, h), jnp.stack(
-                [stats["assignments"], stats["experts_touched"],
-                 stats["max_expert_load"]]).astype(jnp.int32)
+            counted = [stats["assignments"], stats["experts_touched"],
+                       stats["max_expert_load"]]
+            if share:
+                counted.append(stats["local_assignments"])
+            return out.reshape(b, s, h), jnp.stack(counted).astype(
+                jnp.int32)
 
+        shared = [self.shared_gate_w, self.shared_up_w,
+                  self.shared_down_w] if has_shared else []
         return apply_op("moe", fn, x, router_in, valid, self.router_w,
-                        self.gate_w, self.up_w, self.down_w)
+                        self.gate_w, self.up_w, self.down_w, *shared)
+
+
+class GPTGatedMLP(Layer):
+    """``(silu(x Wg) * (x Wu)) Wd``, no biases: the dense SwiGLU MLP
+    (column-, column- and row-split over 'mp'), under the scope
+    ``mlp_dense``."""
+
+    def __init__(self, config: GPTConfig):
+        super().__init__()
+        dt = config.dtype or "float32"
+        init = I.Normal(std=config.initializer_range)
+        h, i = config.hidden_size, config.intermediate_size
+
+        def mk(shape, spec):
+            p = create_parameter_with_attr(
+                shape, dt, None, False, default_initializer=init)
+            p.dist_spec = spec
+            return p
+
+        self.gate_w = mk([h, i], (None, "mp"))
+        self.up_w = mk([h, i], (None, "mp"))
+        self.down_w = mk([i, h], ("mp", None))
+
+    def forward(self, x):
+        def fn(x, w_gate, w_up, w_down):
+            with jax.named_scope("mlp_dense"):
+                return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+
+        return apply_op("gated_mlp", fn, x, self.gate_w, self.up_w,
+                        self.down_w)
 
 
 class GPTMLP(Layer):
@@ -692,8 +857,10 @@ class GPTDecoderLayer(Layer):
         self.attn = GPTAttention(config) if config.classic_attention \
             else GPTGroupedAttention(config, layer)
         self.ln_2 = _norm(config)
-        self.experts = bool(config.moe_num_experts)
-        self.mlp = GPTExpertMLP(config) if self.experts else GPTMLP(config)
+        self.experts = config.layer_experts(layer)
+        self.mlp = GPTExpertMLP(config) if self.experts else (
+            GPTGatedMLP(config) if config.mlp_kind == "swiglu"
+            else GPTMLP(config))
         self.router_reads_attention_input = \
             config.moe_router_input == "attention_input"
         if config.dtype:

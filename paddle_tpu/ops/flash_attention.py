@@ -16,6 +16,13 @@ import jax.numpy as jnp
 from ..framework import place as _place
 
 
+# what the dense path may keep of scores and probabilities: a quarter of
+# a v5e's memory. The largest dense prefill of the benchmark's GPT and
+# SmallThinker servers keeps 2.25 GiB (float32, 32 rows of 16 heads at
+# 768 positions)
+DENSE_SCORES_BYTES = 4 << 30
+
+
 def preferred(q, k, v, mask, causal) -> bool:
     """supported() AND long enough that the kernel beats XLA attention.
 
@@ -24,12 +31,19 @@ def preferred(q, k, v, mask, causal) -> bool:
     gpt2-medium s=512 trains at 40.8% vs 30.6% MFU, s=1024 at 33.2% vs
     24.3%); the kernel's O(S) memory only pays for itself once the
     sq*sk materialization stops fitting HBM (dense s=2048 b=4 OOMs) —
-    hence the gate uses the longer of the two sequence lengths."""
+    hence the gate uses the longer of the two sequence lengths. A
+    shorter call whose scores would not fit either takes the kernel
+    too: the dense path keeps ``b * h * sq * sk`` float32 scores and
+    their probabilities in the inputs' type, and 16 rows of 64 heads at
+    1,024 positions are 6 GiB of them (a served model of many heads
+    under a prefill budget counted in tokens)."""
     if not supported(q, k, v, mask, causal):
         return False
     from ..framework.flags import flag_value
-    return max(q.shape[1], k.shape[1]) >= int(
-        flag_value("FLAGS_flash_min_seqlen"))
+    b, sq, h, _ = q.shape
+    if b * h * sq * k.shape[1] * (4 + q.dtype.itemsize) > DENSE_SCORES_BYTES:
+        return True
+    return max(sq, k.shape[1]) >= int(flag_value("FLAGS_flash_min_seqlen"))
 
 
 def supported(q, k, v, mask, causal) -> bool:

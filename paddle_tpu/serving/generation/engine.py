@@ -491,15 +491,18 @@ class DecodeMetrics:
     def observe_moe(self, aux: dict):
         """What one prefill or decode program's expert layers counted
         (host whole numbers, each summed over its layers), added to
-        the cumulative ``engine.moe``: assignments, experts that got a
-        row, and the rows of each layer's fullest expert."""
+        the cumulative ``engine.moe``: assignments, those of them the
+        experts held here computed (all, where a program counts none
+        apart: every expert is held), held experts that got a row, and
+        the rows of each layer's fullest held expert."""
         with self._lock:
             m = self._moe
             if m is None:
-                m = self._moe = {"assignments": 0, "experts_touched": 0,
-                                 "max_expert_load": 0}
+                m = self._moe = {"assignments": 0, "local_assignments": 0,
+                                 "experts_touched": 0, "max_expert_load": 0}
             for key in m:
-                m[key] += int(aux["moe_" + key])
+                m[key] += int(aux.get("moe_" + key,
+                                      aux["moe_assignments"]))
 
     def set_kv_by_kind(self, by_kind: dict):
         with self._lock:

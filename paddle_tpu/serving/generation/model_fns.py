@@ -173,14 +173,19 @@ class CachedDecoder:
             numbers fetched with them, each summed over the expert
             layers: assignments, experts that got a row, and the rows
             of each layer's fullest expert (over assignments / experts
-            it says how uneven the routing is). Empty for a model that
-            counts nothing: the program then has no such output."""
+            it says how uneven the routing is); where the layers hold a
+            share of their experts, also the assignments the held ones
+            computed. Empty for a model that counts nothing: the
+            program then has no such output."""
             if not aux or "moe" not in aux:
                 return {}
-            per_layer = jnp.sum(jnp.stack(aux["moe"]), axis=0)   # [3]
-            return {"moe_assignments": per_layer[0],
-                    "moe_experts_touched": per_layer[1],
-                    "moe_max_expert_load": per_layer[2]}
+            per_layer = jnp.sum(jnp.stack(aux["moe"]), axis=0)  # [3 | 4]
+            out = {"moe_assignments": per_layer[0],
+                   "moe_experts_touched": per_layer[1],
+                   "moe_max_expert_load": per_layer[2]}
+            if per_layer.shape[0] > 3:
+                out["moe_local_assignments"] = per_layer[3]
+            return out
 
         def _make_fns(use_pallas):
             # One closure set per kernel path. The real jits below bind

@@ -70,3 +70,76 @@ def test_the_cut_configuration_states_its_cut():
                          ("max_seq_len", "max_position_embeddings")):
         assert sizes[ours] == config[theirs], ours
     assert config["num_hidden_layers"] == sizes["num_layers"] == 8
+
+
+def test_the_shared_configuration_states_its_share():
+    """``k-exaone-236b-a23b.json``: ``reduced`` is the depth, the
+    experts held and the vocabulary (each under the source's name and
+    the program's), with the published numbers and the 8-chip
+    deployment beside them; no width differs from the catalog row's."""
+    config = common.load_json(os.path.join(
+        common.ROOT, "benchmarks", "configs", "k-exaone-236b-a23b.json"))
+    assert config["reduced"] == ["num_layers", "num_hidden_layers",
+                                 "moe_num_experts", "num_experts",
+                                 "vocab_size"]
+    cut = config["reduced_from"]
+    assert cut["chips_sharing_a_layer"] == 8
+    assert "expert-parallel" in cut["deployment"]
+    for key, published, run_here in (("num_layers", 48, 5),
+                                     ("moe_num_experts", 128, 16),
+                                     ("vocab_size", 153600, 19200)):
+        assert (cut[key]["published"], cut[key]["run"]) == \
+            (published, run_here) and cut[key]["why"]
+    assert "num_hidden_layers" in cut["num_layers"] \
+        and "num_experts" in cut["moe_num_experts"]
+    assert len(config["assumed"]) == 4
+    assert "multi-token-prediction" in config["not_built"]
+    assert config["serve"]["weights_dtype"] == "bfloat16" \
+        and config["model"]["kwargs"] == {
+            "num_layers": 5, "moe_num_experts": 16, "vocab_size": 19200,
+            "dtype": "bfloat16"}
+    sizes = config["sizes"]
+    # every width and count the source publishes, under both names
+    published = {
+        "hidden_size": 6144, "num_attention_heads": 64,
+        "num_key_value_heads": 8, "head_dim": 128,
+        "intermediate_size": 18432, "moe_intermediate_size": 2048,
+        "num_experts_per_tok": 8, "num_shared_experts": 1,
+        "sliding_window": 128, "rms_norm_eps": 1e-5,
+        "routed_scaling_factor": 2.5, "scoring_func": "sigmoid",
+        "norm_topk_prob": True, "first_k_dense_replace": 1,
+        "n_group": 1, "topk_group": 1, "hidden_act": "silu",
+        "max_position_embeddings": 262144, "num_routed_experts": 128}
+    for key, want in published.items():
+        assert config[key] == want, key
+    assert config["rope_parameters"]["rope_theta"] == 1000000
+    assert len(config["layer_types"]) == len(config["mlp_layer_types"]) == 48
+    for ours, theirs in (("hidden_size", "hidden_size"),
+                         ("num_heads", "num_attention_heads"),
+                         ("num_kv_heads", "num_key_value_heads"),
+                         ("head_dim", "head_dim"),
+                         ("intermediate_size", "intermediate_size"),
+                         ("moe_num_experts", "num_experts"),
+                         ("moe_router_experts", "num_routed_experts"),
+                         ("moe_top_k", "num_experts_per_tok"),
+                         ("moe_intermediate_size", "moe_intermediate_size"),
+                         ("moe_routed_scale", "routed_scaling_factor"),
+                         ("sliding_window", "sliding_window"),
+                         ("layer_norm_eps", "rms_norm_eps"),
+                         ("vocab_size", "vocab_size"),
+                         ("max_seq_len", "max_position_embeddings")):
+        assert sizes[ours] == config[theirs], ours
+    assert sizes["moe_shared_intermediate_size"] == \
+        config["num_shared_experts"] * config["moe_intermediate_size"]
+    assert config["num_hidden_layers"] == sizes["num_layers"] == 5
+    # the layers run are the published layers 0-4 under their kinds
+    preset = common.resolve(config["model"]["preset"], "model.preset")
+    cfg = preset(**config["model"]["kwargs"])
+    assert [("sliding_attention", "full_attention")[not w]
+            for w in cfg.sliding_window_layout] == config["layer_types"][:5]
+    assert [("dense", "sparse")[m] for m in cfg.moe_layout] == \
+        config["mlp_layer_types"][:5]
+    serve = config["serve"]
+    assert (serve["max_batch"], serve["page_size"]) == (128, 16)
+    assert not serve["prefix_cache"]
+    assert serve["num_pages"] == 1 + 128 * -(-serve["max_seq_len"] // 16)

@@ -541,3 +541,96 @@ def test_expert_layer_compiles_to_grouped_matmuls(one_chip, tokens):
     text = compiled.as_text()
     assert text.count('op_name="ragged-dot-none"') == 3
     assert f"[{tokens * 6},{experts}," not in text
+
+
+# ------ a share of the experts, groups of 8, a ring of 9 pages (PR 33) --
+# the shared-expert configuration's serving geometry: 64 query heads
+# over 8 K/V heads of 128, bf16 pools, 128 lanes; the one full layer's
+# 257-page tables over 32,897 pages, a window layer's ring of 9 over
+# 1 + 128 x 9
+EP_HEADS, EP_KV_HEADS, EP_LANES, EP_WINDOW = 64, 8, 128, 128
+EP_KINDS = {"full": (None, 257, 32897), "window": (EP_WINDOW, 9, 1153)}
+
+
+@pytest.mark.parametrize("kind", sorted(EP_KINDS))
+def test_decode_compiles_for_groups_of_eight_and_a_ring_of_nine(
+        one_chip, compiled_kernels, kind):
+    """``paged_attention_update`` as that configuration's decode step
+    calls it: the page-copying kernel with 8 query heads a K/V head,
+    128 lanes, over the whole context and over a ring of 9 pages; the
+    pool reaches the kernel as it lies."""
+    from paddle_tpu.ops.paged_attention import (paged_attention_update,
+                                                ring_pages)
+    window, width, pages = EP_KINDS[kind]
+    assert ring_pages(EP_WINDOW, PAGE) == EP_KINDS["window"][1]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds(kv_pool_shape(pages, PAGE, EP_KV_HEADS, D), jnp.bfloat16)
+    kv = sds((EP_LANES, 1, EP_KV_HEADS, D), jnp.bfloat16)
+    text = jax.jit(
+        functools.partial(paged_attention_update, page_size=PAGE,
+                          kind="decode", window=window),
+        donate_argnums=(3, 4)).lower(
+        sds((EP_LANES, 1, EP_HEADS, D), jnp.bfloat16), kv, kv, pool, pool,
+        sds((EP_LANES, width), jnp.int32), sds((EP_LANES,), jnp.int32),
+        sds((EP_LANES, 1), jnp.bool_), sds((EP_LANES, 1), jnp.int32)
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert not [line for line in text.splitlines()
+                if f"bf16[{pages},{PAGE},{EP_KV_HEADS * D}]" in line
+                and " copy(" in line]
+
+
+@pytest.mark.parametrize("window", [None, EP_WINDOW],
+                         ids=["full", "window"])
+@pytest.mark.parametrize("rows,seq", [(16, 2048), (16, 1024)])
+def test_prefill_of_many_heads_takes_the_kernel_where_scores_would_not_fit(
+        one_chip, compiled_kernels, window, rows, seq):
+    """16 rows of 64 heads: at 2,048 positions the flash kernel by
+    length, at 1,024 because the dense path's scores would be 6 GiB
+    (``flash_attention.DENSE_SCORES_BYTES``)."""
+    from paddle_tpu.ops.flash_attention import attention_bshd
+
+    def sds(heads):
+        return jax.ShapeDtypeStruct((rows, seq, heads, D), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    _compile(functools.partial(attention_bshd, causal=True,
+                               scale=D ** -0.5, window=window),
+             sds(EP_HEADS), sds(EP_KV_HEADS), sds(EP_KV_HEADS))
+
+
+@pytest.mark.parametrize("tokens,block", [(128, 0), (16 * 2048, 4096)],
+                         ids=["decode", "prefill-in-blocks"])
+def test_a_share_of_the_experts_compiles_to_grouped_matmuls(
+        one_chip, tokens, block):
+    """``ops.moe.dropless_moe`` at the published widths of the share: a
+    router of 128 outputs, 16 experts of 6144 x 2048 held, 8 a token by
+    sigmoid scores, a shared expert; the products over the held experts
+    are XLA's grouped matmuls over ``tokens * 8`` sorted rows (a block
+    of 4,096 tokens at a time in a long prefill, whose temporaries are
+    then a block's), and no product is as wide as the router."""
+    from paddle_tpu.ops.moe import dropless_moe
+    hidden, routed, held, inter = 6144, 128, 16, 2048
+
+    def sds(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(functools.partial(
+        dropless_moe, top_k=8, scoring="sigmoid_norm", scale=2.5,
+        activation="silu", token_block=block)).lower(
+        sds(tokens, hidden), sds(tokens, hidden), sds(hidden, routed),
+        sds(held, hidden, inter), sds(held, hidden, inter),
+        sds(held, inter, hidden), valid=sds(tokens, dtype=jnp.bool_),
+        shared=(sds(hidden, inter), sds(hidden, inter), sds(inter, hidden))
+    ).compile()
+    text = compiled.as_text()
+    assert text.count('op_name="ragged-dot-none"') == 3
+    rows = (block or tokens) * 8
+    assert f"bf16[{rows},{hidden}]" in text
+    if block:
+        assert f"bf16[{tokens * 8},{hidden}]" not in text
+        # what it keeps beside its result is a block's, not the call's
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 * 1024 ** 3

@@ -152,3 +152,140 @@ class TestExpertParallel:
         w1 = net.moe.w1._data
         shard_experts = {sh.data.shape[0] for sh in w1.addressable_shards}
         assert shard_experts == {E // 4}
+
+
+# ---------------------------------------------------------------------
+# ops.moe.dropless_moe: what it was for SmallThinker, and what it learnt
+def _dropless_moe_pr29(x, router_in, w_router, w_gate, w_up, w_down, *,
+                       top_k, valid=None):
+    """``ops.moe.dropless_moe`` as PR 29 wrote it (softmax over the
+    chosen, ReLU gate, every expert held), kept here verbatim as the
+    judge of "today's arguments give what they gave"."""
+    import jax
+    import jax.numpy as jnp
+    t, hidden = x.shape
+    n_experts = w_gate.shape[0]
+    logits = jnp.dot(router_in.astype(jnp.float32),
+                     w_router.astype(jnp.float32),
+                     preferred_element_type=jnp.float32)
+    top, experts = jax.lax.top_k(logits, top_k)
+    experts, weights = experts.astype(jnp.int32), jax.nn.softmax(top, -1)
+    if valid is not None:
+        experts = jnp.where(valid[:, None], experts, n_experts)
+    flat = experts.reshape(t * top_k)
+    order = jnp.argsort(flat, stable=True)
+    rows = x[order // top_k]
+    group_sizes = jnp.bincount(
+        flat, length=n_experts + 1)[:n_experts].astype(jnp.int32)
+    gate = jax.lax.ragged_dot(rows, w_gate, group_sizes)
+    up = jax.lax.ragged_dot(rows, w_up, group_sizes)
+    act = (jax.nn.relu(gate) * up).astype(x.dtype)
+    down = jax.lax.ragged_dot(act, w_down, group_sizes)
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(t * top_k, dtype=order.dtype))
+    per = down[back].astype(jnp.float32).reshape(t, top_k, hidden)
+    w = weights
+    if valid is not None:
+        w = jnp.where(valid[:, None], w, 0.0)
+        per = jnp.where(valid[:, None, None], per, 0.0)
+    out = jnp.sum(per * w[:, :, None], axis=1).astype(x.dtype)
+    return out, {"assignments": jnp.sum(group_sizes),
+                 "experts_touched": jnp.sum(group_sizes > 0),
+                 "max_expert_load": jnp.max(group_sizes)}
+
+
+def _moe_arrays(seed, t=24, h=16, e_all=8, held=8, i=12, dtype="float32"):
+    import jax.numpy as jnp
+    rng = np.random.default_rng(seed)
+    x, r = rng.standard_normal((2, t, h)).astype(np.float32)
+    wr = rng.standard_normal((h, e_all)).astype(np.float32)
+    wg, wu = rng.standard_normal((2, held, h, i)).astype(np.float32)
+    wd = rng.standard_normal((held, i, h)).astype(np.float32)
+    return [jnp.asarray(a, dtype) for a in (x, r, wr, wg, wu, wd)]
+
+
+class TestDroplessMoe:
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("masked", [False, True],
+                             ids=["all", "some-dead"])
+    def test_todays_arguments_give_what_they_gave(self, masked, dtype):
+        """Bit for bit, and the same lowered program but for the one
+        count it now also returns."""
+        import jax
+        from paddle_tpu.ops.moe import dropless_moe
+        args = _moe_arrays(0, dtype=dtype)
+        valid = np.arange(24) % 3 != 0 if masked else None
+        new, s_new = dropless_moe(*args, top_k=2, valid=valid)
+        old, s_old = _dropless_moe_pr29(*args, top_k=2, valid=valid)
+        assert np.array_equal(np.asarray(new, np.float32),
+                              np.asarray(old, np.float32))
+        for key, value in s_old.items():
+            assert int(s_new[key]) == int(value), key
+        assert int(s_new["local_assignments"]) == int(s_new["assignments"])
+
+        def body(fn):
+            text = jax.jit(lambda *a: fn(*a, top_k=2, valid=valid)[0]) \
+                .lower(*args).as_text()
+            return text[text.index("{"):]
+        assert body(dropless_moe) == body(_dropless_moe_pr29)
+
+    @pytest.mark.parametrize("activation", ["relu", "silu"])
+    @pytest.mark.parametrize("scoring", ["softmax_top_k", "sigmoid_norm"])
+    def test_scoring_and_activation_against_every_expert_computed(
+            self, scoring, activation):
+        """A share of 3 experts (2..4 of 8) against an explicit loop
+        over tokens and their chosen experts."""
+        from paddle_tpu.ops.moe import dropless_moe
+        x, r, wr, wg, wu, wd = (np.asarray(a) for a in _moe_arrays(
+            1, held=3))
+        k, offset, scale = 3, 2, 2.5
+        got, stats = dropless_moe(x, r, wr, wg, wu, wd, top_k=k,
+                                  scoring=scoring, scale=scale,
+                                  activation=activation, offset=offset)
+        s = r @ wr
+        if scoring == "sigmoid_norm":
+            s = 1 / (1 + np.exp(-s))
+        want = np.zeros_like(x)
+        local = 0
+        for tok in range(x.shape[0]):
+            top = np.argsort(-s[tok])[:k]
+            if scoring == "sigmoid_norm":
+                w = scale * s[tok, top] / s[tok, top].sum()
+            else:
+                w = np.exp(s[tok, top] - s[tok, top].max())
+                w /= w.sum()
+            for weight, ex in zip(w, top):
+                if not offset <= ex < offset + 3:
+                    continue
+                local += 1
+                g = x[tok] @ wg[ex - offset]
+                g = np.maximum(g, 0) if activation == "relu" \
+                    else g / (1 + np.exp(-g))
+                want[tok] += weight * ((g * (x[tok] @ wu[ex - offset]))
+                                       @ wd[ex - offset])
+        np.testing.assert_allclose(np.asarray(got), want, atol=2e-4)
+        assert int(stats["assignments"]) == x.shape[0] * k
+        assert int(stats["local_assignments"]) == local
+
+    def test_shared_expert_is_added_once_a_token(self):
+        from paddle_tpu.ops.moe import dropless_moe
+        x, r, wr, wg, wu, wd = (np.asarray(a) for a in _moe_arrays(2))
+        rng = np.random.default_rng(3)
+        sg, su = rng.standard_normal((2, 16, 12)).astype(np.float32)
+        sd = rng.standard_normal((12, 16)).astype(np.float32)
+        kw = dict(top_k=2, activation="silu")
+        routed, _ = dropless_moe(x, r, wr, wg, wu, wd, **kw)
+        both, _ = dropless_moe(x, r, wr, wg, wu, wd, shared=(sg, su, sd),
+                               **kw)
+        g = x @ sg
+        shared = ((g / (1 + np.exp(-g))) * (x @ su)) @ sd
+        np.testing.assert_allclose(np.asarray(both) - np.asarray(routed),
+                                   shared, atol=2e-4)
+
+    def test_unknown_options_raise(self):
+        from paddle_tpu.ops.moe import dropless_moe
+        args = _moe_arrays(4)
+        with pytest.raises(ValueError, match="scoring"):
+            dropless_moe(*args, top_k=2, scoring="tanh")
+        with pytest.raises(ValueError, match="activation"):
+            dropless_moe(*args, top_k=2, activation="gelu")
