@@ -355,15 +355,16 @@ def logit_parity(srv, model, prompt, new_tokens, seq_bucket) -> tuple:
     tables[0, :len(pages)] = pages
     ids = np.zeros((2, seq_bucket), np.int64)
     ids[0, :n] = prompt
-    last, srv.kv.k, srv.kv.v, _ = srv.decoder.prefill(
-        ids, np.array([n, 0], np.int32), tables[:2], srv.kv.k, srv.kv.v)
+    _, last, srv.kv.k, srv.kv.v, _ = srv.decoder.prefill(
+        ids, np.array([n, 0], np.int32), tables[:2], None, None,
+        srv.kv.k, srv.kv.v)
     lane0 = np.zeros(lanes, bool)
     lane0[0] = True
-    step, srv.kv.k, srv.kv.v, _ = srv.decoder.decode(
+    _, step, srv.kv.k, srv.kv.v, _ = srv.decoder.decode(
         np.where(lane0, new_tokens[0], 0).astype(np.int64),
         np.where(lane0, n, 0).astype(np.int32), lane0,
         np.where(lane0, n + 1, 0).astype(np.int32), tables,
-        srv.kv.k, srv.kv.v)
+        None, None, srv.kv.k, srv.kv.v)
     srv.kv.release(pages)
     return (float(np.abs(np.asarray(last, np.float32)[0]
                          - ref[n - 1]).max()),
@@ -446,17 +447,17 @@ def phase_cached_logits(cfg, *, prompt_len, new_tokens, seq_bucket,
                 kv.alloc_window(total))
     ids = np.zeros((1, seq_bucket), np.int64)
     ids[0, :prompt_len] = prompt
-    last, kv.k, kv.v, _ = dec.prefill(
-        ids, np.array([prompt_len], np.int32), tables, kv.k, kv.v)
+    _, last, kv.k, kv.v, _ = dec.prefill(
+        ids, np.array([prompt_len], np.int32), tables, None, None, kv.k, kv.v)
     rows = [np.asarray(last, np.float32)[0]]
     served = []
     for step in range(new_tokens):
         served.append(int(rows[-1].argmax()))
         ctx = prompt_len + step
-        out, kv.k, kv.v, _ = dec.decode(
+        _, out, kv.k, kv.v, _ = dec.decode(
             np.array([served[-1]], np.int64), np.array([ctx], np.int32),
             np.array([True]), np.array([ctx + 1], np.int32), tables,
-            kv.k, kv.v)
+            None, None, kv.k, kv.v)
         rows.append(np.asarray(out, np.float32)[0])
     got = np.stack(rows)                       # positions n-1 .. n+new-1
     # the full forward over prompt + served tokens, padded to whole

@@ -111,7 +111,8 @@ class HybridParallelInferenceHelper:
                   .reshape(b, pages_per_seq))
         k, v = self.model.init_kv_pools(1 + b * pages_per_seq, page_size)
         lens = np.full(b, prompt_len, np.int32)
-        last, k, v, _ = dec.prefill(ids, lens, tables, k, v)
+        # this loop selects on the host, one RandomState for the batch
+        _, last, k, v, _ = dec.prefill(ids, lens, tables, None, None, k, v)
         rng = np.random.RandomState(seed)
         done = np.zeros(b, bool)
         buf = np.full((b, total), self.pad_token_id, "int64")
@@ -129,10 +130,10 @@ class HybridParallelInferenceHelper:
             # cache the chosen token at `pos`, get logits for pos+1;
             # finished rows keep decoding masked-off via `active`
             active = ~done
-            logits, k, v, _ = dec.decode(
+            _, logits, k, v, _ = dec.decode(
                 buf[:, pos], np.full(b, pos, np.int32), active,
                 np.where(active, pos + 1, pos).astype(np.int32),
-                tables, k, v)
+                tables, None, None, k, v)
             step_logits = np.asarray(logits)
         return buf[:, :total]
 

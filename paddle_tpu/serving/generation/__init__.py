@@ -14,10 +14,17 @@ Pieces:
   layer's backpressure/deadline semantics, continuous batcher worker,
   ``paddle_decode_*`` metrics on the observability registry, warmup +
   warmup-manifest replay over the decode lattice.
-- ``CachedDecoder`` (model_fns.py): the two jitted device entry points
-  (bucketed prefill, fixed-shape decode) over a cache-capable model,
-  KV pools donated where the backend supports it, persistent-compile-
-  cache AOT tier first.
+- ``CachedDecoder`` (model_fns.py): the jitted device entry points
+  over a cache-capable model (bucketed prefill, chunked suffix
+  prefill, fixed-shape decode: each chooses its rows' next tokens
+  itself, greedy or sampled, and returns ``(tokens, logits, k', v',
+  new_signature)``; the speculative verify step returns every window
+  position's logits and no tokens), KV pools donated where the backend
+  supports it, persistent-compile-cache AOT tier first.
+- ``ProgramRunner`` (runner.py): a decoder and its pools; a run fetches
+  the chosen tokens (``[rows]`` int32) and the expert counters in one
+  transfer and leaves the logits on the device unless the caller asks
+  for them.
 - ``PagedKVCache`` (kv_cache.py): preallocated per-layer
   ``[num_pages, page_size, heads * head_dim]`` pools + the host page
   allocator (page 0 reserved as the trash page for masked writes),
@@ -30,8 +37,9 @@ Pieces:
   for speculative decoding (draft proposes k, the target verifies all
   k in one fixed-shape step; output distribution unchanged).
 - ``sample_next_tokens`` (sampling.py): vectorized host-side
-  greedy/temperature selection, shared with
-  ``HybridParallelInferenceHelper.generate``.
+  greedy/temperature selection, the rule the programs implement; the
+  engine's loop no longer calls it, ``HybridParallelInferenceHelper.
+  generate`` does.
 
 Model contract: ``forward(ids, cache=...)`` returning ``(logits,
 (k', v'))`` plus ``init_kv_pools``/``kv_cache_spec`` — implemented by

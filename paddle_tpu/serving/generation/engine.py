@@ -20,13 +20,15 @@ Flow per worker iteration:
    sharing), shrinking the reservation AND the prefill window.
 2. **prefill**: admitted prompts run one forward at their (pow2-row,
    seq-bucket) shape — the PR 1/2 bucket lattice — writing prompt K/V
-   into their pages and sampling the first token. Prefix hits run the
+   into their pages and choosing the first token. Prefix hits run the
    CHUNKED suffix prefill instead (attention reaches the cached
    prefix through the block tables), then every prompt's full pages
    are published to the index.
-3. **decode**: one fixed-shape step for every live lane; sample on
-   host (vectorized, per-request RNG), stream tokens out through each
-   request's ``StreamingFuture``. With a draft model configured, each
+3. **decode**: one fixed-shape step for every live lane. The program
+   chooses each lane's next token (greedy, or sampled at the uniform
+   this loop drew from the request's own RNG) and the step fetches
+   ``[max_batch]`` token ids, never the logits; they stream out through
+   each request's ``StreamingFuture``. With a draft model configured, each
    iteration is instead draft-propose-k + ONE fixed-shape
    ``[max_batch, k+1]`` verify step with accept-and-resample
    (speculative decoding; output distribution unchanged).
@@ -61,7 +63,6 @@ from .kv_cache import PagedKVCache
 from .model_fns import CachedDecoder, supports_cached_decode
 from .prefix_cache import PrefixCache
 from .runner import SITES, ProgramRunner
-from .sampling import sample_next_tokens
 from .spec_decode import accept_tokens, softmax
 
 __all__ = ["GenerationServer", "StreamingFuture", "DecodeMetrics",
@@ -294,7 +295,11 @@ class _ActiveSeq:
 
 class _Ran(NamedTuple):
     """What ``GenerationServer._dispatch`` hands back."""
-    out: np.ndarray         # the first runner's first output, fetched
+    # the first runner's choice a row, fetched (None from a verify
+    # step), and its logits: a device array, unless the caller asked
+    # for them on the host
+    tokens: Optional[np.ndarray]
+    logits: object
     ms: float               # host time of the calls and their fetches
     t_wall: int             # time_ns at their start
     fresh: List[bool]       # per runner: a signature seen first now
@@ -457,6 +462,10 @@ class DecodeMetrics:
         # (``PagedKVCache.by_kind``), as of the last admission, release
         # or decode step
         self._kv_by_kind: Dict[str, dict] = {}
+        # who chose the tokens of each program traffic ran, and what
+        # its runner brought to the host
+        self._select = {"in_program": 0, "on_host": 0}
+        self._fetch_bytes = 0
 
     def switch_phase(self, phase: Optional[str], now: float):
         """The loop thread leaves its open phase at ``now`` (a
@@ -504,6 +513,14 @@ class DecodeMetrics:
                 m[key] += int(aux.get("moe_" + key,
                                       aux["moe_assignments"]))
 
+    def observe_fetch(self, nbytes: int, host_logits: bool):
+        """One program run fetched ``nbytes``: its tokens, chosen in
+        the program, or (``host_logits``) the logits for the host to
+        choose from."""
+        with self._lock:
+            self._select["on_host" if host_logits else "in_program"] += 1
+            self._fetch_bytes += int(nbytes)
+
     def set_kv_by_kind(self, by_kind: dict):
         with self._lock:
             self._kv_by_kind = by_kind
@@ -533,6 +550,8 @@ class DecodeMetrics:
                                call_s_by_shape=dict(
                                    self._prefill_call_s_by_shape)),
                "kv": self._kv_by_kind,
+               "select": dict(self._select),
+               "fetch_bytes": self._fetch_bytes,
                "stream_stall_ms": self._cumulative(self._h_stall),
                "queue_wait_ms": self._cumulative(self._h_qwait)}
         if self._moe is not None:
@@ -1151,12 +1170,15 @@ class GenerationServer:
             feeds = (vec(np.int64), vec(np.int32), vec(bool),
                      vec(np.int32), tables)
         else:
-            ids = np.zeros((rows, seq), np.int64)
-            feeds = (ids, vec(np.int32), tables) if kind == "prefill" \
-                else (ids, vec(np.int32), vec(np.int32), tables)
+            feeds = (np.zeros((rows, seq), np.int64), vec(np.int32))
+            if kind != "prefill":
+                feeds += (vec(np.int32),)   # a start beside the lengths
+            feeds += (tables,)
+        if kind != "verify":
+            feeds += (vec(np.float32), vec(np.float32))     # all greedy
         runners = self._runners[:1] if kind == "verify" else self._runners
-        return sum(self._dispatch(kind, feeds, (), runners,
-                                  record=False).fresh)
+        return sum(self._dispatch(kind, feeds, (), runners, record=False,
+                                  host_logits=kind == "verify").fresh)
 
     def warmup_from_manifest(self, path: Optional[str] = None) -> int:
         """Replay the persisted decode/prefill signatures a previous
@@ -1220,19 +1242,6 @@ class GenerationServer:
         """The page gauges, after the cache manager's books moved."""
         self.metrics.set_kv_pages(self.kv.used_pages, self.kv.free_pages)
         self.metrics.set_kv_by_kind(self.kv.by_kind())
-
-    def _fetch_aux(self) -> dict:
-        """What the decoder's last program counted beside its logits,
-        as host numbers, into the cumulative ``engine.moe`` counters
-        ({} for a model that counts nothing). Called once the logits
-        are here, so the scalars are too."""
-        aux = self.decoder.last_aux
-        if not aux:
-            return {}
-        import jax
-        aux = jax.device_get(aux)
-        self.metrics.observe_moe(aux)
-        return aux
 
     def _enter_decode_call(self, active: List[_ActiveSeq],
                            ctx_after: np.ndarray,
@@ -1544,14 +1553,16 @@ class GenerationServer:
                   seqs: Sequence[_ActiveSeq],
                   runners: Sequence[ProgramRunner], *,
                   stage: Optional[str] = None, record: bool = True,
-                  since: Optional[Tuple[int, float]] = None
-                  ) -> Optional[_Ran]:
+                  since: Optional[Tuple[int, float]] = None,
+                  host_logits: bool = False) -> Optional[_Ran]:
         """Run ``kind``'s program over ``feeds`` on each of ``runners``
         in turn (``self._runners[:1]`` is the target, ``[1:]`` the
         draft, all of it a prefill with its draft mirror) and time the
         lot, from ``since`` (a ``(time_ns, perf_counter)`` pair read
         earlier: a verify step counts the proposal before it) or from
-        now, to the last fetch.
+        now, to the last fetch. Each fetch brings the tokens the program
+        chose and its expert counters; with ``host_logits`` the logits
+        too, for a caller that selects on the host.
 
         The fault barrier: a failure fails the futures of ``seqs``,
         the sequences in the call, and theirs alone, returns their
@@ -1559,21 +1570,25 @@ class GenerationServer:
         ``stage``, ``engine::bookkeeping`` opens at the second clock
         reading and the time goes to ``step_ms[stage]``. ``record=False``
         is a warmup: it has no request to fail (the error is the
-        caller's), enters no manifest and counts no expert."""
+        caller's), enters no manifest and counts no expert and no
+        fetch."""
         t_wall, t0 = since or (time.time_ns(), time.perf_counter())
         target, ran = self._runners[0], []
         try:
             for runner in runners:
-                ran.append(runner.run(kind, feeds))
-                if record and runner is target \
-                        and kind in ("prefill", "decode"):
-                    aux = self._fetch_aux()
-                    if aux and kind == "decode":
+                run = runner.run(kind, feeds, host_logits)
+                ran.append(run)
+                if not record:
+                    continue
+                self.metrics.observe_fetch(run.fetched_bytes, host_logits)
+                if runner is target and run.aux:
+                    self.metrics.observe_moe(run.aux)
+                    if kind == "decode":
                         # what this step's expert layers read, on its
                         # own span
                         self._span.set_arg(
                             "experts_touched",
-                            int(aux["moe_experts_touched"]))
+                            int(run.aux["moe_experts_touched"]))
         except Exception as e:  # noqa: BLE001 - the fault barrier
             if not record:
                 raise
@@ -1588,12 +1603,13 @@ class GenerationServer:
         if stage is not None:
             self._enter_phase("bookkeeping")
             self.metrics.observe_step(stage, ms)
-        for runner, (_, fresh, signature) in zip(runners, ran):
+        for runner, run in zip(runners, ran):
             # the manifest is the target's lattice: a draft's programs
             # are counted, never recorded
-            self._note_dispatch(SITES[kind], fresh, signature,
+            self._note_dispatch(SITES[kind], run.fresh, run.signature,
                                 record=record and runner is target)
-        return _Ran(ran[0][0], ms, t_wall, [fresh for _, fresh, _ in ran])
+        return _Ran(ran[0].tokens, ran[0].logits, ms, t_wall,
+                    [run.fresh for run in ran])
 
     def _account(self, ran: _Ran, seqs: Sequence[_ActiveSeq],
                  envelopes: Sequence[dict], span: str,
@@ -1652,8 +1668,9 @@ class GenerationServer:
             start[i] = seq.prefix_len
             lens[i] = len(tail)
             tables[i] = self._tables[seq.slot]
-        feeds = (ids, lens, tables) if kind == "prefill" \
-            else (ids, start, lens, tables)
+        feeds = ((ids, lens, tables) if kind == "prefill"
+                 else (ids, start, lens, tables)) \
+            + self._selection_feeds(seqs, range(rows), padded)
         ran = self._dispatch(kind, feeds, seqs, self._runners,
                              stage="prefill")
         if ran is None:
@@ -1672,7 +1689,7 @@ class GenerationServer:
                          "compile_miss": ran.fresh[0]})
         self._publish_prompts(seqs)
         self._enter_phase("sample_emit")
-        self._sample_and_emit(seqs, ran.out[:rows])
+        self._emit_batch(seqs, [[t] for t in ran.tokens[:rows].tolist()])
 
     def _publish_prompts(self, seqs: List[_ActiveSeq]):
         """Index each prefilled prompt's FULL pages so later admissions
@@ -1688,13 +1705,28 @@ class GenerationServer:
                                     n_tokens=len(seq.req.prompt))
                 seq.published = True
 
+    def _selection_feeds(self, seqs: Sequence[_ActiveSeq],
+                         rows: Sequence[int], n: int) -> tuple:
+        """``(temperature, uniform)``, float32 ``[n]``, of a program
+        that chooses the next token of each of ``seqs`` in its entry of
+        ``rows``: a sampled request's temperature and the next number
+        of its own ``RandomState`` (so its stream is the same whoever
+        shares the batch); 0 for a greedy one and every other row."""
+        temperature = np.zeros(n, np.float32)
+        uniform = np.zeros(n, np.float32)
+        for seq, row in zip(seqs, rows):
+            if seq.req.temperature > 0.0:
+                temperature[row] = seq.req.temperature
+                uniform[row] = seq.req.rng.random_sample()
+        return temperature, uniform
+
     # ---- one decode iteration ----
     def _decode_feeds(self, seqs: List[_ActiveSeq], tokens: Sequence[int],
                       positions: Sequence[int]) -> tuple:
-        """The decode program's feeds: the lane of each of ``seqs`` is
-        fed its entry of ``tokens`` at its entry of ``positions`` (the
-        slot it writes; the context it reads ends one past it); every
-        other lane is masked dead."""
+        """The decode program's feeds before its selection's: the lane
+        of each of ``seqs`` is fed its entry of ``tokens`` at its entry
+        of ``positions`` (the slot it writes; the context it reads ends
+        one past it); every other lane is masked dead."""
         b = self.max_batch
         toks = np.zeros(b, np.int64)
         pos = np.zeros(b, np.int32)
@@ -1713,9 +1745,11 @@ class GenerationServer:
     def _decode_iteration(self, active: List[_ActiveSeq],
                           stall_t0: Optional[float] = None):
         self._enter_phase("decode_feeds")
+        slots = [s.slot for s in active]
         feeds = self._decode_feeds(active,
                                    [s.last_token for s in active],
-                                   [s.ctx for s in active])
+                                   [s.ctx for s in active]) \
+            + self._selection_feeds(active, slots, self.max_batch)
         # the context this step's attention reads: each live lane's
         # cached positions, the one it writes among them
         self._enter_decode_call(active, feeds[3], stall_t0)
@@ -1736,8 +1770,7 @@ class GenerationServer:
         if self.kv.window is not None:
             self._note_kv_pages()
         self._enter_phase("sample_emit")
-        self._sample_and_emit(active,
-                              ran.out[[s.slot for s in active]])
+        self._emit_batch(active, [[t] for t in ran.tokens[slots].tolist()])
 
     # ---- one speculative iteration: draft proposes, target verifies
     def _spec_iteration(self, active: List[_ActiveSeq],
@@ -1776,7 +1809,7 @@ class GenerationServer:
             seg[s.slot] = k + 1
         ran = self._dispatch("verify", (ids, start, seg, self._tables),
                              active, self._runners[:1], stage="decode",
-                             since=since)
+                             since=since, host_logits=True)
         if ran is None:
             return
         self._steps += 1
@@ -1790,7 +1823,7 @@ class GenerationServer:
             remaining = min(s.req.max_new - s.n_generated,
                             s.max_total - s.ctx)
             emitted, acc = accept_tokens(
-                ran.out[s.slot], draft_toks[s.slot],
+                ran.logits[s.slot], draft_toks[s.slot],
                 draft_probs.get(s.slot), s.req.temperature, s.req.rng,
                 max_emit=remaining,
                 eos_token_id=self.eos_token_id)
@@ -1833,13 +1866,18 @@ class GenerationServer:
         when a draft step failed (the barrier of ``_dispatch`` has
         failed every lane of ``active`` then: none can be verified)."""
         draft = self._runners[1:]
+        # a draft step's own choice is the argmax: a greedy lane's
+        # proposal. A sampled lane's is drawn here from the step's
+        # logits, whose distribution the verdict needs as well
+        greedy = self._selection_feeds((), (), self.max_batch)
+        host_logits = any(s.req.temperature > 0.0 for s in active)
         while True:
             lag = [s for s in active if s.draft_ctx < s.ctx]
             if not lag:
                 break
             feeds = self._decode_feeds(
                 lag, [s.history[s.draft_ctx] for s in lag],
-                [s.draft_ctx for s in lag])
+                [s.draft_ctx for s in lag]) + greedy
             if self._dispatch("decode", feeds, active, draft) is None:
                 return None
             for s in lag:
@@ -1850,13 +1888,13 @@ class GenerationServer:
         for j in range(k):
             ran = self._dispatch(
                 "decode", self._decode_feeds(
-                    active, feed, [s.draft_ctx for s in active]),
-                active, draft)
+                    active, feed, [s.draft_ctx for s in active]) + greedy,
+                active, draft, host_logits=host_logits)
             if ran is None:
                 return None
             for i, s in enumerate(active):
-                row = ran.out[s.slot]
                 if s.req.temperature > 0.0:
+                    row = ran.logits[s.slot]
                     p = softmax(row, s.req.temperature)
                     probs = draft_probs.setdefault(
                         s.slot, np.zeros((k, row.shape[-1])))
@@ -1868,20 +1906,13 @@ class GenerationServer:
                             side="right"),
                         row.shape[-1] - 1))
                 else:
-                    tok = int(row.argmax())
+                    tok = int(ran.tokens[s.slot])
                 draft_toks[s.slot, j] = tok
                 feed[i] = tok
                 s.draft_ctx += 1
         return draft_toks, draft_probs
 
-    # ---- shared harvest: sample, stream, evict ----
-    def _sample_and_emit(self, seqs: List[_ActiveSeq],
-                         logits: np.ndarray):
-        temps = np.array([s.req.temperature for s in seqs], np.float64)
-        uniforms = np.array([s.req.rng.random_sample() for s in seqs])
-        toks = sample_next_tokens(logits, temps, uniforms=uniforms)
-        self._emit_batch(seqs, [[int(t)] for t in toks])
-
+    # ---- shared harvest: stream, evict ----
     def _emit_batch(self, seqs: List[_ActiveSeq],
                     toks_lists: List[List[int]]):
         """Stream each sequence's newly-selected tokens (one from a

@@ -1,27 +1,41 @@
 """Jitted prefill/decode steps over a cache-capable causal-LM Layer.
 
 ``CachedDecoder`` functionalizes the model once (``jit.functional``),
-then exposes exactly two device entry points:
+then exposes four device entry points. Three of them choose the next
+token themselves: beside their feeds they take ``temperature [rows]``
+and ``uniform [rows]`` (float32; a row at temperature 0 takes the
+``argmax`` of its logits, first index on ties, a row above 0 the
+inverse CDF of ``softmax(logits / t)`` at its uniform, the rule of
+``sampling.py``; ``None`` for either is all rows greedy) and return
+``(tokens [rows] int32, logits [rows, vocab], k', v', new_signature)``,
+all device arrays: a caller that wants the tokens alone fetches
+``rows * 4`` bytes and leaves the logits where they are.
 
-- ``prefill(ids, prompt_lens, tables, pools)`` — one forward over a
-  padded prompt window that writes the prompt's K/V into the paged
-  pools and returns only the last real position's logits ``[B, vocab]``:
-  the head is applied to that position's hidden state alone
+- ``prefill(ids, prompt_lens, tables, temperature, uniform, pools)`` —
+  one forward over a padded prompt window that writes the prompt's K/V
+  into the paged pools and computes only the last real position's
+  logits: the head is applied to that position's hidden state alone
   (``GPTKVCache.logits_at``), so no ``[B, S, vocab]`` array exists in
   the program;
-- ``decode(tokens, positions, active, ctx, tables, pools)`` — the
-  fixed-shape ``[max_batch, 1]`` decode step: append one position per
-  live lane, attend through the block tables, return ``[B, vocab]``;
-- ``prefill_chunked(ids, start, seg_lens, tables, pools)`` — suffix
-  prefill at a per-row starting position: window tokens attend to the
-  already-cached prefix through the block tables (kind="chunked"),
-  used after a shared-prefix cache hit so only the unique suffix pays
-  prefill; returns the last real position's logits like ``prefill``;
+- ``decode(tokens, positions, active, ctx, tables, temperature,
+  uniform, pools)`` — the fixed-shape ``[max_batch, 1]`` decode step:
+  append one position per live lane, attend through the block tables;
+- ``prefill_chunked(ids, start, seg_lens, tables, temperature,
+  uniform, pools)`` — suffix prefill at a per-row starting position:
+  window tokens attend to the already-cached prefix through the block
+  tables (kind="chunked"), used after a shared-prefix cache hit so only
+  the unique suffix pays prefill; the last real position's logits like
+  ``prefill``;
 - ``verify(tokens, start, seg_lens, tables, pools)`` — the
   speculative-decoding verify step: ONE fixed-shape
   ``[max_batch, spec_k + 1]`` chunked forward scoring a draft model's
-  proposed tokens, returning ALL window logits ``[B, S, vocab]`` so
-  the host can run accept-and-resample.
+  proposed tokens, returning ALL window logits ``[B, S, vocab]`` (and
+  no tokens: ``(logits, k', v', new_signature)``) so the host can run
+  accept-and-resample.
+
+The sampled rule sits under one ``lax.cond`` on "any row above 0"
+inside the same executable: a batch of greedy rows pays for an argmax,
+and there is one executable a signature whatever the requests ask for.
 
 All are ``jax.jit``-compiled with the KV pools donated on backends
 that support donation (the pools update in place on device), and both
@@ -126,8 +140,9 @@ class CachedDecoder:
         self._donate = bool(donate) if donate is not None \
             else jax.default_backend() != "cpu"
         self._fp: Optional[str] = None
-        # what the last prefill or decode counted beside its logits
-        # (``_aux_out``: device scalars, {} for a model without experts)
+        # what the last program counted beside its logits (``_aux_out``:
+        # device scalars; {} for a model without experts, and after a
+        # chunked prefill or a verify step, which count nothing)
         self.last_aux: dict = {}
         # per-signature AOT memo; False marks "tried, unavailable"
         self._aot: Dict[tuple, object] = {}
@@ -187,6 +202,32 @@ class CachedDecoder:
                 out["moe_local_assignments"] = per_layer[3]
             return out
 
+        def _select(logits, temperature, uniform):
+            """The token each row of ``logits`` ``[B, vocab]`` takes,
+            ``[B]`` int32: the argmax (first index on ties) where the
+            row's temperature is 0, else the first index whose
+            cumulative mass of ``softmax(logits / t)`` exceeds the
+            row's uniform times the whole (``sampling.py``'s rule, in
+            float32). The sampled rule runs only when some row asks
+            for it."""
+            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            hot = temperature > 0
+
+            def sampled():
+                z = logits.astype(jnp.float32) \
+                    / jnp.where(hot, temperature, 1.0)[:, None]
+                cdf = jnp.cumsum(
+                    jnp.exp(z - jnp.max(z, axis=-1, keepdims=True)),
+                    axis=-1)
+                u = uniform * cdf[:, -1]
+                # the count of entries at or under u is that first
+                # index; a uniform that rounds up to 1 takes the last
+                pick = jnp.minimum(jnp.sum(cdf <= u[:, None], axis=-1),
+                                   logits.shape[-1] - 1)
+                return jnp.where(hot, pick.astype(jnp.int32), greedy)
+
+            return jax.lax.cond(jnp.any(hot), sampled, lambda: greedy)
+
         def _make_fns(use_pallas):
             # One closure set per kernel path. The real jits below bind
             # the pinned ``self.use_pallas``; the shadow-verification
@@ -195,7 +236,7 @@ class CachedDecoder:
             # implementation without touching any dispatch state.
 
             def _prefill(params, buffers, ids, prompt_lens, tables,
-                         k, v):
+                         temperature, uniform, k, v):
                 # unified-surface batch pin: under a dp serving mesh
                 # the prefill window shards by request row; meshless
                 # (the single-replica engine default) this is the
@@ -224,11 +265,12 @@ class CachedDecoder:
                     training=False)
                 # tied lm_head leaves logits vocab-sharded under mp:
                 # gather ONCE inside the executable, not on the host
-                return (smesh.replicate(_one_position(logits, idx)), k2,
+                last = smesh.replicate(_one_position(logits, idx))
+                return (_select(last, temperature, uniform), last, k2,
                         v2, _aux_out(cache.aux))
 
             def _decode(params, buffers, tokens, positions, active,
-                        ctx, tables, k, v):
+                        ctx, tables, temperature, uniform, k, v):
                 tokens = constrain_batch(tokens)
                 k = smesh.constrain_pools(k)
                 v = smesh.constrain_pools(v)
@@ -244,8 +286,9 @@ class CachedDecoder:
                 logits, (k2, v2) = functional_call(
                     model, params, buffers, ids, cache=cache,
                     training=False)
-                return (smesh.replicate(logits[:, 0]), k2, v2,
-                        _aux_out(cache.aux))
+                last = smesh.replicate(logits[:, 0])
+                return (_select(last, temperature, uniform), last, k2,
+                        v2, _aux_out(cache.aux))
 
             def _chunked(params, buffers, ids, start, seg_lens, tables,
                          k, v, logits_at=None):
@@ -280,13 +323,14 @@ class CachedDecoder:
                 return smesh.replicate(logits), k2, v2
 
             def _prefill_chunked(params, buffers, ids, start, seg_lens,
-                                 tables, k, v):
+                                 tables, temperature, uniform, k, v):
                 idx = jnp.clip(seg_lens.astype(jnp.int32) - 1, 0,
                                ids.shape[1] - 1)
                 logits, k2, v2 = _chunked(params, buffers, ids, start,
                                           seg_lens, tables, k, v,
                                           logits_at=idx)
-                return _one_position(logits, idx), k2, v2
+                last = _one_position(logits, idx)
+                return _select(last, temperature, uniform), last, k2, v2
 
             return {"prefill": _prefill, "decode": _decode,
                     "chunked": _prefill_chunked, "verify": _chunked}
@@ -294,9 +338,11 @@ class CachedDecoder:
         self._make_fns = _make_fns
         fns = _make_fns(use_pallas)
 
-        donate_pf = (5, 6) if self._donate else ()
-        donate_dc = (7, 8) if self._donate else ()
-        donate_ck = (6, 7) if self._donate else ()
+        # the pools: the last two operands of each program
+        donate_pf = (7, 8) if self._donate else ()
+        donate_dc = (9, 10) if self._donate else ()
+        donate_ck = (8, 9) if self._donate else ()
+        donate_vf = (6, 7) if self._donate else ()
         self._prefill_jit = jax.jit(fns["prefill"],
                                     donate_argnums=donate_pf)
         self._decode_jit = jax.jit(fns["decode"],
@@ -304,7 +350,7 @@ class CachedDecoder:
         self._chunked_jit = jax.jit(fns["chunked"],
                                     donate_argnums=donate_ck)
         self._verify_jit = jax.jit(fns["verify"],
-                                   donate_argnums=donate_ck)
+                                   donate_argnums=donate_vf)
         # shadow-verification support (observability.numerics): oracle
         # jits re-trace the SAME closures with use_pallas=False and NO
         # donation — the oracle runs strictly before the real call so
@@ -347,8 +393,9 @@ class CachedDecoder:
                     # attention_bshd beside it; v6: the head on one
                     # position a row in prefill, counters beside the
                     # logits, windows and grouped heads in the ops;
-                    # v7: the pools' heads folded into their lanes)
-                    "kv_dtype": self.kv_dtype, "v": 7}
+                    # v7: the pools' heads folded into their lanes;
+                    # v8: the programs choose the next token)
+                    "kv_dtype": self.kv_dtype, "v": 8}
             # mesh axes + weight spec-tree hash join the geometry ONLY
             # when the mesh is live: an inert (None / 1-device) mesh
             # must reuse today's fingerprints byte-for-byte, and a mesh
@@ -513,14 +560,17 @@ class CachedDecoder:
         try:
             from ...observability import numerics
             kind = site[len("generate_"):]
+            # a program that selects puts its tokens before its logits
+            at = 0 if kind == "verify" else 1
+            logits, k, v = out[at:at + 3]
             if shadow_out is not None:
-                div = self._divergence_fn()(out[0], shadow_out[0])
+                div = self._divergence_fn()(logits, shadow_out[at])
                 numerics.note_shadow_divergence(
                     kind, self.kv_dtype or "f32", div)
             if numerics.sample_decision(numerics.tripwire_rate()):
-                numerics.note_serving_logits(kind, out[0])
+                numerics.note_serving_logits(kind, logits)
                 if self.kv_dtype == "int8":
-                    numerics.note_int8_scales(kind, out[1], out[2])
+                    numerics.note_int8_scales(kind, k, v)
         except Exception:  # noqa: BLE001 - never break a decode step
             pass
 
@@ -537,36 +587,51 @@ class CachedDecoder:
         self._numerics_note(site, out, shadow_out)
         return out, fresh
 
+    def _selection(self, rows: int, temperature, uniform) -> tuple:
+        """The two per-row operands of a program that selects, float32
+        ``[rows]``; None for either is every row greedy."""
+        return tuple(
+            np.zeros(rows, np.float32) if a is None
+            else np.ascontiguousarray(a, np.float32)
+            for a in (temperature, uniform))
+
     def prefill(self, ids: np.ndarray, prompt_lens: np.ndarray,
-                tables: np.ndarray, k, v):
+                tables: np.ndarray, temperature, uniform, k, v):
         """ids [B, S] int64 (left-aligned, zero-padded); prompt_lens
-        [B] int32 (0 = dead pad row); tables [B, P] int32. Returns
-        ``(last_logits [B, vocab] jax array, k', v', new_signature)``."""
+        [B] int32 (0 = dead pad row); tables [B, P] int32; temperature
+        and uniform [B] float32 (None: greedy). Returns ``(tokens [B]
+        int32, last_logits [B, vocab], k', v', new_signature)``, the
+        arrays on the device."""
         args = (self._params, self._buffers,
                 np.ascontiguousarray(ids, np.int64),
                 np.ascontiguousarray(prompt_lens, np.int32),
-                np.ascontiguousarray(tables, np.int32), k, v)
-        (last, k2, v2, aux), fresh = self._dispatch(
+                np.ascontiguousarray(tables, np.int32),
+                *self._selection(len(ids), temperature, uniform), k, v)
+        (toks, last, k2, v2, aux), fresh = self._dispatch(
             "generate_prefill", self._prefill_jit, args)
         self.last_aux = aux
-        return last, k2, v2, fresh
+        return toks, last, k2, v2, fresh
 
     def prefill_chunked(self, ids: np.ndarray, start: np.ndarray,
-                        seg_lens: np.ndarray, tables: np.ndarray, k, v):
+                        seg_lens: np.ndarray, tables: np.ndarray,
+                        temperature, uniform, k, v):
         """Suffix prefill after a prefix-cache hit. ids [B, S] int64
         (left-aligned suffix tokens); start [B] int32 per-row absolute
         offset (= matched prefix length, 0 = dead row); seg_lens [B]
         int32 real suffix lengths; tables [B, P] int32 (prefix pages
-        first, then the row's private pages). Returns ``(last_logits
-        [B, vocab] jax array, k', v', new_signature)``."""
+        first, then the row's private pages); temperature and uniform
+        as ``prefill`` takes them. Returns ``(tokens [B] int32,
+        last_logits [B, vocab], k', v', new_signature)``."""
         args = (self._params, self._buffers,
                 np.ascontiguousarray(ids, np.int64),
                 np.ascontiguousarray(start, np.int32),
                 np.ascontiguousarray(seg_lens, np.int32),
-                np.ascontiguousarray(tables, np.int32), k, v)
-        (last, k2, v2), fresh = self._dispatch(
+                np.ascontiguousarray(tables, np.int32),
+                *self._selection(len(ids), temperature, uniform), k, v)
+        (toks, last, k2, v2), fresh = self._dispatch(
             "generate_chunked", self._chunked_jit, args)
-        return last, k2, v2, fresh
+        self.last_aux = {}
+        return toks, last, k2, v2, fresh
 
     def verify(self, tokens: np.ndarray, start: np.ndarray,
                seg_lens: np.ndarray, tables: np.ndarray, k, v):
@@ -583,23 +648,27 @@ class CachedDecoder:
                 np.ascontiguousarray(tables, np.int32), k, v)
         (logits, k2, v2), fresh = self._dispatch(
             "generate_verify", self._verify_jit, args)
+        self.last_aux = {}
         return logits, k2, v2, fresh
 
     def decode(self, tokens: np.ndarray, positions: np.ndarray,
                active: np.ndarray, ctx: np.ndarray,
-               tables: np.ndarray, k, v):
+               tables: np.ndarray, temperature, uniform, k, v):
         """One fixed-shape decode step. tokens [B] int64; positions [B]
         int32 (slot being written); active [B] bool; ctx [B] int32
-        visible length INCLUDING this token; tables [B, P] int32.
-        Returns ``(logits [B, vocab] jax array, k', v',
-        new_signature)``."""
+        visible length INCLUDING this token; tables [B, P] int32;
+        temperature and uniform [B] float32 (None: greedy; a dead
+        lane's token means nothing). Returns ``(next_tokens [B] int32,
+        logits [B, vocab], k', v', new_signature)``, the arrays on the
+        device."""
         args = (self._params, self._buffers,
                 np.ascontiguousarray(tokens, np.int64),
                 np.ascontiguousarray(positions, np.int32),
                 np.ascontiguousarray(active, bool),
                 np.ascontiguousarray(ctx, np.int32),
-                np.ascontiguousarray(tables, np.int32), k, v)
-        (logits, k2, v2, aux), fresh = self._dispatch(
+                np.ascontiguousarray(tables, np.int32),
+                *self._selection(len(tokens), temperature, uniform), k, v)
+        (toks, logits, k2, v2, aux), fresh = self._dispatch(
             "generate_decode", self._decode_jit, args)
         self.last_aux = aux
-        return logits, k2, v2, fresh
+        return toks, logits, k2, v2, fresh

@@ -2,16 +2,24 @@
 
 ``GenerationServer`` runs every program of its target decoder, and of
 its draft decoder under speculation, through an instance of this
-class: what a run hands back, when the pools are replaced and when the
-output is fetched are decided here and nowhere else.
+class: what a run hands back, when the pools are replaced and what is
+fetched are decided here and nowhere else.
+
+A run hands back the tokens the program chose, on the host, and the
+program's logits where they are, on the device. One ``jax.device_get``
+brings the tokens and the expert counters (``CachedDecoder.last_aux``):
+``rows * 4`` bytes and a few scalars. The logits come with them only
+for a caller that says it needs them there (``host_logits=True``: a
+verify step, which chooses nothing, and a draft step with a sampled
+lane; no traffic of a server without a draft does).
 """
 from __future__ import annotations
 
-from typing import List, Sequence, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
-__all__ = ["ProgramRunner", "SITES"]
+__all__ = ["ProgramRunner", "Run", "SITES"]
 
 # a kind of program (the name of its ``CachedDecoder`` entry point) to
 # the site it compiles under, which is also its warmup-manifest site
@@ -19,6 +27,22 @@ SITES = {"prefill": "generate_prefill",
          "prefill_chunked": "generate_chunked",
          "decode": "generate_decode",
          "verify": "generate_verify"}
+
+
+class Run(NamedTuple):
+    """What ``ProgramRunner.run`` hands back: ``tokens`` the program's
+    choice ``[rows]`` on the host (None from a verify step), ``logits``
+    its logits (a device array; numpy where the caller asked for them
+    on the host), ``aux`` the expert counters as host numbers,
+    ``fresh`` whether the decoder saw this signature for the first
+    time, ``signature`` the feeds' ``(shape, dtype)`` list as a warmup
+    manifest records it, ``fetched_bytes`` what came to the host."""
+    tokens: Optional[np.ndarray]
+    logits: object
+    aux: dict
+    fresh: bool
+    signature: List[Tuple[tuple, str]]
+    fetched_bytes: int
 
 
 class ProgramRunner:
@@ -31,21 +55,25 @@ class ProgramRunner:
         self.decoder = decoder
         self.pools = pools
 
-    def run(self, kind: str, feeds: Sequence[np.ndarray]
-            ) -> Tuple[np.ndarray, bool, List[Tuple[tuple, str]]]:
+    def run(self, kind: str, feeds: Sequence[np.ndarray],
+            host_logits: bool = False) -> Run:
         """Run ``kind``'s program (a key of ``SITES``) over its numpy
-        ``feeds``. Returns ``(out, fresh, signature)``: the program's
-        first output on the host, whether the decoder saw this
-        signature for the first time, and the feeds' ``(shape,
-        dtype)`` list as a warmup manifest records it.
+        ``feeds`` and fetch what it chose.
 
         The entry point is looked up at call time (a test may have
         replaced it). The pools are replaced as soon as the call
         returns: they were donated, so the old ones are gone whether
         or not the fetch below succeeds."""
+        import jax
         pools = self.pools
-        out, k, v, fresh = getattr(self.decoder, kind)(
+        *chosen, logits, k, v, fresh = getattr(self.decoder, kind)(
             *feeds, pools.k, pools.v)
         pools.k, pools.v = k, v
-        return (np.asarray(out), bool(fresh),
-                [(a.shape, str(a.dtype)) for a in feeds])
+        want = {"tokens": chosen[0] if chosen else None,
+                "aux": self.decoder.last_aux}
+        if host_logits:
+            want["logits"] = logits
+        got = jax.device_get(want)
+        return Run(got["tokens"], got.get("logits", logits), got["aux"],
+                   bool(fresh), [(a.shape, str(a.dtype)) for a in feeds],
+                   sum(a.nbytes for a in jax.tree_util.tree_leaves(got)))

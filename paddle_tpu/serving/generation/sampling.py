@@ -5,6 +5,13 @@ old full-window ``generate()`` ran (O(batch) Python iterations and a
 vocab-sized probability normalization per row, per token): softmax and
 inverse-CDF selection run across the whole batch at once, and rows can
 mix greedy (temperature 0) with sampled selection in the same call.
+
+Who calls it: ``HybridParallelInferenceHelper.generate`` (one
+``RandomState`` for its whole batch) and the tests, which hold the
+programs of ``model_fns.CachedDecoder`` to this rule. The serving
+engine does not: its programs choose in place (``model_fns._select``,
+the same rule in float32) and its speculative path judges proposals
+with ``spec_decode.accept_tokens``.
 """
 from __future__ import annotations
 
@@ -24,9 +31,9 @@ def sample_next_tokens(logits: np.ndarray,
     ``temperature`` is a scalar or per-row vector; rows at 0 take the
     argmax, rows above 0 sample from ``softmax(logits / t)`` by inverse
     CDF. Randomness comes from ``uniforms`` ``[B]`` in [0, 1) when
-    given (the engine draws one uniform per row from each request's own
-    RandomState so interleaved batches stay per-request deterministic),
-    else from ``rng``. Returns ``[B]`` int64.
+    given (one a row, as the engine feeds its programs from each
+    request's own RandomState), else from ``rng``. Returns ``[B]``
+    int64.
     """
     logits = np.asarray(logits)
     b = logits.shape[0]

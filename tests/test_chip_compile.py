@@ -246,7 +246,8 @@ def test_default_decode_program_reads_through_the_table(
     text = dec._decode_jit.lower(
         params, buffers, sds((lanes,), jnp.int64), sds((lanes,), jnp.int32),
         sds((lanes,), jnp.bool_), sds((lanes,), jnp.int32),
-        sds((lanes, pages), jnp.int32), pool, pool).compile().as_text()
+        sds((lanes, pages), jnp.int32), sds((lanes,), jnp.float32),
+        sds((lanes,), jnp.float32), pool, pool).compile().as_text()
     assert text.count("tpu_custom_call") == 2       # one a layer
     assert "paged_attention/attend" in text         # and it carries the scope
     assert f"[{lanes},{slots},{H},{d}]" not in text
@@ -302,12 +303,14 @@ def _lower_serve_program(one_chip, config, program):
         lowered = dec._decode_jit.lower(
             params, buffers, sds((lanes,), jnp.int64),
             sds((lanes,), jnp.int32), sds((lanes,), jnp.bool_),
-            sds((lanes,), jnp.int32), sds((lanes, width), jnp.int32), k, v)
+            sds((lanes,), jnp.int32), sds((lanes, width), jnp.int32),
+            sds((lanes,), jnp.float32), sds((lanes,), jnp.float32), k, v)
     else:
         rows, seq = program
         lowered = dec._prefill_jit.lower(
             params, buffers, sds((rows, seq), jnp.int64),
-            sds((rows,), jnp.int32), sds((rows, width), jnp.int32), k, v)
+            sds((rows,), jnp.int32), sds((rows, width), jnp.int32),
+            sds((rows,), jnp.float32), sds((rows,), jnp.float32), k, v)
     return model, lanes, lowered
 
 

@@ -138,8 +138,8 @@ class TestCacheEquivalence:
                             pages_per_seq=pps)
         k, v = m.init_kv_pools(1 + b * pps, ps)
         tables = make_tables(b, pps)
-        last, k, v, _ = dec.prefill(
-            ids, np.full(b, prompt, np.int32), tables, k, v)
+        _, last, k, v, _ = dec.prefill(
+            ids, np.full(b, prompt, np.int32), tables, None, None, k, v)
         np.testing.assert_allclose(np.asarray(last), full[:, -1, :],
                                    rtol=1e-5, atol=1e-6)
         # 4 greedy decode steps vs the growing full forward
@@ -147,9 +147,9 @@ class TestCacheEquivalence:
         ref_ids = ids
         for step in range(4):
             pos = prompt + step
-            logits, k, v, _ = dec.decode(
+            _, logits, k, v, _ = dec.decode(
                 cur, np.full(b, pos, np.int32), np.ones(b, bool),
-                np.full(b, pos + 1, np.int32), tables, k, v)
+                np.full(b, pos + 1, np.int32), tables, None, None, k, v)
             ref_ids = np.concatenate([ref_ids, cur[:, None]], 1)
             ref = m(paddle.to_tensor(ref_ids)).numpy()[:, -1]
             np.testing.assert_allclose(np.asarray(logits), ref,
@@ -174,14 +174,16 @@ class TestCacheEquivalence:
             ids_b[0] = ids[0]
             lens = np.zeros(b, np.int32)
             lens[0] = 6
-            last, k, v, _ = dec.prefill(ids_b, lens, tables, k, v)
+            _, last, k, v, _ = dec.prefill(ids_b, lens, tables, None, None,
+                                           k, v)
             tok = np.zeros(b, np.int64)
             tok[0] = int(np.asarray(last)[0].argmax())
             active = np.zeros(b, bool)
             active[0] = True
-            logits, k, v, _ = dec.decode(
+            _, logits, k, v, _ = dec.decode(
                 tok, np.full(b, 6, np.int32), active,
-                np.where(active, 7, 0).astype(np.int32), tables, k, v)
+                np.where(active, 7, 0).astype(np.int32), tables, None, None,
+                k, v)
             outs.append(np.asarray(logits)[0])
         np.testing.assert_allclose(outs[0], outs[1], rtol=1e-5,
                                    atol=1e-6)
@@ -232,6 +234,197 @@ class TestSampling:
             for _ in range(3000)])
         freq = np.bincount(draws, minlength=3) / 3000.0
         np.testing.assert_allclose(freq, [0.7, 0.2, 0.1], atol=0.03)
+
+
+# ------------------------------------------- selection in the program
+def twin_model():
+    """A model whose every logit has a twin: the tied head's rows 2i
+    and 2i + 1 are one vector, so each row's best logit is tied and
+    the first of the pair has to win."""
+    m, cfg = make_model()
+    w = m.gpt.embeddings.word_embeddings.weight
+    twins = w.numpy().copy()
+    twins[1::2] = twins[0::2]
+    w.set_value(twins)
+    return m, cfg
+
+
+def run_selecting(dec, kind, m, temperature=None, uniform=None):
+    """One call of ``kind`` over fresh pools, lane 1 dead: ``(tokens,
+    logits)`` on the host."""
+    b, ps, pps = dec.max_batch, dec.page_size, dec.pages_per_seq
+    k, v = m.init_kv_pools(1 + b * pps, ps)
+    tables = make_tables(b, pps)
+    ids = np.random.RandomState(5).randint(0, 64, (b, 6)).astype(np.int64)
+    lens = np.full(b, 6, np.int32)
+    lens[1] = 0
+    sel = (temperature, uniform)
+    if kind == "prefill":
+        toks, logits, *_ = dec.prefill(ids, lens, tables, *sel, k, v)
+    elif kind == "prefill_chunked":
+        toks, logits, *_ = dec.prefill_chunked(
+            ids, np.zeros(b, np.int32), lens, tables, *sel, k, v)
+    else:
+        _, _, k, v, _ = dec.prefill(ids, lens, tables, None, None, k, v)
+        toks, logits, *_ = dec.decode(
+            ids[:, 0], lens, lens > 0, lens + (lens > 0), tables, *sel,
+            k, v)
+    return np.asarray(toks), np.asarray(logits)
+
+
+KINDS = ["prefill", "prefill_chunked", "decode"]
+
+
+class TestSelectionInTheProgram:
+    @pytest.mark.parametrize("kind", KINDS)
+    def test_tokens_are_the_argmax_of_the_logits_beside_them(self, kind):
+        """Ties go to the first index, as ``np.argmax`` has it, and a
+        dead lane's row is chosen from like any other."""
+        m, cfg = twin_model()
+        dec = CachedDecoder(m, max_batch=3, page_size=4, pages_per_seq=4,
+                            donate=False)
+        toks, logits = run_selecting(dec, kind, m)
+        assert toks.shape == (3,) and toks.dtype == np.int32
+        np.testing.assert_array_equal(logits[:, 0::2], logits[:, 1::2])
+        np.testing.assert_array_equal(toks, logits.argmax(-1))
+        assert (toks % 2 == 0).all()
+
+    @pytest.mark.parametrize("kind", KINDS)
+    def test_a_sampled_row_leaves_the_greedy_rows_as_they_were(self, kind):
+        m, cfg = make_model()
+        dec = CachedDecoder(m, max_batch=3, page_size=4, pages_per_seq=4,
+                            donate=False)
+        greedy, logits = run_selecting(dec, kind, m)
+        temperature = np.array([0.0, 0.0, 5.0], np.float32)
+        picks = set()
+        for u in (0.05, 0.35, 0.65, 0.95):
+            toks, again = run_selecting(
+                dec, kind, m, temperature, np.full(3, u, np.float32))
+            np.testing.assert_array_equal(again, logits)
+            np.testing.assert_array_equal(toks[:2], greedy[:2])
+            picks.add(int(toks[2]))
+            want = sample_next_tokens(logits, temperature,
+                                      uniforms=np.full(3, u))
+            np.testing.assert_array_equal(toks, want)
+        assert len(picks) > 1           # the third row did sample
+        # one program served both: no second signature for sampling
+        assert len(dec.compiled_signatures) == (2 if kind == "decode"
+                                                else 1)
+
+    def test_sampled_choices_follow_softmax_over_temperature(self):
+        """``TestSampling``'s frequency test through the decode
+        program: 64 lanes read one context, each draws at its own
+        uniform, 40 steps over."""
+        m, cfg = make_model()
+        b, ps, pps, t = 64, 4, 2, 0.5
+        dec = CachedDecoder(m, max_batch=b, page_size=ps,
+                            pages_per_seq=pps, donate=False)
+        k, v = m.init_kv_pools(1 + b * pps, ps)
+        tables = make_tables(b, pps)
+        ids = np.tile(np.array([[5, 7, 9]], np.int64), (b, 1))
+        lens = np.full(b, 3, np.int32)
+        _, _, k, v, _ = dec.prefill(ids, lens, tables, None, None, k, v)
+        rng = np.random.RandomState(0)
+        draws, same = [], 0
+        for _ in range(40):
+            u = rng.random_sample(b)
+            toks, logits, *_ = dec.decode(
+                np.full(b, 2, np.int64), lens, np.ones(b, bool), lens + 1,
+                tables, np.full(b, t, np.float32), u, k, v)
+            toks, logits = np.asarray(toks), np.asarray(logits)
+            same += int((toks == sample_next_tokens(
+                logits, t, uniforms=u)).sum())
+            draws.extend(toks)
+        # float32 in the program, float64 on the host: a draw within a
+        # rounding of a boundary may fall on the other side
+        assert same >= 0.995 * len(draws)
+        z = logits[0].astype(np.float64) / t
+        p = np.exp(z - z.max())
+        p /= p.sum()
+        freq = np.bincount(draws, minlength=p.size) / len(draws)
+        top = np.argsort(p)[-3:]
+        assert p[top].min() > 0.03
+        np.testing.assert_allclose(freq[top], p[top], atol=0.03)
+
+    def test_a_uniform_that_rounds_to_one_takes_the_last_token(self):
+        m, cfg = make_model()
+        dec = CachedDecoder(m, max_batch=3, page_size=4, pages_per_seq=4,
+                            donate=False)
+        toks, logits = run_selecting(
+            dec, "prefill", m, np.full(3, 1.0, np.float32),
+            np.full(3, 1.0 - 1e-12))
+        assert (toks == logits.shape[-1] - 1).all()
+
+    def test_a_seeded_stream_is_the_same_alone_and_in_company(self):
+        m, cfg = make_model()
+        ask = dict(max_new_tokens=10, temperature=0.8, seed=11)
+        with GenerationServer(m, max_batch=4, page_size=8,
+                              name="company") as srv:
+            alone = srv.generate([5, 7, 9], **ask)
+            others = [srv.submit_generate([3 + i, 4], max_new_tokens=12,
+                                          temperature=0.5 * (i % 2),
+                                          seed=i)
+                      for i in range(3)]
+            beside = srv.submit_generate([5, 7, 9], **ask)
+            assert beside.result(timeout=60) == alone
+            for f in others:
+                assert len(f.result(timeout=60)) == 12
+
+    def test_a_greedy_step_brings_token_ids_and_nothing_else(self):
+        """No traffic of a server without a draft fetches a logit: a
+        decode step brings ``max_batch`` token ids (and its counters,
+        where the model has experts), and says so."""
+        m, cfg = make_model()
+        with GenerationServer(m, max_batch=4, page_size=8,
+                              name="fetch") as srv:
+            srv.warmup(seq_buckets=[8])
+            assert srv.metrics_snapshot()["engine"]["fetch_bytes"] == 0
+            want = self_reference(m, cfg, [5, 7, 9], 6)
+            assert srv.generate([5, 7, 9], max_new_tokens=6) == want
+            eng = srv.metrics_snapshot()["engine"]
+            assert eng["select"] == {"in_program": 6, "on_host": 0}
+            # one prefill row, then five steps of four lanes
+            assert eng["fetch_bytes"] == 4 * (1 + 5 * 4)
+            assert eng["fetch_bytes"] <= 6 * srv.max_batch * 8
+            # the logits are there for a caller that asks
+            run = srv._runners[0].run(
+                "decode", srv._decode_feeds([], [], [])
+                + srv._selection_feeds([], [], srv.max_batch),
+                host_logits=True)
+            assert isinstance(run.logits, np.ndarray)
+            assert run.logits.shape == (4, cfg.vocab_size)
+            np.testing.assert_array_equal(run.tokens,
+                                          run.logits.argmax(-1))
+            assert run.fetched_bytes == run.logits.nbytes + 4 * 4
+
+    def test_speculation_judges_from_logits_on_the_host(self):
+        """A verify step chooses nothing: its logits come to the host,
+        and a draft step's do while a lane samples; a greedy lane's
+        proposal is the draft program's own choice."""
+        m, cfg = make_model()
+        want = self_reference(m, cfg, [5, 7, 9], 8)
+        with GenerationServer(m, max_batch=2, page_size=8, draft_model=m,
+                              spec_k=3, name="spec-select") as srv:
+            assert srv.generate([5, 7, 9], max_new_tokens=8) == want
+            greedy = srv.metrics_snapshot()["engine"]["select"]
+            # the verify steps alone: every draft step chose in program
+            spec = srv.metrics_snapshot()["spec"]
+            assert greedy["on_host"] * 3 == spec["proposed"]
+            assert greedy["in_program"] >= 2 + 3 * greedy["on_host"]
+            a = srv.generate([5, 7, 9], max_new_tokens=8,
+                             temperature=0.8, seed=3)
+            b = srv.generate([5, 7, 9], max_new_tokens=8,
+                             temperature=0.8, seed=3)
+            assert a == b and len(a) == 8
+            sampled = srv.metrics_snapshot()["engine"]["select"]
+            verifies = (srv.metrics_snapshot()["spec"]["proposed"]
+                        - spec["proposed"]) // 3
+            # now the three draft steps of a round fetch logits too
+            assert sampled["on_host"] - greedy["on_host"] == 4 * verifies
+
+
+def self_reference(m, cfg, prompt, n):
+    return TestGenerationServer()._reference(m, cfg, prompt, n)
 
 
 # ----------------------------------------------------- the engine

@@ -90,6 +90,11 @@ HAND_COUNTED = {
     "engine.queue_wait_ms.counts.-1": len(PROMPT_LENS),
     # the first iteration began with no live stream: it stalls nobody
     "engine.stream_stall_ms.counts.-1": MAX_NEW - 2,
+    # every program chose its rows' tokens: two prefill groups and the
+    # decode steps, and an int32 a row is all that came to the host
+    # (two rows a prefill, four lanes a step; no expert counts)
+    "engine.select": {"in_program": 2 + MAX_NEW - 1, "on_host": 0},
+    "engine.fetch_bytes": 4 * (2 * 2 + (MAX_NEW - 1) * 4),
 }
 
 
@@ -101,7 +106,8 @@ def test_engine_counters_equal_hand_counted(lockstep_run, key):
         part = int(part) if part.lstrip("-").isdigit() else part
         got, before = got[part], before[part]
     assert got == HAND_COUNTED[key]
-    assert before in (0, {})         # cumulative since the server started
+    # cumulative since the server started
+    assert before in (0, {}, {"in_program": 0, "on_host": 0})
 
 
 @pytest.mark.parametrize("step", range(MAX_NEW - 1))
@@ -130,7 +136,7 @@ def test_only_read_counters_are_kept(lockstep_run):
     eng = lockstep_run["snap1"]["engine"]
     # "moe" joins them for a model with expert layers (PR 29)
     assert set(eng) == {"loop_s", "prefill", "kv", "stream_stall_ms",
-                        "queue_wait_ms"}
+                        "queue_wait_ms", "select", "fetch_bytes"}
     assert set(eng["prefill"]) == {"prompt_tokens", "padded_tokens",
                                    "split_groups", "by_shape",
                                    "call_s_by_shape"}
@@ -406,9 +412,11 @@ def lowered_programs():
     decode_args = (dec._params, dec._buffers, np.zeros(b, np.int64),
                    np.zeros(b, np.int32), np.zeros(b, bool),
                    np.zeros(b, np.int32), np.zeros((b, p), np.int32),
+                   np.zeros(b, np.float32), np.zeros(b, np.float32),
                    srv.kv.k, srv.kv.v)
     prefill_args = (dec._params, dec._buffers, np.zeros((b, 8), np.int64),
                     np.zeros(b, np.int32), np.zeros((b, p), np.int32),
+                    np.zeros(b, np.float32), np.zeros(b, np.float32),
                     srv.kv.k, srv.kv.v)
     return {"decode": dec._decode_jit.lower(*decode_args).compile()
             .as_text(),

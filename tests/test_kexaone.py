@@ -163,22 +163,31 @@ def test_the_token_block_changes_no_number(reference):
 
 def serve(model, prompts, max_new, **server_kw):
     """``prompts`` (of distinct lengths) served together. Returns the
-    tokens and, by prompt length, the logits the server sampled each
-    token from (its sampler is handed them), and the last snapshot."""
+    tokens and, by prompt length, the logits each token was chosen from
+    (a dispatch leaves them on the device beside the tokens its program
+    chose: a decode step's row is the lane, a prefill's the sequence's
+    place in the call), and the last snapshot."""
     seen = {}
     kw = dict(max_batch=4, page_size=PAGE, num_pages=64, max_seq_len=64,
               seq_buckets=[8, 16, 32], start=False)
     kw.update(server_kw)
     srv = GenerationServer(model, **kw)
-    sample = srv._sample_and_emit
+    dispatch = srv._dispatch
 
-    def spy(seqs, logits):
-        for seq, row in zip(seqs, logits):
-            seen.setdefault(len(seq.req.prompt), []).append(np.array(row))
+    def spy(kind, feeds, seqs, *args, **kwargs):
+        ran = dispatch(kind, feeds, seqs, *args, **kwargs)
+        logits = np.asarray(ran.logits)
+        for i, seq in enumerate(seqs):
+            row = seq.slot if kind == "decode" else i
+            seen.setdefault(len(seq.req.prompt), []).append(
+                np.array(logits[row]))
+            # the program's choice is the first best of that row
+            assert ran.tokens[row] == logits[row].argmax()
+            # a lane never holds more of a window layer than its ring
             assert len(seq.window_pages) <= srv.kv.ring_pages
-        return sample(seqs, logits)
+        return ran
 
-    srv._sample_and_emit = spy
+    srv._dispatch = spy
     futures = [srv.submit_generate(p, max_new_tokens=max_new)
                for p in prompts]
     srv.start()

@@ -139,8 +139,8 @@ def _decoder(kv_dtype=None):
               .reshape(b, pps))
     ids = np.random.RandomState(0).randint(
         0, cfg.vocab_size, (b, prompt)).astype("int64")
-    last, k, v, _ = dec.prefill(
-        ids, np.full(b, prompt, np.int32), tables, k, v)
+    _, last, k, v, _ = dec.prefill(
+        ids, np.full(b, prompt, np.int32), tables, None, None, k, v)
     cur = np.asarray(last).argmax(-1)
     return dec, tables, k, v, cur, prompt
 
@@ -149,9 +149,9 @@ def _decode_steps(dec, tables, k, v, cur, prompt, n):
     b = tables.shape[0]
     for i in range(n):
         pos = prompt + i
-        logits, k, v, _ = dec.decode(
+        _, logits, k, v, _ = dec.decode(
             cur, np.full(b, pos, np.int32), np.ones(b, bool),
-            np.full(b, pos + 1, np.int32), tables, k, v)
+            np.full(b, pos + 1, np.int32), tables, None, None, k, v)
         cur = np.asarray(logits).argmax(-1)
     return k, v, cur
 

@@ -362,13 +362,13 @@ def test_int8_logits_parity_through_cached_decoder():
         ids = np.array([[3, 5, 7, 11, 0, 0, 0, 0],
                         [2, 4, 6, 8, 10, 12, 0, 0]], np.int64)
         lens = np.array([4, 6], np.int32)
-        last, k, v, _ = dec.prefill(ids, lens, tables, k, v)
+        _, last, k, v, _ = dec.prefill(ids, lens, tables, None, None, k, v)
         logits_seq = [np.asarray(last)]
         ctx = lens.copy()
         for step in range(3):
             tok = np.asarray(last).argmax(-1).astype(np.int64)
-            logits, k, v, _ = dec.decode(tok, ctx, np.ones(B, bool),
-                                         ctx + 1, tables, k, v)
+            _, logits, k, v, _ = dec.decode(tok, ctx, np.ones(B, bool),
+                                         ctx + 1, tables, None, None, k, v)
             # a new array: the call above may still be reading the old
             # one (dispatch is asynchronous and the CPU backend aliases
             # numpy buffers), and `ctx += 1` in place raced with it

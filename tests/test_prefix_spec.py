@@ -157,14 +157,14 @@ class TestCopyOnWrite:
         ids = prefix[None, :].astype(np.int64).repeat(2, 0)
         lens = np.array([8, 0], np.int32)
         for tbl in (t_shared, t_private):
-            _, k, v, _ = dec.prefill(ids, lens, tbl, k, v)
+            _, _, k, v, _ = dec.prefill(ids, lens, tbl, None, None, k, v)
         outs = []
         for tbl in (t_shared, t_private):
             sid = np.zeros((2, 8), np.int64)
             sid[0, :5] = suffix
-            last, k, v, _ = dec.prefill_chunked(
+            _, last, k, v, _ = dec.prefill_chunked(
                 sid, np.array([8, 0], np.int32),
-                np.array([5, 0], np.int32), tbl, k, v)
+                np.array([5, 0], np.int32), tbl, None, None, k, v)
             outs.append(np.asarray(last)[0])
         np.testing.assert_array_equal(outs[0], outs[1])
 
@@ -180,11 +180,11 @@ class TestCopyOnWrite:
             0, cfg.vocab_size, (1, 7)).astype(np.int64)
         t1 = 1 + np.arange(pps, dtype=np.int32)[None, :]
         t2 = 1 + pps + np.arange(pps, dtype=np.int32)[None, :]
-        last_a, k, v, _ = dec.prefill(
-            ids, np.array([7], np.int32), t1, k, v)
-        last_b, k, v, _ = dec.prefill_chunked(
+        _, last_a, k, v, _ = dec.prefill(
+            ids, np.array([7], np.int32), t1, None, None, k, v)
+        _, last_b, k, v, _ = dec.prefill_chunked(
             ids, np.zeros(1, np.int32), np.array([7], np.int32),
-            t2, k, v)
+            t2, None, None, k, v)
         np.testing.assert_allclose(np.asarray(last_a),
                                    np.asarray(last_b),
                                    rtol=1e-5, atol=1e-6)

@@ -42,19 +42,25 @@ def run_entry_points(model, mesh, use_pallas, kv_dtype=""):
                     [9, 10, 11, 12, 13, 14, 0, 0]], np.int64)
     plens = np.array([4, 6], np.int32)
     tables = np.array([[1, 2, 3, 4], [5, 6, 7, 8]], np.int32)
-    last, k, v, _ = dec.prefill(ids, plens, tables, k, v)
+    t0, last, k, v, _ = dec.prefill(ids, plens, tables, None, None, k, v)
     toks = np.array([3, 4], np.int64)
     act = np.array([True, True])
     ctx = plens + 1
-    dc, k, v, _ = dec.decode(toks, plens.copy(), act, ctx, tables, k, v)
+    t1, dc, k, v, _ = dec.decode(toks, plens.copy(), act, ctx, tables,
+                                 None, None, k, v)
     suffix = np.array([[20, 21, 0, 0], [22, 23, 24, 0]], np.int64)
     start = ctx.astype(np.int32)
     slens = np.array([2, 3], np.int32)
-    ck, k, v, _ = dec.prefill_chunked(suffix, start, slens, tables, k, v)
+    t2, ck, k, v, _ = dec.prefill_chunked(suffix, start, slens, tables,
+                                          None, None, k, v)
     draft = np.array([[30, 31], [32, 33]], np.int64)
     vstart = (start + slens).astype(np.int32)
     vlens = np.array([2, 2], np.int32)
     vf, k, v, _ = dec.verify(draft, vstart, vlens, tables, k, v)
+    # the programs choose after the gather of a vocabulary-sharded head
+    for chosen, logits in ((t0, last), (t1, dc), (t2, ck)):
+        np.testing.assert_array_equal(np.asarray(chosen),
+                                      np.asarray(logits).argmax(-1))
     return [np.asarray(x) for x in (last, dc, ck, vf)]
 
 

@@ -446,12 +446,12 @@ def _run(args):
     tables = (1 + np.arange(b * pages_per_seq, dtype=np.int32)
               .reshape(b, pages_per_seq))
     lens = np.full(b, plen, np.int32)
-    last, k, v, _ = dec.prefill(prompts, lens, tables, k, v)
+    _, last, k, v, _ = dec.prefill(prompts, lens, tables, None, None, k, v)
     cur = np.asarray(last).argmax(-1)
     ref_ids = np.concatenate([prompts, cur[:, None]], 1)
-    logits, k, v, _ = dec.decode(
+    _, logits, k, v, _ = dec.decode(
         cur, np.full(b, plen, np.int32), np.ones(b, bool),
-        np.full(b, plen + 1, np.int32), tables, k, v)
+        np.full(b, plen + 1, np.int32), tables, None, None, k, v)
     ref = model(paddle.to_tensor(ref_ids)).numpy()[:, -1]
     equiv = float(np.abs(np.asarray(logits) - ref).max())
 

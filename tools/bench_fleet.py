@@ -462,20 +462,21 @@ def _mesh_decode_trial(model, mesh, *, batch, page_size, pages_per_seq,
     plens = np.full((batch,), prefill_len, np.int32)
     tables = (1 + np.arange(batch * pages_per_seq, dtype=np.int32)
               .reshape(batch, pages_per_seq))
-    last, k, v, _ = dec.prefill(ids, plens, tables, k, v)
+    _, last, k, v, _ = dec.prefill(ids, plens, tables, None, None, k, v)
     toks = np.asarray(last).argmax(-1).astype(np.int64)
     active = np.ones((batch,), bool)
     streams = [toks.copy()]
     # untimed warmup step compiles the decode executable
     pos = plens.astype(np.int32)
-    lg, k, v, _ = dec.decode(toks, pos, active, pos + 1, tables, k, v)
+    _, lg, k, v, _ = dec.decode(toks, pos, active, pos + 1, tables,
+                                None, None, k, v)
     toks = np.asarray(lg).argmax(-1).astype(np.int64)
     streams.append(toks.copy())
     t0 = time.perf_counter()
     for i in range(steps):
         pos = (plens + 1 + i).astype(np.int32)
-        lg, k, v, _ = dec.decode(toks, pos, active, pos + 1, tables,
-                                 k, v)
+        _, lg, k, v, _ = dec.decode(toks, pos, active, pos + 1, tables,
+                                    None, None, k, v)
         toks = np.asarray(lg).argmax(-1).astype(np.int64)
         streams.append(toks.copy())
     dt = time.perf_counter() - t0

@@ -64,8 +64,8 @@ def _build_decoder():
               .reshape(b, pps))
     ids = np.random.RandomState(0).randint(
         0, cfg.vocab_size, (b, prompt)).astype("int64")
-    last, k, v, _ = dec.prefill(
-        ids, np.full(b, prompt, np.int32), tables, k, v)
+    _, last, k, v, _ = dec.prefill(
+        ids, np.full(b, prompt, np.int32), tables, None, None, k, v)
     cur = np.asarray(last).argmax(-1)
     capacity = ps * pps
     return {"dec": dec, "k": k, "v": v, "tables": tables,
@@ -83,9 +83,9 @@ def _decode_loop(st, steps: int) -> float:
     t0 = time.perf_counter()
     for i in range(steps):
         pos = prompt + (i % (cap - prompt - 1))
-        logits, k, v, _ = dec.decode(
+        _, logits, k, v, _ = dec.decode(
             cur, np.full(b, pos, np.int32), np.ones(b, bool),
-            np.full(b, pos + 1, np.int32), tables, k, v)
+            np.full(b, pos + 1, np.int32), tables, None, None, k, v)
         cur = np.asarray(logits).argmax(-1)
     dt = time.perf_counter() - t0
     st["k"], st["v"], st["cur"] = k, v, cur

@@ -319,15 +319,17 @@ def _serving_aot(model, serving_axes, page_size: int, max_batch: int):
     ids = jnp.zeros((b, s), dtype=jnp.int32)
     plens = jnp.full((b,), s, dtype=jnp.int32)
     tables = jnp.zeros((b, pages_per_seq), dtype=jnp.int32)
+    greedy = (jnp.zeros((b,), dtype=jnp.float32),) * 2
     prefill = dec._prefill_jit.lower(
-        dec._params, dec._buffers, ids, plens, tables, k, v).compile()
+        dec._params, dec._buffers, ids, plens, tables, *greedy,
+        k, v).compile()
     tokens = jnp.zeros((b,), dtype=jnp.int32)
     positions = jnp.full((b,), s, dtype=jnp.int32)
     active = jnp.ones((b,), dtype=bool)
     ctx = jnp.full((b,), s + 1, dtype=jnp.int32)
     decode = dec._decode_jit.lower(
         dec._params, dec._buffers, tokens, positions, active, ctx,
-        tables, k, v).compile()
+        tables, *greedy, k, v).compile()
     out = {}
     for site, comp in (("prefill", prefill), ("decode", decode)):
         ma = comp.memory_analysis()
