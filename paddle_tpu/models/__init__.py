@@ -7,6 +7,9 @@ from .gpt import (  # noqa: F401
     gpt_tiny, gpt2_small, gpt2_large, gpt3_1p3b, smallthinker_21ba3b,
     k_exaone_236b_a23b,
 )
+from .nemotron_h import (  # noqa: F401
+    NemotronHConfig, NemotronHForCausalLM, nemotron_3_super_120b_a12b,
+)
 from .bert import (  # noqa: F401
     BertConfig, BertModel, BertForPretraining,
     BertForSequenceClassification, BertPretrainingCriterion, bert_tiny,

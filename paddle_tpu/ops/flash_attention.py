@@ -211,15 +211,15 @@ def flash_attention_bshd(q, k, v, causal=False, scale=None, window=None):
 def attention_bshd(q, k, v, causal=False, scale=None, use_flash=True,
                    window=None):
     """THE flash-or-dense selection point for maskless attention in
-    [B,S,H,D] layout: Pallas kernel when ``use_flash`` and preferred()
-    (supported shapes AND seq >= FLAGS_flash_min_seqlen — the measured
-    win/loss boundary, PERF.md), else the XLA softmax reference. Both
-    the module attention path and the stacked SPMD decoder route here
-    so the gating can never diverge between them. ``k``/``v`` may hold
-    fewer heads than ``q`` (K/V head ``g`` serves query heads ``g*G ..
-    g*G+G-1``); ``window`` (causal only) lets a row see its last
-    ``window`` positions, itself among them."""
-    if use_flash and preferred(q, k, v, None, causal):
+    [B,S,H,D] layout, for the module attention path and the stacked SPMD
+    decoder alike: the Pallas kernel when ``use_flash`` and preferred()
+    (supported shapes AND seq >= FLAGS_flash_min_seqlen, the measured
+    boundary, PERF.md) or ``use_flash="always"`` and supported(), else
+    the XLA softmax reference. ``k``/``v`` may hold fewer heads than
+    ``q`` (K/V head ``g`` serves query heads ``g*G .. g*G+G-1``); a row
+    sees its last ``window`` positions (causal only), itself included."""
+    if use_flash and (preferred(q, k, v, None, causal) or (
+            use_flash == "always" and supported(q, k, v, None, causal))):
         return flash_attention_bshd(q, k, v, causal=causal, scale=scale,
                                     window=window)
     if window is not None or k.shape[2] != q.shape[2]:

@@ -39,7 +39,8 @@ import jax.numpy as jnp
 
 __all__ = ["route_top_k", "route_sigmoid_norm", "dropless_moe"]
 
-_ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
+_ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu,
+                "relu2": lambda x: jnp.square(jax.nn.relu(x))}
 
 
 def _router_logits(router_in, w_router):
@@ -82,16 +83,18 @@ def dropless_moe(x, router_in, w_router, w_gate, w_up, w_down, *,
                  offset: int = 0, shared=None, token_block: int = 0):
     """``sum_{e in C, e held} w_e * E_e(x)`` a token, ``C`` the
     ``top_k`` experts the router chooses among all of them, ``E_e(x) =
-    (act(x @ w_gate[e]) * (x @ w_up[e])) @ w_down[e]``; with ``shared``
-    (the three weights of one more expert every token goes through)
-    ``+ E_shared(x)``.
+    (act(x @ w_gate[e]) * (x @ w_up[e])) @ w_down[e]``, or with
+    ``w_gate=None`` the ungated ``act(x @ w_up[e]) @ w_down[e]``; with
+    ``shared`` (the three weights of one more gated expert every token
+    goes through) ``+ E_shared(x)``.
 
-    x, router_in: [T, H] (the router may read another tensor than the
-    experts); w_router: [H, E_all]; w_gate, w_up: [E, H, I]; w_down:
-    [E, I, H], the experts ``offset .. offset + E - 1`` of ``E_all``
-    (``E <= E_all``); ``scoring``: ``"softmax_top_k"``
-    (``route_top_k``) or ``"sigmoid_norm"`` (``route_sigmoid_norm``
-    with ``scale``); ``activation``: ``"relu"`` or ``"silu"``;
+    x: [T, H]; router_in: [T, R] (the router may read another tensor,
+    of another width, than the experts); w_router: [R, E_all]; w_gate,
+    w_up: [E, H, I]; w_down: [E, I, H], the experts ``offset .. offset
+    + E - 1`` of ``E_all`` (``E <= E_all``); ``scoring``:
+    ``"softmax_top_k"`` (``route_top_k``) or ``"sigmoid_norm"``
+    (``route_sigmoid_norm`` with ``scale``); ``activation``:
+    ``"relu"``, ``"silu"`` or ``"relu2"`` (``relu(x)^2``);
     ``valid``: [T] bool or None: a row that is padding or a dead lane
     goes to no expert, touches none and gets zeros. With
     ``token_block`` a call of more rows than that is computed a block
@@ -106,7 +109,7 @@ def dropless_moe(x, router_in, w_router, w_gate, w_up, w_down, *,
     got a row) and ``max_expert_load`` (rows of the fullest of them).
     """
     t, hidden = x.shape
-    n_held, n_all = w_gate.shape[0], w_router.shape[1]
+    n_held, n_all = w_up.shape[0], w_router.shape[1]
     whole = n_held == n_all and not offset
     if not whole and not 0 <= offset <= n_all - n_held:
         raise ValueError(f"experts {offset}..{offset + n_held - 1} are not "
@@ -143,9 +146,13 @@ def dropless_moe(x, router_in, w_router, w_gate, w_up, w_down, *,
                 group_sizes = jnp.bincount(
                     flat, length=n_held + 1)[:n_held].astype(jnp.int32)
             with jax.named_scope("experts"):
-                gate = jax.lax.ragged_dot(rows, w_gate, group_sizes)
-                up = jax.lax.ragged_dot(rows, w_up, group_sizes)
-                act_rows = (act(gate) * up).astype(x.dtype)
+                if w_gate is None:
+                    act_rows = act(jax.lax.ragged_dot(
+                        rows, w_up, group_sizes)).astype(x.dtype)
+                else:
+                    gate = jax.lax.ragged_dot(rows, w_gate, group_sizes)
+                    up = jax.lax.ragged_dot(rows, w_up, group_sizes)
+                    act_rows = (act(gate) * up).astype(x.dtype)
                 down = jax.lax.ragged_dot(act_rows, w_down, group_sizes)
             with jax.named_scope("combine"):
                 # back to token order: row i of the sorted list is

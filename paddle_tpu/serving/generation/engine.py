@@ -268,18 +268,21 @@ class _Request:
 class _ActiveSeq:
     """One live lane of the in-flight decode batch."""
 
-    __slots__ = ("req", "slot", "pages", "window_pages", "ctx",
-                 "max_total", "last_token", "n_generated", "last_emit_t",
-                 "prefix_len", "history", "draft_ctx", "published")
+    __slots__ = ("req", "slot", "pages", "window_pages", "state_slot",
+                 "ctx", "max_total", "last_token", "n_generated",
+                 "last_emit_t", "prefix_len", "history", "draft_ctx",
+                 "published")
 
     def __init__(self, req: _Request, slot: int, pages: List[int],
                  max_total: int, prefix_len: int = 0,
-                 window_pages: Sequence[int] = ()):
+                 window_pages: Sequence[int] = (), state_slot: int = 0):
         self.req = req
         self.slot = slot
         self.pages = pages              # prefix pages first, private after
         # the ring of the window layers (kv_cache.py): its own, unshared
         self.window_pages = list(window_pages)
+        # and the slot of the state layers' pools (0: the model has none)
+        self.state_slot = int(state_slot)
         self.ctx = len(req.prompt)      # tokens whose K/V is cached
         self.max_total = max_total      # prompt + generation budget
         self.last_token = -1
@@ -776,9 +779,19 @@ class GenerationServer:
                 "prefix_cache=True with a model whose window layers "
                 "keep a ring a sequence: a ring cannot be shared (what "
                 "it held of the prefix is overwritten)")
+        if self.kv.state_columns and (prefix_cache or draft_model
+                                      is not None):
+            raise ValueError(
+                f"{'prefix_cache=True' if prefix_cache else 'a draft model'}"
+                f" with a model whose layers keep a recurrent state a "
+                f"sequence (the 'state' kind of kv_cache_spec()): a "
+                f"state slot holds the state after a sequence's last "
+                f"token alone, so no shared prefix can be resumed from "
+                f"it and no verify window rolled back")
         if prefix_cache is None:
-            prefix_cache = self.kv.window is None and bool(
-                _flag("FLAGS_decode_prefix_cache", True))
+            prefix_cache = self.kv.window is None \
+                and not self.kv.state_columns and bool(
+                    _flag("FLAGS_decode_prefix_cache", True))
         self.prefix = PrefixCache(self.kv) if prefix_cache else None
         # ---- speculative decoding (draft proposes, target verifies)
         self.spec_k = int(spec_k if spec_k is not None
@@ -1251,14 +1264,19 @@ class GenerationServer:
         positions over ``active`` live lanes (the work behind
         ``paged_attn_roofline``, on the trace's own clock), and for a
         model with window layers ``window_context_tokens``, the part of
-        each lane's context such a layer reads (``min(ctx, window)``).
-        ``stall_t0`` is when this loop iteration began, if it began
-        with a live stream (the end of the last iteration's
+        each lane's context such a layer reads (``min(ctx, window)``),
+        for one with state layers ``state_slots_live``, the slots the
+        step reads and writes. ``stall_t0`` is when this loop iteration
+        began, if it began with a live stream (the end of the last
+        iteration's
         sample_emit): what every running stream has waited since, the
         admissions and prefill groups of this iteration, is the
         stream stall."""
         args = {"active": len(active),
                 "context_tokens": int(ctx_after.sum())}
+        if self.kv.state_columns:
+            # a live lane reads and writes its slot of every state layer
+            args["state_slots_live"] = len(active)
         if self.kv.window is not None:
             args["window_context_tokens"] = int(
                 np.minimum(ctx_after, self.kv.window).sum())
@@ -1473,6 +1491,17 @@ class GenerationServer:
                 if ring is None:
                     self.kv.release(pages)
                     break
+                # and the state layers' slot, sized for every lane too
+                try:
+                    state_slot = self.kv.alloc_state()
+                except BaseException:
+                    self.kv.release(pages)
+                    self.kv.release_window(ring)
+                    raise
+                if state_slot is None:
+                    self.kv.release(pages)
+                    self.kv.release_window(ring)
+                    break
                 # exception barrier (pdlint RP001): between taking the
                 # reservation and publishing it into self._slots no
                 # failure may keep the references — a leaked page never
@@ -1483,6 +1512,7 @@ class GenerationServer:
                 except BaseException:
                     self.kv.release(pages)
                     self.kv.release_window(ring)
+                    self.kv.release_state(state_slot)
                     raise
                 try:
                     if self.prefix is not None:
@@ -1493,14 +1523,16 @@ class GenerationServer:
                     slot = free_slots.pop(0)
                     seq = _ActiveSeq(req, slot, shared + pages,
                                      max_total, prefix_len=matched,
-                                     window_pages=ring)
+                                     window_pages=ring,
+                                     state_slot=state_slot)
                     self._slots[slot] = seq
                 except BaseException:
                     self.kv.release(shared + pages)
                     self.kv.release_window(ring)
+                    self.kv.release_state(state_slot)
                     raise
                 self.kv.fill_row(self._tables[slot], seq.pages,
-                                 seq.window_pages)
+                                 seq.window_pages, seq.state_slot)
                 # its prompt's pages past the ring are never written
                 self.kv.note_positions(0, len(req.prompt))
                 admitted.append(seq)
@@ -2013,6 +2045,8 @@ class GenerationServer:
         freed = self.kv.release(seq.pages)
         self.kv.release_window(seq.window_pages)
         seq.window_pages = []
+        self.kv.release_state(seq.state_slot)
+        seq.state_slot = 0
         self.metrics.observe_evictions(freed)
         self.metrics.count(event)
         self._note_kv_pages()

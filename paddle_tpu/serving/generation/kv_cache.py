@@ -31,6 +31,16 @@ engine feeds (the full table's columns, then the ring's), allocation
 and freeing. Ring pages are a sequence's own, never shared: the prefix
 cache cannot index a layer that forgets.
 
+A third kind, ``state``: a layer that keeps a recurrent state a
+sequence (a state-space mixer's convolution tail and SSM state;
+``bytes_per_slot`` however long the sequence grows) holds one *slot* of
+its pools a sequence. Those pools too are sized from the lanes, ``1 +
+max_batch`` slots with slot 0 the trash slot, and the slot is the last
+column of the table row: a prefill row is not a lane, so a program
+finds a row's slot in its table row. A slot is a sequence's own, handed
+out at admission and taken back with its pages; what it held is not
+cleared (a prefill writes the slot from zeros, it never reads it).
+
 Thread-safety: the engine's worker thread is the only mutator; the
 allocator itself is plain data guarded by the engine lock.
 """
@@ -39,7 +49,7 @@ from __future__ import annotations
 import math
 from typing import Dict, List, Optional
 
-__all__ = ["PagedKVCache", "window_of"]
+__all__ = ["PagedKVCache", "window_of", "has_state"]
 
 
 def window_of(spec: dict) -> Optional[int]:
@@ -48,6 +58,12 @@ def window_of(spec: dict) -> Optional[int]:
     context (or the model declares no kinds)."""
     kind = (spec.get("kinds") or {}).get("window")
     return int(kind["window"]) if kind else None
+
+
+def has_state(spec: dict) -> bool:
+    """Whether a model's ``kv_cache_spec()`` names layers of the
+    ``state`` kind."""
+    return "state" in (spec.get("kinds") or {})
 
 
 class PagedKVCache:
@@ -70,27 +86,48 @@ class PagedKVCache:
         # absmax scales alongside int8 values (ops/paged_attention)
         self.kv_dtype = dtype if isinstance(dtype, str) else ""
         # the window kind: a ring a lane, a pool sized from the lanes
-        self.window = window_of(model.kv_cache_spec())
+        spec = model.kv_cache_spec()
+        self.window = window_of(spec)
         self.ring_pages = 0
         self.window_num_pages = 0
-        if self.window is None:
-            self.k, self.v = model.init_kv_pools(self.num_pages,
-                                                 self.page_size, dtype)
-        else:
-            if not max_batch:
-                raise ValueError(
-                    "a model with window layers sizes their pool from "
-                    "the lanes: PagedKVCache needs max_batch")
+        # the state kind: a slot a lane, pools sized from the lanes
+        self.state_num_slots = 0
+        by_lanes = {}       # what init_kv_pools is told beyond the pages
+        state = has_state(spec)
+        if (self.window is not None or state) and not max_batch:
+            raise ValueError(
+                "a model with window or state layers sizes their pools "
+                "from the lanes: PagedKVCache needs max_batch")
+        if self.window is not None:
             from ...ops.paged_attention import ring_pages
             self.ring_pages = ring_pages(self.window, self.page_size)
             self.window_num_pages = 1 + int(max_batch) * self.ring_pages
-            self.k, self.v = model.init_kv_pools(
-                self.num_pages, self.page_size, dtype,
-                window_pages=self.window_num_pages)
+            by_lanes["window_pages"] = self.window_num_pages
+        if state:
+            self.state_num_slots = 1 + int(max_batch)
+            by_lanes["state_slots"] = self.state_num_slots
+        self.k, self.v = model.init_kv_pools(self.num_pages,
+                                             self.page_size, dtype,
+                                             **by_lanes)
         self._window_free: List[int] = list(
             range(self.window_num_pages - 1, 0, -1))
         self._window_held = set()
         self.window_pages_recycled = 0
+        self._state_free: List[int] = list(
+            range(self.state_num_slots - 1, 0, -1))
+        self._state_held = set()
+        self._pool_bytes_by_kind = self._bytes_by_kind(spec)
+        if state:
+            # the arrays, not the spec's word: a state pool in another
+            # precision than kv_cache_spec() states is a slot of other
+            # bytes, and is refused here
+            want = int(spec["kinds"]["state"]["bytes_per_slot"])
+            got = self._pool_bytes_by_kind["state"] / self.state_num_slots
+            if got != want:
+                raise ValueError(
+                    f"the state pools hold {got:g} bytes a slot and "
+                    f"kv_cache_spec() states {want}: the pools' shapes "
+                    f"or types are not the spec's")
         # serving mesh (serving/mesh.py): heads-sharded committed
         # placement of the pool leaves. EVERYTHING host-side below —
         # free list, refcounts, block tables — is layout-agnostic and
@@ -115,9 +152,10 @@ class PagedKVCache:
 
     @property
     def used_pages(self) -> int:
-        """Pages some sequence or the prefix index holds, of either
-        kind: 0 is what a drained server shows."""
-        return self.capacity - len(self._free) + len(self._window_held)
+        """Pages and state slots some sequence or the prefix index
+        holds, of every kind: 0 is what a drained server shows."""
+        return self.capacity - len(self._free) + len(self._window_held) \
+            + len(self._state_held)
 
     def pages_for(self, tokens: int) -> int:
         """Pages needed to hold ``tokens`` positions."""
@@ -130,8 +168,10 @@ class PagedKVCache:
 
     def table_width(self, max_seq_len: int) -> int:
         """Columns of the one block-table row a sequence has: the full
-        layers' table, then the window layers' ring."""
-        return self.pages_for(max_seq_len) + self.ring_pages
+        layers' table, then the window layers' ring, then the state
+        slot."""
+        return self.pages_for(max_seq_len) + self.ring_pages \
+            + self.state_columns
 
     def alloc_window(self, tokens: int) -> Optional[List[int]]:
         """The ring pages of a sequence that will hold ``tokens``
@@ -157,14 +197,49 @@ class PagedKVCache:
             self._window_held.remove(p)
             self._window_free.append(p)
 
-    def fill_row(self, row, pages: List[int],
-                 window_pages: List[int]) -> None:
-        """Write a sequence's pages into its block-table row."""
+    # ---- the state kind
+    @property
+    def state_capacity(self) -> int:
+        return max(0, self.state_num_slots - 1)
+
+    @property
+    def state_columns(self) -> int:
+        """Columns the state slot takes of a table row: 1, or 0 for a
+        model without state layers."""
+        return 1 if self.state_num_slots else 0
+
+    def alloc_state(self) -> Optional[int]:
+        """The slot of a sequence's recurrent state. 0 (no slot) for a
+        model without state layers; None if none is free, which
+        ``max_batch`` lanes cannot make it."""
+        if not self.state_num_slots:
+            return 0
+        if not self._state_free:
+            return None
+        slot = self._state_free.pop()
+        self._state_held.add(slot)
+        return slot
+
+    def release_state(self, slot: int) -> None:
+        if not slot:
+            return
+        if slot not in self._state_held:
+            raise RuntimeError(f"double free: state slot {slot} is not "
+                               f"held")
+        self._state_held.remove(slot)
+        self._state_free.append(slot)
+
+    def fill_row(self, row, pages: List[int], window_pages: List[int],
+                 state_slot: int = 0) -> None:
+        """Write a sequence's pages and state slot into its
+        block-table row."""
         row[:] = 0
         row[:len(pages)] = pages
         if window_pages:
-            at = len(row) - self.ring_pages
+            at = len(row) - self.ring_pages - self.state_columns
             row[at:at + len(window_pages)] = window_pages
+        if self.state_columns:
+            row[-1] = state_slot
 
     def note_positions(self, lo: int, hi: int) -> None:
         """A sequence wrote (or skipped past) positions ``lo .. hi-1``
@@ -177,16 +252,37 @@ class PagedKVCache:
         self.window_pages_recycled += max(0, last - first + 1)
 
     def by_kind(self) -> dict:
-        """``metrics_snapshot()["engine"]["kv"]``: pages in use and
-        capacity of each kind's pool, and the ring entries that came
-        to hold a later page than their first."""
+        """``metrics_snapshot()["engine"]["kv"]``: pages (of the
+        ``state`` kind: slots) in use and capacity of each kind's
+        pool, the pools' device bytes by kind, and the ring entries
+        that came to hold a later page than their first."""
         kinds = {"full": (self.capacity - len(self._free), self.capacity)}
         if self.window is not None:
             kinds["window"] = (len(self._window_held),
                                self.window_capacity)
+        if self.state_num_slots:
+            kinds["state"] = (len(self._state_held), self.state_capacity)
         return {"pages_in_use": {k: v[0] for k, v in kinds.items()},
                 "capacity": {k: v[1] for k, v in kinds.items()},
+                "pool_bytes": self._pool_bytes_by_kind,
                 "window_pages_recycled": self.window_pages_recycled}
+
+    def _bytes_by_kind(self, spec: dict) -> dict:
+        """Device bytes of each kind's pools (the layers
+        ``kv_cache_spec()["kinds"]`` lists under it; one stacked pool
+        is all ``full``)."""
+        import jax
+
+        def nbytes(tree):
+            return sum(int(a.size) * int(a.dtype.itemsize)
+                       for a in jax.tree_util.tree_leaves(tree))
+
+        kinds = spec.get("kinds")
+        if not kinds or not isinstance(self.k, list):
+            return {"full": nbytes((self.k, self.v))}
+        return {kind: sum(nbytes((self.k[i], self.v[i]))
+                          for i in what["layers"])
+                for kind, what in kinds.items()}
 
     def pool_bytes(self) -> int:
         """Device bytes resident in the K+V pools (quantized pools
@@ -265,16 +361,20 @@ class PagedKVCache:
         window_leaked = self.window_capacity - len(self._window_free) \
             - len(self._window_held)
         overlap += sorted(set(self._window_free) & self._window_held)
+        state_leaked = self.state_capacity - len(self._state_free) \
+            - len(self._state_held)
+        overlap += sorted(set(self._state_free) & self._state_held)
         return {
             "capacity": self.capacity,
             "free": len(self._free),
             "referenced": len(self._ref),
             "leaked": self.capacity - len(self._free) - len(self._ref)
-            + window_leaked,
+            + window_leaked + state_leaked,
             "double_booked": overlap,
             "nonpositive_refcounts": bad_refs,
             "ok": (len(self._free) + len(self._ref) == self.capacity
-                   and not window_leaked and not overlap
+                   and not window_leaked and not state_leaked
+                   and not overlap
                    and not bad_refs),
         }
 

@@ -130,6 +130,9 @@ class CachedDecoder:
         self.max_positions = int(
             max_positions if max_positions is not None
             else model.kv_cache_spec()["max_seq_len"])
+        # a layer that keeps a recurrent state a sequence (kv_cache.py,
+        # the 'state' kind) cannot continue a window from its slot
+        self.has_state = "state" in (spec.get("kinds") or {})
         self._params, self._buffers = state_arrays(model)
         if smesh.live:
             # committed mp-sharded placement: GSPMD partitions every
@@ -595,6 +598,14 @@ class CachedDecoder:
             else np.ascontiguousarray(a, np.float32)
             for a in (temperature, uniform))
 
+    def _refuse_window_for_state(self, what: str):
+        if self.has_state:
+            raise NotImplementedError(
+                f"{what} with {type(self.model).__name__}: its layers of "
+                f"the 'state' kind keep the state after a sequence's "
+                f"last token alone, so a window cannot start from a "
+                f"cached prefix nor be rolled back")
+
     def prefill(self, ids: np.ndarray, prompt_lens: np.ndarray,
                 tables: np.ndarray, temperature, uniform, k, v):
         """ids [B, S] int64 (left-aligned, zero-padded); prompt_lens
@@ -622,6 +633,7 @@ class CachedDecoder:
         first, then the row's private pages); temperature and uniform
         as ``prefill`` takes them. Returns ``(tokens [B] int32,
         last_logits [B, vocab], k', v', new_signature)``."""
+        self._refuse_window_for_state("prefill_chunked")
         args = (self._params, self._buffers,
                 np.ascontiguousarray(ids, np.int64),
                 np.ascontiguousarray(start, np.int32),
@@ -641,6 +653,7 @@ class CachedDecoder:
         every proposal in one device step. Rejected positions' K/V
         writes land on the lane's already-reserved pages and are rolled
         back by context-length truncation, never by pool mutation."""
+        self._refuse_window_for_state("verify")
         args = (self._params, self._buffers,
                 np.ascontiguousarray(tokens, np.int64),
                 np.ascontiguousarray(start, np.int32),

@@ -143,3 +143,127 @@ def test_the_shared_configuration_states_its_share():
     assert (serve["max_batch"], serve["page_size"]) == (128, 16)
     assert not serve["prefix_cache"]
     assert serve["num_pages"] == 1 + 128 * -(-serve["max_seq_len"] // 16)
+
+
+def test_the_hybrid_configuration_states_its_share():
+    """``nemotron-3-super-120b-a12b.json``: ``reduced`` is the depth,
+    the experts held and the vocabulary (each under the source's name
+    and the program's), with the published numbers and the 4-chip
+    deployment beside them; no width differs from the catalog row's;
+    the layers run are the first 11 letters of the whole pattern."""
+    config = common.load_json(os.path.join(
+        common.ROOT, "benchmarks", "configs",
+        "nemotron-3-super-120b-a12b.json"))
+    assert config["source"] == (
+        "https://huggingface.co/nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-"
+        "BF16/blob/main/config.json")
+    assert config["reduced"] == ["num_layers", "num_hidden_layers",
+                                 "moe_num_experts", "n_routed_experts",
+                                 "vocab_size"]
+    cut = config["reduced_from"]
+    assert cut["chips_sharing_a_layer"] == 4
+    assert "expert-parallel" in cut["deployment"]
+    for key, published, run_here in (("num_layers", 88, 11),
+                                     ("moe_num_experts", 512, 128),
+                                     ("vocab_size", 131072, 32768)):
+        assert (cut[key]["published"], cut[key]["run"]) == \
+            (published, run_here) and cut[key]["why"]
+    assert "num_hidden_layers" in cut["num_layers"] \
+        and "n_routed_experts" in cut["moe_num_experts"]
+    assert sorted(config["assumed"]) == [
+        "attention_has_no_positions", "gated_norm_per_group",
+        "latent_placement", "state_precision", "ungated_relu2"]
+    assert "multi-token-prediction" in config["not_built"]
+    assert config["serve"]["weights_dtype"] == "bfloat16" \
+        and config["model"]["kwargs"] == {
+            "num_layers": 11, "moe_num_experts": 128, "vocab_size": 32768,
+            "dtype": "bfloat16"}
+    # every width and count the source publishes, under its own name
+    published = {
+        "hidden_size": 4096, "mamba_num_heads": 128, "mamba_head_dim": 64,
+        "expand": 2, "ssm_state_size": 128, "n_groups": 8,
+        "conv_kernel": 4, "chunk_size": 128, "num_attention_heads": 32,
+        "num_key_value_heads": 2, "head_dim": 128,
+        "num_experts_per_tok": 22, "routed_scaling_factor": 5,
+        "norm_topk_prob": True, "n_group": 1, "topk_group": 1,
+        "moe_latent_size": 1024, "moe_intermediate_size": 2688,
+        "moe_shared_expert_intermediate_size": 5376, "n_shared_experts": 1,
+        "mlp_hidden_act": "relu2", "layer_norm_epsilon": 1e-5,
+        "use_conv_bias": True, "tie_word_embeddings": False,
+        "max_position_embeddings": 262144, "router_experts": 512,
+        "num_nextn_predict_layers": 1, "mtp_hybrid_override_pattern": "*E"}
+    for key, want in published.items():
+        assert config[key] == want, key
+    pattern = config["hybrid_override_pattern"]
+    assert len(pattern) == 88
+    assert [pattern.count(c) for c in "M*E"] == [40, 8, 40]
+    assert [pattern[:11].count(c) for c in "M*E"] == [5, 1, 5]
+    sizes = config["sizes"]
+    for ours, theirs in (("hidden_size", "hidden_size"),
+                         ("pattern", "hybrid_override_pattern"),
+                         ("num_heads", "num_attention_heads"),
+                         ("num_kv_heads", "num_key_value_heads"),
+                         ("head_dim", "head_dim"),
+                         ("mamba_num_heads", "mamba_num_heads"),
+                         ("mamba_head_dim", "mamba_head_dim"),
+                         ("ssm_state_size", "ssm_state_size"),
+                         ("mamba_n_groups", "n_groups"),
+                         ("conv_kernel", "conv_kernel"),
+                         ("chunk_size", "chunk_size"),
+                         ("moe_num_experts", "n_routed_experts"),
+                         ("moe_router_experts", "router_experts"),
+                         ("moe_top_k", "num_experts_per_tok"),
+                         ("moe_routed_scale", "routed_scaling_factor"),
+                         ("moe_latent_size", "moe_latent_size"),
+                         ("moe_intermediate_size", "moe_intermediate_size"),
+                         ("moe_shared_intermediate_size",
+                          "moe_shared_expert_intermediate_size"),
+                         ("layer_norm_eps", "layer_norm_epsilon"),
+                         ("vocab_size", "vocab_size"),
+                         ("max_seq_len", "max_position_embeddings")):
+        assert sizes[ours] == config[theirs], ours
+    assert sizes["mamba_num_heads"] * sizes["mamba_head_dim"] == \
+        config["expand"] * sizes["hidden_size"]
+    assert config["num_hidden_layers"] == sizes["num_layers"] == 11
+    assert sizes["ssm_state_dtype"] == "float32"
+    # the layers run are the published layers 0-10 under their kinds
+    preset = common.resolve(config["model"]["preset"], "model.preset")
+    cfg = preset(**config["model"]["kwargs"])
+    assert cfg.kinds == pattern[:11] == "MEMEMEM*EME"
+    # the whole preset is the published model: its size is its name
+    assert abs(preset().num_params() / 1e9 - 120.67) < 0.005
+    serve = config["serve"]
+    assert serve["max_batch"] in (128, 96, 64) and serve["page_size"] == 16
+    assert not serve["prefix_cache"]
+    assert serve["num_pages"] == \
+        1 + serve["max_batch"] * -(-serve["max_seq_len"] // 16)
+    assert serve["seq_buckets"] == [512, 1024, 2048]
+    assert serve["parity_tol_why"] and serve["num_pages_why"]
+    entry = next(c for c in MANIFEST["configs"]
+                 if c["name"] == "nemotron-3-super-120b-a12b")
+    assert entry["source"] == config["source"]
+    assert entry["reduced"] == config["reduced"]
+    cell = next(w for w in MANIFEST["workloads"]
+                if w["name"] == "serve-nemotron3-reasoning")
+    assert (cell["config"], cell["traffic"], cell["chips"]) == \
+        ("nemotron-3-super-120b-a12b", "backlog-reasoning", 1)
+
+
+def test_no_key_of_the_catalog_row_differs_but_the_reduced():
+    """Where the catalog beside the ``model-configs`` guide can be read:
+    the file holds every number of the row's ``config`` under the same
+    key, but for the three that ``reduced`` names."""
+    import json
+    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if not os.path.exists(catalog):
+        pytest.skip("no catalog here")
+    config = common.load_json(os.path.join(
+        common.ROOT, "benchmarks", "configs",
+        "nemotron-3-super-120b-a12b.json"))
+    with open(catalog) as f:
+        row = next(r for r in map(json.loads, f)
+                   if r["source_url"] == config["source"])
+    differs = {k for k, v in row["config"].items() if config.get(k) != v}
+    assert differs == {"num_hidden_layers", "n_routed_experts",
+                       "vocab_size"}
+    assert differs <= set(config["reduced"])

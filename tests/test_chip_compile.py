@@ -637,3 +637,169 @@ def test_a_share_of_the_experts_compiles_to_grouped_matmuls(
         assert f"bf16[{tokens * 8},{hidden}]" not in text
         # what it keeps beside its result is a block's, not the call's
         assert compiled.memory_analysis().temp_size_in_bytes < 2 * 1024 ** 3
+
+
+# -- one mixer a layer: groups of 16, a state pool, latent experts (PR 35) --
+# the hybrid configuration's serving geometry: 32 query heads over 2 K/V
+# heads of 128 (a folded pool 256 lanes wide), bf16, 128 lanes, the one
+# attention layer's 257-page tables over 32,897 pages; five state-space
+# layers of 128 heads of 64 with a state of 128 over 129 slots; 128 held
+# of 512 ungated relu^2 experts of 1024 x 2688, 22 a token
+HY_HEADS, HY_KV_HEADS, HY_LANES, HY_WIDTH, HY_PAGES = 32, 2, 128, 257, 32897
+HY_CUT = dict(num_layers=11, moe_num_experts=128, vocab_size=32768,
+              dtype="bfloat16")
+CHIP_BYTES = int(15.75 * 1024 ** 3)
+
+
+def test_decode_compiles_for_groups_of_sixteen_over_a_256_lane_pool(
+        one_chip, compiled_kernels):
+    """``paged_attention_update`` as the hybrid configuration's decode
+    step calls it: the page-copying kernel with 16 query heads a K/V
+    head, 128 lanes, two K/V heads folded into 256 lanes; the pool
+    reaches the kernel as it lies."""
+    from paddle_tpu.ops.paged_attention import paged_attention_update
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    shape = kv_pool_shape(HY_PAGES, PAGE, HY_KV_HEADS, D)
+    assert shape[-1] == 256
+    pool = sds(shape, jnp.bfloat16)
+    kv = sds((HY_LANES, 1, HY_KV_HEADS, D), jnp.bfloat16)
+    text = jax.jit(
+        functools.partial(paged_attention_update, page_size=PAGE,
+                          kind="decode", window=None),
+        donate_argnums=(3, 4)).lower(
+        sds((HY_LANES, 1, HY_HEADS, D), jnp.bfloat16), kv, kv, pool, pool,
+        sds((HY_LANES, HY_WIDTH), jnp.int32), sds((HY_LANES,), jnp.int32),
+        sds((HY_LANES, 1), jnp.bool_), sds((HY_LANES, 1), jnp.int32)
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert not [line for line in text.splitlines()
+                if f"bf16[{HY_PAGES},{PAGE},256]" in line
+                and " copy(" in line]
+
+
+@pytest.mark.parametrize("rows,seq", [(16, 2048), (1, 2048), (16, 1024),
+                                      (1, 512)])
+def test_prefill_of_groups_of_sixteen_takes_the_flash_kernel(
+        one_chip, compiled_kernels, rows, seq):
+    """The hybrid model asks for the kernel always
+    (``use_flash="always"``): 16 rows at 1,024 positions keep 3 GiB of
+    dense scores, under ``DENSE_SCORES_BYTES``, and the chip has no
+    room for them beside that prefill's other layers; a caller that
+    says ``True`` still gets the dense path below 2,048."""
+    from paddle_tpu.ops.flash_attention import attention_bshd
+
+    def sds(heads):
+        return jax.ShapeDtypeStruct((rows, seq, heads, D), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    args = (sds(HY_HEADS), sds(HY_KV_HEADS), sds(HY_KV_HEADS))
+    _compile(functools.partial(attention_bshd, causal=True, scale=D ** -0.5,
+                               window=None, use_flash="always"), *args)
+    if seq < 2048:
+        text = jax.jit(functools.partial(
+            attention_bshd, causal=True, scale=D ** -0.5, window=None,
+            use_flash=True)).lower(*args).compile().as_text()
+        assert "tpu_custom_call" not in text
+
+
+@pytest.mark.parametrize("tokens,block", [(128, 0), (16 * 2048, 2048)],
+                         ids=["decode", "prefill-in-blocks"])
+def test_ungated_latent_experts_compile_to_grouped_matmuls(
+        one_chip, tokens, block):
+    """``dropless_moe`` as the hybrid configuration's expert layer calls
+    it for a prefill (2,048 tokens at a time in a long one), and at the
+    rows of a decode step (whose 128 lanes the model itself computes
+    unsorted: the test below): the router reads 4,096 columns, the
+    experts a latent of 1,024; 128 held of 512, 22 a token, ungated:
+    two grouped matmuls over ``tokens * 22`` sorted rows."""
+    from paddle_tpu.ops.moe import dropless_moe
+    hidden, latent, routed, held, inter = 4096, 1024, 512, 128, 2688
+
+    def sds(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda x, r, wr, w1, w2, valid: dropless_moe(
+            x, r, wr, None, w1, w2, top_k=22, scoring="sigmoid_norm",
+            scale=5.0, activation="relu2", token_block=block, valid=valid)
+    ).lower(sds(tokens, latent), sds(tokens, hidden), sds(hidden, routed),
+            sds(held, latent, inter), sds(held, inter, latent),
+            sds(tokens, dtype=jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert text.count('op_name="ragged-dot-none"') == 2
+    assert f"bf16[{(block or tokens) * 22},{latent}]" in text
+    if block:
+        assert f"bf16[{tokens * 22},{latent}]" not in text
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 * 1024 ** 3
+
+
+@pytest.mark.parametrize("program", ["decode", (16, 2048), (16, 1024)],
+                         ids=["decode-128", "prefill-16x2048",
+                              "prefill-16x1024"])
+def test_the_hybrid_cells_programs_fit_the_chip_beside_their_pools(
+        one_chip, compiled_kernels, program):
+    """The decode program, the largest prefill program and the one the
+    compiler refused while its attention was dense ("Used 15.86G of
+    15.75G hbm") of the cell ``serve-nemotron3-reasoning`` (11 layers,
+    128 held experts, a quarter of the vocabulary, bfloat16; abstract
+    weights), compiled
+    for the chip over donated pools of the cell's sizes: arguments and
+    temporaries together stay under the chip's 15.75 GiB, the decode
+    step keeps nothing of a state pool's size beside the pools (the
+    states are advanced where they lie), and both hold the attention
+    layer's kernel."""
+    import paddle_tpu as paddle
+    from paddle_tpu import models
+    from paddle_tpu.jit.functional import state_arrays
+    from paddle_tpu.serving.generation.model_fns import CachedDecoder
+    with paddle.LazyGuard():
+        model = models.NemotronHForCausalLM(
+            models.nemotron_3_super_120b_a12b(**HY_CUT))
+    model.eval()
+    width = HY_WIDTH + 1                    # the table, then the state slot
+    dec = CachedDecoder(model, max_batch=HY_LANES, page_size=PAGE,
+                        pages_per_seq=width, donate=True,
+                        max_positions=4112, kv_dtype="")
+    assert dec.use_pallas is True
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    def described(tree):
+        return jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), tree)
+
+    params, buffers = described(state_arrays(model))
+    k, v = described(jax.eval_shape(lambda: model.init_kv_pools(
+        HY_PAGES, PAGE, state_slots=1 + HY_LANES)))
+    assert tuple(v[0].shape) == (129, 128, 64, 128) \
+        and v[0].dtype == jnp.float32
+    if program == "decode":
+        rows = HY_LANES
+        lowered = dec._decode_jit.lower(
+            params, buffers, sds((rows,), jnp.int64), sds((rows,), jnp.int32),
+            sds((rows,), jnp.bool_), sds((rows,), jnp.int32),
+            sds((rows, width), jnp.int32), sds((rows,), jnp.float32),
+            sds((rows,), jnp.float32), k, v)
+    else:
+        rows, seq = program
+        lowered = dec._prefill_jit.lower(
+            params, buffers, sds((rows, seq), jnp.int64),
+            sds((rows,), jnp.int32), sds((rows, width), jnp.int32),
+            sds((rows,), jnp.float32), sds((rows,), jnp.float32), k, v)
+    compiled = lowered.compile()
+    plan = compiled.memory_analysis()
+    assert plan.argument_size_in_bytes + plan.temp_size_in_bytes \
+        < CHIP_BYTES
+    # the pools are written where they lie
+    assert plan.alias_size_in_bytes > 3 * 1024 ** 3
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ssm/" in text
+    # 128 lanes x 22 reach the router's 512 outputs: a decode step
+    # computes every held expert, a prefill sorts its rows by expert
+    assert ("ragged-dot" in text) == (program != "decode")
+    if program == "decode":
+        assert plan.temp_size_in_bytes < 512 * 1024 ** 2
+        assert "ssm/state_update" in text

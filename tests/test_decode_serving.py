@@ -97,6 +97,26 @@ class TestPagedKVCache:
             kv.free(pages)
 
 
+    def test_a_model_without_state_layers_has_no_slot(self):
+        """The state kind (PR 35) costs a model without such layers
+        nothing: no column of the table row, slot 0 from
+        ``alloc_state``, nothing counted."""
+        m, _ = make_model()
+        kv = PagedKVCache(m, num_pages=5, page_size=4, max_batch=2)
+        assert kv.state_columns == 0 and kv.state_capacity == 0
+        assert kv.table_width(16) == 4
+        assert kv.alloc_state() == 0
+        kv.release_state(0)
+        row = np.full(4, -1, np.int32)
+        kv.fill_row(row, [3, 1], [], kv.alloc_state())
+        assert row.tolist() == [3, 1, 0, 0]
+        assert kv.used_pages == 0
+        by_kind = kv.by_kind()
+        assert set(by_kind["capacity"]) == {"full"}
+        assert by_kind["pool_bytes"] == {"full": kv.pool_bytes()}
+        kv.assert_no_leaks()
+
+
 # ------------------------------------------------------ cache numerics
 class TestCacheEquivalence:
     @pytest.mark.parametrize("stacked", [False, True])

@@ -140,7 +140,8 @@ def test_only_read_counters_are_kept(lockstep_run):
     assert set(eng["prefill"]) == {"prompt_tokens", "padded_tokens",
                                    "split_groups", "by_shape",
                                    "call_s_by_shape"}
-    assert set(eng["kv"]) == {"pages_in_use", "capacity",
+    # "pool_bytes" (by kind, PR 35): state_mib_per_slot.hybrid reads it
+    assert set(eng["kv"]) == {"pages_in_use", "capacity", "pool_bytes",
                               "window_pages_recycled"}
     assert set(eng["kv"]["capacity"]) == {"full"}
     assert set(eng["queue_wait_ms"]) == {"le", "counts"}

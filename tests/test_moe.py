@@ -282,6 +282,66 @@ class TestDroplessMoe:
         np.testing.assert_allclose(np.asarray(both) - np.asarray(routed),
                                    shared, atol=2e-4)
 
+    def test_ungated_relu2_experts_in_a_latent_against_a_loop(self):
+        """``w_gate=None``, ``activation="relu2"``: ``relu(x W1)^2 W2``,
+        the experts reading a latent half as wide as what the router
+        reads; a share of 3 experts (2..4 of 8), some rows dead,
+        against an explicit loop over tokens and their chosen
+        experts."""
+        from paddle_tpu.ops.moe import dropless_moe
+        rng = np.random.default_rng(5)
+        t, wide, lat, inter, k, offset, scale = 24, 16, 8, 12, 3, 2, 5.0
+        r = rng.standard_normal((t, wide)).astype(np.float32)
+        x = rng.standard_normal((t, lat)).astype(np.float32)
+        wr = rng.standard_normal((wide, 8)).astype(np.float32)
+        w1 = rng.standard_normal((3, lat, inter)).astype(np.float32)
+        w2 = rng.standard_normal((3, inter, lat)).astype(np.float32)
+        valid = np.arange(t) % 4 != 0
+        got, stats = dropless_moe(
+            x, r, wr, None, w1, w2, top_k=k, scoring="sigmoid_norm",
+            scale=scale, activation="relu2", offset=offset, valid=valid)
+        s = 1 / (1 + np.exp(-(r @ wr)))
+        want = np.zeros_like(x)
+        local = 0
+        for tok in np.flatnonzero(valid):
+            top = np.argsort(-s[tok])[:k]
+            w = scale * s[tok, top] / s[tok, top].sum()
+            for weight, ex in zip(w, top):
+                if offset <= ex < offset + 3:
+                    local += 1
+                    h = np.square(np.maximum(x[tok] @ w1[ex - offset], 0))
+                    want[tok] += weight * (h @ w2[ex - offset])
+        assert tuple(got.shape) == (t, lat)
+        np.testing.assert_allclose(np.asarray(got), want, atol=5e-4,
+                                   rtol=1e-5)
+        assert not np.asarray(got)[~valid].any()
+        assert int(stats["assignments"]) == int(valid.sum()) * k
+        assert int(stats["local_assignments"]) == local
+        # in blocks of rows it is the same numbers and the same counts
+        many, s2 = dropless_moe(
+            x, r, wr, None, w1, w2, top_k=k, scoring="sigmoid_norm",
+            scale=scale, activation="relu2", offset=offset, valid=valid,
+            token_block=8)
+        np.testing.assert_allclose(np.asarray(many), np.asarray(got),
+                                   atol=1e-5, rtol=0)
+        assert {n: int(v) for n, v in stats.items()} == \
+            {n: int(v) for n, v in s2.items()}
+
+    def test_a_gate_makes_relu2_a_gated_expert(self):
+        """The activation is the gate's where there is a gate:
+        ``(relu(x Wg)^2 * (x Wu)) Wd``."""
+        from paddle_tpu.ops.moe import dropless_moe
+        x, r, wr, wg, wu, wd = (np.asarray(a) for a in _moe_arrays(6))
+        got, _ = dropless_moe(x, r, wr, wg, wu, wd, top_k=8,
+                              scoring="sigmoid_norm", activation="relu2")
+        s = 1 / (1 + np.exp(-(r @ wr)))
+        w = s / s.sum(1, keepdims=True)
+        want = sum(w[:, e:e + 1] * ((np.square(np.maximum(x @ wg[e], 0))
+                                     * (x @ wu[e])) @ wd[e])
+                   for e in range(8))
+        np.testing.assert_allclose(np.asarray(got), want, atol=2e-3,
+                                   rtol=1e-4)
+
     def test_unknown_options_raise(self):
         from paddle_tpu.ops.moe import dropless_moe
         args = _moe_arrays(4)
