@@ -21,10 +21,11 @@ Pieces:
   new_signature)``; the speculative verify step returns every window
   position's logits and no tokens), KV pools donated where the backend
   supports it, persistent-compile-cache AOT tier first.
-- ``ProgramRunner`` (runner.py): a decoder and its pools; a run fetches
-  the chosen tokens (``[rows]`` int32) and the expert counters in one
-  transfer and leaves the logits on the device unless the caller asks
-  for them.
+- ``ProgramRunner`` (runner.py): a decoder and its pools; ``enqueue``
+  calls a program and reads nothing, ``harvest`` fetches the chosen
+  tokens (``[rows]`` int32) and the expert counters in one transfer
+  and leaves the logits on the device unless the caller asks for them;
+  the engine's loop enqueues decode step i+1 before it harvests step i.
 - ``PagedKVCache`` (kv_cache.py): preallocated per-layer
   ``[num_pages, page_size, heads * head_dim]`` pools + the host page
   allocator (page 0 reserved as the trash page for masked writes),

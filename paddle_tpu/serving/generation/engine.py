@@ -28,7 +28,13 @@ Flow per worker iteration:
    chooses each lane's next token (greedy, or sampled at the uniform
    this loop drew from the request's own RNG) and the step fetches
    ``[max_batch]`` token ids, never the logits; they stream out through
-   each request's ``StreamingFuture``. With a draft model configured, each
+   each request's ``StreamingFuture``. The loop runs ONE STEP AHEAD:
+   each lane's last token stays on the device, so step i+1 is enqueued
+   (from what the host knows without step i's tokens: positions,
+   tables, temperatures, uniforms) before step i is harvested, and the
+   dispatch, the fetch, the emission, the finish checks and the next
+   admission all run beside a program instead of between two
+   (``_decode_iteration``). With a draft model configured, each
    iteration is instead draft-propose-k + ONE fixed-shape
    ``[max_batch, k+1]`` verify step with accept-and-resample
    (speculative decoding; output distribution unchanged).
@@ -44,6 +50,7 @@ worker.
 """
 from __future__ import annotations
 
+import concurrent.futures
 import threading
 import time
 import weakref
@@ -62,7 +69,7 @@ from ..request import (DeadlineExceededError, QueueFullError,
 from .kv_cache import PagedKVCache
 from .model_fns import CachedDecoder, supports_cached_decode
 from .prefix_cache import PrefixCache
-from .runner import SITES, ProgramRunner
+from .runner import SITES, Enqueued, ProgramRunner
 from .spec_decode import accept_tokens, softmax
 
 __all__ = ["GenerationServer", "StreamingFuture", "DecodeMetrics",
@@ -296,6 +303,12 @@ class _ActiveSeq:
         self.published = False              # prompt pages in the index
 
 
+class _Step(NamedTuple):
+    """A decode step enqueued and not yet harvested."""
+    enqueued: Enqueued          # its outputs, on the device
+    seqs: List[_ActiveSeq]      # whose lanes it computes, when enqueued
+
+
 class _Ran(NamedTuple):
     """What ``GenerationServer._dispatch`` hands back."""
     # the first runner's choice a row, fetched (None from a verify
@@ -469,6 +482,10 @@ class DecodeMetrics:
         # its runner brought to the host
         self._select = {"in_program": 0, "on_host": 0}
         self._fetch_bytes = 0
+        # how the loop ran ahead: decode programs enqueued while their
+        # predecessor was unharvested / on an empty pipe, and lane-steps
+        # computed for a lane that had ended by the time of the harvest
+        self._run_ahead = {"ahead": 0, "drained": 0, "late_lanes": 0}
 
     def switch_phase(self, phase: Optional[str], now: float):
         """The loop thread leaves its open phase at ``now`` (a
@@ -524,6 +541,11 @@ class DecodeMetrics:
             self._select["on_host" if host_logits else "in_program"] += 1
             self._fetch_bytes += int(nbytes)
 
+    def observe_run_ahead(self, key: str, n: int = 1):
+        """``n`` more of ``engine.run_ahead[key]``."""
+        with self._lock:
+            self._run_ahead[key] += int(n)
+
     def set_kv_by_kind(self, by_kind: dict):
         with self._lock:
             self._kv_by_kind = by_kind
@@ -555,6 +577,7 @@ class DecodeMetrics:
                "kv": self._kv_by_kind,
                "select": dict(self._select),
                "fetch_bytes": self._fetch_bytes,
+               "run_ahead": dict(self._run_ahead),
                "stream_stall_ms": self._cumulative(self._h_stall),
                "queue_wait_ms": self._cumulative(self._h_qwait)}
         if self._moe is not None:
@@ -855,6 +878,13 @@ class GenerationServer:
         self._worker: Optional[threading.Thread] = None
         self._steps = 0
         self._span: Optional[RecordEvent] = None   # the open phase's
+        # the decode step enqueued and not yet harvested: the loop
+        # thread's alone, like _span and _steps (the loop ends with
+        # nothing in flight: it drains before it aborts)
+        self._inflight: Optional[_Step] = None
+        self._loop_thread: Optional[threading.Thread] = None
+        # what other threads asked the loop to do between two steps
+        self._posted: List[tuple] = []
         # readiness gate (mirrors InferenceServer): not-ready until a
         # warmup pass completes, so a fleet router skips cold engines
         self._ready_gate = bool(
@@ -935,16 +965,24 @@ class GenerationServer:
         weights while in-flight sequences keep streaming. Cached
         prefix pages hold K/V computed with the OLD weights, so the
         index is cleared — serving them to new-weight requests would
-        be silent staleness."""
-        self.decoder.refresh_params()
-        if self.draft is not None:
-            self.draft.refresh_params()
-        self.clear_prefix_cache()
+        be silent staleness. The swap happens between two steps, with
+        no program in flight (``_between_steps``): every program runs
+        on one set of weights."""
+        def swap():
+            self.decoder.refresh_params()
+            if self.draft is not None:
+                self.draft.refresh_params()
+            self._clear_prefix()
+        self._between_steps(swap)
 
     def clear_prefix_cache(self) -> int:
         """Drop every unpinned cached prefix page back to the free
         list (pages shared with in-flight sequences stay until those
-        finish). Returns the number of pages freed."""
+        finish), between two steps. Returns the number of pages
+        freed."""
+        return self._between_steps(self._clear_prefix)
+
+    def _clear_prefix(self) -> int:
         if self.prefix is None:
             return 0
         with self._lock:
@@ -953,6 +991,48 @@ class GenerationServer:
                 self.metrics.set_kv_pages(self.kv.used_pages,
                                           self.kv.free_pages)
             return n
+
+    def _between_steps(self, fn: Callable[[], object]):
+        """``fn()`` with no program in flight, and its result: on the
+        loop thread at the top of its next iteration, once it has
+        harvested what it had enqueued (the caller waits); inline where
+        no loop runs, or on the loop thread itself."""
+        post = None
+        with self._lock:
+            on_loop = threading.current_thread() is self._loop_thread
+            if self._loop_running and not on_loop:
+                post = (fn, concurrent.futures.Future())
+                self._posted.append(post)
+                self._lock.notify_all()
+        if on_loop:
+            self._drain("admit")
+        if post is None:
+            return fn()
+        while not concurrent.futures.wait(post[1:], 0.05).done:
+            with self._lock:
+                # the loop ended before it came round: run it here
+                if not self._loop_running and post in self._posted:
+                    self._posted.remove(post)
+                    return fn()
+        return post[1].result()
+
+    def _run_posted(self):
+        """The loop thread, at the top of an iteration: what other
+        threads posted, after a drain."""
+        if not self._posted:
+            return
+        self._drain("admit")
+        while True:
+            with self._lock:
+                # taken one by one: what a dying loop leaves here, its
+                # poster runs itself
+                if not self._posted:
+                    return
+                fn, result = self._posted.pop(0)
+            try:
+                result.set_result(fn())
+            except BaseException as e:  # noqa: BLE001 - the poster's
+                result.set_exception(e)
 
     @property
     def queue_depth(self) -> int:
@@ -1175,12 +1255,14 @@ class GenerationServer:
         of that kind takes, unrecorded: the site stays tagged in the
         manifest by traffic alone, so a restarted engine replays what
         was observed. Returns the number of fresh signatures. A verify
-        step warms the target alone: a draft never verifies."""
+        step warms the target alone: a draft never verifies. A decode
+        step is run with its tokens on the host and on the device, the
+        two forms of its one signature."""
         def vec(dtype):
             return np.zeros(rows, dtype)
         tables = np.zeros((rows, self.pages_per_seq), np.int32)
         if kind == "decode":
-            feeds = (vec(np.int64), vec(np.int32), vec(bool),
+            feeds = (vec(np.int32), vec(np.int32), vec(bool),
                      vec(np.int32), tables)
         else:
             feeds = (np.zeros((rows, seq), np.int64), vec(np.int32))
@@ -1190,8 +1272,18 @@ class GenerationServer:
         if kind != "verify":
             feeds += (vec(np.float32), vec(np.float32))     # all greedy
         runners = self._runners[:1] if kind == "verify" else self._runners
-        return sum(self._dispatch(kind, feeds, (), runners, record=False,
-                                  host_logits=kind == "verify").fresh)
+        fresh = sum(self._dispatch(kind, feeds, (), runners, record=False,
+                                   host_logits=kind == "verify").fresh)
+        if kind == "decode" and self.draft is None:
+            # the other forms of the tokens that traffic's steps take
+            # (``_decode_iteration``): as a step left them on the
+            # device, and with the host's written over them there
+            target = self._runners[0]
+            step = target.enqueue(kind, feeds)
+            tokens = self.decoder.lane_tokens(
+                step.tokens, np.full(rows, -1, np.int32))
+            fresh += target.run(kind, (tokens,) + feeds[1:]).fresh
+        return fresh
 
     def warmup_from_manifest(self, path: Optional[str] = None) -> int:
         """Replay the persisted decode/prefill signatures a previous
@@ -1285,14 +1377,27 @@ class GenerationServer:
             self.metrics.observe_stream_stall((now - stall_t0) * 1e3)
 
     def _loop(self):
+        """The worker: admit and prefill, re-form the batch, one decode
+        iteration; again. With plain decoding one decode step is in
+        flight across iterations (``self._inflight``): iteration j
+        enqueues step j+1, then harvests and emits step j, so the
+        admission and the prefills of iteration j+1, too, run while
+        step j+1 does (a prefill's program queues behind it on the
+        device, and its pages are written after it). Whatever reads or
+        rewrites a lane from the host drains first (``_drain``): a
+        park, an abort, what ``_between_steps`` posts. A server with a
+        draft harvests each program before it forms the next (the host
+        judges the proposals): nothing is ever in flight there."""
         with self._lock:
             self._loop_running = True
+            self._loop_thread = threading.current_thread()
         try:
             while True:
                 # unlocked: nothing but this thread writes _slots
                 now = self._enter_phase("admit")
                 stall_t0 = now if any(
                     s is not None for s in self._slots) else None
+                self._run_posted()
                 self._admit_and_prefill()
                 self._enter_phase("bookkeeping")
                 with self._lock:
@@ -1301,7 +1406,7 @@ class GenerationServer:
                     if self._abort:
                         self._do_abort()
                         return
-                    if not active:
+                    if not active and self._inflight is None:
                         if self._closed and not self._queue:
                             return
                         self._enter_phase("wait")
@@ -1315,6 +1420,7 @@ class GenerationServer:
             self._enter_phase(None)
             with self._lock:
                 self._loop_running = False
+                self._loop_thread = None
 
     def _evict_expired_streams(self):
         """Deadline check at batch re-form (lock held): an in-flight
@@ -1351,6 +1457,10 @@ class GenerationServer:
                 len(s.pages) for s in self._slots
                 if s is not None and s.req.prio_rank > rank):
             return False        # not even parking everyone would fit
+        # a parked stream resumes from its history: the token of the
+        # step in flight has to be in it (and the harvest may end a
+        # stream, so the victims are chosen after it)
+        self._drain("admit")
         victims = [s for s in self._slots
                    if s is not None and s.req.prio_rank > rank]
         victims.sort(key=lambda s: (-s.req.prio_rank,
@@ -1407,7 +1517,8 @@ class GenerationServer:
 
     def _do_abort(self):
         """drain=False shutdown: fail everything still live (lock
-        held)."""
+        held), after the harvest of what is in flight."""
+        self._drain("bookkeeping")
         err = ServerClosedError("engine shut down before completion")
         for req in self._queue:
             req.future._fail(err, reason="shutdown")
@@ -1610,26 +1721,12 @@ class GenerationServer:
             for runner in runners:
                 run = runner.run(kind, feeds, host_logits)
                 ran.append(run)
-                if not record:
-                    continue
-                self.metrics.observe_fetch(run.fetched_bytes, host_logits)
-                if runner is target and run.aux:
-                    self.metrics.observe_moe(run.aux)
-                    if kind == "decode":
-                        # what this step's expert layers read, on its
-                        # own span
-                        self._span.set_arg(
-                            "experts_touched",
-                            int(run.aux["moe_experts_touched"]))
+                if record:
+                    self._observe_run(kind, runner, run, host_logits)
         except Exception as e:  # noqa: BLE001 - the fault barrier
             if not record:
                 raise
-            with self._lock:
-                for seq in seqs:
-                    seq.req.future._fail(e)
-                    self._release(seq, "failed")
-            self._trace_finish(seqs, "error",
-                               error=f"{type(e).__name__}: {e}")
+            self._fail(seqs, e)
             return None
         ms = (time.perf_counter() - t0) * 1e3
         if stage is not None:
@@ -1642,6 +1739,32 @@ class GenerationServer:
                                 record=record and runner is target)
         return _Ran(ran[0].tokens, ran[0].logits, ms, t_wall,
                     [run.fresh for run in ran])
+
+    def _observe_run(self, kind: str, runner: ProgramRunner, run,
+                     host_logits: bool = False):
+        """What one harvested program of traffic leaves in the
+        counters: its fetch, and the target's expert counts."""
+        self.metrics.observe_fetch(run.fetched_bytes, host_logits)
+        if runner is self._runners[0] and run.aux:
+            self.metrics.observe_moe(run.aux)
+            if kind == "decode":
+                # what the harvested step's expert layers read, on the
+                # open engine::decode_call span: the span of the step
+                # enqueued after it, so one step late
+                self._span.set_arg("experts_touched",
+                                   int(run.aux["moe_experts_touched"]))
+
+    def _fail(self, seqs: Sequence[_ActiveSeq], e: Exception):
+        """The fault barrier's other side: the futures of ``seqs``
+        fail with ``e``, their pages and lanes return (a sequence that
+        had ended already is left as it ended)."""
+        with self._lock:
+            seqs = list({id(s): s for s in seqs
+                         if self._slots[s.slot] is s}.values())
+            for seq in seqs:
+                seq.req.future._fail(e)
+                self._release(seq, "failed")
+        self._trace_finish(seqs, "error", error=f"{type(e).__name__}: {e}")
 
     def _account(self, ran: _Ran, seqs: Sequence[_ActiveSeq],
                  envelopes: Sequence[dict], span: str,
@@ -1758,9 +1881,11 @@ class GenerationServer:
         """The decode program's feeds before its selection's: the lane
         of each of ``seqs`` is fed its entry of ``tokens`` at its entry
         of ``positions`` (the slot it writes; the context it reads ends
-        one past it); every other lane is masked dead."""
+        one past it); every other lane is masked dead. The tables are
+        a copy: a step may be in flight while ``_release`` and
+        ``fill_row`` rewrite ``self._tables`` in place."""
         b = self.max_batch
-        toks = np.zeros(b, np.int64)
+        toks = np.zeros(b, np.int32)
         pos = np.zeros(b, np.int32)
         mask = np.zeros(b, bool)
         ctx_after = np.zeros(b, np.int32)
@@ -1772,37 +1897,120 @@ class GenerationServer:
             pos[slot] = position
             mask[slot] = True
             ctx_after[slot] = position + 1
-        return toks, pos, mask, ctx_after, self._tables
+        return toks, pos, mask, ctx_after, self._tables.copy()
 
     def _decode_iteration(self, active: List[_ActiveSeq],
                           stall_t0: Optional[float] = None):
+        """Enqueue the next decode step, THEN harvest the one in
+        flight: with step i running, step i+1 is formed from what the
+        host knows without step i's tokens and handed to the runtime,
+        and only then are step i's tokens fetched, emitted and booked.
+
+        *The next step's lanes* are those of ``active`` the host cannot
+        yet rule out. A lane riding in the step in flight goes on
+        unless that step's token ends it by length (``n_generated + 1``
+        reaches ``max_new``, or the position after it leaves the
+        reservation: what ``_emit_batch`` will find) or its caller has
+        cancelled; it is fed at ``ctx + 1``, and its token is the one
+        the step in flight will return, where it lies. A lane that is
+        not riding (prefilled since, or the pipe is empty) is fed the
+        token the host holds, through ``lane_tokens`` where the others'
+        are on the device. What the host cannot know a step early is an
+        ``eos``: such a lane runs one more position, inside its own
+        reservation, whose token the harvest drops (``late_lanes``).
+
+        *The harvest* emits to the sequences that still own their lane
+        (a release since the enqueue, by eviction or a failed prefill
+        neighbour, makes the lane late as an ``eos`` does), advances
+        their ``ctx`` and runs the finish checks. An error on either
+        side fails the sequences of both steps: the successor consumed
+        the pools its predecessor returned.
+
+        ``engine::decode_call`` brackets "enqueue i+1 ... step i's fetch
+        returned" and carries the arguments of the step it enqueues
+        (none where it enqueues nothing): that program starts when its
+        predecessor ends, inside this span. ``step_ms["decode"]`` is
+        that span's time, for every step harvested."""
         self._enter_phase("decode_feeds")
-        slots = [s.slot for s in active]
-        feeds = self._decode_feeds(active,
-                                   [s.last_token for s in active],
-                                   [s.ctx for s in active]) \
-            + self._selection_feeds(active, slots, self.max_batch)
-        # the context this step's attention reads: each live lane's
-        # cached positions, the one it writes among them
-        self._enter_decode_call(active, feeds[3], stall_t0)
-        ran = self._dispatch("decode", feeds, active, self._runners[:1],
-                             stage="decode")
-        if ran is None:
+        target, flying = self._runners[0], self._inflight
+        riding = {id(s) for s in flying.seqs} if flying else ()
+        lanes = [s for s in active if id(s) not in riding or (
+            s.n_generated + 1 < s.req.max_new
+            and s.ctx + 2 <= s.max_total
+            and not s.req.future._cancel_requested)]
+        feeds = None
+        if lanes:
+            feeds = self._decode_feeds(
+                lanes, [s.last_token for s in lanes],
+                [s.ctx + (id(s) in riding) for s in lanes]) \
+                + self._selection_feeds(lanes, [s.slot for s in lanes],
+                                        self.max_batch)
+            # the context the enqueued step's attention reads: each
+            # lane's cached positions, the one it writes among them
+            self._enter_decode_call(lanes, feeds[3], stall_t0)
+        else:
+            self._enter_phase("decode_call")
+        t_wall, t0 = time.time_ns(), time.perf_counter()
+        self._inflight = run = None
+        try:
+            if lanes:
+                tokens = feeds[0]
+                if flying is not None:
+                    # the riding lanes' last tokens are on the device;
+                    # the others', which the host holds, are written
+                    # over that vector there
+                    tokens = flying.enqueued.tokens
+                    held = [s for s in lanes if id(s) not in riding]
+                    if held:
+                        fresh = np.full(self.max_batch, -1, np.int32)
+                        for s in held:
+                            fresh[s.slot] = s.last_token
+                        tokens = self.decoder.lane_tokens(tokens, fresh)
+                self._inflight = _Step(
+                    target.enqueue("decode", (tokens,) + feeds[1:]), lanes)
+                self.metrics.observe_run_ahead(
+                    "drained" if flying is None else "ahead")
+            if flying is not None:
+                run = target.harvest(flying.enqueued)
+                self._observe_run("decode", target, run)
+        except Exception as e:  # noqa: BLE001 - the fault barrier
+            self._inflight = None
+            self._fail((flying.seqs if flying else []) + lanes, e)
             return
+        if run is None:
+            return
+        ms = (time.perf_counter() - t0) * 1e3
+        self._enter_phase("bookkeeping")
+        self.metrics.observe_step("decode", ms)
+        self._note_dispatch(SITES["decode"], run.fresh, run.signature)
         self._steps += 1
-        self.metrics.observe_occupancy(len(active))
+        # the lanes the step computed, and those of them still owned
+        # by the sequence it computed them for
+        step, n = flying.seqs, len(flying.seqs)
+        self.metrics.observe_occupancy(n)
+        live = [s for s in step if self._slots[s.slot] is s]
+        if len(live) < n:
+            self.metrics.observe_run_ahead("late_lanes", n - len(live))
         self._account(
-            ran, active, [self._decode_envelope(ran.ms, len(active))],
-            "decode_step",
-            lambda seq: {"step": seq.n_generated,
-                         "occupancy": len(active)})
-        for seq in active:
+            _Ran(run.tokens, None, ms, t_wall, [run.fresh]), live,
+            [self._decode_envelope(ms, n)], "decode_step",
+            lambda seq: {"step": seq.n_generated, "occupancy": n})
+        for seq in live:
             self.kv.note_positions(seq.ctx, seq.ctx + 1)
             seq.ctx += 1
         if self.kv.window is not None:
             self._note_kv_pages()
         self._enter_phase("sample_emit")
-        self._emit_batch(active, [[t] for t in ran.tokens[slots].tolist()])
+        self._emit_batch(
+            live, [[t] for t in run.tokens[[s.slot for s in live]].tolist()])
+
+    def _drain(self, resume: str):
+        """Harvest and emit the step in flight, if there is one, and
+        go back to phase ``resume`` (loop thread). After it the host
+        holds every live lane's last token, history and ``ctx``."""
+        if self._inflight is not None:
+            self._decode_iteration([])
+            self._enter_phase(resume)
 
     # ---- one speculative iteration: draft proposes, target verifies
     def _spec_iteration(self, active: List[_ActiveSeq],

@@ -19,7 +19,13 @@ all device arrays: a caller that wants the tokens alone fetches
   the program;
 - ``decode(tokens, positions, active, ctx, tables, temperature,
   uniform, pools)`` — the fixed-shape ``[max_batch, 1]`` decode step:
-  append one position per live lane, attend through the block tables;
+  append one position per live lane, attend through the block tables.
+  ``tokens`` is ``[max_batch]`` int32, the type the step returns, so one
+  step's choice is the next step's operand where it lies, on the
+  device; a host array is cast to the same type, and both forms are one
+  signature and one executable. ``lane_tokens(carried, fresh)`` writes
+  the tokens the host knows (a prefill's choice) over such a vector
+  without fetching it;
 - ``prefill_chunked(ids, start, seg_lens, tables, temperature,
   uniform, pools)`` — suffix prefill at a per-row starting position:
   window tokens attend to the already-cached prefix through the block
@@ -362,6 +368,12 @@ class CachedDecoder:
         self._oracle_fns = None
         self._oracle_jits: Dict[str, object] = {}
         self._div_jit = None
+        # the one program beside the model's: a lane takes the token
+        # the host names (0 and above), else keeps the one it carried
+        def _lane_tokens(carried, fresh):
+            return jnp.where(fresh >= 0, fresh, carried)
+
+        self._lane_tokens_jit = jax.jit(_lane_tokens)
 
     def refresh_params(self):
         """Re-snapshot the model's current parameter arrays (they are
@@ -397,8 +409,10 @@ class CachedDecoder:
                     # position a row in prefill, counters beside the
                     # logits, windows and grouped heads in the ops;
                     # v7: the pools' heads folded into their lanes;
-                    # v8: the programs choose the next token)
-                    "kv_dtype": self.kv_dtype, "v": 8}
+                    # v8: the programs choose the next token; v9: the
+                    # decode step takes its tokens as it returns them,
+                    # [max_batch] int32)
+                    "kv_dtype": self.kv_dtype, "v": 9}
             # mesh axes + weight spec-tree hash join the geometry ONLY
             # when the mesh is live: an inert (None / 1-device) mesh
             # must reuse today's fingerprints byte-for-byte, and a mesh
@@ -664,18 +678,33 @@ class CachedDecoder:
         self.last_aux = {}
         return logits, k2, v2, fresh
 
-    def decode(self, tokens: np.ndarray, positions: np.ndarray,
+    def lane_tokens(self, carried, fresh: np.ndarray):
+        """``carried`` ``[B]`` int32 (a decode step's tokens, on the
+        device, maybe of a step still running) with ``fresh`` ``[B]``
+        written over it where ``fresh`` is 0 or more: the lanes whose
+        token the host knows (a prefill chose it). One small program,
+        nothing fetched; the result is a ``decode`` operand."""
+        return self._lane_tokens_jit(
+            carried, np.ascontiguousarray(fresh, np.int32))
+
+    def decode(self, tokens, positions: np.ndarray,
                active: np.ndarray, ctx: np.ndarray,
                tables: np.ndarray, temperature, uniform, k, v):
-        """One fixed-shape decode step. tokens [B] int64; positions [B]
+        """One fixed-shape decode step. tokens [B] int32, on the host
+        (any integer type: it is cast) or on the device (the tokens an
+        earlier step returned, or ``lane_tokens`` of them: the same
+        signature, and no host in between); positions [B]
         int32 (slot being written); active [B] bool; ctx [B] int32
         visible length INCLUDING this token; tables [B, P] int32;
         temperature and uniform [B] float32 (None: greedy; a dead
         lane's token means nothing). Returns ``(next_tokens [B] int32,
         logits [B, vocab], k', v', new_signature)``, the arrays on the
         device."""
-        args = (self._params, self._buffers,
-                np.ascontiguousarray(tokens, np.int64),
+        import jax
+        if not (isinstance(tokens, jax.Array)
+                and tokens.dtype == np.int32):
+            tokens = np.ascontiguousarray(tokens, np.int32)
+        args = (self._params, self._buffers, tokens,
                 np.ascontiguousarray(positions, np.int32),
                 np.ascontiguousarray(active, bool),
                 np.ascontiguousarray(ctx, np.int32),

@@ -244,7 +244,7 @@ def test_default_decode_program_reads_through_the_table(
         lambda a: sds(a.shape, a.dtype), state_arrays(model))
     pool = [sds(kv_pool_shape(1 + lanes * 8, PAGE, H, d), jnp.float32)] * 2
     text = dec._decode_jit.lower(
-        params, buffers, sds((lanes,), jnp.int64), sds((lanes,), jnp.int32),
+        params, buffers, sds((lanes,), jnp.int32), sds((lanes,), jnp.int32),
         sds((lanes,), jnp.bool_), sds((lanes,), jnp.int32),
         sds((lanes, pages), jnp.int32), sds((lanes,), jnp.float32),
         sds((lanes,), jnp.float32), pool, pool).compile().as_text()
@@ -301,7 +301,7 @@ def _lower_serve_program(one_chip, config, program):
         pages, PAGE, window_pages=window_pages)))
     if program == "decode":
         lowered = dec._decode_jit.lower(
-            params, buffers, sds((lanes,), jnp.int64),
+            params, buffers, sds((lanes,), jnp.int32),
             sds((lanes,), jnp.int32), sds((lanes,), jnp.bool_),
             sds((lanes,), jnp.int32), sds((lanes, width), jnp.int32),
             sds((lanes,), jnp.float32), sds((lanes,), jnp.float32), k, v)

@@ -889,3 +889,507 @@ class TestHybridHelperMigration:
         h = HybridParallelInferenceHelper(Toy(), max_length=8)
         out = h.generate(np.array([[1, 2]], "int64"), max_new_tokens=3)
         assert out.shape == (1, 5)
+
+
+# ------------------------------------- the loop runs one step ahead
+# (PR 36) ``_decode_iteration`` enqueues step i+1 before it harvests
+# step i; each lane's last token stays on the device between the two.
+RA_PAGE, RA_SEQ = 4, 64
+
+
+def _ra_model(kind):
+    """A tiny model of each kind of layer the cache manager knows: full
+    context alone, window layers that keep a ring a lane, state layers
+    that keep a slot a lane."""
+    from paddle_tpu import models
+    paddle.seed(7)
+    if kind == "gpt":
+        m = GPTForCausalLM(gpt_tiny(use_flash_attention=False))
+    elif kind == "ring":
+        pattern = (0, 1, 1, 1)
+        m = models.GPTForCausalLM(models.GPTConfig(
+            vocab_size=128, hidden_size=32, num_layers=4, num_heads=4,
+            num_kv_heads=2, head_dim=8, max_seq_len=256, norm="rmsnorm",
+            layer_norm_eps=1e-6, bias=False, position="rope",
+            rope_layout=pattern, sliding_window=8,
+            sliding_window_layout=pattern, tie_word_embeddings=False,
+            use_flash_attention=False))
+    else:
+        m = models.NemotronHForCausalLM(models.NemotronHConfig(
+            vocab_size=128, hidden_size=32, num_layers=4, pattern="ME*M",
+            max_seq_len=256, num_heads=4, num_kv_heads=2, head_dim=8,
+            mamba_num_heads=4, mamba_head_dim=8, ssm_state_size=8,
+            mamba_n_groups=2, chunk_size=8, moe_num_experts=4,
+            moe_router_experts=4, moe_top_k=2, moe_latent_size=16,
+            moe_intermediate_size=16, moe_shared_intermediate_size=16,
+            use_flash_attention=False))
+    m.eval()
+    return m
+
+
+def _ra_server(model, **kw):
+    kw.setdefault("max_batch", 3)
+    return GenerationServer(model, page_size=RA_PAGE, max_seq_len=RA_SEQ,
+                            seq_buckets=[8, 16, 32], use_pallas=False,
+                            start=False, **kw)
+
+
+def drive(model, prompt, n, temperature=0.0, seed=None):
+    """The stream of one request as a step-by-step drive of
+    ``CachedDecoder`` gives it: alone in lane 0, every step fetched
+    before the next is formed and its token fed back from the host,
+    the uniforms drawn as the engine draws them (one a program, from
+    the request's own ``RandomState``)."""
+    b = 3
+    kv = PagedKVCache(model, num_pages=40, page_size=RA_PAGE, max_batch=b)
+    width = kv.table_width(RA_SEQ)
+    dec = CachedDecoder(model, max_batch=b, page_size=RA_PAGE,
+                        pages_per_seq=width, max_positions=RA_SEQ,
+                        donate=False, use_pallas=False)
+    total = len(prompt) + n
+    tables = np.zeros((b, width), np.int32)
+    kv.fill_row(tables[0], kv.alloc(kv.pages_for(total)),
+                kv.alloc_window(total), kv.alloc_state() or 0)
+    rng = np.random.RandomState(seed)
+
+    def selection(rows):
+        t, u = np.zeros(rows, np.float32), np.zeros(rows, np.float32)
+        if temperature > 0:
+            t[0], u[0] = temperature, rng.random_sample()
+        return t, u
+
+    bucket = next(s for s in (8, 16, 32) if s >= len(prompt))
+    ids = np.zeros((1, bucket), np.int64)
+    ids[0, :len(prompt)] = prompt
+    toks, _, k, v, _ = dec.prefill(
+        ids, np.array([len(prompt)], np.int32), tables[:1],
+        *selection(1), kv.k, kv.v)
+    out, ctx = [int(np.asarray(toks)[0])], len(prompt)
+    while len(out) < n:
+        tokens, pos = np.zeros(b, np.int64), np.zeros(b, np.int32)
+        tokens[0], pos[0] = out[-1], ctx
+        toks, _, k, v, _ = dec.decode(
+            tokens, pos, np.arange(b) == 0, pos + 1, tables,
+            *selection(b), k, v)
+        out.append(int(np.asarray(toks)[0]))
+        ctx += 1
+    return out
+
+
+def until_eos(stream, eos):
+    return stream[:stream.index(eos) + 1] if eos in stream else stream
+
+
+class Recorder:
+    """The target runner of ``srv`` with its two halves wrapped: every
+    ``enqueue`` and ``harvest`` of a decode step and every emission in
+    ``events``, in the order the loop thread made them, and the feeds
+    of each enqueued step as they were handed over beside a copy."""
+
+    def __init__(self, srv, before_harvest=None):
+        self.events, self.feeds = [], []
+        self.before_harvest = before_harvest
+        runner = srv._runners[0]
+        enqueue, harvest, emit = (runner.enqueue, runner.harvest,
+                                  srv._emit_batch)
+        self._steps = {}     # id(Enqueued) -> the step's number
+
+        def spy_enqueue(kind, feeds):
+            step = enqueue(kind, feeds)
+            if kind == "decode":
+                n = len(self.feeds)
+                self._steps[id(step)] = n
+                self.feeds.append((step, feeds, [
+                    np.array(f) for f in feeds[1:]]))
+                self.events.append(("enqueue", n))
+            return step
+
+        def spy_harvest(step, host_logits=False):
+            n = self._steps.get(id(step))
+            if n is not None:
+                if self.before_harvest is not None:
+                    self.before_harvest(n)
+                self.events.append(("harvest", n))
+            return harvest(step, host_logits)
+
+        def spy_emit(seqs, toks):
+            self.events.append(("emit", [s.slot for s in seqs]))
+            return emit(seqs, toks)
+
+        runner.enqueue, runner.harvest = spy_enqueue, spy_harvest
+        srv._emit_batch = spy_emit
+
+    def order(self, what):
+        return [i for i, e in enumerate(self.events) if e == what]
+
+
+class TestRunAhead:
+    ASKS = [  # prompt length, max_new, temperature, seed
+        (3, 12, 0.0, None),      # 0: hits eos mid-run
+        (5, 10, 0.8, 5),         # 1: cancelled after its 4th token
+        (6, 14, 0.0, None),      # 2: its deadline passes after its 6th
+        (9, 9, 1.1, 9),          # 3: waits, then takes a freed lane
+        (13, 6, 0.0, None),      # 4: waits; its prompt is four pages
+        (2, 11, 0.7, 2),         # 5: waits
+    ]
+
+    @pytest.mark.parametrize("kind", ["gpt", "ring", "state"])
+    def test_streams_equal_a_step_by_step_drive(self, kind):
+        """Six requests over three lanes, greedy and seeded-sampled:
+        one ends by ``eos`` mid-run, one is cancelled, one evicted by
+        its deadline, all cross page boundaries, three are admitted
+        into lanes freed the step before. Every stream is token for
+        token what ``CachedDecoder`` driven one harvested step at a
+        time gives that request alone; ``late_lanes`` counts the one
+        step each lane ran past an end the host could not know."""
+        model = _ra_model(kind)
+        rng = np.random.RandomState(3)
+        asks = [(rng.randint(1, 100, n), new, t, seed)
+                for n, new, t, seed in self.ASKS]
+        plain = [drive(model, *ask) for ask in asks]
+        # an eos that request 0 reaches mid-run and not at its start,
+        # and the two streams ended from outside not before their ends
+        eos = next(t for i, t in enumerate(plain[0][3:-2], 3)
+                   if t not in plain[0][:i] + plain[1][:6] + plain[2][:8])
+        want = [until_eos(s, eos) for s in plain]
+        assert 3 < len(want[0]) < len(plain[0]) - 1
+        # the two streams ended from outside are mid-run when they are
+        assert len(want[1]) > 5 and len(want[2]) > 7
+        srv = _ra_server(model, eos_token_id=eos, name=f"ahead-{kind}")
+        futs = [srv.submit_generate(p, max_new_tokens=new, temperature=t,
+                                    seed=seed) for p, new, t, seed in asks]
+
+        def meddle(step):
+            if len(futs[1].tokens()) == 3 and not futs[1]._cancel_requested:
+                futs[1].cancel()        # its 4th token is in this harvest
+            if len(futs[2].tokens()) == 5:
+                for seq in srv._slots:  # its 6th; evicted at the re-form
+                    if seq is not None and seq.req.future is futs[2]:
+                        seq.req.hard_deadline = 0.0
+
+        rec = Recorder(srv, before_harvest=meddle)
+        with srv:
+            srv.start()
+            got = []
+            for i, f in enumerate(futs):
+                if i == 2:
+                    with pytest.raises(DeadlineExceededError):
+                        f.result(timeout=120)
+                    got.append(f.tokens())
+                else:
+                    got.append(f.result(timeout=120))
+            srv.shutdown(drain=True)    # the last late step is in by now
+            snap = srv.metrics_snapshot()
+            assert futs[0].finish_reason == "eos"
+            assert futs[1].finish_reason == "cancelled"
+            assert futs[2].finish_reason == "deadline"
+            want[1], want[2] = want[1][:4], want[2][:6]
+            for i, (g, w) in enumerate(zip(got, want)):
+                assert g == w, (kind, i)
+            # a lane runs one step past an end the host learns of at
+            # the harvest: an eos before the last token the request
+            # may have (a length's end is known a step early), the
+            # cancel and the eviction; an eos in a prefill's token
+            # never reached a decode step
+            late = 2 + sum(1 for w, (_, new, _, _) in zip(want, asks)
+                           if w[-1] == eos and 1 < len(w) < new)
+            assert late >= 3
+            ahead = snap["engine"]["run_ahead"]
+            assert ahead["late_lanes"] == late
+            steps = snap["batch_occupancy"]["steps"]
+            assert ahead["ahead"] + ahead["drained"] == steps
+            assert ahead["drained"] == 1      # the first step alone
+            srv.kv.assert_no_leaks()
+            assert srv.kv.used_pages == 0 or srv.prefix is not None
+
+    def test_only_an_eos_costs_a_lane_step(self):
+        """What the host knows a step early it uses: streams that end
+        by length waste nothing, and the one that ends by ``eos`` runs
+        exactly one more position, whose token nobody sees."""
+        model = _ra_model("gpt")
+        ref = drive(model, [5, 7, 9], 8)
+        eos = next(t for i, t in enumerate(ref[2:-2], 2)
+                   if t not in ref[:i])
+        with _ra_server(model, name="by-length") as srv:
+            srv.start()
+            futs = [srv.submit_generate([5, 7, 9 + i], max_new_tokens=4 + i)
+                    for i in range(5)]
+            assert [len(f.result(timeout=60)) for f in futs] == \
+                [4, 5, 6, 7, 8]
+            assert srv.metrics_snapshot()["engine"]["run_ahead"][
+                "late_lanes"] == 0
+        with _ra_server(model, eos_token_id=eos, name="by-eos") as srv:
+            srv.start()
+            assert srv.generate([5, 7, 9], max_new_tokens=8) == \
+                until_eos(ref, eos)
+        # (the stream ends at the harvest of its last step; the step
+        # after it is harvested an iteration later: read after the end)
+        snap = srv.metrics_snapshot()
+        assert snap["engine"]["run_ahead"]["late_lanes"] == 1
+        # the dropped token was not counted as generated
+        assert snap["tokens_total"] == len(until_eos(ref, eos))
+
+    def test_enqueue_precedes_the_harvest_before_it(self):
+        """With a recording runner: step i+1 is enqueued before step i
+        is harvested, step i is emitted before step i+2 is enqueued,
+        and every step but the first is enqueued with its predecessor
+        in flight (the counter says so)."""
+        model = _ra_model("gpt")
+        srv = _ra_server(model, name="order")
+        rec = Recorder(srv)
+        with srv:
+            srv.start()
+            srv.generate([5, 7, 9], max_new_tokens=7)
+        n = len(rec.feeds)
+        assert n == 6                      # 7 tokens: a prefill, 6 steps
+        emits = [i for i, e in enumerate(rec.events) if e[0] == "emit"]
+        assert len(emits) == 1 + n         # the prefill's, then a step's
+        for i in range(n):
+            (enq,), (har,) = rec.order(("enqueue", i)), rec.order(
+                ("harvest", i))
+            assert enq < har
+            if i + 1 < n:
+                assert rec.order(("enqueue", i + 1))[0] < har
+            if i + 2 < n:
+                # emission follows the fetch directly
+                assert rec.events[har + 1][0] == "emit"
+                assert har + 1 < rec.order(("enqueue", i + 2))[0]
+        ahead = srv.metrics_snapshot()["engine"]["run_ahead"]
+        assert ahead == {"ahead": n - 1, "drained": 1, "late_lanes": 0}
+
+    def test_the_next_step_takes_its_tokens_from_the_device(self):
+        """A step enqueued behind another is handed that step's tokens
+        where they lie (or ``lane_tokens`` of them after an admission),
+        never an array of the host's; both forms are one signature."""
+        import jax
+        model = _ra_model("gpt")
+        want = drive(model, [4, 2], 4)
+        srv = _ra_server(model, name="resident")
+        with srv:
+            srv.warmup(seq_buckets=[8])
+            rec = Recorder(srv)
+            misses = srv.metrics_snapshot()["compile_cache"]["misses"]
+            srv.start()
+            # (long enough that the second joins it for certain)
+            first = srv.submit_generate([5, 7, 9], max_new_tokens=48)
+            while len(first.tokens()) < 3:
+                time.sleep(0.002)
+            late = srv.submit_generate([4, 2], max_new_tokens=4)
+            assert len(first.result(timeout=60)) == 48
+            assert late.result(timeout=60) == want
+            assert srv.metrics_snapshot()["compile_cache"][
+                "misses"] == misses
+        forms = [type(feeds[0]) for _, feeds, _ in rec.feeds]
+        assert forms[0] is np.ndarray and forms[0] is not forms[1]
+        assert all(isinstance(feeds[0], jax.Array)
+                   for _, feeds, _ in rec.feeds[1:])
+        for (prev, _, _), (_, feeds, _) in zip(rec.feeds, rec.feeds[1:]):
+            assert feeds[0].dtype == np.int32
+            assert feeds[0].shape == prev.tokens.shape
+        # the step after the admission is not its predecessor's vector
+        # itself: the prefill's token was written over it
+        merged = [feeds[0] is not prev.tokens for (prev, _, _), (_, feeds, _)
+                  in zip(rec.feeds, rec.feeds[1:])]
+        assert sum(merged) == 1
+
+    def test_warm_leaves_no_signature_for_traffic(self):
+        """``_warm("decode")`` runs both forms of the tokens operand
+        (and ``lane_tokens``): traffic compiles nothing."""
+        from jax._src import monitoring
+        model = _ra_model("gpt")
+        compiles = []
+        with _ra_server(model, name="warm-forms") as srv:
+            assert srv._warm("decode", srv.max_batch) == 1
+            assert srv._warm("decode", srv.max_batch) == 0
+            srv.warmup(seq_buckets=[8])
+            warmed = srv.metrics_snapshot()["compile_cache"]["misses"]
+
+            def listen(name, *a, **kw):
+                if "backend_compile" in name:
+                    compiles.append(name)
+
+            monitoring.register_event_duration_secs_listener(listen)
+            try:
+                srv.start()
+                a = srv.submit_generate([5, 7, 9], max_new_tokens=6)
+                b = srv.submit_generate([4, 2], max_new_tokens=3,
+                                        temperature=0.9, seed=1)
+                c = srv.submit_generate([8], max_new_tokens=5)
+                for f in (a, b, c):
+                    f.result(timeout=60)
+                d = srv.generate([3, 3, 3], max_new_tokens=4)
+            finally:
+                monitoring.unregister_event_duration_listener(listen)
+            assert len(d) == 4
+            snap = srv.metrics_snapshot()
+            assert snap["compile_cache"]["misses"] == warmed
+            assert compiles == []
+
+    def test_feeds_in_flight_outlive_a_release(self):
+        """A step's feeds are its own: ``_release`` zeroes the lane's
+        row of the engine's tables in place, and ``fill_row`` rewrites
+        it for the next owner, while the step that was handed that row
+        may still be running."""
+        model = _ra_model("gpt")
+        srv = _ra_server(model, max_batch=2, name="snapshot")
+        rec = Recorder(srv)
+        with srv:
+            srv.start()
+            futs = [srv.submit_generate([5, 7, 9], max_new_tokens=3),
+                    srv.submit_generate([1, 2], max_new_tokens=9),
+                    srv.submit_generate([6, 6, 6, 6, 6], max_new_tokens=4)]
+            for f in futs:
+                f.result(timeout=60)
+            assert not srv._tables.any()        # every row was zeroed
+        assert len(rec.feeds) >= 8
+        for _, feeds, copies in rec.feeds:
+            assert feeds[4] is not srv._tables
+            assert not np.shares_memory(feeds[4], srv._tables)
+            for handed, copy in zip(feeds[1:], copies):
+                np.testing.assert_array_equal(handed, copy)
+            assert feeds[4][feeds[2]].any(axis=1).all()  # live rows named
+
+    @pytest.mark.parametrize("where", ["enqueue", "harvest"])
+    def test_a_fault_on_either_side_fails_both_steps(self, where):
+        """A program that raises when it is enqueued, and one whose
+        error surfaces at its harvest with a successor already in
+        flight on the pools it returned: the sequences of both steps
+        fail, typed, their pages and lanes return, and the worker
+        serves the next request."""
+        import jax
+        model = _ra_model("gpt")
+        # (the references first: a drive traces the model, and so does
+        # a live server's first program of a shape, in another thread)
+        want_ok, want_again = (drive(model, [4, 4], 5),
+                               drive(model, [5, 7, 9], 12))
+        srv = _ra_server(model, max_batch=2, name=f"fault-{where}")
+        runner = srv._runners[0]
+        real, calls = getattr(runner, where), []
+
+        def bomb(*a, **kw):
+            if a[0] == "decode" or where == "harvest":
+                calls.append(1)
+                if len(calls) == 3:
+                    raise RuntimeError(f"injected at {where}")
+            return real(*a, **kw)
+
+        with srv:
+            bad = [srv.submit_generate(p, max_new_tokens=12)
+                   for p in ([5, 7, 9], [1, 2, 3])]
+            ok = srv.submit_generate([4, 4], max_new_tokens=5)
+            setattr(runner, where, bomb)
+            srv.start()
+            for f in bad:
+                with pytest.raises(RuntimeError, match="injected at"):
+                    f.result(timeout=60)
+                assert f.finish_reason == "error"
+                assert 1 <= len(f.tokens()) < 12
+            assert ok.result(timeout=60) == want_ok
+            assert srv._inflight is None
+            counters = srv.metrics_snapshot()["counters"]
+            assert (counters["failed"], counters["completed"]) == (2, 1)
+            assert srv.generate([5, 7, 9], max_new_tokens=12) == want_again
+            srv.clear_prefix_cache()
+            srv.kv.assert_no_leaks()
+            assert srv.kv.free_pages == srv.kv.capacity
+            assert all(not a.is_deleted() for a in
+                       jax.tree_util.tree_leaves((srv.kv.k, srv.kv.v)))
+
+    @pytest.mark.parametrize("what", ["park", "shutdown", "refresh_params",
+                                      "clear_prefix_cache", "abort"])
+    def test_whatever_touches_a_lane_drains_first(self, what):
+        """A park, a drained shutdown, a weight swap, a prefix-cache
+        clear and an abort all find the pipe empty: every enqueued
+        step has been harvested (and its token emitted) when they act."""
+        from paddle_tpu.serving.scheduling import (AdmissionController,
+                                                   SchedulerPolicy,
+                                                   TenantPolicy)
+        model = _ra_model("gpt")
+        want_long, want_gold = (drive(model, [5, 7, 9], 50),
+                                drive(model, [1, 2, 3], 6))
+        kw = {}
+        if what == "park":
+            kw = dict(num_pages=1 + 14, prefix_cache=False,
+                      scheduler=AdmissionController(
+                          policy=SchedulerPolicy(tenants={
+                              "gold": TenantPolicy("gold",
+                                                   priority="realtime"),
+                              "bulk": TenantPolicy("bulk",
+                                                   priority="batch")}),
+                          name="t_drain_park"))
+        srv = _ra_server(model, max_batch=2, name=f"drain-{what}", **kw)
+        rec = Recorder(srv)
+        seen = []
+
+        def pipe():
+            enq = sum(1 for e in rec.events if e[0] == "enqueue")
+            har = sum(1 for e in rec.events if e[0] == "harvest")
+            seen.append((enq, har, srv._inflight))
+
+        for name in ("_park", "_do_abort", "_clear_prefix"):
+            real = getattr(srv, name)
+
+            def spy(*a, _real=real, **kw):
+                if _real.__name__ != "_do_abort":
+                    pipe()
+                out = _real(*a, **kw)
+                if _real.__name__ == "_do_abort":
+                    pipe()      # it drains itself, then fails the rest
+                return out
+
+            setattr(srv, name, spy)
+        refresh = srv.decoder.refresh_params
+        srv.decoder.refresh_params = lambda: (pipe(), refresh())[1]
+        srv.start()
+        tenant = dict(tenant="bulk") if what == "park" else {}
+        long = srv.submit_generate([5, 7, 9], max_new_tokens=50, **tenant)
+        while len(long.tokens()) < 4:
+            time.sleep(0.002)
+        if what == "park":
+            # all 14 pages are the long stream's: the realtime request
+            # needs it parked
+            gold = srv.submit_generate([1, 2, 3], max_new_tokens=6,
+                                       tenant="gold")
+            assert gold.result(timeout=60) == want_gold
+            assert long.result(timeout=60) == want_long
+            assert srv.metrics_snapshot()["counters"]["parked"] == 1
+        elif what == "refresh_params":
+            srv.refresh_params()
+            assert long.result(timeout=60) == want_long
+        elif what == "clear_prefix_cache":
+            srv.clear_prefix_cache()
+            assert long.result(timeout=60) == want_long
+        if what == "abort":
+            srv.shutdown(drain=False)
+            assert long.finish_reason == "shutdown"
+            assert long.tokens() == want_long[:len(long.tokens())]
+        else:
+            srv.shutdown(drain=True)
+            assert long.finish_reason == "length"
+        if what == "shutdown":
+            pipe()
+        assert seen, what
+        for enq, har, inflight in seen:
+            assert enq == har and inflight is None
+        assert srv._inflight is None
+        srv.kv.assert_no_leaks()
+
+    def test_a_server_with_a_draft_never_runs_ahead(self):
+        """Speculation judges each round's proposals on the host, so
+        it harvests every program before it forms the next: nothing is
+        in flight between iterations, and the counter stays at 0."""
+        model = _ra_model("gpt")
+        want = drive(model, [5, 7, 9], 8)
+        srv = _ra_server(model, max_batch=2, draft_model=model, spec_k=3,
+                         name="draft-drains")
+        rec = Recorder(srv)
+        with srv:
+            srv.start()
+            assert srv.generate([5, 7, 9], max_new_tokens=8) == want
+            assert srv._inflight is None
+            assert srv.metrics_snapshot()["engine"]["run_ahead"] == {
+                "ahead": 0, "drained": 0, "late_lanes": 0}
+        # every decode program the target ran was harvested at once
+        assert not rec.feeds or all(
+            rec.order(("harvest", i))[0] == rec.order(("enqueue", i))[0] + 1
+            for i in range(len(rec.feeds)))

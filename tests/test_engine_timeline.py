@@ -110,6 +110,74 @@ def test_engine_counters_equal_hand_counted(lockstep_run, key):
     assert before in (0, {}, {"in_program": 0, "on_host": 0})
 
 
+def test_decode_call_span_carries_the_enqueued_steps_arguments():
+    """The loop runs a step ahead (PR 36): ``engine::decode_call``
+    opens, the NEXT step is enqueued, the step in flight is harvested,
+    the span closes. Its arguments are those of the step it enqueues,
+    also where that step has a lane more than the one it harvests; the
+    span that only harvests says nothing; ``step_ms["decode"]`` and the
+    step count advance once a harvested step."""
+    srv = make_server(make_model(), max_batch=2)
+    srv.warmup()
+    log = []
+    runner = srv._runners[0]
+    enter, phase = srv._enter_decode_call, srv._enter_phase
+    enqueue, harvest = runner.enqueue, runner.harvest
+
+    def spy_enter(active, ctx_after, stall_t0):
+        log.append(("span", len(active), int(ctx_after.sum())))
+        return enter(active, ctx_after, stall_t0)
+
+    def spy_phase(name, **args):
+        if name == "decode_call" and not args and log[-1][0] != "span":
+            log.append(("span", None, None))
+        return phase(name, **args)
+
+    def spy_enqueue(kind, feeds):
+        if kind == "decode":
+            log.append(("enqueue", int(feeds[2].sum()), int(feeds[3].sum())))
+        return enqueue(kind, feeds)
+
+    def spy_harvest(step, host_logits=False):
+        if step.signature[0][0] == (srv.max_batch,):
+            log.append(("harvest",))
+        return harvest(step, host_logits)
+
+    srv._enter_decode_call, srv._enter_phase = spy_enter, spy_phase
+    runner.enqueue, runner.harvest = spy_enqueue, spy_harvest
+    srv.start()
+    # (long enough that the second surely joins it, however slow the
+    # test's own thread is)
+    first = srv.submit_generate(np.arange(1, 4), max_new_tokens=48)
+    while len(first.tokens()) < 3:
+        time.sleep(0.002)
+    second = srv.submit_generate(np.arange(1, 6), max_new_tokens=3)
+    assert len(first.result(60)) == 48 and len(second.result(60)) == 3
+    srv.shutdown()
+    snap = srv.metrics_snapshot()
+    spans = [i for i, e in enumerate(log) if e[0] == "span"]
+    for at, end in zip(spans, spans[1:] + [len(log)]):
+        _, active, context = log[at]
+        inside = log[at + 1:end]
+        if active is None:      # nothing left to enqueue: the harvest
+            assert inside == [("harvest",)]
+            continue
+        # the step it enqueues, by its own feeds, before any harvest
+        assert inside[0] == ("enqueue", active, context)
+        assert inside[1:] in ([], [("harvest",)])
+    enqueued = [e for e in log if e[0] == "enqueue"]
+    assert {e[1] for e in enqueued} == {1, 2}    # the second lane joined
+    # the first step with two lanes was enqueued with one in flight
+    joined = log.index(next(e for e in enqueued if e[1] == 2))
+    assert log[joined + 1] == ("harvest",) and log[joined - 1][1] == 2
+    steps = len(enqueued)
+    assert steps == len([e for e in log if e == ("harvest",)])
+    assert snap["batch_occupancy"]["steps"] == steps
+    assert snap["step_ms"]["decode"]["count"] == steps
+    assert snap["engine"]["run_ahead"]["ahead"] \
+        + snap["engine"]["run_ahead"]["drained"] == steps
+
+
 @pytest.mark.parametrize("step", range(MAX_NEW - 1))
 def test_decode_call_span_says_what_its_attention_reads(lockstep_run, step):
     """``active`` live lanes and ``context_tokens`` cached positions:
@@ -135,8 +203,11 @@ def test_queue_wait_counts_from_submit_to_slot(lockstep_run):
 def test_only_read_counters_are_kept(lockstep_run):
     eng = lockstep_run["snap1"]["engine"]
     # "moe" joins them for a model with expert layers (PR 29)
+    # "run_ahead" (PR 36): decode_run_ahead_pct.backlog reads it
     assert set(eng) == {"loop_s", "prefill", "kv", "stream_stall_ms",
-                        "queue_wait_ms", "select", "fetch_bytes"}
+                        "queue_wait_ms", "select", "fetch_bytes",
+                        "run_ahead"}
+    assert set(eng["run_ahead"]) == {"ahead", "drained", "late_lanes"}
     assert set(eng["prefill"]) == {"prompt_tokens", "padded_tokens",
                                    "split_groups", "by_shape",
                                    "call_s_by_shape"}
@@ -330,9 +401,14 @@ def test_annotation_carries_args_and_late_args(profiled_run):
 
 def test_decode_call_args_reach_the_profile(profiled_run):
     """One stream at a time: every step has one live lane, and the
-    contexts of the session's steps are the hand-counted ones."""
+    contexts of the session's steps are the hand-counted ones. A span
+    carries the arguments of the step it enqueues; a stream's last
+    span enqueues nothing (it harvests the last step) and says
+    nothing."""
     spans = [s for line in profiled_run["lines"].values()
              for n, s in line if n == "engine::decode_call"]
+    assert len(spans) == len(PROMPT_LENS) * MAX_NEW
+    spans = [s for s in spans if "active" in s]
     assert len(spans) == len(PROMPT_LENS) * (MAX_NEW - 1)
     assert {int(s["active"]) for s in spans} == {1}
     assert sorted(int(s["context_tokens"]) for s in spans) == sorted(

@@ -150,23 +150,36 @@ def serve(model, prompts, max_new, **server_kw):
               seq_buckets=[8, 16, 32], start=False)
     kw.update(server_kw)
     srv = GenerationServer(model, **kw)
-    dispatch = srv._dispatch
+    dispatch, enqueue = srv._dispatch, srv._runners[0].enqueue
+
+    def note(seq, row, tokens, logits):
+        seen.setdefault(len(seq.req.prompt), []).append(
+            np.array(logits[row]))
+        assert tokens[row] == logits[row].argmax()
+        # the slot is the cache manager's, in the row's last column
+        assert 0 < seq.state_slot <= srv.max_batch
+        assert srv._tables[seq.slot, -1] == seq.state_slot
+        slots[len(seq.req.prompt)] = seq.state_slot
 
     def spy(kind, feeds, seqs, *args, **kwargs):
+        """A prefill: a sequence's row is its place in the call."""
         ran = dispatch(kind, feeds, seqs, *args, **kwargs)
         logits = np.asarray(ran.logits)
         for i, seq in enumerate(seqs):
-            row = seq.slot if kind == "decode" else i
-            seen.setdefault(len(seq.req.prompt), []).append(
-                np.array(logits[row]))
-            assert ran.tokens[row] == logits[row].argmax()
-            # the slot is the cache manager's, in the row's last column
-            assert 0 < seq.state_slot <= srv.max_batch
-            assert srv._tables[seq.slot, -1] == seq.state_slot
-            slots[len(seq.req.prompt)] = seq.state_slot
+            note(seq, i, ran.tokens, logits)
         return ran
 
-    srv._dispatch = spy
+    def step_spy(kind, feeds):
+        """A decode step, as the loop enqueues it (a step ahead of its
+        harvest): a sequence's row is its lane."""
+        step = enqueue(kind, feeds)
+        if kind == "decode":
+            tokens, logits = np.asarray(step.tokens), np.asarray(step.logits)
+            for lane in np.flatnonzero(feeds[2]):
+                note(srv._slots[lane], lane, tokens, logits)
+        return step
+
+    srv._dispatch, srv._runners[0].enqueue = spy, step_spy
     futures = [srv.submit_generate(p, max_new_tokens=max_new)
                for p in prompts]
     srv.start()

@@ -86,22 +86,35 @@ def serve(model, prompts, max_new, **server_kw):
               seq_buckets=[8, 16, 32], start=False)
     kw.update(server_kw)
     srv = GenerationServer(model, **kw)
-    dispatch = srv._dispatch
+    dispatch, enqueue = srv._dispatch, srv._runners[0].enqueue
+
+    def note(seq, row, tokens, logits):
+        seen.setdefault(len(seq.req.prompt), []).append(
+            np.array(logits[row]))
+        # the program's choice is the first best of that row
+        assert tokens[row] == logits[row].argmax()
+        # a lane never holds more of a window layer than its ring
+        assert len(seq.window_pages) <= srv.kv.ring_pages
 
     def spy(kind, feeds, seqs, *args, **kwargs):
+        """A prefill: a sequence's row is its place in the call."""
         ran = dispatch(kind, feeds, seqs, *args, **kwargs)
         logits = np.asarray(ran.logits)
         for i, seq in enumerate(seqs):
-            row = seq.slot if kind == "decode" else i
-            seen.setdefault(len(seq.req.prompt), []).append(
-                np.array(logits[row]))
-            # the program's choice is the first best of that row
-            assert ran.tokens[row] == logits[row].argmax()
-            # a lane never holds more of a window layer than its ring
-            assert len(seq.window_pages) <= srv.kv.ring_pages
+            note(seq, i, ran.tokens, logits)
         return ran
 
-    srv._dispatch = spy
+    def step_spy(kind, feeds):
+        """A decode step, as the loop enqueues it (a step ahead of its
+        harvest): a sequence's row is its lane."""
+        step = enqueue(kind, feeds)
+        if kind == "decode":
+            tokens, logits = np.asarray(step.tokens), np.asarray(step.logits)
+            for lane in np.flatnonzero(feeds[2]):
+                note(srv._slots[lane], lane, tokens, logits)
+        return step
+
+    srv._dispatch, srv._runners[0].enqueue = spy, step_spy
     futures = [srv.submit_generate(p, max_new_tokens=max_new)
                for p in prompts]
     srv.start()
