@@ -7,6 +7,7 @@ reference's HostTracer + CudaTracer pair.
 from __future__ import annotations
 
 import contextlib
+import gc
 import json
 import os
 import threading
@@ -144,13 +145,16 @@ class RecordEvent:
     the serving layer's ``{"rows": 8, "padded": 8}``) lands in the
     chrome-trace event's ``args`` field and in the annotation's stats, and
     can be extended during the span via ``set_arg`` — the serving
-    pipeline stamps measured stage times onto its spans this way."""
+    pipeline stamps measured stage times onto its spans this way. After
+    ``end`` ``elapsed_s`` is the span's length by the same two clock
+    readings the host tracer records, for a caller that counts it too."""
 
     def __init__(self, name, event_type=None, args=None):
         self.name = name
         self.args = dict(args) if args else None
         self._annotation = None
         self._start = None
+        self.elapsed_s = 0.0
 
     def set_arg(self, key, value):
         if self.args is None:
@@ -177,6 +181,7 @@ class RecordEvent:
             self._annotation = None
         if self._start is not None:
             end = time.perf_counter_ns()
+            self.elapsed_s = (end - self._start) / 1e9
             _tracer.add(self.name, self._start, end,
                         threading.get_ident(), self.args)
             sink = _span_sink
@@ -193,6 +198,49 @@ class RecordEvent:
     def __exit__(self, *exc):
         self.end()
         return False
+
+
+class _FullCollections:
+    """``gc.callbacks`` hook: a ``python::gc`` annotation round each
+    collection of the oldest generation, on the collecting thread's line
+    of the trace; the younger generations return at once. A bare
+    ``TraceAnnotation``, not a ``RecordEvent``: a collection runs between
+    any two bytecodes, also where this thread holds the host tracer's or
+    a metric's lock, and the annotation takes no Python lock.
+    Collections never overlap, so one open annotation is all there is."""
+
+    def __init__(self):
+        self._annotation = None
+
+    def __call__(self, phase, info):
+        if info["generation"] != 2:
+            return
+        try:
+            if phase == "start":
+                self._begin()
+            else:
+                self._end()
+        except Exception:  # noqa: BLE001 - telemetry must never fail a
+            self._annotation = None    # collection
+
+    def _begin(self):
+        self._annotation = jax.profiler.TraceAnnotation("python::gc")
+        self._annotation.__enter__()
+
+    def _end(self):
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
+
+
+_on_gc = _FullCollections()
+
+
+def trace_full_collections():
+    """Register ``_on_gc`` once a process (every ``GenerationServer``
+    asks)."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
 
 
 def make_scheduler(*, closed: int, ready: int, record: int, repeat: int = 0,

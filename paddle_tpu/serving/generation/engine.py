@@ -62,7 +62,7 @@ from typing import (Callable, Dict, List, NamedTuple, Optional, Sequence,
 import numpy as np
 
 from ...observability import tracing
-from ...profiler import RecordEvent
+from ...profiler import RecordEvent, trace_full_collections
 from ..bucketing import ShapeBucketPolicy
 from ..request import (DeadlineExceededError, QueueFullError,
                        QuotaExceededError, ServerClosedError)
@@ -465,8 +465,13 @@ class DecodeMetrics:
         # cumulative since the server started: the difference of two
         # snapshots is exact for any window
         self._loop_s = dict.fromkeys(PHASES, 0.0)
+        # the loop thread's CPU time by phase: under the wall time by
+        # what it spent waiting for the GIL, a core or a lock
+        self._loop_cpu_s = dict.fromkeys(PHASES, 0.0)
         self._phase: Optional[str] = None     # the open phase
         self._phase_t0 = 0.0                  # perf_counter at its start
+        self._phase_cpu0 = 0.0                # thread_time at its start
+        self._cpu_clock = None                # the loop thread's clock
         self._prefill = {"prompt_tokens": 0, "padded_tokens": 0,
                          "split_groups": 0}
         self._prefill_by_shape: Dict[str, int] = {}
@@ -478,23 +483,31 @@ class DecodeMetrics:
         # (``PagedKVCache.by_kind``), as of the last admission, release
         # or decode step
         self._kv_by_kind: Dict[str, dict] = {}
-        # who chose the tokens of each program traffic ran, and what
-        # its runner brought to the host
-        self._select = {"in_program": 0, "on_host": 0}
+        # what the runners brought to the host for traffic's programs
         self._fetch_bytes = 0
+        # the target's programs of traffic by kind: how many were
+        # enqueued and harvested, and the host's seconds in each half
+        # (``launch_s`` the executable's call inside the enqueue)
+        self._dispatch: Dict[str, Dict[str, float]] = {}
         # how the loop ran ahead: decode programs enqueued while their
         # predecessor was unharvested / on an empty pipe, and lane-steps
         # computed for a lane that had ended by the time of the harvest
         self._run_ahead = {"ahead": 0, "drained": 0, "late_lanes": 0}
 
-    def switch_phase(self, phase: Optional[str], now: float):
+    def switch_phase(self, phase: Optional[str], now: float, cpu: float):
         """The loop thread leaves its open phase at ``now`` (a
-        ``perf_counter`` reading) and enters ``phase``; None closes the
-        timeline (the loop ended)."""
+        ``perf_counter`` reading) and ``cpu`` (its ``thread_time``) and
+        enters ``phase``; None closes the timeline (the loop ended)."""
         with self._lock:
             if self._phase is not None:
                 self._loop_s[self._phase] += now - self._phase_t0
-            self._phase, self._phase_t0 = phase, now
+                self._loop_cpu_s[self._phase] += cpu - self._phase_cpu0
+            elif phase is not None:
+                # the timeline opens on the loop thread: its CPU clock,
+                # for a snapshot taken on another thread
+                self._cpu_clock = time.pthread_getcpuclockid(
+                    threading.get_ident())
+            self._phase, self._phase_t0, self._phase_cpu0 = phase, now, cpu
 
     def observe_prefill_dispatch(self, padded_rows: int, seq_bucket: int,
                                  prompt_tokens: int, call_ms: float):
@@ -533,13 +546,33 @@ class DecodeMetrics:
                 m[key] += int(aux.get("moe_" + key,
                                       aux["moe_assignments"]))
 
-    def observe_fetch(self, nbytes: int, host_logits: bool):
-        """One program run fetched ``nbytes``: its tokens, chosen in
-        the program, or (``host_logits``) the logits for the host to
-        choose from."""
+    def observe_fetch(self, nbytes: int):
+        """One program run fetched ``nbytes``."""
         with self._lock:
-            self._select["on_host" if host_logits else "in_program"] += 1
             self._fetch_bytes += int(nbytes)
+
+    def _dispatch_of(self, kind: str) -> Dict[str, float]:
+        return self._dispatch.setdefault(kind, {
+            "enqueued": 0, "enqueue_s": 0.0, "launch_s": 0.0,
+            "harvested": 0, "harvest_s": 0.0})
+
+    def observe_enqueue(self, kind: str, enqueue_s: float,
+                        launch_s: float):
+        """One of the target's ``kind`` programs was enqueued in
+        ``enqueue_s``, ``launch_s`` of it the executable's call."""
+        with self._lock:
+            d = self._dispatch_of(kind)
+            d["enqueued"] += 1
+            d["enqueue_s"] += enqueue_s
+            d["launch_s"] += launch_s
+
+    def observe_harvest(self, kind: str, harvest_s: float):
+        """One of the target's ``kind`` programs was harvested after a
+        wait of ``harvest_s``."""
+        with self._lock:
+            d = self._dispatch_of(kind)
+            d["harvested"] += 1
+            d["harvest_s"] += harvest_s
 
     def observe_run_ahead(self, key: str, n: int = 1):
         """``n`` more of ``engine.run_ahead[key]``."""
@@ -564,19 +597,21 @@ class DecodeMetrics:
 
     def _engine_snapshot(self) -> dict:
         """The ``"engine"`` section (lock held)."""
-        loop_s = dict(self._loop_s)
+        loop_s, loop_cpu_s = dict(self._loop_s), dict(self._loop_cpu_s)
         if self._phase is not None:
             # the open phase so far: the phases then sum to the loop
-            # thread's wall time at this very moment
+            # thread's wall and CPU time at this very moment
             loop_s[self._phase] += time.perf_counter() - self._phase_t0
-        out = {"loop_s": loop_s,
+            loop_cpu_s[self._phase] += time.clock_gettime(
+                self._cpu_clock) - self._phase_cpu0
+        out = {"loop_s": loop_s, "loop_cpu_s": loop_cpu_s,
                "prefill": dict(self._prefill,
                                by_shape=dict(self._prefill_by_shape),
                                call_s_by_shape=dict(
                                    self._prefill_call_s_by_shape)),
                "kv": self._kv_by_kind,
-               "select": dict(self._select),
                "fetch_bytes": self._fetch_bytes,
+               "dispatch": {k: dict(v) for k, v in self._dispatch.items()},
                "run_ahead": dict(self._run_ahead),
                "stream_stall_ms": self._cumulative(self._h_stall),
                "queue_wait_ms": self._cumulative(self._h_qwait)}
@@ -878,6 +913,7 @@ class GenerationServer:
         self._worker: Optional[threading.Thread] = None
         self._steps = 0
         self._span: Optional[RecordEvent] = None   # the open phase's
+        trace_full_collections()       # python::gc spans, once a process
         # the decode step enqueued and not yet harvested: the loop
         # thread's alone, like _span and _steps (the loop ends with
         # nothing in flight: it drains before it aborts)
@@ -1336,7 +1372,7 @@ class GenerationServer:
         if self._span is not None:
             self._span.end()
         now = time.perf_counter()
-        self.metrics.switch_phase(phase, now)
+        self.metrics.switch_phase(phase, now, time.thread_time())
         self._span = None
         if phase is not None:
             self._span = RecordEvent("engine::" + phase, args=args)
@@ -1713,16 +1749,19 @@ class GenerationServer:
         ``stage``, ``engine::bookkeeping`` opens at the second clock
         reading and the time goes to ``step_ms[stage]``. ``record=False``
         is a warmup: it has no request to fail (the error is the
-        caller's), enters no manifest and counts no expert and no
-        fetch."""
+        caller's), enters no manifest and counts no expert, no fetch and
+        no dispatch."""
         t_wall, t0 = since or (time.time_ns(), time.perf_counter())
         target, ran = self._runners[0], []
         try:
             for runner in runners:
-                run = runner.run(kind, feeds, host_logits)
+                step = runner.enqueue(kind, feeds)
+                if record:
+                    self._observe_enqueue(kind, runner, step)
+                run = runner.harvest(step, host_logits)
                 ran.append(run)
                 if record:
-                    self._observe_run(kind, runner, run, host_logits)
+                    self._observe_run(kind, runner, run)
         except Exception as e:  # noqa: BLE001 - the fault barrier
             if not record:
                 raise
@@ -1740,12 +1779,23 @@ class GenerationServer:
         return _Ran(ran[0].tokens, ran[0].logits, ms, t_wall,
                     [run.fresh for run in ran])
 
-    def _observe_run(self, kind: str, runner: ProgramRunner, run,
-                     host_logits: bool = False):
+    def _observe_enqueue(self, kind: str, runner: ProgramRunner,
+                         step: Enqueued):
+        """What one enqueued program of traffic leaves in the counters:
+        the target's enqueue time (``engine.dispatch``)."""
+        if runner is self._runners[0]:
+            self.metrics.observe_enqueue(kind, step.enqueue_s,
+                                         step.launch_s)
+
+    def _observe_run(self, kind: str, runner: ProgramRunner, run):
         """What one harvested program of traffic leaves in the
-        counters: its fetch, and the target's expert counts."""
-        self.metrics.observe_fetch(run.fetched_bytes, host_logits)
-        if runner is self._runners[0] and run.aux:
+        counters: its fetch, and the target's wait for it and expert
+        counts."""
+        self.metrics.observe_fetch(run.fetched_bytes)
+        if runner is not self._runners[0]:
+            return
+        self.metrics.observe_harvest(kind, run.harvest_s)
+        if run.aux:
             self.metrics.observe_moe(run.aux)
             if kind == "decode":
                 # what the harvested step's expert layers read, on the
@@ -1930,7 +1980,9 @@ class GenerationServer:
         returned" and carries the arguments of the step it enqueues
         (none where it enqueues nothing): that program starts when its
         predecessor ends, inside this span. ``step_ms["decode"]`` is
-        that span's time, for every step harvested."""
+        that span's time, for every step harvested. The runner's
+        ``runner::enqueue`` and ``runner::harvest`` spans split it, and
+        ``engine.dispatch["decode"]`` sums their seconds."""
         self._enter_phase("decode_feeds")
         target, flying = self._runners[0], self._inflight
         riding = {id(s) for s in flying.seqs} if flying else ()
@@ -1966,8 +2018,9 @@ class GenerationServer:
                         for s in held:
                             fresh[s.slot] = s.last_token
                         tokens = self.decoder.lane_tokens(tokens, fresh)
-                self._inflight = _Step(
-                    target.enqueue("decode", (tokens,) + feeds[1:]), lanes)
+                step = target.enqueue("decode", (tokens,) + feeds[1:])
+                self._inflight = _Step(step, lanes)
+                self._observe_enqueue("decode", target, step)
                 self.metrics.observe_run_ahead(
                     "drained" if flying is None else "ahead")
             if flying is not None:

@@ -58,6 +58,8 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from ...profiler import RecordEvent
+
 __all__ = ["CachedDecoder", "supports_cached_decode"]
 
 
@@ -153,6 +155,9 @@ class CachedDecoder:
         # device scalars; {} for a model without experts, and after a
         # chunked prefill or a verify step, which count nothing)
         self.last_aux: dict = {}
+        # the last program's executable call, on the host's clock: the
+        # ``decoder::launch`` span's length
+        self.last_launch_s = 0.0
         # per-signature AOT memo; False marks "tried, unavailable"
         self._aot: Dict[tuple, object] = {}
         self.compiled_signatures = set()    # (site, shape-sig) seen
@@ -592,14 +597,19 @@ class CachedDecoder:
             pass
 
     def _dispatch(self, site: str, jitted, args) -> Tuple[object, bool]:
-        """Returns ``(outputs, was_new_signature)``."""
+        """Returns ``(outputs, was_new_signature)``. The executable's
+        call is the ``decoder::launch`` span; ``last_launch_s`` its
+        length."""
         sig = (site,) + self._sig_of(args)
         fresh = sig not in self.compiled_signatures
         self.compiled_signatures.add(sig)
         shadow_out = self._numerics_shadow(site, args)
         aot = self._aot_exec(site, jitted, args)
         fn = aot or jitted
-        out = fn(*args)
+        span = RecordEvent("decoder::launch")
+        with span:
+            out = fn(*args)
+        self.last_launch_s = span.elapsed_s
         self._xstats_note(site, sig, jitted, args, aot is not None)
         self._numerics_note(site, out, shadow_out)
         return out, fresh

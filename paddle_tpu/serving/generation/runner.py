@@ -22,12 +22,21 @@ its predecessor returned, and an ``Enqueued``'s ``tokens`` are a valid
 harvest-of-enqueue, for the callers that need the result before they
 can form the next program (a prefill, a verify step, a draft's steps,
 a warm-up).
+
+Each half is a ``profiler.RecordEvent`` on the calling thread, so on the
+trace's clock: ``runner::enqueue`` round the call and the swap (inside
+it the decoder's ``decoder::launch`` round the executable's own call)
+and ``runner::harvest`` round the ``device_get``. Their lengths ride on
+what each half returns (``enqueue_s``, ``launch_s``, ``harvest_s``) for
+the engine to count; the runner keeps no counter.
 """
 from __future__ import annotations
 
 from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
+
+from ...profiler import RecordEvent
 
 __all__ = ["Enqueued", "ProgramRunner", "Run", "SITES"]
 
@@ -46,12 +55,16 @@ class Enqueued(NamedTuple):
     ``aux`` the expert counters as device scalars; and two host facts,
     ``fresh`` (the decoder saw this signature for the first time) and
     ``signature``, the feeds' ``(shape, dtype)`` list as a warmup
-    manifest records it."""
+    manifest records it; ``enqueue_s`` the host's time in ``enqueue``,
+    ``launch_s`` the part of it in the executable's call (the runtime's
+    argument handling, transfers and launch)."""
     tokens: object
     logits: object
     aux: dict
     fresh: bool
     signature: List[Tuple[tuple, str]]
+    enqueue_s: float
+    launch_s: float
 
 
 class Run(NamedTuple):
@@ -60,13 +73,15 @@ class Run(NamedTuple):
     ``logits`` its logits (a device array; numpy where the caller asked
     for them on the host), ``aux`` the expert counters as host numbers,
     ``fresh`` and ``signature`` as ``Enqueued`` has them,
-    ``fetched_bytes`` what came to the host."""
+    ``fetched_bytes`` what came to the host, ``harvest_s`` the host's
+    wait for it."""
     tokens: Optional[np.ndarray]
     logits: object
     aux: dict
     fresh: bool
     signature: List[Tuple[tuple, str]]
     fetched_bytes: int
+    harvest_s: float
 
 
 class ProgramRunner:
@@ -90,13 +105,15 @@ class ProgramRunner:
         replaced it). The pools are replaced as soon as the call
         returns: they were donated, so the old ones are gone whether
         or not the program, or its harvest, succeeds."""
-        pools = self.pools
-        *chosen, logits, k, v, fresh = getattr(self.decoder, kind)(
-            *feeds, pools.k, pools.v)
-        pools.k, pools.v = k, v
+        pools, span = self.pools, RecordEvent("runner::enqueue")
+        with span:
+            *chosen, logits, k, v, fresh = getattr(self.decoder, kind)(
+                *feeds, pools.k, pools.v)
+            pools.k, pools.v = k, v
+            signature = [(tuple(a.shape), str(a.dtype)) for a in feeds]
         return Enqueued(chosen[0] if chosen else None, logits,
-                        self.decoder.last_aux, bool(fresh),
-                        [(tuple(a.shape), str(a.dtype)) for a in feeds])
+                        self.decoder.last_aux, bool(fresh), signature,
+                        span.elapsed_s, self.decoder.last_launch_s)
 
     def harvest(self, enqueued: Enqueued,
                 host_logits: bool = False) -> Run:
@@ -107,10 +124,13 @@ class ProgramRunner:
         want = {"tokens": enqueued.tokens, "aux": enqueued.aux}
         if host_logits:
             want["logits"] = enqueued.logits
-        got = jax.device_get(want)
+        span = RecordEvent("runner::harvest")
+        with span:
+            got = jax.device_get(want)
         return Run(got["tokens"], got.get("logits", enqueued.logits),
                    got["aux"], enqueued.fresh, enqueued.signature,
-                   sum(a.nbytes for a in jax.tree_util.tree_leaves(got)))
+                   sum(a.nbytes for a in jax.tree_util.tree_leaves(got)),
+                   span.elapsed_s)
 
     def run(self, kind: str, feeds: Sequence,
             host_logits: bool = False) -> Run:

@@ -402,7 +402,8 @@ class TestSelectionInTheProgram:
             want = self_reference(m, cfg, [5, 7, 9], 6)
             assert srv.generate([5, 7, 9], max_new_tokens=6) == want
             eng = srv.metrics_snapshot()["engine"]
-            assert eng["select"] == {"in_program": 6, "on_host": 0}
+            # no logits came back: six programs' fetches are under a row
+            assert eng["fetch_bytes"] < cfg.vocab_size * 4
             # one prefill row, then five steps of four lanes
             assert eng["fetch_bytes"] == 4 * (1 + 5 * 4)
             assert eng["fetch_bytes"] <= 6 * srv.max_batch * 8
@@ -426,21 +427,27 @@ class TestSelectionInTheProgram:
         with GenerationServer(m, max_batch=2, page_size=8, draft_model=m,
                               spec_k=3, name="spec-select") as srv:
             assert srv.generate([5, 7, 9], max_new_tokens=8) == want
-            greedy = srv.metrics_snapshot()["engine"]["select"]
-            # the verify steps alone: every draft step chose in program
+            greedy = srv.metrics_snapshot()["engine"]["fetch_bytes"]
+            # the verify steps' logits alone: every draft step chose in
+            # program and brought its lanes' ids (so did both prefills)
             spec = srv.metrics_snapshot()["spec"]
-            assert greedy["on_host"] * 3 == spec["proposed"]
-            assert greedy["in_program"] >= 2 + 3 * greedy["on_host"]
+            verify_bytes = 4 * srv.max_batch * (srv.spec_k + 1) \
+                * cfg.vocab_size
+            draft_bytes = 4 * srv.max_batch * cfg.vocab_size
+            assert greedy // verify_bytes * 3 == spec["proposed"]
+            assert greedy % verify_bytes >= 4 * (
+                2 + srv.max_batch * spec["proposed"])
             a = srv.generate([5, 7, 9], max_new_tokens=8,
                              temperature=0.8, seed=3)
             b = srv.generate([5, 7, 9], max_new_tokens=8,
                              temperature=0.8, seed=3)
             assert a == b and len(a) == 8
-            sampled = srv.metrics_snapshot()["engine"]["select"]
+            sampled = srv.metrics_snapshot()["engine"]["fetch_bytes"]
             verifies = (srv.metrics_snapshot()["spec"]["proposed"]
                         - spec["proposed"]) // 3
             # now the three draft steps of a round fetch logits too
-            assert sampled["on_host"] - greedy["on_host"] == 4 * verifies
+            assert (sampled - greedy) // (
+                verify_bytes + 3 * draft_bytes) == verifies
 
 
 def self_reference(m, cfg, prompt, n):

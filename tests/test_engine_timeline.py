@@ -4,6 +4,7 @@ the profiler's clock, cumulative phase sums and dispatch counters in
 difference of two snapshots, and the named scopes round paged and
 flash attention."""
 import bisect
+import gc
 import glob
 import os
 import re
@@ -56,7 +57,9 @@ def lockstep_run():
     """Four prompts queued BEFORE the loop starts, so the first
     iteration admits all of them: two prefill groups (buckets 8 and
     16, two rows each), then four decode iterations in lockstep. The
-    spans as the in-process tracer keeps them, with their arguments."""
+    spans as the in-process tracer keeps them, with their arguments;
+    all of them Python-side (the native recorder would keep those
+    without arguments apart, under the kernel's thread ids)."""
     from paddle_tpu import profiler
     model = make_model()
     srv = make_server(model)
@@ -65,15 +68,24 @@ def lockstep_run():
             for p in prompts()]
     time.sleep(QUEUED_S)
     snap0 = srv.metrics_snapshot()
-    with profiler.Profiler(timer_only=True):
-        srv.start()
-        tokens = [f.result(120) for f in futs]
-        snap1 = srv.metrics_snapshot()
-        srv.shutdown()
-        calls = [e["args"] for e in profiler._tracer.events
-                 if e["name"] == "engine::decode_call"]
+    tracer, kept = profiler._HostTracer(), profiler._tracer
+    tracer._native = False
+    profiler._tracer = tracer
+    try:
+        with profiler.Profiler(timer_only=True):
+            srv.start()
+            tokens = [f.result(120) for f in futs]
+            snap1 = srv.metrics_snapshot()
+            srv.shutdown()
+    finally:
+        profiler._tracer = kept
+    # (a span that enqueues nothing carries no arguments)
+    calls = [e["args"] for e in tracer.events
+             if e["name"] == "engine::decode_call" and "args" in e]
+    spans = [(e["name"], e["ts"], e["ts"] + e["dur"], e["tid"])
+             for e in tracer.events]
     return {"model": model, "tokens": tokens, "snap0": snap0,
-            "snap1": snap1, "decode_calls": calls}
+            "snap1": snap1, "decode_calls": calls, "spans": spans}
 
 
 # every prompt's first token comes from its prefill, the other four
@@ -90,11 +102,16 @@ HAND_COUNTED = {
     "engine.queue_wait_ms.counts.-1": len(PROMPT_LENS),
     # the first iteration began with no live stream: it stalls nobody
     "engine.stream_stall_ms.counts.-1": MAX_NEW - 2,
-    # every program chose its rows' tokens: two prefill groups and the
-    # decode steps, and an int32 a row is all that came to the host
-    # (two rows a prefill, four lanes a step; no expert counts)
-    "engine.select": {"in_program": 2 + MAX_NEW - 1, "on_host": 0},
+    # every program chose its rows' tokens: an int32 a row is all that
+    # came to the host (two rows a prefill, four lanes a step; no
+    # expert counts)
     "engine.fetch_bytes": 4 * (2 * 2 + (MAX_NEW - 1) * 4),
+    # the two prefill groups and the decode steps, each enqueued and
+    # harvested once; the warm-up's programs are not counted
+    "engine.dispatch.prefill.enqueued": 2,
+    "engine.dispatch.prefill.harvested": 2,
+    "engine.dispatch.decode.enqueued": MAX_NEW - 1,
+    "engine.dispatch.decode.harvested": MAX_NEW - 1,
 }
 
 
@@ -104,10 +121,15 @@ def test_engine_counters_equal_hand_counted(lockstep_run, key):
     before = lockstep_run["snap0"]
     for part in key.split("."):
         part = int(part) if part.lstrip("-").isdigit() else part
-        got, before = got[part], before[part]
+        got = got[part]
+        # (a kind of program the server has not run has no entry yet)
+        if isinstance(before, dict):
+            before = before.get(part, 0)
+        elif before != 0:
+            before = before[part]
     assert got == HAND_COUNTED[key]
     # cumulative since the server started
-    assert before in (0, {}, {"in_program": 0, "on_host": 0})
+    assert before in (0, {})
 
 
 def test_decode_call_span_carries_the_enqueued_steps_arguments():
@@ -204,9 +226,15 @@ def test_only_read_counters_are_kept(lockstep_run):
     eng = lockstep_run["snap1"]["engine"]
     # "moe" joins them for a model with expert layers (PR 29)
     # "run_ahead" (PR 36): decode_run_ahead_pct.backlog reads it
-    assert set(eng) == {"loop_s", "prefill", "kv", "stream_stall_ms",
-                        "queue_wait_ms", "select", "fetch_bytes",
-                        "run_ahead"}
+    # "loop_cpu_s" and "dispatch" (PR 37): engine_host_cpu_pct and the
+    # decode_enqueue/launch/harvest_wait readers read them
+    assert set(eng) == {"loop_s", "loop_cpu_s", "prefill", "kv",
+                        "stream_stall_ms", "queue_wait_ms", "fetch_bytes",
+                        "dispatch", "run_ahead"}
+    assert set(eng["dispatch"]) == {"prefill", "decode"}
+    assert all(set(d) == {"enqueued", "enqueue_s", "launch_s",
+                          "harvested", "harvest_s"}
+               for d in eng["dispatch"].values())
     assert set(eng["run_ahead"]) == {"ahead", "drained", "late_lanes"}
     assert set(eng["prefill"]) == {"prompt_tokens", "padded_tokens",
                                    "split_groups", "by_shape",
@@ -228,6 +256,77 @@ def test_prefill_call_time_is_kept_by_shape(lockstep_run):
     assert sum(by_shape.values()) <= eng["loop_s"]["prefill"]
     assert lockstep_run["snap0"]["engine"]["prefill"][
         "call_s_by_shape"] == {}
+
+
+# ------------------------- inside the decode call (PR 37): the halves
+def test_dispatch_counts_what_the_loop_enqueued_and_harvested(lockstep_run):
+    snap = lockstep_run["snap1"]
+    decode, ahead = snap["engine"]["dispatch"]["decode"], \
+        snap["engine"]["run_ahead"]
+    assert decode["enqueued"] == ahead["ahead"] + ahead["drained"]
+    assert decode["harvested"] == snap["batch_occupancy"]["steps"]
+
+
+@pytest.mark.parametrize("kind", ["prefill", "decode"])
+def test_the_launch_lies_inside_the_enqueue(lockstep_run, kind):
+    d = lockstep_run["snap1"]["engine"]["dispatch"][kind]
+    assert 0 <= d["launch_s"] <= d["enqueue_s"]
+    assert d["harvest_s"] >= 0
+
+
+@pytest.mark.parametrize("kind,phase", [("prefill", "prefill"),
+                                        ("decode", "decode_call")])
+def test_the_halves_lie_inside_their_phase(lockstep_run, kind, phase):
+    eng = lockstep_run["snap1"]["engine"]
+    d = eng["dispatch"][kind]
+    assert d["enqueue_s"] + d["harvest_s"] <= eng["loop_s"][phase]
+
+
+def test_warmup_adds_nothing(lockstep_run):
+    """The warm-up ran every program the traffic runs, and none of
+    them is counted."""
+    eng = lockstep_run["snap0"]["engine"]
+    assert eng["dispatch"] == {} and eng["fetch_bytes"] == 0
+
+
+@pytest.mark.parametrize("phase", PHASES)
+def test_loop_cpu_time_is_at_most_its_wall_time(lockstep_run, phase):
+    """Each phase's CPU time, the open one's too (snap1 is taken while
+    the loop runs, on another thread), is no larger than its wall
+    time, give or take the two clocks' readings."""
+    for snap in (lockstep_run["snap0"], lockstep_run["snap1"]):
+        eng = snap["engine"]
+        assert set(eng["loop_cpu_s"]) == set(eng["loop_s"])
+        assert 0 <= eng["loop_cpu_s"][phase] <= eng["loop_s"][phase] + 1e-3
+
+
+def test_runner_spans_nest_in_the_engine_phases(lockstep_run):
+    """Every ``runner::enqueue`` starts inside an ``engine::decode_call``
+    (a decode step) or an ``engine::prefill`` span and holds one
+    ``decoder::launch``; every ``runner::harvest`` starts inside one of
+    the two too. All on the loop's thread."""
+    spans = lockstep_run["spans"]
+    dispatch = lockstep_run["snap1"]["engine"]["dispatch"]
+
+    def named(name):
+        return [s for s in spans if s[0] == name]
+
+    def held(events, phase):
+        holders = named("engine::" + phase)
+        return [e for e in events
+                if any(h[1] <= e[1] < h[2] for h in holders)]
+    enqueues, harvests = named("runner::enqueue"), named("runner::harvest")
+    for kind, phase in (("decode", "decode_call"), ("prefill", "prefill")):
+        assert len(held(enqueues, phase)) == dispatch[kind]["enqueued"]
+        assert len(held(harvests, phase)) == dispatch[kind]["harvested"]
+    assert len(enqueues) == len(harvests) == 2 + MAX_NEW - 1
+    launches = named("decoder::launch")
+    for e in enqueues:
+        assert sum(1 for ln in launches
+                   if e[1] <= ln[1] and ln[2] <= e[2]) == 1
+    assert len(launches) == len(enqueues)
+    assert len({s[3] for s in enqueues + harvests
+                + named("engine::decode_call")}) == 1
 
 
 @pytest.mark.parametrize("i", range(len(PROMPT_LENS)))
@@ -358,6 +457,7 @@ def profiled_run(tmp_path_factory):
             ev.set_arg("late", 7)
             tokens = [srv.generate(p, max_new_tokens=MAX_NEW)
                       for p in prompts()]
+            gc.collect()                      # a python::gc span here
         time.sleep(0.3)                       # some engine::wait
     finally:
         jax.profiler.stop_trace()
@@ -397,6 +497,34 @@ def test_annotation_carries_args_and_late_args(profiled_run):
     outer_line = next(k for k, v in profiled_run["lines"].items()
                       if any(n == "test::outer" for n, _ in v))
     assert engine_line != outer_line
+
+
+@pytest.mark.parametrize("name", ["runner::enqueue", "decoder::launch",
+                                  "runner::harvest"])
+def test_profile_holds_the_runner_spans_on_the_engines_line(profiled_run,
+                                                            name):
+    line = next(v for v in profiled_run["lines"].values()
+                if any(n.startswith("engine::") for n, _ in v))
+    assert name in {n for n, _ in line}
+
+
+def test_a_full_collection_is_a_span_on_the_collecting_thread(
+        profiled_run):
+    """``gc.collect()`` inside ``test::outer``: its ``python::gc`` span
+    lies on that span's line."""
+    line = next(v for v in profiled_run["lines"].values()
+                if any(n == "test::outer" for n, _ in v))
+    assert "python::gc" in {n for n, _ in line}
+
+
+def test_younger_generations_open_no_gc_span():
+    from paddle_tpu import profiler
+    profiler._on_gc("start", {"generation": 0})
+    profiler._on_gc("start", {"generation": 1})
+    assert profiler._on_gc._annotation is None
+    profiler.trace_full_collections()           # as every server asks
+    profiler.trace_full_collections()
+    assert gc.callbacks.count(profiler._on_gc) == 1
 
 
 def test_decode_call_args_reach_the_profile(profiled_run):
