@@ -754,10 +754,11 @@ class GPTExpertMLP(Layer):
 
     def forward(self, x, router_in, valid=None):
         """x, router_in: [B, S, H]; valid: [B, S] bool or None. Returns
-        ``(out [B, S, H], stats int32 [3 or 4])``: assignments, held
+        ``(out [B, S, H], stats int32 [3 or 6])``: assignments, held
         experts touched, the fullest held expert's rows and, where this
         layer holds a share of the experts, the assignments its own
-        experts computed (all of them otherwise)."""
+        experts computed, and whether its grouped products were handed
+        the capacity's rows (``narrow_calls``) or all (``wide_calls``)."""
         import jax.numpy as jnp
 
         from ..ops.moe import dropless_moe
@@ -774,7 +775,8 @@ class GPTExpertMLP(Layer):
             counted = [stats["assignments"], stats["experts_touched"],
                        stats["max_expert_load"]]
             if share:
-                counted.append(stats["local_assignments"])
+                counted += [stats["local_assignments"],
+                            stats["narrow_calls"], stats["wide_calls"]]
             return out.reshape(b, s, h), jnp.stack(counted).astype(
                 jnp.int32)
 

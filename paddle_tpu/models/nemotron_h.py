@@ -392,7 +392,7 @@ class NemotronHExperts(Layer):
     def forward(self, u, valid=None, decode=False):
         """u: [B, S, H]; valid: [B, S] bool or None; ``decode``: the
         rows are a decode step's lanes. Returns ``(out [B, S, H], stats
-        int32 [3 or 4])`` as ``GPTExpertMLP`` does."""
+        int32 [3 or 6])`` as ``GPTExpertMLP`` does."""
         from ..ops.moe import dropless_moe
         options, share = self.options, self.share
         # lanes that hand every expert of the router a row or more in
@@ -425,7 +425,10 @@ class NemotronHExperts(Layer):
             counted = [stats["assignments"], stats["experts_touched"],
                        stats["max_expert_load"]]
             if share:
-                counted.append(stats["local_assignments"])
+                # every held expert computed sorts no rows: neither count
+                counted += [stats["local_assignments"],
+                            stats.get("narrow_calls", 0),
+                            stats.get("wide_calls", 0)]
             return out.reshape(b, s, h), jnp.stack(counted).astype(
                 jnp.int32)
 

@@ -22,6 +22,16 @@ not. What the absent experts would add is left out: the sum over the
 shares of a layer is the layer. There is no exchange here: on one chip
 the layer runs without it.
 
+XLA's grouped matmul pays for every row it is handed, in a group or
+not (PERF.md section 6, PR 33), so a share hands its products only the
+first ``cap`` sorted rows, twice what even routing gives its experts
+(``2 * T * top_k * held / E_all`` rounded up to whole tiles of 128, at
+most ``T * top_k``), where its experts got no more rows than that;
+else all ``T * top_k``, so the layer stays dropless (``jax.lax.cond``,
+opened outside the scopes so that each branch's operations carry
+``moe/...`` as before). A layer that holds every expert has no
+capacity and no branch.
+
 Precision: the router's logits, its scores and the weighted sum are
 float32; the products run in the weights' type with float32
 accumulation.
@@ -106,7 +116,10 @@ def dropless_moe(x, router_in, w_router, w_gate, w_up, w_down, *,
     numbers of this call: ``assignments`` (valid rows x top_k, wherever
     their experts live), ``local_assignments`` (those of them the
     experts held here computed), ``experts_touched`` (held experts that
-    got a row) and ``max_expert_load`` (rows of the fullest of them).
+    got a row) and ``max_expert_load`` (rows of the fullest of them);
+    a share's also ``narrow_calls`` (1 where the products were handed
+    ``cap`` rows; with ``token_block``, in every block) and
+    ``wide_calls`` (1 where they were handed all of them).
     """
     t, hidden = x.shape
     n_held, n_all = w_up.shape[0], w_router.shape[1]
@@ -121,8 +134,15 @@ def dropless_moe(x, router_in, w_router, w_gate, w_up, w_down, *,
     act = _ACTIVATIONS[activation]
 
     def block(x, router_in, valid):
-        """``(out [t, H], group_sizes [E])`` of ``t`` rows."""
+        """``(out [t, H], group_sizes [E], narrow)`` of ``t`` rows:
+        ``narrow`` (a share's) is 1 where the grouped products were
+        handed the first ``cap`` sorted rows alone, else 0."""
         t = x.shape[0]
+        n = t * top_k
+        # a share's capacity: twice the rows even routing gives its
+        # experts, in whole tiles of 128 rows
+        cap = n if whole else min(
+            n, -(-2 * n * n_held // (n_all * 128)) * 128)
         with jax.named_scope("moe"):
             with jax.named_scope("route"):
                 if scoring == "softmax_top_k":
@@ -140,51 +160,82 @@ def dropless_moe(x, router_in, w_router, w_gate, w_up, w_down, *,
                     # past every expert: sorted last, counted in no group
                     experts = jnp.where(valid[:, None], experts, n_held)
             with jax.named_scope("dispatch"):
-                flat = experts.reshape(t * top_k)
+                flat = experts.reshape(n)
                 order = jnp.argsort(flat, stable=True)
-                rows = x[order // top_k]                   # [T*k, H]
+                if cap == n:
+                    rows = x[order // top_k]                   # [T*k, H]
                 group_sizes = jnp.bincount(
                     flat, length=n_held + 1)[:n_held].astype(jnp.int32)
-            with jax.named_scope("experts"):
-                if w_gate is None:
-                    act_rows = act(jax.lax.ragged_dot(
-                        rows, w_up, group_sizes)).astype(x.dtype)
-                else:
-                    gate = jax.lax.ragged_dot(rows, w_gate, group_sizes)
-                    up = jax.lax.ragged_dot(rows, w_up, group_sizes)
-                    act_rows = (act(gate) * up).astype(x.dtype)
-                down = jax.lax.ragged_dot(act_rows, w_down, group_sizes)
-            with jax.named_scope("combine"):
-                # back to token order: row i of the sorted list is
-                # assignment order[i]
-                back = jnp.zeros_like(order).at[order].set(
-                    jnp.arange(t * top_k, dtype=order.dtype))
-                per = down[back].astype(jnp.float32).reshape(
-                    t, top_k, hidden)
-                w = weights
-                if not whole:
-                    # rows past the last group are not computed: those
-                    # of absent experts, and every invalid row's
-                    here = experts < n_held
-                    w = jnp.where(here, w, 0.0)
-                    per = jnp.where(here[:, :, None], per, 0.0)
-                elif valid is not None:
-                    w = jnp.where(valid[:, None], w, 0.0)
-                    per = jnp.where(valid[:, None, None], per, 0.0)
-                out = jnp.sum(per * w[:, :, None], axis=1)
-                if shared is None:
-                    out = out.astype(x.dtype)
-            if shared is not None:
-                with jax.named_scope("shared"):
-                    # whole on every chip of the deployment: counted
-                    # once beside the routed sum of all the shares. The
-                    # sum and its rounding are in the scope too: a
-                    # fusion is named for its root
-                    every = _gated(x, *shared, act)
-                    if valid is not None:
-                        every = jnp.where(valid[:, None], every, 0.0)
-                    out = (out + every).astype(x.dtype)
-            return out, group_sizes
+
+        def weighted_sum(rows):
+            """The sorted ``rows`` (the first of them) through their
+            experts, put back in token order, weighted and summed."""
+            given = rows.shape[0]
+            with jax.named_scope("moe"):
+                with jax.named_scope("experts"):
+                    if w_gate is None:
+                        act_rows = act(jax.lax.ragged_dot(
+                            rows, w_up, group_sizes)).astype(x.dtype)
+                    else:
+                        gate = jax.lax.ragged_dot(rows, w_gate, group_sizes)
+                        up = jax.lax.ragged_dot(rows, w_up, group_sizes)
+                        act_rows = (act(gate) * up).astype(x.dtype)
+                    down = jax.lax.ragged_dot(act_rows, w_down, group_sizes)
+                with jax.named_scope("combine"):
+                    # back to token order: row i of the sorted list is
+                    # assignment order[i]
+                    back = jnp.zeros_like(order).at[order].set(
+                        jnp.arange(n, dtype=order.dtype))
+                    if given < n:
+                        # a row not handed in lies past the last group
+                        back = jnp.minimum(back, given - 1)
+                    per = down[back].astype(jnp.float32).reshape(
+                        t, top_k, hidden)
+                    w = weights
+                    if not whole:
+                        # rows past the last group are not computed:
+                        # those of absent experts, and every invalid
+                        # row's
+                        here = experts < n_held
+                        w = jnp.where(here, w, 0.0)
+                        per = jnp.where(here[:, :, None], per, 0.0)
+                    elif valid is not None:
+                        w = jnp.where(valid[:, None], w, 0.0)
+                        per = jnp.where(valid[:, None, None], per, 0.0)
+                    out = jnp.sum(per * w[:, :, None], axis=1)
+                    if shared is None:
+                        out = out.astype(x.dtype)
+            return out
+
+        def handed(m):
+            """The first ``m`` sorted rows through ``weighted_sum``."""
+            def run():
+                with jax.named_scope("moe"), jax.named_scope("dispatch"):
+                    rows = jnp.asarray(x)[order[:m] // top_k]
+                return weighted_sum(rows)
+            return run
+
+        if cap == n:
+            out = weighted_sum(rows)
+            narrow = None if whole else jnp.int32(0)
+        else:
+            # the sort puts every row a held expert computes first, so
+            # where they are no more than ``cap`` the products need see
+            # no other; else all of them, and the layer stays dropless
+            fits = jnp.sum(group_sizes) <= cap
+            out = jax.lax.cond(fits, handed(cap), handed(n))
+            narrow = fits.astype(jnp.int32)
+        if shared is not None:
+            with jax.named_scope("moe"), jax.named_scope("shared"):
+                # whole on every chip of the deployment: counted once
+                # beside the routed sum of all the shares. The sum and
+                # its rounding are in the scope too: a fusion is named
+                # for its root
+                every = _gated(x, *shared, act)
+                if valid is not None:
+                    every = jnp.where(valid[:, None], every, 0.0)
+                out = (out + every).astype(x.dtype)
+        return out, group_sizes, narrow
 
     if token_block and t > token_block:
         n = -(-t // token_block)
@@ -194,12 +245,14 @@ def dropless_moe(x, router_in, w_router, w_gate, w_up, w_down, *,
             a = jnp.pad(a, [(0, n * token_block - t)] + [(0, 0)] * (a.ndim - 1))
             return a.reshape(n, token_block, *a.shape[1:])
 
-        out, sizes = jax.lax.map(
+        out, sizes, narrow = jax.lax.map(
             lambda a: block(*a), (blocks(x), blocks(router_in), blocks(live)))
         out = out.reshape(n * token_block, hidden)[:t]
         group_sizes = jnp.sum(sizes, axis=0)
+        if narrow is not None:
+            narrow = jnp.min(narrow)
     else:
-        out, group_sizes = block(x, router_in, valid)
+        out, group_sizes, narrow = block(x, router_in, valid)
     with jax.named_scope("moe"):
         local = jnp.sum(group_sizes)
         stats = {"assignments": local if whole else top_k * (
@@ -207,4 +260,7 @@ def dropless_moe(x, router_in, w_router, w_gate, w_up, w_down, *,
                  "local_assignments": local,
                  "experts_touched": jnp.sum(group_sizes > 0),
                  "max_expert_load": jnp.max(group_sizes)}
+        if not whole:
+            stats["narrow_calls"] = narrow
+            stats["wide_calls"] = 1 - narrow
     return out, stats

@@ -536,12 +536,17 @@ class DecodeMetrics:
         the cumulative ``engine.moe``: assignments, those of them the
         experts held here computed (all, where a program counts none
         apart: every expert is held), held experts that got a row, and
-        the rows of each layer's fullest held expert."""
+        the rows of each layer's fullest held expert; where the layers
+        hold a share, also the layers whose grouped products were
+        handed the capacity's rows alone (``narrow_calls``) or all of
+        them (``wide_calls``)."""
         with self._lock:
             m = self._moe
             if m is None:
                 m = self._moe = {"assignments": 0, "local_assignments": 0,
                                  "experts_touched": 0, "max_expert_load": 0}
+                if "moe_narrow_calls" in aux:
+                    m.update(narrow_calls=0, wide_calls=0)
             for key in m:
                 m[key] += int(aux.get("moe_" + key,
                                       aux["moe_assignments"]))

@@ -204,16 +204,19 @@ class CachedDecoder:
             of each layer's fullest expert (over assignments / experts
             it says how uneven the routing is); where the layers hold a
             share of their experts, also the assignments the held ones
-            computed. Empty for a model that counts nothing: the
-            program then has no such output."""
+            computed and the layers whose grouped products were handed
+            the capacity's rows alone or all of them. Empty for a model
+            that counts nothing: the program then has no such output."""
             if not aux or "moe" not in aux:
                 return {}
-            per_layer = jnp.sum(jnp.stack(aux["moe"]), axis=0)  # [3 | 4]
+            per_layer = jnp.sum(jnp.stack(aux["moe"]), axis=0)  # [3 | 6]
             out = {"moe_assignments": per_layer[0],
                    "moe_experts_touched": per_layer[1],
                    "moe_max_expert_load": per_layer[2]}
             if per_layer.shape[0] > 3:
                 out["moe_local_assignments"] = per_layer[3]
+                out["moe_narrow_calls"] = per_layer[4]
+                out["moe_wide_calls"] = per_layer[5]
             return out
 
         def _select(logits, temperature, uniform):
@@ -416,8 +419,9 @@ class CachedDecoder:
                     # v7: the pools' heads folded into their lanes;
                     # v8: the programs choose the next token; v9: the
                     # decode step takes its tokens as it returns them,
-                    # [max_batch] int32)
-                    "kv_dtype": self.kv_dtype, "v": 9}
+                    # [max_batch] int32; v10: a share of the experts
+                    # hands its products the capacity's rows)
+                    "kv_dtype": self.kv_dtype, "v": 10}
             # mesh axes + weight spec-tree hash join the geometry ONLY
             # when the mesh is live: an inert (None / 1-device) mesh
             # must reuse today's fingerprints byte-for-byte, and a mesh

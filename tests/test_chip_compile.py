@@ -614,7 +614,10 @@ def test_a_share_of_the_experts_compiles_to_grouped_matmuls(
     sigmoid scores, a shared expert; the products over the held experts
     are XLA's grouped matmuls over ``tokens * 8`` sorted rows (a block
     of 4,096 tokens at a time in a long prefill, whose temporaries are
-    then a block's), and no product is as wide as the router."""
+    then a block's), and no product is as wide as the router. Each
+    product is there twice: over the capacity's quarter of the rows
+    (twice the 16/128 even routing gives) and, where more rows are the
+    held experts', over all of them."""
     from paddle_tpu.ops.moe import dropless_moe
     hidden, routed, held, inter = 6144, 128, 16, 2048
 
@@ -630,9 +633,10 @@ def test_a_share_of_the_experts_compiles_to_grouped_matmuls(
         shared=(sds(hidden, inter), sds(hidden, inter), sds(inter, hidden))
     ).compile()
     text = compiled.as_text()
-    assert text.count('op_name="ragged-dot-none"') == 3
+    assert text.count('op_name="ragged-dot-none"') == 6
     rows = (block or tokens) * 8
     assert f"bf16[{rows},{hidden}]" in text
+    assert f"bf16[{rows // 4},{hidden}]" in text
     if block:
         assert f"bf16[{tokens * 8},{hidden}]" not in text
         # what it keeps beside its result is a block's, not the call's
@@ -714,7 +718,9 @@ def test_ungated_latent_experts_compile_to_grouped_matmuls(
     rows of a decode step (whose 128 lanes the model itself computes
     unsorted: the test below): the router reads 4,096 columns, the
     experts a latent of 1,024; 128 held of 512, 22 a token, ungated:
-    two grouped matmuls over ``tokens * 22`` sorted rows."""
+    two grouped matmuls over ``tokens * 22`` sorted rows, and the same
+    two over the capacity's half of them (twice the quarter even
+    routing gives)."""
     from paddle_tpu.ops.moe import dropless_moe
     hidden, latent, routed, held, inter = 4096, 1024, 512, 128, 2688
 
@@ -729,8 +735,10 @@ def test_ungated_latent_experts_compile_to_grouped_matmuls(
             sds(held, latent, inter), sds(held, inter, latent),
             sds(tokens, dtype=jnp.bool_)).compile()
     text = compiled.as_text()
-    assert text.count('op_name="ragged-dot-none"') == 2
-    assert f"bf16[{(block or tokens) * 22},{latent}]" in text
+    assert text.count('op_name="ragged-dot-none"') == 4
+    rows = (block or tokens) * 22
+    assert f"bf16[{rows},{latent}]" in text
+    assert f"bf16[{rows // 2},{latent}]" in text
     if block:
         assert f"bf16[{tokens * 22},{latent}]" not in text
         assert compiled.memory_analysis().temp_size_in_bytes < 2 * 1024 ** 3

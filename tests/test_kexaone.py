@@ -247,6 +247,29 @@ def test_prefill_then_decode_matches_the_reference_logits(
     # this share holds 4 of the router's 16
     assert 0 < moe["local_assignments"] < moe["assignments"]
     assert 0 < moe["experts_touched"] <= moe["local_assignments"]
+    # one of the two counts a layer a program
+    programs = sum(d["harvested"] for d in snap["engine"]["dispatch"].values())
+    assert moe["narrow_calls"] + moe["wide_calls"] == 4 * programs
+
+
+def test_a_prefill_hands_its_products_the_capacitys_rows(model, reference):
+    """A 40-token prompt in a bucket of 64: 256 sorted rows a layer, a
+    capacity of 128 (twice the 64 of even routing over 4 of 16 held),
+    so the prefill's products are handed 128 rows; a decode step's 16
+    rows are no more than one tile and are all handed in. The logits
+    are the reference's either way."""
+    prompt = np.random.default_rng(7).integers(0, 128, 40)
+    tokens, seen, snap = serve(model, [prompt], 4, seq_buckets=[64],
+                               max_seq_len=64)
+    ids = np.concatenate([prompt, tokens[0][:-1]])[None]
+    want = np.asarray(reference.logits(
+        state_arrays(model)[0], ids, model.config,
+        positions=np.arange(39, ids.shape[1])))[0]
+    np.testing.assert_allclose(np.stack(seen[40]), want, atol=1e-4, rtol=0)
+    moe, runs = snap["engine"]["moe"], snap["engine"]["dispatch"]
+    assert runs["prefill"]["harvested"] == 1
+    assert moe["narrow_calls"] == 4
+    assert moe["wide_calls"] == 4 * runs["decode"]["harvested"]
 
 
 def test_pools_are_one_full_layer_and_four_rings(model):

@@ -204,6 +204,40 @@ def _moe_arrays(seed, t=24, h=16, e_all=8, held=8, i=12, dtype="float32"):
     return [jnp.asarray(a, dtype) for a in (x, r, wr, wg, wu, wd)]
 
 
+def _share_full_width(x, router_in, w_router, w_gate, w_up, w_down, *,
+                      top_k, scoring, scale, activation, offset, valid):
+    """A share's layer as it was before its capacity: all ``T * top_k``
+    sorted rows handed to the grouped products (sigmoid routing)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.moe import _ACTIVATIONS, route_sigmoid_norm
+    t, hidden = x.shape
+    n_held = w_up.shape[0]
+    act = _ACTIVATIONS[activation]
+    experts, weights = route_sigmoid_norm(router_in, w_router, top_k, scale)
+    experts = jnp.where((experts >= offset) & (experts < offset + n_held),
+                        experts - offset, n_held)
+    if valid is not None:
+        experts = jnp.where(valid[:, None], experts, n_held)
+    flat = experts.reshape(t * top_k)
+    order = jnp.argsort(flat, stable=True)
+    rows = x[order // top_k]
+    sizes = jnp.bincount(flat, length=n_held + 1)[:n_held].astype(jnp.int32)
+    if w_gate is None:
+        h = act(jax.lax.ragged_dot(rows, w_up, sizes))
+    else:
+        h = act(jax.lax.ragged_dot(rows, w_gate, sizes)) \
+            * jax.lax.ragged_dot(rows, w_up, sizes)
+    down = jax.lax.ragged_dot(h.astype(x.dtype), w_down, sizes)
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(t * top_k, dtype=order.dtype))
+    per = down[back].astype(jnp.float32).reshape(t, top_k, hidden)
+    here = experts < n_held
+    per = jnp.where(here[:, :, None], per, 0.0)
+    w = jnp.where(here, weights, 0.0)
+    return jnp.sum(per * w[:, :, None], axis=1).astype(x.dtype)
+
+
 class TestDroplessMoe:
     @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
     @pytest.mark.parametrize("masked", [False, True],
@@ -341,6 +375,91 @@ class TestDroplessMoe:
                    for e in range(8))
         np.testing.assert_allclose(np.asarray(got), want, atol=2e-3,
                                    rtol=1e-4)
+
+    @pytest.mark.parametrize("masked", [False, True], ids=["all", "some-dead"])
+    @pytest.mark.parametrize("routing", ["even", "to-the-held"])
+    @pytest.mark.parametrize("kind", ["gated-silu", "ungated-relu2"])
+    def test_a_share_hands_its_products_the_capacitys_rows(
+            self, kind, routing, masked):
+        """64 tokens x 4 of a router of 16 outputs, experts 4..7 held:
+        256 sorted rows, a capacity of 128. Routed evenly (about 64 rows
+        held) the products are handed 128 and give what handing all 256
+        gives; a router biased to the held experts sends them all 256
+        and the layer, handed all, is still dropless."""
+        from paddle_tpu.ops.moe import dropless_moe
+        rng = np.random.default_rng(8)
+        t, wide, k, offset, held = 64, 16, 4, 4, 4
+        gated = kind == "gated-silu"
+        lat = wide if gated else 8
+        act = (lambda g: g / (1 + np.exp(-g))) if gated \
+            else (lambda g: np.square(np.maximum(g, 0)))
+        r = rng.standard_normal((t, wide)).astype(np.float32)
+        x = rng.standard_normal((t, lat)).astype(np.float32)
+        wr = 0.5 * rng.standard_normal((wide, 16)).astype(np.float32)
+        if routing == "to-the-held":
+            r[:, 0] = 1.0
+            wr[0, offset:offset + held] += 20.0
+        wg = rng.standard_normal((held, lat, 12)).astype(np.float32) \
+            if gated else None
+        wu = rng.standard_normal((held, lat, 12)).astype(np.float32)
+        wd = rng.standard_normal((held, 12, lat)).astype(np.float32)
+        valid = np.arange(t) % 5 != 0 if masked else None
+        kw = dict(top_k=k, scoring="sigmoid_norm", scale=2.5, offset=offset,
+                  activation="silu" if gated else "relu2", valid=valid)
+        got, stats = dropless_moe(x, r, wr, wg, wu, wd, **kw)
+        narrow = routing == "even"
+        assert (int(stats["narrow_calls"]), int(stats["wide_calls"])) == \
+            ((1, 0) if narrow else (0, 1))
+        assert (int(stats["local_assignments"]) <= 128) == narrow
+        # today's full width: every sorted row handed to the products
+        want = _share_full_width(x, r, wr, wg, wu, wd, **kw)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-6, atol=1e-6)
+        # the same tokens twice, a block of 64 at a time: each block
+        # takes the branch its own rows choose, and the call counts once
+        twice = dict(kw, valid=None if valid is None
+                     else np.concatenate([valid, valid]), token_block=t)
+        both, s2 = dropless_moe(*(np.concatenate([a, a]) for a in (x, r)),
+                                wr, wg, wu, wd, **twice)
+        np.testing.assert_allclose(np.asarray(both),
+                                   np.concatenate([got, got]), atol=1e-6)
+        assert (int(s2["narrow_calls"]), int(s2["wide_calls"])) == \
+            (int(stats["narrow_calls"]), int(stats["wide_calls"]))
+        if narrow:
+            return
+        s = 1 / (1 + np.exp(-(r @ wr)))
+        loop = np.zeros_like(x)
+        for tok in range(t) if valid is None else np.flatnonzero(valid):
+            top = np.argsort(-s[tok])[:k]
+            for weight, ex in zip(2.5 * s[tok, top] / s[tok, top].sum(),
+                                  top):
+                if offset <= ex < offset + held:
+                    e = ex - offset
+                    h = act(x[tok] @ (wg[e] if gated else wu[e]))
+                    if gated:
+                        h = h * (x[tok] @ wu[e])
+                    loop[tok] += weight * (h @ wd[e])
+        np.testing.assert_allclose(np.asarray(got), loop, atol=5e-4,
+                                   rtol=1e-4)
+
+    def test_a_whole_layer_has_no_capacity_and_no_branch(self):
+        """Every expert held: no ``conditional`` in the program and no
+        count beyond the four; a share of them has both."""
+        import jax
+        from paddle_tpu.ops.moe import dropless_moe
+        args = _moe_arrays(9, t=64)
+
+        def program(**kw):
+            fn = jax.jit(lambda *a: dropless_moe(*a, top_k=4, **kw))
+            return fn.lower(*args).compile().as_text(), fn(*args)[1]
+        text, stats = program()
+        assert " conditional(" not in text
+        assert set(stats) == {"assignments", "local_assignments",
+                              "experts_touched", "max_expert_load"}
+        share = _moe_arrays(9, t=64, e_all=32)
+        text = jax.jit(lambda *a: dropless_moe(*a, top_k=4, offset=8)[0]) \
+            .lower(*share).compile().as_text()
+        assert " conditional(" in text
 
     def test_unknown_options_raise(self):
         from paddle_tpu.ops.moe import dropless_moe

@@ -234,6 +234,12 @@ def test_prefill_then_decode_matches_the_reference_logits(
         np.testing.assert_allclose(seen[n][0], seen_whole[n][0],
                                    atol=2e-6, rtol=0)
     assert snap_whole["engine"]["moe"]["assignments"] == 5 * 5 * (27 + 4)
+    # the decode step computes every held expert (4 lanes x 5 reach the
+    # router's 16) and counts neither; the [4, 32] prefill, 640 sorted
+    # rows of a share of 4 in 16, is one call a layer with a capacity
+    # of 256
+    moe_whole = snap_whole["engine"]["moe"]
+    assert moe_whole["narrow_calls"] + moe_whole["wide_calls"] == 5
     kv = snap["engine"]["kv"]
     assert kv["capacity"] == {"full": 63, "state": 4}
     assert kv["pages_in_use"] == {"full": 0, "state": 0}
@@ -245,6 +251,10 @@ def test_prefill_then_decode_matches_the_reference_logits(
     # five expert layers, five experts a token, wherever they live
     assert moe["assignments"] == 5 * 5 * (27 + 4 * 29)
     assert 0 < moe["local_assignments"] < moe["assignments"]
+    # a row at a time: four calls a layer of 160 sorted rows, a capacity
+    # of 128 (even routing gives 40)
+    assert moe["narrow_calls"] + moe["wide_calls"] == 5 * 4
+    assert moe["narrow_calls"] >= 5
 
 
 def test_a_slot_is_clean(model, reference):
@@ -520,8 +530,11 @@ def test_a_wide_decode_step_computes_every_held_expert(model):
                                    atol=5e-4, rtol=1e-5)
         if valid is not None:
             assert not np.asarray(got)[~valid].any()
+        # the same four counts; it hands no grouped product any rows,
+        # so it counts neither a narrow nor a wide call
         assert {n: int(v) for n, v in stats.items()} == \
-            {n: int(v) for n, v in counts.items()}
+            {n: int(counts[n]) for n in stats}
+        assert set(counts) - set(stats) == {"narrow_calls", "wide_calls"}
     # which of the two a program holds: 16 router outputs, 5 a token
     layer = model.backbone.layers[1]
     u = paddle.to_tensor(rng.standard_normal((4, 1, 64)).astype(f))

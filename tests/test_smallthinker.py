@@ -154,6 +154,8 @@ def test_prefill_then_decode_matches_the_reference_logits(
     moe = snap["engine"]["moe"]
     assert moe["assignments"] == 8 * 2 * (5 + 23 + 2 * 36)   # dropless
     assert 0 < moe["experts_touched"] <= moe["assignments"]
+    # every expert is held: no capacity, so neither count
+    assert "narrow_calls" not in moe and "wide_calls" not in moe
 
 
 def test_window_pool_is_sized_from_the_window_not_the_context(model):
