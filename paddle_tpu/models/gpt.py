@@ -42,7 +42,7 @@ __all__ = [
     "GPTConfig", "GPTModel", "GPTForCausalLM", "GPTPretrainingCriterion",
     "GPTKVCache",
     "gpt_tiny", "gpt2_small", "gpt2_medium", "gpt3_1p3b",
-    "smallthinker_21ba3b", "k_exaone_236b_a23b",
+    "smallthinker_21ba3b", "k_exaone_236b_a23b", "glm_4p7_flash",
 ]
 
 
@@ -120,13 +120,29 @@ class GPTConfig:
     moe_token_block: int = 0         # rows an expert layer computes at a
     #                                  time (bounds what a long prefill
     #                                  keeps); 0 -> all at once
+    moe_selection_bias: bool = False  # a bias added to the sigmoid scores
+    #                                  to choose the experts, not to weigh
+    #                                  them (zero at initialisation)
+    # latent attention (DeepSeek-V2's multi-head latent attention): q
+    # through a rank-q_lora_rank bottleneck; K and V of every head made
+    # from one normed latent of kv_lora_rank beside one rotary key part
+    # of qk_rope_head_dim that all heads share, which is all the cache
+    # keeps of a token; a head's query and key are qk_nope_head_dim +
+    # qk_rope_head_dim wide, its value v_head_dim. 0 -> no latent
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     NEW_FIELDS = ("num_kv_heads", "head_dim", "norm", "bias", "position",
                   "sliding_window", "moe_num_experts", "dtype", "qk_norm",
                   "mlp_kind", "moe_layout", "moe_router_experts",
                   "moe_expert_offset", "moe_scoring", "moe_routed_scale",
                   "moe_activation", "moe_shared_intermediate_size",
-                  "moe_token_block")
+                  "moe_token_block", "moe_selection_bias", "q_lora_rank",
+                  "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+                  "v_head_dim")
 
     def __post_init__(self):
         if self.intermediate_size == 0:
@@ -134,6 +150,17 @@ class GPTConfig:
         fresh = GPTConfig.__dataclass_fields__
         changed = [f for f in self.NEW_FIELDS
                    if getattr(self, f) != fresh[f].default]
+        latent = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
+                  self.qk_rope_head_dim, self.v_head_dim)
+        if any(latent) and not (all(latent) and self.position == "rope"
+                                and not (self.bias or self.qk_norm
+                                         or self.sliding_window)):
+            raise ValueError(
+                "latent attention needs q_lora_rank, kv_lora_rank, "
+                "qk_nope_head_dim, qk_rope_head_dim, v_head_dim and rope "
+                "positions, and takes no bias, qk_norm or sliding_window")
+        if any(latent) and self.head_dim == 0:
+            self.head_dim = self.qk_nope_head_dim + self.qk_rope_head_dim
         if self.head_dim == 0:
             assert self.hidden_size % self.num_heads == 0
             self.head_dim = self.hidden_size // self.num_heads
@@ -177,6 +204,9 @@ class GPTConfig:
             raise ValueError(
                 f"moe_scoring must be 'softmax_top_k' or 'sigmoid_norm', "
                 f"got {self.moe_scoring!r}")
+        if self.moe_selection_bias and self.moe_scoring != "sigmoid_norm":
+            raise ValueError("moe_selection_bias chooses among sigmoid "
+                             "scores: it needs moe_scoring='sigmoid_norm'")
         if self.moe_activation not in ("relu", "silu"):
             raise ValueError(f"moe_activation must be 'relu' or 'silu', "
                              f"got {self.moe_activation!r}")
@@ -220,6 +250,13 @@ class GPTConfig:
         return bool(self.moe_num_experts) and bool(
             self.moe_layout[layer] if self.moe_layout else 1)
 
+    @property
+    def latent_width(self) -> int:
+        """Values a token a layer of a latent attention's cache (the
+        latent and the shared rotary key part), 0 without one."""
+        return self.kv_lora_rank + self.qk_rope_head_dim \
+            if self.kv_lora_rank else 0
+
     def num_params(self) -> int:
         """Parameters of the model these fields describe, reckoned
         without building it."""
@@ -228,6 +265,14 @@ class GPTConfig:
             self.num_kv_heads * self.head_dim
         norm = h * (2 if self.norm == "layernorm" else 1)
         attn = h * (qd + 2 * kvd) + qd * h
+        if self.kv_lora_rank:
+            nh = self.num_heads
+            attn = (h * self.q_lora_rank + self.q_lora_rank
+                    + self.q_lora_rank * qd
+                    + h * self.latent_width + self.kv_lora_rank
+                    + self.kv_lora_rank * nh * (self.qk_nope_head_dim
+                                                + self.v_head_dim)
+                    + nh * self.v_head_dim * h)
         if self.bias:
             attn += qd + 2 * kvd + h
         if self.qk_norm:
@@ -235,6 +280,8 @@ class GPTConfig:
         sparse = h * self.moe_router_experts + 3 * h * (
             self.moe_num_experts * self.moe_intermediate_size
             + self.moe_shared_intermediate_size)
+        if self.moe_selection_bias:
+            sparse += self.moe_router_experts
         if self.mlp_kind == "swiglu":
             dense = 3 * h * self.intermediate_size
         else:
@@ -341,6 +388,39 @@ def k_exaone_236b_a23b(**kw) -> GPTConfig:
     return GPTConfig(**d)
 
 
+def glm_4p7_flash(**kw) -> GPTConfig:
+    """GLM-4.7-Flash (zai-org, config.json, ``glm4_moe_lite``): 47
+    layers of latent attention, hidden 2,048, 20 heads whose queries
+    pass a rank-768 bottleneck and whose keys and values are made from a
+    512-wide latent beside a 64-wide rotary key part shared by all heads
+    (queries and keys 192 + 64, values 256), RMSNorm (eps 1e-5), RoPE
+    (theta 1e6), no biases, untied head, vocabulary 154,880; layer 0 a
+    SwiGLU MLP of width 10,240, every other layer 64 SwiGLU experts of
+    width 1,536, 4 a token chosen by sigmoid scores plus a selection
+    bias and weighted by the scores normalised over the chosen and
+    scaled 1.8, beside one shared expert of the same width. The
+    multi-token-prediction module is not built. ``num_layers`` keeps
+    the leading layers; ``moe_num_experts`` (with ``moe_expert_offset``)
+    is the share of the 64 experts held, ``vocab_size`` the rows of the
+    vocabulary held; ``dtype`` is the parameters'."""
+    layers = int(kw.get("num_layers", 47))
+    d = dict(vocab_size=154880, hidden_size=2048, num_layers=layers,
+             num_heads=20, max_seq_len=202752, intermediate_size=10240,
+             norm="rmsnorm", layer_norm_eps=1e-5, bias=False,
+             position="rope", rope_theta=1e6, q_lora_rank=768,
+             kv_lora_rank=512, qk_nope_head_dim=192, qk_rope_head_dim=64,
+             v_head_dim=256, mlp_kind="swiglu",
+             moe_layout=tuple(int(i >= 1) for i in range(layers)),
+             moe_num_experts=64, moe_router_experts=64, moe_top_k=4,
+             moe_intermediate_size=1536, moe_shared_intermediate_size=1536,
+             moe_scoring="sigmoid_norm", moe_routed_scale=1.8,
+             moe_selection_bias=True, moe_activation="silu",
+             moe_token_block=4096, tie_word_embeddings=False,
+             use_flash_attention=True)
+    d.update(kw)
+    return GPTConfig(**d)
+
+
 def _seq_constraint(x):
     """Sequence-parallel activation sharding over the 'sep' mesh axis
     ([B, S, H] → S sharded) — the unified surface's
@@ -361,7 +441,8 @@ class GPTKVCache:
       ``[num_layers, num_pages, page_size, heads * head_dim]`` Tensor
       for ``GPTStackedTransformer`` (the layout is
       ``ops.paged_attention.kv_pool_shape``'s). Page 0 is the trash page
-      (ops/paged_attention.py).
+      (ops/paged_attention.py). A latent attention's layer has its one
+      latent pool in ``k`` (``latent_pool_shape``) and ``()`` in ``v``.
     - ``block_tables``: [B, P] int32 logical-page → pool-page map.
     - ``ctx_len``: [B] int32 visible context length INCLUDING the
       positions written by this forward.
@@ -716,10 +797,158 @@ class GPTGroupedAttention(Layer):
         return res[0], k_pool, v_pool
 
 
+class GPTLatentAttention(Layer):
+    """Multi-head latent attention (DeepSeek-V2), under the scope
+    ``mla``. With ``h`` the normed input:
+
+    - ``q = RMSNorm(h Wqa) Wqb`` (sub-scope ``q_proj``), per head
+      ``[nope | rope]``, RoPE on the rope part;
+    - ``[c | k_pe] = h Wkva``, ``c = RMSNorm(c)``, RoPE on ``k_pe``, one
+      key part all heads share (``kv_proj``): ``[c | k_pe]`` is all the
+      cache keeps of a token, ``kv_lora_rank + qk_rope_head_dim`` values
+      for every head;
+    - per head ``[k_nope | v] = c Wkvb``, ``k = [k_nope | k_pe]``,
+      scores ``q . k / sqrt(nope + rope)``, causal;
+      ``out = concat(softmax v) Wo`` (``out_proj``).
+
+    A prefill (and a call without a cache) computes that as written,
+    every head's keys and values made from the latent (``kv_proj``). A
+    decode step computes the same function in absorbed form: each
+    head's ``q_nope`` through its key up-projection (``absorb``) meets
+    the cached rows directly, its softmax-weighted sum of latents goes
+    through its value up-projection (``v_up``). The cache's write and
+    the attention over it are ``ops.paged_attention``'s
+    (``paged_latent_attention_update``)."""
+
+    def __init__(self, config: GPTConfig, layer: int):
+        super().__init__()
+        self.num_heads = config.num_heads
+        self.nope, self.rope = config.qk_nope_head_dim, \
+            config.qk_rope_head_dim
+        self.v_dim, self.latent = config.v_head_dim, config.kv_lora_rank
+        self.eps = float(config.layer_norm_eps)
+        self.rope_theta = config.rope_theta
+        self.use_flash = config.use_flash_attention
+        dt = config.dtype or "float32"
+        init = I.Normal(std=config.initializer_range)
+        h, nh = config.hidden_size, self.num_heads
+
+        def mk(shape, spec=None, ones=False):
+            p = create_parameter_with_attr(
+                shape, dt, None, False,
+                default_initializer=I.Constant(1.0) if ones else init)
+            if spec is not None:
+                p.dist_spec = spec
+            return p
+
+        self.q_a_w = mk([h, config.q_lora_rank])
+        self.q_a_norm_w = mk([config.q_lora_rank], ones=True)
+        self.q_b_w = mk([config.q_lora_rank, nh * (self.nope + self.rope)],
+                        (None, "mp"))
+        self.kv_a_w = mk([h, config.latent_width])
+        self.kv_a_norm_w = mk([self.latent], ones=True)
+        self.kv_b_w = mk([self.latent, nh * (self.nope + self.v_dim)],
+                         (None, "mp"))
+        self.out_w = mk([nh * self.v_dim, h], ("mp", None))
+
+    def _weights(self):
+        return [self.q_a_w, self.q_a_norm_w, self.q_b_w, self.kv_a_w,
+                self.kv_a_norm_w, self.kv_b_w, self.out_w]
+
+    def forward(self, x, kv_cache=None):
+        import jax.numpy as jnp
+        nh, nope, rope, dv, lat = self.num_heads, self.nope, self.rope, \
+            self.v_dim, self.latent
+        eps, theta, use_flash = self.eps, self.rope_theta, self.use_flash
+        scale = 1.0 / math.sqrt(nope + rope)
+        scope = jax.named_scope
+
+        def project(x, positions, q_a, q_norm, q_b, kv_a, kv_norm):
+            """``(q_nope, q_pe, c, k_pe)``: [B, S, H, nope], [B, S, H,
+            rope], [B, S, latent], [B, S, 1, rope]."""
+            b, s, _ = x.shape
+            if positions is None:
+                positions = jnp.broadcast_to(
+                    jnp.arange(s, dtype=jnp.int32), (b, s))
+            with scope("mla"):
+                with scope("q_proj"):
+                    q = (_rms_norm(x @ q_a, q_norm, eps) @ q_b).reshape(
+                        b, s, nh, nope + rope)
+                    q_pe = _rope(q[..., nope:], positions, theta)
+                with scope("kv_proj"):
+                    ckr = x @ kv_a
+                    c = _rms_norm(ckr[..., :lat], kv_norm, eps)
+                    k_pe = _rope(ckr[..., None, lat:], positions, theta)
+            return q[..., :nope], q_pe, c, k_pe
+
+        def expand(q_nope, q_pe, c, k_pe, kv_b):
+            """Every head's query, key and value, [B, S, H, D]."""
+            b, s = c.shape[:2]
+            with scope("mla"), scope("kv_proj"):
+                kv = (c @ kv_b).reshape(b, s, nh, nope + dv)
+                k = jnp.concatenate(
+                    [kv[..., :nope],
+                     jnp.broadcast_to(k_pe, (b, s, nh, rope))], -1)
+            return jnp.concatenate([q_nope, q_pe], -1), k, kv[..., nope:]
+
+        def out(o, out_w):
+            b, s = o.shape[:2]
+            with scope("mla"), scope("out_proj"):
+                return o.reshape(b, s, nh * dv) @ out_w
+
+        if kv_cache is None:
+            def fn(x, q_a, q_norm, q_b, kv_a, kv_norm, kv_b, out_w):
+                from ..ops.flash_attention import attention_bshd
+                parts = project(x, None, q_a, q_norm, q_b, kv_a, kv_norm)
+                o = attention_bshd(*expand(*parts, kv_b), causal=True,
+                                   scale=scale, use_flash=use_flash)
+                return out(o, out_w)
+            return apply_op("latent_attention", fn, x, *self._weights())
+
+        from ..ops.paged_attention import paged_latent_attention_update
+        leaves, pool_def = jax.tree_util.tree_flatten(kv_cache.k)
+
+        def fn(x, tables, ctx, valid, positions, q_a, q_norm, q_b, kv_a,
+               kv_norm, kv_b, out_w, *pool_leaves, page_size, kind,
+               use_pallas):
+            pool = jax.tree_util.tree_unflatten(pool_def, pool_leaves)
+            q_nope, q_pe, c, k_pe = project(x, positions, q_a, q_norm, q_b,
+                                            kv_a, kv_norm)
+            latent = jnp.concatenate([c, k_pe[:, :, 0]], -1)
+            kw = dict(page_size=page_size, kind=kind, scale=scale,
+                      value_dim=lat, use_pallas=use_pallas)
+            if kind == "prefill":
+                o, pool = paged_latent_attention_update(
+                    None, latent, pool, tables, ctx, valid, positions,
+                    expanded=expand(q_nope, q_pe, c, k_pe, kv_b),
+                    use_flash=use_flash, **kw)
+            else:
+                w = kv_b.reshape(lat, nh, nope + dv)
+                with scope("mla"), scope("absorb"):
+                    q = jnp.concatenate([jnp.einsum(
+                        "bshd,chd->bshc", q_nope, w[..., :nope],
+                        preferred_element_type=jnp.float32).astype(
+                            q_nope.dtype), q_pe], -1)
+                u, pool = paged_latent_attention_update(
+                    q, latent, pool, tables, ctx, valid, positions, **kw)
+                with scope("mla"), scope("v_up"):
+                    o = jnp.einsum("bshc,chd->bshd", u, w[..., nope:])
+            return (out(o, out_w), *jax.tree_util.tree_leaves(pool))
+
+        res = apply_op(
+            "paged_attention", fn, x, kv_cache.block_tables,
+            kv_cache.ctx_len, kv_cache.valid, kv_cache.positions,
+            *self._weights(), *leaves, page_size=kv_cache.page_size,
+            kind=kv_cache.kind, use_pallas=kv_cache.use_pallas)
+        return res[0], jax.tree_util.tree_unflatten(pool_def, res[1:]), \
+            kv_cache.v
+
+
 class GPTExpertMLP(Layer):
     """Dropless top-k gated experts (``ops.moe.dropless_moe``): the
     weights of one projection of the experts held here are one stacked
-    array, the router is as wide as the model has experts, and a shared
+    array, the router is as wide as the model has experts (with a
+    selection bias as wide where the config asks for one), and a shared
     expert (every token's) has three plain weights of its own."""
 
     def __init__(self, config: GPTConfig):
@@ -751,6 +980,13 @@ class GPTExpertMLP(Layer):
             self.shared_gate_w = mk([h, si])
             self.shared_up_w = mk([h, si])
             self.shared_down_w = mk([si, h])
+        self.has_bias = config.moe_selection_bias
+        if self.has_bias:
+            # zero, as a trained bias starts: each seed's routing is then
+            # what it would be without one
+            self.router_bias = create_parameter_with_attr(
+                [config.moe_router_experts], dt, None, False,
+                default_initializer=I.Constant(0.0))
 
     def forward(self, x, router_in, valid=None):
         """x, router_in: [B, S, H]; valid: [B, S] bool or None. Returns
@@ -762,12 +998,14 @@ class GPTExpertMLP(Layer):
         import jax.numpy as jnp
 
         from ..ops.moe import dropless_moe
-        options, share, has_shared = self.options, self.share, \
-            self.has_shared
+        options, share, has_shared, has_bias = self.options, self.share, \
+            self.has_shared, self.has_bias
 
         def fn(x, router_in, valid, *weights):
             b, s, h = x.shape
-            extra = {"shared": weights[4:]} if has_shared else {}
+            extra = {"shared": weights[4:7]} if has_shared else {}
+            if has_bias:
+                extra["bias"] = weights[-1]
             out, stats = dropless_moe(
                 x.reshape(b * s, h), router_in.reshape(b * s, h),
                 *weights[:4], **options, **extra,
@@ -782,8 +1020,9 @@ class GPTExpertMLP(Layer):
 
         shared = [self.shared_gate_w, self.shared_up_w,
                   self.shared_down_w] if has_shared else []
+        bias = [self.router_bias] if has_bias else []
         return apply_op("moe", fn, x, router_in, valid, self.router_w,
-                        self.gate_w, self.up_w, self.down_w, *shared)
+                        self.gate_w, self.up_w, self.down_w, *shared, *bias)
 
 
 class GPTGatedMLP(Layer):
@@ -856,8 +1095,12 @@ class GPTDecoderLayer(Layer):
     def __init__(self, config: GPTConfig, layer: int = 0):
         super().__init__()
         self.ln_1 = _norm(config)
-        self.attn = GPTAttention(config) if config.classic_attention \
-            else GPTGroupedAttention(config, layer)
+        if config.kv_lora_rank:
+            self.attn = GPTLatentAttention(config, layer)
+        elif config.classic_attention:
+            self.attn = GPTAttention(config)
+        else:
+            self.attn = GPTGroupedAttention(config, layer)
         self.ln_2 = _norm(config)
         self.experts = config.layer_experts(layer)
         self.mlp = GPTExpertMLP(config) if self.experts else (
@@ -1330,10 +1573,20 @@ class GPTForCausalLM(Layer):
         ``(int8 values, f32 per-slot-per-head scales)`` tuples (see
         ops.paged_attention for the quantized-pool contract). Returns
         raw jax arrays ``(k, v)`` — engine plumbing, not Tensors."""
-        from ..ops.paged_attention import new_kv_pool
+        import jax.numpy as jnp
+
+        from ..ops.paged_attention import latent_pool_shape, new_kv_pool
         cfg = self.config
         dtype = dtype or \
             self.gpt.embeddings.word_embeddings.weight._data.dtype
+        if cfg.kv_lora_rank:
+            # latent attention: one row a token for all heads, no V
+            if isinstance(dtype, str) and dtype == "int8":
+                raise ValueError("a latent pool is kept in a float type: "
+                                 "int8 pools are not built for it")
+            shape = latent_pool_shape(num_pages, page_size, cfg.latent_width)
+            return ([jnp.zeros(shape, dtype) for _ in range(cfg.num_layers)],
+                    [()] * cfg.num_layers)
 
         def mk(n, lead=()):
             return new_kv_pool(n, page_size, cfg.num_kv_heads,
@@ -1352,8 +1605,12 @@ class GPTForCausalLM(Layer):
         so sizing and shardcheck agree on pool cost. ``kinds`` says
         what each kind of layer keeps: ``full`` the whole context,
         ``window`` the last ``window`` positions (absent where no layer
-        is of that kind)."""
-        from ..ops.paged_attention import kv_pool_bytes
+        is of that kind). A latent attention's ``full`` kind says
+        ``latent``: the values a token a layer of its one pool, which
+        holds one row a token for every head and no V (``num_kv_heads``
+        1 and ``head_dim`` the row's lanes, ``latent_pool_shape``, are
+        the pool's geometry)."""
+        from ..ops.paged_attention import kv_pool_bytes, latent_pool_shape
         cfg = self.config
         nh, hd = cfg.num_kv_heads, cfg.head_dim
         per_token = cfg.num_layers * 2 * kv_pool_bytes(
@@ -1361,6 +1618,12 @@ class GPTForCausalLM(Layer):
         windows = [cfg.layer_window(i) for i in range(cfg.num_layers)]
         kinds = {"full": {"layers": [i for i, w in enumerate(windows)
                                      if not w], "window": None}}
+        if cfg.kv_lora_rank:
+            nh, hd = 1, latent_pool_shape(1, 1, cfg.latent_width)[2]
+            kinds["full"]["latent"] = cfg.latent_width
+            per_token = cfg.num_layers * kv_pool_bytes(
+                1, 1, nh, hd, kv_dtype or str(
+                    self.gpt.embeddings.word_embeddings.weight._data.dtype))
         if any(windows):
             kinds["window"] = {
                 "layers": [i for i, w in enumerate(windows) if w],

@@ -68,14 +68,21 @@ def route_top_k(router_in, w_router, top_k: int):
     return experts.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
 
 
-def route_sigmoid_norm(router_in, w_router, top_k: int, scale: float):
+def route_sigmoid_norm(router_in, w_router, top_k: int, scale: float,
+                       bias=None):
     """``(experts [T, k] int32, weights [T, k] f32)``: the ``top_k``
     largest of ``s = sigmoid(logits)`` a token, each weighted ``scale *
     s_e / sum of the chosen s`` (DeepSeek-V3's ``scoring_func`` sigmoid
-    with ``norm_topk_prob`` and ``routed_scaling_factor``, one group,
-    no selection bias). All in float32."""
+    with ``norm_topk_prob`` and ``routed_scaling_factor``, one group).
+    With ``bias`` ([E_all], DeepSeek-V3's ``noaux_tc`` selection bias)
+    the experts are the ``top_k`` largest of ``s + bias`` and are still
+    weighed by ``s``. All in float32."""
     scores = jax.nn.sigmoid(_router_logits(router_in, w_router))
-    top, experts = jax.lax.top_k(scores, top_k)
+    if bias is None:
+        top, experts = jax.lax.top_k(scores, top_k)
+    else:
+        _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        top = jnp.take_along_axis(scores, experts, axis=-1)
     weights = jnp.float32(scale) * top / jnp.sum(top, axis=-1,
                                                  keepdims=True)
     return experts.astype(jnp.int32), weights
@@ -90,7 +97,8 @@ def _gated(x, w_gate, w_up, w_down, act):
 def dropless_moe(x, router_in, w_router, w_gate, w_up, w_down, *,
                  top_k: int, valid=None, scoring: str = "softmax_top_k",
                  scale: float = 1.0, activation: str = "relu",
-                 offset: int = 0, shared=None, token_block: int = 0):
+                 offset: int = 0, shared=None, token_block: int = 0,
+                 bias=None):
     """``sum_{e in C, e held} w_e * E_e(x)`` a token, ``C`` the
     ``top_k`` experts the router chooses among all of them, ``E_e(x) =
     (act(x @ w_gate[e]) * (x @ w_up[e])) @ w_down[e]``, or with
@@ -103,7 +111,8 @@ def dropless_moe(x, router_in, w_router, w_gate, w_up, w_down, *,
     w_up: [E, H, I]; w_down: [E, I, H], the experts ``offset .. offset
     + E - 1`` of ``E_all`` (``E <= E_all``); ``scoring``:
     ``"softmax_top_k"`` (``route_top_k``) or ``"sigmoid_norm"``
-    (``route_sigmoid_norm`` with ``scale``); ``activation``:
+    (``route_sigmoid_norm`` with ``scale`` and, where given, the
+    selection ``bias`` [E_all]); ``activation``:
     ``"relu"``, ``"silu"`` or ``"relu2"`` (``relu(x)^2``);
     ``valid``: [T] bool or None: a row that is padding or a dead lane
     goes to no expert, touches none and gets zeros. With
@@ -129,6 +138,8 @@ def dropless_moe(x, router_in, w_router, w_gate, w_up, w_down, *,
                          f"among the router's {n_all}")
     if scoring not in ("softmax_top_k", "sigmoid_norm"):
         raise ValueError(f"unknown scoring {scoring!r}")
+    if bias is not None and scoring != "sigmoid_norm":
+        raise ValueError("a selection bias chooses among sigmoid scores")
     if activation not in _ACTIVATIONS:
         raise ValueError(f"unknown activation {activation!r}")
     act = _ACTIVATIONS[activation]
@@ -150,7 +161,7 @@ def dropless_moe(x, router_in, w_router, w_gate, w_up, w_down, *,
                                                    top_k)
                 else:
                     experts, weights = route_sigmoid_norm(
-                        router_in, w_router, top_k, scale)
+                        router_in, w_router, top_k, scale, bias)
                 if not whole:
                     # an expert that lives elsewhere: past every group
                     experts = jnp.where(

@@ -22,6 +22,11 @@ substitute for dynamic-length writes. Trash-page contents are garbage
 and must never be gathered for a live position (the block tables of
 live sequences only reference allocated pages).
 
+A latent attention (DeepSeek-V2's multi-head latent attention) keeps
+one row a token for all its heads, the normed latent beside the rotary
+key part they share, and no V: ``latent_pool_shape`` lays it out,
+``paged_latent_attention_update`` writes it and attends over it.
+
 These are pure jax functions; the model layer threads them through
 ``apply_op`` (models/gpt.py) and the decode engine jits them via
 ``serving.generation.model_fns``.
@@ -51,7 +56,8 @@ __all__ = ["flat_slots", "write_pool", "gather_pool",
            "paged_attention_update", "kernel_by_default", "is_quantized_pool",
            "quantize_kv_rows", "dequantize_kv", "kv_pool_bytes",
            "resolve_kv_dtype", "kv_pool_shape", "kv_pool_heads_spec",
-           "new_kv_pool"]
+           "new_kv_pool", "latent_pool_shape",
+           "paged_latent_attention_update"]
 
 KINDS = ("prefill", "decode", "chunked")
 KV_DTYPES = ("", "float32", "bfloat16", "int8")
@@ -75,6 +81,20 @@ def kv_pool_heads_spec(ndim: int, axis: str = "mp") -> tuple:
     so equal contiguous blocks of it are whole heads (``kv_heads %
     mp == 0``)."""
     return (None,) * (ndim - 1) + (axis,)
+
+
+def latent_pool_shape(num_pages, page_size, width):
+    """Shape of one latent attention's pool (``paged_latent_attention_
+    update``): a token's row of ``width`` values for every head, in
+    whole 128-lane tiles (576 values in 640 lanes, the last 64 zeros).
+    That costs no byte beside a row of 576 lanes: the chip's tiled
+    layout keeps such a row in 640 lanes too, or else makes the page
+    axis minor, which puts two copies of the whole pool round every
+    program that reads it (compiled for a v5e: PERF.md section 4); and
+    the decode kernel copies a page with its own DMA only where the row
+    is whole tiles."""
+    return (int(num_pages), int(page_size),
+            -(-int(width) // 128) * 128)
 
 
 def new_kv_pool(num_pages, page_size, kv_heads, head_dim, dtype, lead=()):
@@ -439,6 +459,77 @@ def paged_attention_update(q, k, v, k_pool, v_pool, block_tables,
             positions, page_size=page_size, kind=kind,
             use_flash=use_flash, use_pallas=use_pallas, mesh=mesh,
             window=None if window is None else int(window))
+
+
+def paged_latent_attention_update(q, latent, pool, block_tables, ctx_len,
+                                  valid, positions, *, page_size: int,
+                                  kind: str, scale: float, value_dim: int,
+                                  expanded=None, use_flash: bool = True,
+                                  use_pallas=None):
+    """One latent-attention layer's cache-aware attention (multi-head
+    latent attention, DeepSeek-V2): write this call's latent rows into
+    the layer's one pool, then attend.
+
+    latent: [B, S, W], a token's row for every head: the normed latent
+    (``value_dim`` wide) and then the rotated key part all heads share;
+    pool: [num_pages, page_size, L] (``latent_pool_shape``: the row in
+    whole lane tiles, zeros past W; no V pool); block_tables, ctx_len,
+    valid, positions as ``paged_attention_update`` takes them.
+
+    kind="prefill": ``expanded`` is ``(q, k, v)`` [B, S, H, D] with
+    every head's keys and values made from the latent: ordinary causal
+    attention over the window (``attention_bshd``); returns [B, S, H,
+    Dv]. kind="decode": ``q`` [B, 1, H, W] holds the absorbed queries
+    (a head's query through its key up-projection, beside its rotary
+    part), so a score is ``q . row`` and the value of a position is its
+    row's first ``value_dim`` lanes, the latent, which the caller takes
+    through each head's value up-projection; returns [B, 1, H,
+    value_dim]. The page-copying kernel attends where ``use_pallas``
+    (None: ``kernel_by_default``), the pure body below elsewhere.
+    A chunked window is not built for a latent pool."""
+    if kind not in ("prefill", "decode"):
+        raise ValueError(f"a latent pool serves prefill and decode, not "
+                         f"kind={kind!r}")
+    b, s, w = latent.shape
+    lanes = pool.shape[-1]
+    with jax.named_scope("paged_attention"):
+        with jax.named_scope("kv_write"):
+            slots = flat_slots(block_tables, positions, valid, page_size)
+            rows = jnp.pad(latent.reshape(b * s, 1, w),
+                           ((0, 0), (0, 0), (0, lanes - w)))
+            pool = write_pool(pool, slots.reshape(b * s), rows)
+        with jax.named_scope("attend"):
+            if kind == "prefill":
+                from .flash_attention import attention_bshd
+                return attention_bshd(*expanded, causal=True, scale=scale,
+                                      use_flash=use_flash), pool
+            if use_pallas is None:
+                use_pallas = kernel_by_default(page_size)
+            if use_pallas:
+                from . import pallas_paged_attention as ppa
+                out = ppa.paged_latent_decode(
+                    q[:, 0], pool, block_tables, ctx_len,
+                    page_size=page_size, scale=scale, value_dim=value_dim)
+            else:
+                rows = gather_pool(pool, block_tables, 1)[:, :, 0, :w]
+                out = _latent_decode_attention(q[:, 0], rows, ctx_len,
+                                               scale, value_dim)
+    return out[:, None].astype(q.dtype), pool
+
+
+def _latent_decode_attention(q, rows, ctx_len, scale, value_dim):
+    """The pure body's decode over gathered latent rows: q [B, H, W],
+    rows [B, T, W], ctx_len [B]. Scores and softmax in f32, the values
+    the rows' first ``value_dim`` lanes. Returns [B, H, value_dim] f32."""
+    logits = jnp.einsum("bhw,btw->bht", q, rows,
+                        preferred_element_type=jnp.float32) \
+        * jnp.float32(scale)
+    seen = jnp.arange(rows.shape[1])[None, None, :] < ctx_len[:, None, None]
+    probs = jax.nn.softmax(jnp.where(seen, logits, jnp.float32(-1e30)),
+                           axis=-1)
+    return jnp.einsum("bht,btc->bhc", probs.astype(rows.dtype),
+                      rows[..., :value_dim],
+                      preferred_element_type=jnp.float32)
 
 
 def kernel_by_default(page_size: int) -> bool:

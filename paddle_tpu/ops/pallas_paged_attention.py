@@ -10,7 +10,7 @@ read K/V *through the block table* instead, for the pages a lane's
 TPU they are the path (``paged_attention.kernel_by_default``); the pure
 body stays as the oracle they are tested against.
 
-Two kernels:
+Three kernels:
 
 - ``_decode_kernel`` — ``decode`` (S == 1, mask ``t < ctx_len[b]``;
   PagedAttention decode, Kwon et al. SOSP '23) over float pools. Grid
@@ -44,6 +44,12 @@ Two kernels:
   by passing the pool that many times with per-subtile index maps
   (table-adjacent pages are not pool-adjacent, so one BlockSpec cannot
   cover them).
+- ``_latent_decode_kernel`` — ``decode`` over a latent pool (one row a
+  token for every head of a latent attention,
+  ``paged_attention.paged_latent_attention_update``): ``_decode_kernel``'s
+  page copies and online softmax, a lane's absorbed queries of all heads
+  against a chunk of rows in one MXU product, the values the rows' first
+  ``value_dim`` lanes.
 
 Serving ``prefill`` does not read the pool at all and is not routed
 here.
@@ -85,7 +91,8 @@ from ..framework import place as _place
 from .pallas_attention import LANES, NEG_INF, _i0
 from .paged_attention import is_quantized_pool
 
-__all__ = ["paged_attention", "supported", "decode_copies_pages"]
+__all__ = ["paged_attention", "supported", "decode_copies_pages",
+           "paged_latent_decode"]
 
 
 def supported(q, k_pool, block_tables, page_size: int, kind: str) -> bool:
@@ -492,6 +499,116 @@ def _paged_decode(q, k_pool, v_pool, tables, ctx, *, page_size, scale,
     return _unfold_outputs(
         _inference_only(_run)(tables, ctx, q, k_pool, v_pool), hq,
         lanes // d)
+
+
+def _latent_decode_kernel(tables_ref, ctx_ref, q_ref, pool_hbm, o_ref, buf,
+                          sem, *, page_size, chunk, scale, value_dim):
+    """Grid program for one lane of a latent pool (one row a token for
+    every head: ``paged_attention.paged_latent_attention_update``):
+    every live page of its context, and no other, is copied HBM -> VMEM
+    by this program's own DMAs (two slots: chunk c+1 is in flight while
+    chunk c is attended, as in ``_decode_kernel``), and all the lane's
+    heads' absorbed queries meet a chunk in one MXU product folded into
+    a running softmax (``_attend_group``), the values being the rows'
+    first ``value_dim`` lanes. A dead lane starts no copy and emits
+    zeros.
+
+    Scalar prefetch: tables [B, P] i32, ctx [B] i32. q_ref: [H, L] in
+    the pool's type, zero past the row's values; pool_hbm: [num_pages,
+    page_size, L], left in HBM; o_ref: [H, value_dim] f32; buf: [2,
+    chunk*page_size, L]; sem: a DMA semaphore a slot."""
+    b = pl.program_id(0)
+    ps = page_size
+    ctx = ctx_ref[b]
+    n_pages = jnp.minimum((ctx + (ps - 1)) // jnp.int32(ps),
+                          tables_ref.shape[1])
+    n_chunks = (n_pages + (chunk - 1)) // jnp.int32(chunk)
+
+    @pl.when(b == 0)
+    def _init():
+        # a part-filled chunk's stale slots weigh exp(-1e30 - m) == 0 but
+        # meet the MXU: what VMEM held before the first copy must be finite
+        buf[...] = jnp.zeros_like(buf)
+
+    def each_live_page(c, slot, fn):
+        for j in range(chunk):        # static: a chunk is a few pages
+            @pl.when(c * chunk + j < n_pages)
+            def _page(j=j):
+                fn(pltpu.make_async_copy(
+                    pool_hbm.at[tables_ref[b, c * chunk + j]],
+                    buf.at[slot, pl.ds(j * ps, ps)], sem.at[slot]))
+
+    @pl.when(n_chunks > 0)
+    def _first():
+        each_live_page(_i0(), _i0(), lambda dma: dma.start())
+
+    q = q_ref[...]
+
+    def _attend(c, carry):
+        slot = c % jnp.int32(2)
+
+        @pl.when(c + 1 < n_chunks)
+        def _next():
+            each_live_page(c + 1, 1 - slot, lambda dma: dma.start())
+
+        each_live_page(c, slot, lambda dma: dma.wait())
+        t = c * (chunk * ps) + jax.lax.broadcasted_iota(
+            jnp.int32, (1, chunk * ps), 1)
+        rows = buf[slot]
+        return _attend_group(q, rows, rows[:, :value_dim], t < ctx, *carry,
+                             scale)
+
+    heads = q_ref.shape[0]
+    _, l, acc = jax.lax.fori_loop(
+        0, n_chunks, _attend,
+        (jnp.full((heads, 1), NEG_INF, jnp.float32),
+         jnp.zeros((heads, 1), jnp.float32),
+         jnp.zeros((heads, value_dim), jnp.float32)))
+    o_ref[...] = acc / jnp.maximum(l, jnp.float32(1e-30))
+
+
+def paged_latent_decode(q, pool, tables, ctx, *, page_size, scale,
+                        value_dim, pages_per_chunk=None):
+    """The page-copying decode kernel over a latent pool: q [B, H, W]
+    (absorbed queries beside their rotary parts), pool [num_pages,
+    page_size, L] (``paged_attention.latent_pool_shape``: a row of W
+    values in whole lane tiles, L >= W). Returns [B, H, value_dim] f32:
+    each head's softmax-weighted sum of its context's latents."""
+    from . import autotune
+
+    b, h, w = q.shape
+    lanes = pool.shape[2]
+    chunk = autotune.paged_decode_chunk(
+        page_size, lanes, pool.dtype.itemsize, tables.shape[1],
+        on_mxu=True, override=pages_per_chunk)
+    # the row's lanes past its values hold zeros, and so do the queries'
+    q = jnp.pad(q.astype(pool.dtype), ((0, 0), (0, 0), (0, lanes - w)))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(b,),
+        in_specs=[pl.BlockSpec((None, h, lanes),
+                               lambda bi, ts, cs: (bi, _i0(), _i0())),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((None, h, value_dim),
+                               lambda bi, ts, cs: (bi, _i0(), _i0())),
+        scratch_shapes=[pltpu.VMEM((2, chunk * page_size, lanes),
+                                   pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,))])
+    kernel = functools.partial(
+        _latent_decode_kernel, page_size=page_size, chunk=chunk,
+        scale=float(scale), value_dim=int(value_dim))
+
+    def _run(tables, ctx, q, pool):
+        return pl.pallas_call(
+            kernel, grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((b, h, value_dim), jnp.float32),
+            # sequential: the buffer is zeroed by the first program
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=not _place.on_tpu(),
+        )(tables, ctx, q, pool)
+
+    return _inference_only(_run)(tables.astype(jnp.int32),
+                                 ctx.astype(jnp.int32), q, pool)
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, ctx_len, valid,

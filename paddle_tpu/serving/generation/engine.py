@@ -851,10 +851,17 @@ class GenerationServer:
                 f"state slot holds the state after a sequence's last "
                 f"token alone, so no shared prefix can be resumed from "
                 f"it and no verify window rolled back")
+        if self.kv.latent and (prefix_cache or draft_model is not None):
+            raise ValueError(
+                f"{'prefix_cache=True' if prefix_cache else 'a draft model'}"
+                f" with a latent attention (the 'latent' of "
+                f"kv_cache_spec()): a prefix hit and a verify window "
+                f"attend a window of positions over the cache, and no "
+                f"such attention is built over a latent pool")
         if prefix_cache is None:
             prefix_cache = self.kv.window is None \
-                and not self.kv.state_columns and bool(
-                    _flag("FLAGS_decode_prefix_cache", True))
+                and not self.kv.state_columns and not self.kv.latent \
+                and bool(_flag("FLAGS_decode_prefix_cache", True))
         self.prefix = PrefixCache(self.kv) if prefix_cache else None
         # ---- speculative decoding (draft proposes, target verifies)
         self.spec_k = int(spec_k if spec_k is not None

@@ -41,6 +41,12 @@ finds a row's slot in its table row. A slot is a sequence's own, handed
 out at admission and taken back with its pages; what it held is not
 cleared (a prefill writes the slot from zeros, it never reads it).
 
+A latent attention (``kinds["full"]["latent"]``) keeps the full kind's
+pages and its one table, but a layer's pool holds one row a token for
+every head (the latent and the rotary key part all heads share) and no
+V: its bytes are reported as ``pool_bytes["latent"]`` and compared with
+``kv_cache_spec()`` when the pools are built, as the state pools' are.
+
 Thread-safety: the engine's worker thread is the only mutator; the
 allocator itself is plain data guarded by the engine lock.
 """
@@ -49,7 +55,7 @@ from __future__ import annotations
 import math
 from typing import Dict, List, Optional
 
-__all__ = ["PagedKVCache", "window_of", "has_state"]
+__all__ = ["PagedKVCache", "window_of", "has_state", "latent_of"]
 
 
 def window_of(spec: dict) -> Optional[int]:
@@ -64,6 +70,14 @@ def has_state(spec: dict) -> bool:
     """Whether a model's ``kv_cache_spec()`` names layers of the
     ``state`` kind."""
     return "state" in (spec.get("kinds") or {})
+
+
+def latent_of(spec: dict) -> Optional[int]:
+    """The width of a latent attention's cached row (the ``full`` kind's
+    ``latent``: one row a token for every head, no V), from a model's
+    ``kv_cache_spec()``; None where the full layers cache K and V."""
+    full = (spec.get("kinds") or {}).get("full") or {}
+    return full.get("latent")
 
 
 class PagedKVCache:
@@ -126,6 +140,18 @@ class PagedKVCache:
             if got != want:
                 raise ValueError(
                     f"the state pools hold {got:g} bytes a slot and "
+                    f"kv_cache_spec() states {want}: the pools' shapes "
+                    f"or types are not the spec's")
+        self.latent = latent_of(spec)
+        if self.latent:
+            # likewise a latent pool: one row a token for all heads
+            want = int(model.kv_cache_spec(
+                self.kv_dtype)["kv_bytes_per_token"])
+            got = self._pool_bytes_by_kind["latent"] / (
+                self.num_pages * self.page_size)
+            if got != want:
+                raise ValueError(
+                    f"the latent pools hold {got:g} bytes a token and "
                     f"kv_cache_spec() states {want}: the pools' shapes "
                     f"or types are not the spec's")
         # serving mesh (serving/mesh.py): heads-sharded committed
@@ -270,7 +296,8 @@ class PagedKVCache:
     def _bytes_by_kind(self, spec: dict) -> dict:
         """Device bytes of each kind's pools (the layers
         ``kv_cache_spec()["kinds"]`` lists under it; one stacked pool
-        is all ``full``)."""
+        is all ``full``; a latent attention's full pools are named
+        ``latent``)."""
         import jax
 
         def nbytes(tree):
@@ -280,8 +307,8 @@ class PagedKVCache:
         kinds = spec.get("kinds")
         if not kinds or not isinstance(self.k, list):
             return {"full": nbytes((self.k, self.v))}
-        return {kind: sum(nbytes((self.k[i], self.v[i]))
-                          for i in what["layers"])
+        return {"latent" if what.get("latent") else kind:
+                sum(nbytes((self.k[i], self.v[i])) for i in what["layers"])
                 for kind, what in kinds.items()}
 
     def pool_bytes(self) -> int:

@@ -141,6 +141,10 @@ class CachedDecoder:
         # a layer that keeps a recurrent state a sequence (kv_cache.py,
         # the 'state' kind) cannot continue a window from its slot
         self.has_state = "state" in (spec.get("kinds") or {})
+        # nor can a latent pool take a window (no chunked attention
+        # over it is built)
+        from .kv_cache import latent_of
+        self.has_latent = bool(latent_of(spec))
         self._params, self._buffers = state_arrays(model)
         if smesh.live:
             # committed mp-sharded placement: GSPMD partitions every
@@ -626,13 +630,19 @@ class CachedDecoder:
             else np.ascontiguousarray(a, np.float32)
             for a in (temperature, uniform))
 
-    def _refuse_window_for_state(self, what: str):
+    def _refuse_window(self, what: str):
         if self.has_state:
             raise NotImplementedError(
                 f"{what} with {type(self.model).__name__}: its layers of "
                 f"the 'state' kind keep the state after a sequence's "
                 f"last token alone, so a window cannot start from a "
                 f"cached prefix nor be rolled back")
+        if self.has_latent:
+            raise NotImplementedError(
+                f"{what} with {type(self.model).__name__}: its latent "
+                f"attention's pool serves prefill and decode, and no "
+                f"window of several positions over a cached prefix is "
+                f"built for it")
 
     def prefill(self, ids: np.ndarray, prompt_lens: np.ndarray,
                 tables: np.ndarray, temperature, uniform, k, v):
@@ -661,7 +671,7 @@ class CachedDecoder:
         first, then the row's private pages); temperature and uniform
         as ``prefill`` takes them. Returns ``(tokens [B] int32,
         last_logits [B, vocab], k', v', new_signature)``."""
-        self._refuse_window_for_state("prefill_chunked")
+        self._refuse_window("prefill_chunked")
         args = (self._params, self._buffers,
                 np.ascontiguousarray(ids, np.int64),
                 np.ascontiguousarray(start, np.int32),
@@ -681,7 +691,7 @@ class CachedDecoder:
         every proposal in one device step. Rejected positions' K/V
         writes land on the lane's already-reserved pages and are rolled
         back by context-length truncation, never by pool mutation."""
-        self._refuse_window_for_state("verify")
+        self._refuse_window("verify")
         args = (self._params, self._buffers,
                 np.ascontiguousarray(tokens, np.int64),
                 np.ascontiguousarray(start, np.int32),

@@ -811,3 +811,105 @@ def test_the_hybrid_cells_programs_fit_the_chip_beside_their_pools(
     if program == "decode":
         assert plan.temp_size_in_bytes < 512 * 1024 ** 2
         assert "ssm/state_update" in text
+
+
+# -- latent attention: one 576-value row a token for 20 heads -------------
+# the latent configuration's serving geometry: 20 heads whose absorbed
+# queries are 512 + 64 wide, a row of 640 lanes (whole tiles), bf16,
+# 128 lanes, 257-page tables over 32,897 pages; a share of 8 of 64
+# SwiGLU experts of 2048 x 1536, 4 a token, a selection bias
+LA_HEADS, LA_WIDTH, LA_LATENT, LA_LANES = 20, 576, 512, 128
+LA_CUT = dict(num_layers=2, moe_num_experts=8, vocab_size=19360)
+
+
+def test_latent_decode_compiles_over_a_640_lane_pool(one_chip,
+                                                     compiled_kernels):
+    """``paged_latent_attention_update`` as the latent configuration's
+    decode step calls it: the page-copying kernel, 20 heads a lane in
+    one MXU product, a 576-value row in 640 lanes; the pool reaches the
+    kernel as it lies, row-major (a 576-lane pool is kept by the chip
+    with its page axis minor, and copied twice round the kernel)."""
+    from paddle_tpu.ops.paged_attention import (
+        latent_pool_shape, paged_latent_attention_update)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    shape = latent_pool_shape(HY_PAGES, PAGE, LA_WIDTH)
+    assert shape[-1] == 640
+    text = jax.jit(
+        functools.partial(paged_latent_attention_update, page_size=PAGE,
+                          kind="decode", scale=1 / 16, value_dim=LA_LATENT),
+        donate_argnums=(2,)).lower(
+        sds((LA_LANES, 1, LA_HEADS, LA_WIDTH), jnp.bfloat16),
+        sds((LA_LANES, 1, LA_WIDTH), jnp.bfloat16), sds(shape, jnp.bfloat16),
+        sds((LA_LANES, HY_WIDTH), jnp.int32), sds((LA_LANES,), jnp.int32),
+        sds((LA_LANES, 1), jnp.bool_), sds((LA_LANES, 1), jnp.int32)
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    pool = rf"bf16\[{HY_PAGES},{PAGE},640\]"
+    assert set(re.findall(pool + r"\{([\d,]+)", text)) == {"2,1,0"}
+    assert not re.findall(pool + r"\S* copy\(", text)
+
+
+@pytest.mark.parametrize("program", ["decode", (16, 2048)],
+                         ids=["decode-128", "prefill-16x2048"])
+def test_the_latent_cells_programs_compile_over_their_pools(
+        one_chip, compiled_kernels, program):
+    """The decode program and the largest prefill program of a two-layer
+    cut of the cell ``serve-glm47flash-reasoning`` (a dense layer and an
+    expert layer, abstract bfloat16 weights), compiled for the chip over
+    donated latent pools of the cell's 32,897 pages: the pools are
+    written where they lie and never copied, the decode step holds the
+    latent kernel, the prefill the flash kernel at heads of 256, and
+    the ``mla`` scopes name the projections."""
+    import paddle_tpu as paddle
+    from paddle_tpu import models
+    from paddle_tpu.jit.functional import state_arrays
+    from paddle_tpu.serving.generation.model_fns import CachedDecoder
+    with paddle.LazyGuard():
+        model = models.GPTForCausalLM(models.glm_4p7_flash(**LA_CUT))
+    model.eval()
+    dec = CachedDecoder(model, max_batch=LA_LANES, page_size=PAGE,
+                        pages_per_seq=HY_WIDTH, donate=True,
+                        max_positions=4112, kv_dtype="")
+    assert dec.use_pallas is True
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    def described(tree):
+        return jax.tree_util.tree_map(lambda a: sds(
+            a.shape, jnp.bfloat16 if jnp.issubdtype(a.dtype, jnp.floating)
+            else a.dtype), tree)
+
+    params, buffers = described(state_arrays(model))
+    k, v = described(jax.eval_shape(lambda: model.init_kv_pools(
+        HY_PAGES, PAGE)))
+    assert v == [()] * 2
+    width = HY_WIDTH
+    if program == "decode":
+        rows = LA_LANES
+        lowered = dec._decode_jit.lower(
+            params, buffers, sds((rows,), jnp.int32), sds((rows,), jnp.int32),
+            sds((rows,), jnp.bool_), sds((rows,), jnp.int32),
+            sds((rows, width), jnp.int32), sds((rows,), jnp.float32),
+            sds((rows,), jnp.float32), k, v)
+    else:
+        rows, seq = program
+        lowered = dec._prefill_jit.lower(
+            params, buffers, sds((rows, seq), jnp.int64),
+            sds((rows,), jnp.int32), sds((rows, width), jnp.int32),
+            sds((rows,), jnp.float32), sds((rows,), jnp.float32), k, v)
+    compiled = lowered.compile()
+    plan = compiled.memory_analysis()
+    assert plan.argument_size_in_bytes + plan.temp_size_in_bytes < CHIP_BYTES
+    assert plan.alias_size_in_bytes >= 2 * HY_PAGES * PAGE * 640 * 2
+    text = compiled.as_text()
+    pool = rf"bf16\[{HY_PAGES},{PAGE},640\]"
+    assert set(re.findall(pool + r"\{([\d,]+)", text)) == {"2,1,0"}
+    assert not re.findall(pool + r"\S* copy\(", text)
+    assert "mla/" in text and "tpu_custom_call" in text
+    if program == "decode":
+        assert "mla/absorb" in text and "mla/v_up" in text
+        assert plan.temp_size_in_bytes < 256 * 1024 ** 2

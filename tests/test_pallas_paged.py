@@ -671,3 +671,52 @@ def test_update_decode_parity_through_the_write(head_dim):
     for i in (1, 2):
         np.testing.assert_array_equal(np.asarray(got[True][i]),
                                       np.asarray(got[False][i]))
+
+
+@pytest.mark.parametrize("chunk", [None, 1, 3])
+@pytest.mark.parametrize("pool_dtype", [jnp.float32, jnp.bfloat16])
+def test_latent_decode_kernel_against_the_pure_body(pool_dtype, chunk):
+    """The latent pool's page-copying kernel against the pure body that
+    gathers the table: 5 heads of absorbed queries 40 wide (a 32-wide
+    latent and an 8-wide rotary key part, in a row of 128 lanes whose
+    last 88 are zeros) over ragged lanes, one of them dead; through the
+    write, the same pool either way."""
+    from paddle_tpu.ops.paged_attention import (
+        latent_pool_shape, paged_latent_attention_update)
+    from paddle_tpu.ops.pallas_paged_attention import paged_latent_decode
+    heads, width, latent, pages = 5, 40, 32, 4
+    rng = np.random.RandomState(3)
+    ctx = jnp.asarray([19, 0, 32, 7], jnp.int32)
+    B = ctx.shape[0]
+    tables = jnp.asarray(1 + rng.permutation(B * pages).reshape(B, pages),
+                         jnp.int32)
+    shape = latent_pool_shape(1 + B * pages, PS, width)
+    assert shape[2] == 128
+    rows = rng.randn(*shape[:2], width)
+    pool = jnp.asarray(np.pad(rows, ((0, 0), (0, 0), (0, 128 - width))),
+                       pool_dtype)
+    q = jnp.asarray(rng.randn(B, 1, heads, width), pool_dtype)
+    new = jnp.asarray(rng.randn(B, 1, width), pool_dtype)
+    valid = jnp.asarray(np.asarray(ctx) > 0)[:, None]
+    pos = jnp.maximum(ctx - 1, 0)[:, None]
+    got = {up: paged_latent_attention_update(
+        q, new, pool, tables, ctx, valid, pos, page_size=PS, kind="decode",
+        scale=0.25, value_dim=latent, use_pallas=up) for up in (False, True)}
+    live = np.asarray(ctx) > 0
+    tol = 2e-5 if pool_dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(
+        np.asarray(got[True][0], np.float32)[live],
+        np.asarray(got[False][0], np.float32)[live], rtol=tol, atol=tol)
+    np.testing.assert_array_equal(np.asarray(got[True][1], np.float32),
+                                  np.asarray(got[False][1], np.float32))
+    written = np.asarray(got[True][1], np.float32)
+    assert not written[..., width:].any()
+    # the kernel alone, at other chunk lengths: the pure body's numbers
+    out = paged_latent_decode(q[:, 0], got[True][1], tables, ctx,
+                              page_size=PS, scale=0.25, value_dim=latent,
+                              pages_per_chunk=chunk)
+    assert out.shape == (B, heads, latent) and out.dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(out)[live], np.asarray(got[False][0], np.float32)[
+            live, 0], rtol=tol, atol=tol)
+    assert np.all(np.asarray(out)[~live] == 0)
