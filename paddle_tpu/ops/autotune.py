@@ -159,16 +159,67 @@ def paged_decode_chunk(page_size: int, lanes: int, itemsize: int,
     and to what fits the kernel's VMEM (a buffer pads to the native
     tile: 8 sublanes of 32 bits, 128 lanes)."""
     if override is not None:
-        if override < 1:
-            raise ValueError(f"pages_per_chunk must be >= 1, got "
-                             f"{override}")
-        return int(override)
+        return _chunk_override(override)
+    chunk = PAGED_DECODE_PAGES_PER_CHUNK_MXU if on_mxu \
+        else PAGED_DECODE_PAGES_PER_CHUNK
+    return _chunk_fit(chunk, 4, page_size, lanes, itemsize, pages_per_seq)
+
+
+# The latent kernel's (``_latent_decode_kernel``: a lane's 20 query
+# heads in one MXU product over a chunk) at the latent cell's shape:
+# 128 lanes, 640-lane bfloat16 rows (576 live), 16-slot pages, 257-page
+# tables over 32,897 pages, contexts drawn as ``backlog-reasoning``
+# holds them (mean 1,396 positions), on a v5e. ms a call, the copies
+# started in loops, a lane's first chunk started by the program before
+# it / by its own:
+#   pages a chunk          16            32            64            128
+#   a product, the chunk   0.661/0.704   0.525/0.565   0.475/0.560   0.485
+#   ... its live pages
+#       rounded up to 32   -             -             0.466         0.441
+#       rounded up to 16   -             0.528/0.565   0.463/0.544   0.442
+# The unrolled kernel before them (16 pages, its own first chunk):
+# 0.716. At 64 pages, copies in groups of 8 and waits a set bit of the
+# count read 0.479; a wait a page 0.597, a loop of single copies 0.562,
+# a third or fourth slot 0.49. Under a wait a page, products of 16
+# pages in a loop read 0.790 against 0.638 for one. Each compiles in
+# 1-3 s.
+PAGED_LATENT_PAGES_PER_CHUNK = 128
+# Page copies started back to back in one step of the loop that starts
+# a chunk's (8 read the same at 64 pages, 4 3% slower).
+PAGED_LATENT_COPY_GROUP = 16
+# A chunk's product takes its live pages rounded up to this many.
+PAGED_LATENT_PRODUCT_STEP = 32
+
+
+def paged_latent_chunk(page_size: int, lanes: int, itemsize: int,
+                       pages_per_seq: int, override=None) -> Tuple[int, int]:
+    """``(pages per chunk, pages per product step)`` of the latent
+    decode kernel for a pool whose page is ``[page_size, lanes]``: its
+    own constants, the chunk cut to what the table holds and to what
+    fits the kernel's VMEM (one pool, two slots), the step a divisor of
+    the chunk. ``override`` names the chunk."""
+    chunk = _chunk_override(override) if override is not None else \
+        _chunk_fit(PAGED_LATENT_PAGES_PER_CHUNK, 2, page_size, lanes,
+                   itemsize, pages_per_seq)
+    step = PAGED_LATENT_PRODUCT_STEP
+    return chunk, step if chunk % step == 0 else chunk
+
+
+def _chunk_override(override) -> int:
+    if override < 1:
+        raise ValueError(f"pages_per_chunk must be >= 1, got {override}")
+    return int(override)
+
+
+def _chunk_fit(chunk: int, buffers: int, page_size: int, lanes: int,
+               itemsize: int, pages_per_seq: int) -> int:
+    """``chunk`` cut to what the table holds and to what ``buffers``
+    chunk buffers may take of PAGED_DECODE_VMEM_BYTES (a buffer pads to
+    the native tile: 8 sublanes of 32 bits, 128 lanes)."""
     sublanes = 8 * max(1, 4 // itemsize)
     page_bytes = (-(-page_size // sublanes) * sublanes
                   * -(-lanes // 128) * 128 * itemsize)
-    fit = PAGED_DECODE_VMEM_BYTES // (4 * page_bytes)
-    chunk = PAGED_DECODE_PAGES_PER_CHUNK_MXU if on_mxu \
-        else PAGED_DECODE_PAGES_PER_CHUNK
+    fit = PAGED_DECODE_VMEM_BYTES // (buffers * page_bytes)
     return int(max(1, min(chunk, pages_per_seq, fit)))
 
 

@@ -47,9 +47,11 @@ Three kernels:
 - ``_latent_decode_kernel`` — ``decode`` over a latent pool (one row a
   token for every head of a latent attention,
   ``paged_attention.paged_latent_attention_update``): ``_decode_kernel``'s
-  page copies and online softmax, a lane's absorbed queries of all heads
-  against a chunk of rows in one MXU product, the values the rows' first
-  ``value_dim`` lanes.
+  online softmax, a lane's absorbed queries of all heads against a chunk
+  of rows in one MXU product, the values the rows' first ``value_dim``
+  lanes; its page copies are issued in loops, chunks of
+  ``autotune.paged_latent_chunk`` pages, and run on from each live lane
+  into the next.
 
 Serving ``prefill`` does not read the pool at all and is not routed
 here.
@@ -502,61 +504,131 @@ def _paged_decode(q, k_pool, v_pool, tables, ctx, *, page_size, scale,
 
 
 def _latent_decode_kernel(tables_ref, ctx_ref, q_ref, pool_hbm, o_ref, buf,
-                          sem, *, page_size, chunk, scale, value_dim):
+                          sem, slot_ref, *, page_size, chunk, group, step,
+                          scale, value_dim):
     """Grid program for one lane of a latent pool (one row a token for
     every head: ``paged_attention.paged_latent_attention_update``):
     every live page of its context, and no other, is copied HBM -> VMEM
-    by this program's own DMAs (two slots: chunk c+1 is in flight while
-    chunk c is attended, as in ``_decode_kernel``), and all the lane's
+    by DMAs issued in loops, a chunk at a time into two slots (chunk
+    c+1 is in flight while chunk c is attended), and all the lane's
     heads' absorbed queries meet a chunk in one MXU product folded into
     a running softmax (``_attend_group``), the values being the rows'
-    first ``value_dim`` lanes. A dead lane starts no copy and emits
-    zeros.
+    first ``value_dim`` lanes. A chunk's product takes its live pages
+    rounded up to ``step``. A dead lane starts no copy and emits zeros.
+
+    The copies run on across lanes: the programs run in order, and the
+    one that attends a lane's last chunk starts the first chunk of the
+    next live lane into the other slot, which ``slot_ref`` hands on; the
+    first program starts the first live lane's. So the DMA engine is not
+    idle over a lane's last product, its output and the next program's
+    start.
 
     Scalar prefetch: tables [B, P] i32, ctx [B] i32. q_ref: [H, L] in
     the pool's type, zero past the row's values; pool_hbm: [num_pages,
     page_size, L], left in HBM; o_ref: [H, value_dim] f32; buf: [2,
-    chunk*page_size, L]; sem: a DMA semaphore a slot."""
+    chunk*page_size, L]; sem: a DMA semaphore a slot; slot_ref: [1] i32
+    in SMEM, the slot that holds the lane's first chunk."""
     b = pl.program_id(0)
+    lanes = ctx_ref.shape[0]
     ps = page_size
-    ctx = ctx_ref[b]
-    n_pages = jnp.minimum((ctx + (ps - 1)) // jnp.int32(ps),
-                          tables_ref.shape[1])
-    n_chunks = (n_pages + (chunk - 1)) // jnp.int32(chunk)
+
+    def pages(lane):
+        return jnp.minimum((ctx_ref[lane] + (ps - 1)) // jnp.int32(ps),
+                           tables_ref.shape[1])
+
+    def next_live(lane):
+        """The first lane after ``lane`` with a context, else ``lanes``."""
+        return jax.lax.while_loop(
+            lambda n: (n < lanes)
+            & (ctx_ref[jnp.minimum(n, lanes - 1)] == 0),
+            lambda n: n + 1, lane + 1)
+
+    def start(lane, c, slot, n_pages):
+        """Start the copies of the live pages of ``lane``'s chunk ``c``:
+        whole groups of ``group``, each unrolled, then one at a time."""
+        first = c * chunk
+        live = jnp.minimum(n_pages - first, chunk)
+
+        def copy(j):
+            pltpu.make_async_copy(
+                pool_hbm.at[tables_ref[lane, first + j]],
+                buf.at[slot, pl.ds(pl.multiple_of(j * ps, ps), ps)],
+                sem.at[slot]).start()
+
+        def _group(i, carry):
+            for k in range(group):
+                copy(i * group + k)
+            return carry
+
+        def _page(j, carry):
+            copy(j)
+            return carry
+
+        whole = live // jnp.int32(group)
+        jax.lax.fori_loop(0, whole, _group, 0)
+        jax.lax.fori_loop(whole * group, live, _page, 0)
+
+    def wait(slot, live):
+        """Wait for ``live`` pages' copies into ``slot``: a wait takes the
+        slot's semaphore down by its buffer's bytes, so one wait a set
+        bit of the count, of that many pages, covers them all."""
+        k = 1
+        while k <= chunk:
+            @pl.when((live & k) != 0)
+            def _wait(k=k):
+                rows = buf.at[slot, pl.ds(0, k * ps)]
+                pltpu.make_async_copy(rows, rows, sem.at[slot]).wait()
+            k *= 2
 
     @pl.when(b == 0)
     def _init():
-        # a part-filled chunk's stale slots weigh exp(-1e30 - m) == 0 but
-        # meet the MXU: what VMEM held before the first copy must be finite
+        # a product's stale rows weigh exp(-1e30 - m) == 0 but meet the
+        # MXU: what VMEM held before the first copy must be finite
         buf[...] = jnp.zeros_like(buf)
+        slot_ref[0] = jnp.int32(0)
+        first = next_live(jnp.int32(-1))
 
-    def each_live_page(c, slot, fn):
-        for j in range(chunk):        # static: a chunk is a few pages
-            @pl.when(c * chunk + j < n_pages)
-            def _page(j=j):
-                fn(pltpu.make_async_copy(
-                    pool_hbm.at[tables_ref[b, c * chunk + j]],
-                    buf.at[slot, pl.ds(j * ps, ps)], sem.at[slot]))
+        @pl.when(first < lanes)
+        def _first():
+            start(first, _i0(), _i0(), pages(first))
 
-    @pl.when(n_chunks > 0)
-    def _first():
-        each_live_page(_i0(), _i0(), lambda dma: dma.start())
-
+    ctx = ctx_ref[b]
+    n_pages = pages(b)
+    n_chunks = (n_pages + (chunk - 1)) // jnp.int32(chunk)
+    slot0 = slot_ref[0]
     q = q_ref[...]
 
     def _attend(c, carry):
-        slot = c % jnp.int32(2)
+        slot = (slot0 + c) % jnp.int32(2)
 
         @pl.when(c + 1 < n_chunks)
         def _next():
-            each_live_page(c + 1, 1 - slot, lambda dma: dma.start())
+            start(b, c + 1, 1 - slot, n_pages)
 
-        each_live_page(c, slot, lambda dma: dma.wait())
-        t = c * (chunk * ps) + jax.lax.broadcasted_iota(
-            jnp.int32, (1, chunk * ps), 1)
-        rows = buf[slot]
-        return _attend_group(q, rows, rows[:, :value_dim], t < ctx, *carry,
-                             scale)
+        @pl.when(c + 1 == n_chunks)
+        def _next_lane():
+            slot_ref[0] = 1 - slot
+            n = next_live(b)
+
+            @pl.when(n < lanes)
+            def _start():
+                start(n, _i0(), 1 - slot, pages(n))
+
+        live = jnp.minimum(n_pages - c * chunk, chunk)
+        wait(slot, live)
+
+        def product(size):
+            def _product(carry):
+                rows = buf[slot, pl.ds(0, size * ps)]
+                t = c * (chunk * ps) + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, size * ps), 1)
+                return _attend_group(q, rows, rows[:, :value_dim], t < ctx,
+                                     *carry, scale)
+            return _product
+
+        return jax.lax.switch((live - 1) // jnp.int32(step),
+                              [product(size) for size in
+                               range(step, chunk + 1, step)], carry)
 
     heads = q_ref.shape[0]
     _, l, acc = jax.lax.fori_loop(
@@ -578,9 +650,9 @@ def paged_latent_decode(q, pool, tables, ctx, *, page_size, scale,
 
     b, h, w = q.shape
     lanes = pool.shape[2]
-    chunk = autotune.paged_decode_chunk(
+    chunk, step = autotune.paged_latent_chunk(
         page_size, lanes, pool.dtype.itemsize, tables.shape[1],
-        on_mxu=True, override=pages_per_chunk)
+        override=pages_per_chunk)
     # the row's lanes past its values hold zeros, and so do the queries'
     q = jnp.pad(q.astype(pool.dtype), ((0, 0), (0, 0), (0, lanes - w)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -592,16 +664,19 @@ def paged_latent_decode(q, pool, tables, ctx, *, page_size, scale,
                                lambda bi, ts, cs: (bi, _i0(), _i0())),
         scratch_shapes=[pltpu.VMEM((2, chunk * page_size, lanes),
                                    pool.dtype),
-                        pltpu.SemaphoreType.DMA((2,))])
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.SMEM((1,), jnp.int32)])
     kernel = functools.partial(
         _latent_decode_kernel, page_size=page_size, chunk=chunk,
+        group=autotune.PAGED_LATENT_COPY_GROUP, step=step,
         scale=float(scale), value_dim=int(value_dim))
 
     def _run(tables, ctx, q, pool):
         return pl.pallas_call(
             kernel, grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, h, value_dim), jnp.float32),
-            # sequential: the buffer is zeroed by the first program
+            # sequential: a program finds its first chunk started by the
+            # one before it, in the slot that one left in SMEM
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=not _place.on_tpu(),

@@ -424,8 +424,9 @@ class CachedDecoder:
                     # v8: the programs choose the next token; v9: the
                     # decode step takes its tokens as it returns them,
                     # [max_batch] int32; v10: a share of the experts
-                    # hands its products the capacity's rows)
-                    "kv_dtype": self.kv_dtype, "v": 10}
+                    # hands its products the capacity's rows; v11: the
+                    # latent kernel's copies run on across lanes)
+                    "kv_dtype": self.kv_dtype, "v": 11}
             # mesh axes + weight spec-tree hash join the geometry ONLY
             # when the mesh is live: an inert (None / 1-device) mesh
             # must reuse today's fingerprints byte-for-byte, and a mesh

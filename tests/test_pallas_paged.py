@@ -673,20 +673,63 @@ def test_update_decode_parity_through_the_write(head_dim):
                                       np.asarray(got[False][i]))
 
 
-@pytest.mark.parametrize("chunk", [None, 1, 3])
-@pytest.mark.parametrize("pool_dtype", [jnp.float32, jnp.bfloat16])
-def test_latent_decode_kernel_against_the_pure_body(pool_dtype, chunk):
+# lanes of 4 pages of 8 slots, each a pattern of contexts
+LATENT_LANES = {
+    "ragged": [19, 0, 32, 7],
+    "first-dead": [0, 19, 32, 7],
+    "two-dead": [19, 0, 0, 7],
+    "last-dead": [19, 32, 7, 0],
+    "all-dead": [0, 0, 0, 0],
+    "full-table": [32, 32, 25, 32],
+    "one-page": [8, 1, 3, 5],
+}
+# (pool dtype, pages a chunk, lanes, (copies a group, pages a product
+# step) or None for the constants)
+LATENT_CASES = [(dtype, chunk, "ragged", None)
+                for dtype in (jnp.float32, jnp.bfloat16)
+                for chunk in (None, 1, 3)] + [
+    (jnp.float32, 6, "ragged", None),           # longer than the table
+    (jnp.bfloat16, 6, "ragged", None),
+] + [(jnp.bfloat16, chunk, lanes, None)
+     for lanes in list(LATENT_LANES)[1:] for chunk in (None, 1)] + [
+    (jnp.float32, 4, "ragged", (2, 2)),
+    (jnp.bfloat16, 4, "first-dead", (2, 2)),
+    (jnp.bfloat16, 4, "two-dead", (3, 1)),
+]
+
+
+def _latent_case_id(case):
+    dtype, chunk, lanes, steps = case
+    parts = [jnp.dtype(dtype).name, str(chunk)]
+    if lanes != "ragged":
+        parts.append(lanes)
+    if steps is not None:
+        parts.append("group%d-step%d" % steps)
+    return "-".join(parts)
+
+
+@pytest.mark.parametrize("pool_dtype,chunk,lanes,steps", LATENT_CASES,
+                         ids=[_latent_case_id(c) for c in LATENT_CASES])
+def test_latent_decode_kernel_against_the_pure_body(pool_dtype, chunk, lanes,
+                                                    steps, monkeypatch):
     """The latent pool's page-copying kernel against the pure body that
     gathers the table: 5 heads of absorbed queries 40 wide (a 32-wide
     latent and an 8-wide rotary key part, in a row of 128 lanes whose
-    last 88 are zeros) over ragged lanes, one of them dead; through the
-    write, the same pool either way."""
+    last 88 are zeros) over lanes of a pattern of contexts (dead lanes
+    first, last, in a row or all; tables filled exactly; single pages),
+    whose copies cross from each live lane to the next; through the
+    write, the same pool either way. ``steps`` sets the copies a group
+    and the pages a product step, so that a chunk's copies take both
+    loops and its product each size."""
+    if steps is not None:
+        monkeypatch.setattr(autotune, "PAGED_LATENT_COPY_GROUP", steps[0])
+        monkeypatch.setattr(autotune, "PAGED_LATENT_PRODUCT_STEP", steps[1])
     from paddle_tpu.ops.paged_attention import (
         latent_pool_shape, paged_latent_attention_update)
     from paddle_tpu.ops.pallas_paged_attention import paged_latent_decode
     heads, width, latent, pages = 5, 40, 32, 4
     rng = np.random.RandomState(3)
-    ctx = jnp.asarray([19, 0, 32, 7], jnp.int32)
+    ctx = jnp.asarray(LATENT_LANES[lanes], jnp.int32)
     B = ctx.shape[0]
     tables = jnp.asarray(1 + rng.permutation(B * pages).reshape(B, pages),
                          jnp.int32)
@@ -720,3 +763,38 @@ def test_latent_decode_kernel_against_the_pure_body(pool_dtype, chunk):
         np.asarray(out)[live], np.asarray(got[False][0], np.float32)[
             live, 0], rtol=tol, atol=tol)
     assert np.all(np.asarray(out)[~live] == 0)
+
+
+@pytest.mark.parametrize("shape,chunk", [
+    ((16, 2048, 4, 128, False), 4),   # GPT, 16 heads of 128, vector unit
+    ((16, 1024, 4, 64, False), 4),    # GPT, 16 heads of 64, vector unit
+    ((16, 512, 2, 576, True), 16),    # 28 over 4 heads of 128, full context
+    ((16, 512, 2, 257, True), 16),    # the same over a ring of 257
+], ids=["gpt-128", "gpt-64", "grouped-full", "grouped-ring"])
+def test_decode_chunk_of_the_other_kernels_is_their_own(shape, chunk):
+    """``paged_decode_chunk`` serves ``_decode_kernel`` alone, with its
+    own constants: at the shapes its ladder was timed at it chooses what
+    it chose before the latent kernel had constants of its own."""
+    page_size, lanes, itemsize, pages, on_mxu = shape
+    assert autotune.paged_decode_chunk(page_size, lanes, itemsize, pages,
+                                       on_mxu=on_mxu) == chunk
+
+
+def test_latent_chunk_is_its_own_constant_cut_to_table_and_vmem():
+    """The latent kernel's (pages a chunk, pages a product step): its
+    own constants at the latent cell's shape, the chunk cut to the
+    table and to the VMEM two slots may take, the step whole chunks
+    where it does not divide one, a named chunk taken as named."""
+    chunk = autotune.PAGED_LATENT_PAGES_PER_CHUNK
+    step = autotune.PAGED_LATENT_PRODUCT_STEP
+    assert chunk % step == 0
+    assert autotune.paged_latent_chunk(16, 640, 2, 257) == (chunk, step)
+    assert autotune.paged_latent_chunk(16, 640, 2, 4) == (4, 4)
+    fit = autotune.PAGED_DECODE_VMEM_BYTES // (2 * 16 * 8 * 640 * 2)
+    assert fit < chunk and fit % step
+    assert autotune.paged_latent_chunk(16, 8 * 640, 2, 257) == (fit, fit)
+    assert autotune.paged_latent_chunk(16, 640, 2, 257, override=3) == (3, 3)
+    assert autotune.paged_latent_chunk(16, 640, 2, 4, override=2 * step) \
+        == (2 * step, step)
+    with pytest.raises(ValueError):
+        autotune.paged_latent_chunk(16, 640, 2, 257, override=0)
