@@ -5,7 +5,7 @@ from .gpt import (  # noqa: F401
     GPTConfig, GPTKVCache, GPTModel, GPTForCausalLM,
     GPTPretrainingCriterion, gpt2_medium,
     gpt_tiny, gpt2_small, gpt2_large, gpt3_1p3b, smallthinker_21ba3b,
-    k_exaone_236b_a23b, glm_4p7_flash,
+    k_exaone_236b_a23b, glm_4p7_flash, brumby_14b_base,
 )
 from .nemotron_h import (  # noqa: F401
     NemotronHConfig, NemotronHForCausalLM, nemotron_3_super_120b_a12b,

@@ -43,6 +43,7 @@ __all__ = [
     "GPTKVCache",
     "gpt_tiny", "gpt2_small", "gpt2_medium", "gpt3_1p3b",
     "smallthinker_21ba3b", "k_exaone_236b_a23b", "glm_4p7_flash",
+    "brumby_14b_base",
 ]
 
 
@@ -134,6 +135,16 @@ class GPTConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # power retention of degree retention_degree in place of softmax
+    # attention on every layer (ops/retention.py): the squared scaled dot
+    # product over the same q/k/v projections and GQA groups, a
+    # recurrent state a K/V head instead of a KV cache. 0 -> attention
+    retention_degree: int = 0
+    retention_gate: bool = False     # a forget gate a K/V head, sigmoid(
+    #                                  u . w_g + b_g) (else the state
+    #                                  never decays)
+    retention_eps: float = 1e-6      # added to the normaliser
+    retention_state_dtype: str = "float32"  # what a slot keeps S, z in
 
     NEW_FIELDS = ("num_kv_heads", "head_dim", "norm", "bias", "position",
                   "sliding_window", "moe_num_experts", "dtype", "qk_norm",
@@ -142,7 +153,8 @@ class GPTConfig:
                   "moe_activation", "moe_shared_intermediate_size",
                   "moe_token_block", "moe_selection_bias", "q_lora_rank",
                   "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
-                  "v_head_dim")
+                  "v_head_dim", "retention_degree", "retention_gate",
+                  "retention_eps", "retention_state_dtype")
 
     def __post_init__(self):
         if self.intermediate_size == 0:
@@ -217,6 +229,18 @@ class GPTConfig:
                 f"experts {self.moe_expert_offset}.."
                 f"{self.moe_expert_offset + self.moe_num_experts - 1} "
                 f"are not among the router's {self.moe_router_experts}")
+        if self.retention_degree not in (0, 2):
+            raise ValueError(f"retention_degree must be 0 (attention) or "
+                             f"2, got {self.retention_degree}")
+        if self.retention_degree and (
+                any(latent) or self.sliding_window or self.bias
+                or self.head_dim % 2):
+            raise ValueError(
+                "power retention takes no latent attention, "
+                "sliding_window or bias, and an even head_dim")
+        if self.retention_gate and not self.retention_degree:
+            raise ValueError("retention_gate gates power retention: it "
+                             "needs retention_degree")
         if self.recompute not in ("full", "dots", "attn", "none"):
             raise ValueError(
                 f"recompute must be 'full', 'dots', 'attn' or 'none', "
@@ -257,6 +281,13 @@ class GPTConfig:
         return self.kv_lora_rank + self.qk_rope_head_dim \
             if self.kv_lora_rank else 0
 
+    @property
+    def retention_state_dim(self) -> int:
+        """``D``: the width of a retention layer's key feature (its state
+        is ``[D, head_dim]`` a K/V head), 0 without retention."""
+        from ..ops.retention import state_dim
+        return state_dim(self.head_dim) if self.retention_degree else 0
+
     def num_params(self) -> int:
         """Parameters of the model these fields describe, reckoned
         without building it."""
@@ -277,6 +308,8 @@ class GPTConfig:
             attn += qd + 2 * kvd + h
         if self.qk_norm:
             attn += 2 * self.head_dim
+        if self.retention_gate:
+            attn += (h + 1) * self.num_kv_heads
         sparse = h * self.moe_router_experts + 3 * h * (
             self.moe_num_experts * self.moe_intermediate_size
             + self.moe_shared_intermediate_size)
@@ -421,6 +454,24 @@ def glm_4p7_flash(**kw) -> GPTConfig:
     return GPTConfig(**d)
 
 
+def brumby_14b_base(**kw) -> GPTConfig:
+    """Brumby-14B-Base (manifestai, config.json, ``brumby``): 40 layers of
+    hidden 5,120 whose attention is power retention of degree 2 (40 query
+    heads over 8 K/V heads of 128, q/k RMSNorm, RoPE theta 1e6, a sigmoid
+    forget gate a K/V head), a SwiGLU MLP of width 17,408, RMSNorm (eps
+    1e-6), no biases, an untied head over 151,936 rows: 14.77 B
+    parameters. ``num_layers`` keeps the leading layers; ``dtype`` is the
+    parameters'."""
+    d = dict(vocab_size=151936, hidden_size=5120, num_layers=40,
+             num_heads=40, num_kv_heads=8, head_dim=128, max_seq_len=32768,
+             intermediate_size=17408, norm="rmsnorm", layer_norm_eps=1e-6,
+             bias=False, position="rope", rope_theta=1e6, qk_norm=True,
+             mlp_kind="swiglu", retention_degree=2, retention_gate=True,
+             tie_word_embeddings=False)
+    d.update(kw)
+    return GPTConfig(**d)
+
+
 def _seq_constraint(x):
     """Sequence-parallel activation sharding over the 'sep' mesh axis
     ([B, S, H] → S sharded) — the unified surface's
@@ -519,6 +570,82 @@ class GPTKVCache:
             self.block_tables if block_tables is None else block_tables,
             self.ctx_len, self.valid, self.positions,
             use_pallas=self.use_pallas, mesh=self.mesh, aux=self.aux)
+
+
+def _prefill_in_row_blocks(backbone, input_ids, cache, per: int):
+    """A served prefill of more rows than ``per``, ``per`` rows at a time
+    through every layer of ``backbone``: a scan over row blocks whose
+    carry is the pools (a row's pages, ring and slot are its own, so the
+    blocks' writes never meet). Returns ``(h [R, 1, hidden], (k', v'))``,
+    ``h`` at each row's ``cache.logits_at``. The expert counters of the
+    blocks are summed into ``cache.aux`` (exact for ``assignments`` and
+    ``local_assignments``; ``experts_touched`` and ``max_expert_load``
+    then count an expert once a block)."""
+    import jax.numpy as jnp
+
+    def wrap(tree):
+        return jax.tree_util.tree_map(
+            lambda a: Tensor(a, stop_gradient=True), tree)
+
+    def data(tree):
+        return jax.tree_util.tree_map(
+            lambda t: t._data, tree,
+            is_leaf=lambda t: isinstance(t, Tensor))
+
+    rows = input_ids.shape[0]
+    n = -(-rows // per)
+
+    def blocks(t):
+        a = t._data
+        a = jnp.pad(a, [(0, n * per - rows)] + [(0, 0)] * (a.ndim - 1))
+        return a.reshape(n, per, *a.shape[1:])
+
+    def block(pools, fed):
+        ids, tables, lens, valid, positions, at = fed
+        sub = GPTKVCache(
+            "prefill", cache.page_size, *wrap(pools), *wrap(
+                (tables, lens, valid, positions)),
+            use_pallas=cache.use_pallas, mesh=cache.mesh, aux={})
+        h, pools = backbone(Tensor(ids, stop_gradient=True), cache=sub)
+        h = jnp.take_along_axis(
+            h._data, at.astype(jnp.int32)[:, None, None], axis=1)
+        counted = sub.aux.get("moe", [])
+        return data(pools), (h, jnp.stack(counted) if counted
+                             else jnp.zeros((0,), jnp.int32))
+
+    pools, (h, counted) = jax.lax.scan(
+        block, data((cache.k, cache.v)),
+        tuple(blocks(t) for t in (
+            input_ids, cache.block_tables, cache.ctx_len, cache.valid,
+            cache.positions, cache.logits_at)))
+    if cache.aux is not None and counted.shape[-1]:
+        cache.aux.setdefault("moe", []).extend(jnp.sum(counted, axis=0))
+    h = h.reshape(n * per, 1, h.shape[-1])[:rows]
+    return Tensor(h, stop_gradient=True), wrap(pools)
+
+
+def cached_hidden(backbone, input_ids, cache, block_tokens: int = 0):
+    """``(h, (k', v'))`` of a cached forward through ``backbone``, ``h``
+    at each row's ``cache.logits_at`` where the cache names one. With
+    ``block_tokens``, a served prefill of more rows than ``block_tokens``
+    positions buy runs whole rows at a time through all the layers
+    (``_prefill_in_row_blocks``): what it keeps beside the pools is one
+    block's, however many rows the dispatch holds."""
+    rows, length = input_ids.shape
+    per = max(1, block_tokens // length)
+    if block_tokens and cache.kind == "prefill" \
+            and cache.logits_at is not None and rows > per:
+        return _prefill_in_row_blocks(backbone, input_ids, cache, per)
+    h, pools = backbone(input_ids, cache=cache)
+    if cache.logits_at is not None:
+        # the head meets one position a row, not the window
+        import jax.numpy as jnp
+        h = apply_op(
+            "take_positions",
+            lambda h, at: jnp.take_along_axis(
+                h, at.astype(jnp.int32)[:, None, None], axis=1),
+            h, cache.logits_at)
+    return h, pools
 
 
 class GPTEmbeddings(Layer):
@@ -721,34 +848,38 @@ class GPTGroupedAttention(Layer):
         return [self.q_norm_w, self.k_norm_w] \
             if self.qk_norm_eps is not None else []
 
-    def forward(self, x, kv_cache=None):
+    def _qkv(self, x, positions, q_w, k_w, v_w, extra):
+        """``(q [B, S, H, D], k, v [B, S, Hk, D])`` of ``x``: the three
+        projections, their biases, the q and k norms and the positions
+        (``positions`` None: 0 .. S-1). ``extra``: the biases, then the
+        q and k norms' weights."""
         import jax.numpy as jnp
         nh, nkv, hd = self.num_heads, self.num_kv_heads, self.head_dim
-        theta, window, use_flash = self.rope_theta, self.window, \
-            self.use_flash
-        has_bias = self.has_bias
-        norm_eps = self.qk_norm_eps
+        theta, norm_eps = self.rope_theta, self.qk_norm_eps
         nb = len(self._biases())
+        biases, norms = extra[:nb], extra[nb:]
+        b, s, _ = x.shape
+        q, k, v = x @ q_w, x @ k_w, x @ v_w
+        if self.has_bias:
+            q, k, v = q + biases[0], k + biases[1], v + biases[2]
+        q = q.reshape(b, s, nh, hd)
+        k = k.reshape(b, s, nkv, hd)
+        v = v.reshape(b, s, nkv, hd)
+        if norm_eps is not None:
+            q = _rms_norm(q, norms[0], norm_eps)
+            k = _rms_norm(k, norms[1], norm_eps)
+        if theta is not None:
+            if positions is None:
+                positions = jnp.broadcast_to(
+                    jnp.arange(s, dtype=jnp.int32), (b, s))
+            q, k = _rope(q, positions, theta), _rope(k, positions, theta)
+        return q, k, v
 
-        def qkv(x, positions, q_w, k_w, v_w, extra):
-            # extra: the biases, then the q and k norms' weights
-            biases, norms = extra[:nb], extra[nb:]
-            b, s, _ = x.shape
-            q, k, v = x @ q_w, x @ k_w, x @ v_w
-            if has_bias:
-                q, k, v = q + biases[0], k + biases[1], v + biases[2]
-            q = q.reshape(b, s, nh, hd)
-            k = k.reshape(b, s, nkv, hd)
-            v = v.reshape(b, s, nkv, hd)
-            if norm_eps is not None:
-                q = _rms_norm(q, norms[0], norm_eps)
-                k = _rms_norm(k, norms[1], norm_eps)
-            if theta is not None:
-                if positions is None:
-                    positions = jnp.broadcast_to(
-                        jnp.arange(s, dtype=jnp.int32), (b, s))
-                q, k = _rope(q, positions, theta), _rope(k, positions, theta)
-            return q, k, v
+    def forward(self, x, kv_cache=None):
+        nh, hd = self.num_heads, self.head_dim
+        window, use_flash = self.window, self.use_flash
+        has_bias = self.has_bias
+        qkv = self._qkv
 
         def out(a, out_w, biases):
             b, s = a.shape[:2]
@@ -944,6 +1075,111 @@ class GPTLatentAttention(Layer):
             kv_cache.v
 
 
+class GPTPowerRetention(GPTGroupedAttention):
+    """Power retention of degree 2 in attention's place (``ops/retention.py``):
+    ``GPTGroupedAttention``'s projections, q/k norms and positions, a
+    forget gate a K/V head ``sigmoid(u . w_g + b_g)`` from the layer's
+    normed input, and the squared scaled dot product over the gated past.
+    A sequence keeps one slot of two pools (``S``, ``z``) a layer: the
+    ``state`` kind; the slot is the last column of its block-table row."""
+
+    CHUNK = 128          # positions a prefill's chunk computes at once
+
+    def __init__(self, config: GPTConfig, layer: int):
+        super().__init__(config, layer)
+        self.eps = float(config.retention_eps)
+        self.gated = bool(config.retention_gate)
+        if self.gated:
+            dt = config.dtype or "float32"
+            self.gate_w = create_parameter_with_attr(
+                [config.hidden_size, self.num_kv_heads], dt, None, False,
+                default_initializer=I.Normal(std=config.initializer_range))
+            # a bias of 3 to 8: gates of about 0.95 to 0.9997 at
+            # initialisation, so a state carries from tens to thousands
+            # of positions
+            self.gate_b = create_parameter_with_attr(
+                [self.num_kv_heads], dt, None, False,
+                default_initializer=I.Uniform(3.0, 8.0))
+
+    def _weights(self):
+        return [self.q_w, self.k_w, self.v_w, self.out_w, *self._biases(),
+                *self._norms()] + (
+            [self.gate_w, self.gate_b] if self.gated else [])
+
+    def _project(self, u, positions, weights):
+        """``(q [B, S, Hk, G, d], k, v [B, S, Hk, d], log gamma [B, S,
+        Hk] float32)`` of the normed input ``u``."""
+        import jax.numpy as jnp
+        nkv = self.num_kv_heads
+        q_w, k_w, v_w, _, *rest = weights
+        extra = len(self._biases()) + len(self._norms())
+        with jax.named_scope("retention"), jax.named_scope("qkv"):
+            b, s, _ = u.shape
+            q, k, v = self._qkv(u, positions, q_w, k_w, v_w, rest[:extra])
+            if self.gated:
+                log_gate = jax.nn.log_sigmoid(
+                    jnp.dot(u, rest[extra],
+                            preferred_element_type=jnp.float32)
+                    + rest[extra + 1].astype(jnp.float32))
+            else:
+                log_gate = jnp.zeros((b, s, nkv), jnp.float32)
+        return q.reshape(b, s, nkv, self.num_heads // nkv, self.head_dim), \
+            k, v, log_gate
+
+    def _out(self, y, out_w):
+        b, s = y.shape[:2]
+        with jax.named_scope("retention"), jax.named_scope("out"):
+            return y.reshape(b, s, -1) @ out_w
+
+    def forward(self, x, kv_cache=None):
+        import jax.numpy as jnp
+
+        from ..ops.paged_attention import kernel_by_default
+        from ..ops.retention import retention_decode_pools, retention_prefill
+        from ..ops.ssm import write_slots
+        from .state_cache import split_state_column
+        chunk, eps = self.CHUNK, self.eps
+        if kv_cache is None:
+            def fn(x, *weights):
+                q, k, v, g = self._project(x, None, weights)
+                y, _, _ = retention_prefill(
+                    q, k, v, g, jnp.ones(x.shape[:2], bool), chunk=chunk,
+                    eps=eps)
+                return self._out(y, weights[3])
+            return apply_op("power_retention", fn, x, *self._weights())
+        kind = kv_cache.kind
+        # who advances the states: the decoder pins it as for attention
+        use_pallas = kernel_by_default(kv_cache.page_size) \
+            if kv_cache.use_pallas is None else bool(kv_cache.use_pallas)
+        if kind == "chunked":
+            raise NotImplementedError(
+                "a retention layer cannot continue a window from a slot's "
+                "state (prefill_chunked, verify): the 'state' kind of "
+                "kv_cache_spec()")
+
+        def fn(x, tables, valid, positions, s_pool, z_pool, *weights):
+            slots = split_state_column(tables, paged=False)[1].astype(
+                jnp.int32)
+            q, k, v, g = self._project(x, positions, weights)
+            if kind == "prefill":
+                y, s, z = retention_prefill(q, k, v, g, valid, chunk=chunk,
+                                            eps=eps)
+                # a dead row's table is zeros: the trash slot
+                with jax.named_scope("retention"), \
+                        jax.named_scope("chunk_scan"):
+                    s_pool = write_slots(s_pool, slots, s)
+                    z_pool = write_slots(z_pool, slots, z)
+                return self._out(y, weights[3]), s_pool, z_pool
+            y, s_pool, z_pool = retention_decode_pools(
+                q[:, 0], k[:, 0], v[:, 0], g[:, 0], slots, valid[:, 0],
+                s_pool, z_pool, eps=eps, use_pallas=use_pallas)
+            return self._out(y[:, None], weights[3]), s_pool, z_pool
+
+        return apply_op("power_retention", fn, x, kv_cache.block_tables,
+                        kv_cache.valid, kv_cache.positions, kv_cache.k,
+                        kv_cache.v, *self._weights())
+
+
 class GPTExpertMLP(Layer):
     """Dropless top-k gated experts (``ops.moe.dropless_moe``): the
     weights of one projection of the experts held here are one stacked
@@ -1095,7 +1331,9 @@ class GPTDecoderLayer(Layer):
     def __init__(self, config: GPTConfig, layer: int = 0):
         super().__init__()
         self.ln_1 = _norm(config)
-        if config.kv_lora_rank:
+        if config.retention_degree:
+            self.attn = GPTPowerRetention(config, layer)
+        elif config.kv_lora_rank:
             self.attn = GPTLatentAttention(config, layer)
         elif config.classic_attention:
             self.attn = GPTAttention(config)
@@ -1521,18 +1759,18 @@ class GPTForCausalLM(Layer):
                 else I.Normal(std=config.initializer_range))
             if config.dtype:
                 self.lm_head.to(dtype=config.dtype)
+        # tokens of a served prefill computed at a time, whole rows,
+        # through all the layers (``cached_hidden``); 0: the whole
+        # dispatch at once. A retention layer's prefill keeps a row's
+        # state and its chunk's feature maps beside the pools, and a
+        # [16, 2048] window at once leaves the compiler 80 MB short of
+        # the chip (PERF.md, section 4)
+        self.prefill_block_tokens = 1024 if config.retention_degree else 0
 
     def forward(self, input_ids, cache=None):
         if cache is not None:
-            h, pools = self.gpt(input_ids, cache=cache)
-            if cache.logits_at is not None:
-                # the head meets one position a row, not the window
-                import jax.numpy as jnp
-                h = apply_op(
-                    "take_positions",
-                    lambda h, at: jnp.take_along_axis(
-                        h, at.astype(jnp.int32)[:, None, None], axis=1),
-                    h, cache.logits_at)
+            h, pools = cached_hidden(self.gpt, input_ids, cache,
+                                     self.prefill_block_tokens)
         else:
             h = self.gpt(input_ids)
         tied = self.config.tie_word_embeddings
@@ -1557,8 +1795,16 @@ class GPTForCausalLM(Layer):
         return logits
 
     # ---- paged KV-cache plumbing (serving.generation engine) ----
+    def _state_slot(self):
+        """One slot of a retention layer's two pools (``S``, ``z``), in
+        ``retention_state_dtype``."""
+        from ..ops.retention import slot_shapes
+        cfg = self.config
+        return tuple((shape, cfg.retention_state_dtype) for shape in
+                     slot_shapes(cfg.num_kv_heads, cfg.head_dim))
+
     def init_kv_pools(self, num_pages: int, page_size: int, dtype=None,
-                      window_pages=None):
+                      window_pages=None, state_slots=None):
         """Zeroed paged K/V pools shaped for this model: a list of
         per-layer arrays in the pool's resident layout
         (``ops.paged_attention.kv_pool_shape``: heads folded into the
@@ -1572,11 +1818,19 @@ class GPTForCausalLM(Layer):
         ``dtype`` may also be the string ``"int8"``: pools then become
         ``(int8 values, f32 per-slot-per-head scales)`` tuples (see
         ops.paged_attention for the quantized-pool contract). Returns
-        raw jax arrays ``(k, v)`` — engine plumbing, not Tensors."""
+        raw jax arrays ``(k, v)`` — engine plumbing, not Tensors.
+        A retention layer keeps no pages: its pair is the ``S`` and
+        ``z`` pools of ``state_slots`` slots (``state_cache.state_pools``;
+        slot 0 the trash slot)."""
         import jax.numpy as jnp
 
         from ..ops.paged_attention import latent_pool_shape, new_kv_pool
+        from .state_cache import state_pools
         cfg = self.config
+        if cfg.retention_degree:
+            pools = [state_pools(self._state_slot(), state_slots)
+                     for _ in range(cfg.num_layers)]
+            return [s for s, _ in pools], [z for _, z in pools]
         dtype = dtype or \
             self.gpt.embeddings.word_embeddings.weight._data.dtype
         if cfg.kv_lora_rank:
@@ -1609,9 +1863,25 @@ class GPTForCausalLM(Layer):
         ``latent``: the values a token a layer of its one pool, which
         holds one row a token for every head and no V (``num_kv_heads``
         1 and ``head_dim`` the row's lanes, ``latent_pool_shape``, are
-        the pool's geometry)."""
+        the pool's geometry). Retention layers keep no pages: they are
+        the ``state`` kind (``state_cache.state_kind``) and ``full``
+        lists no layer."""
         from ..ops.paged_attention import kv_pool_bytes, latent_pool_shape
+        from .state_cache import state_kind
         cfg = self.config
+        if cfg.retention_degree:
+            layers = list(range(cfg.num_layers))
+            return {"num_layers": cfg.num_layers,
+                    "num_heads": cfg.num_heads,
+                    "num_kv_heads": cfg.num_kv_heads,
+                    "head_dim": cfg.head_dim,
+                    "max_seq_len": cfg.max_seq_len,
+                    "stacked": False,
+                    "kv_dtype": kv_dtype or "",
+                    "kv_bytes_per_token": 0,
+                    "kinds": {"full": {"layers": [], "window": None},
+                              "state": state_kind(layers,
+                                                  self._state_slot())}}
         nh, hd = cfg.num_kv_heads, cfg.head_dim
         per_token = cfg.num_layers * 2 * kv_pool_bytes(
             1, 1, nh, hd, kv_dtype or None)

@@ -47,7 +47,8 @@ from ..nn import initializer as I
 from ..nn.initializer_utils import create_parameter_with_attr
 from ..nn.layer.container import LayerList
 from ..nn.layer.layers import Layer
-from .gpt import GPTGroupedAttention, GPTKVCache, GPTRMSNorm
+from .gpt import GPTGroupedAttention, GPTRMSNorm, cached_hidden
+from .state_cache import split_state_column, state_kind, state_pools
 
 __all__ = ["NemotronHConfig", "NemotronHForCausalLM",
            "nemotron_3_super_120b_a12b"]
@@ -345,7 +346,8 @@ class NemotronHMamba(Layer):
                 "kind of kv_cache_spec()")
 
         def fn(u, tables, valid, lens, tail_pool, s_pool, *weights):
-            slots = tables[:, -1].astype(jnp.int32)
+            slots = split_state_column(tables, paged=False)[1].astype(
+                jnp.int32)
             if kind == "prefill":
                 from ..ops.ssm import write_slots
                 out, tail, s = self._window(u, valid, lens, weights)
@@ -488,14 +490,9 @@ class NemotronHModel(Layer):
             return self.norm_f(h)
         # one row of the block table: the full layers' columns, then the
         # state slot (where the model has layers of that kind)
-        tables = cache.block_tables
+        full = cache.block_tables
         if self.config.layers_of("M"):
-            if tables.shape[1] < 2:
-                raise ValueError("block tables of one column leave none "
-                                 "beside the state slot")
-            full = tables[:, :tables.shape[1] - 1]
-        else:
-            full = tables
+            full = split_state_column(full, paged=True)[0]
         k_new, v_new = [], []
         for i, layer in enumerate(self.layers):
             view = cache.layer_view(
@@ -513,7 +510,7 @@ class NemotronHForCausalLM(Layer):
         self.backbone = NemotronHModel(config)
         self.lm_head = _mk(config, [config.hidden_size, config.vocab_size])
         # tokens of a served prefill computed at a time, whole rows,
-        # through all the layers (``_prefill_in_row_blocks``): a 16-row
+        # through all the layers (``gpt.cached_hidden``): a 16-row
         # prefill of 2,048 positions is sixteen passes of one row over
         # the donated pools, keeps a row's temporaries, and its body is
         # the program a one-row prefill is. Not an option: 1,024 is the
@@ -521,77 +518,12 @@ class NemotronHForCausalLM(Layer):
         # gave a schedule that ends (PERF.md section 6, PR 35)
         self.prefill_block_tokens = 1024
 
-    def _prefill_in_row_blocks(self, input_ids, cache, per: int):
-        """A served prefill of more rows than ``per``, ``per`` rows at
-        a time through every layer: a scan over row blocks whose carry
-        is the pools (a row's pages, ring and slot are its own, so the
-        blocks' writes never meet). Returns ``(h [R, 1, hidden], (k',
-        v'))``, ``h`` at each row's ``cache.logits_at``. The expert
-        counters of the blocks are summed into ``cache.aux`` (exact for
-        ``assignments`` and ``local_assignments``; ``experts_touched``
-        and ``max_expert_load`` then count an expert once a block)."""
-        from ..core.tensor import Tensor
-
-        def wrap(tree):
-            return jax.tree_util.tree_map(
-                lambda a: Tensor(a, stop_gradient=True), tree)
-
-        def data(tree):
-            return jax.tree_util.tree_map(
-                lambda t: t._data, tree,
-                is_leaf=lambda t: isinstance(t, Tensor))
-
-        rows = input_ids.shape[0]
-        n = -(-rows // per)
-
-        def blocks(t):
-            a = t._data
-            a = jnp.pad(a, [(0, n * per - rows)] + [(0, 0)] * (a.ndim - 1))
-            return a.reshape(n, per, *a.shape[1:])
-
-        def block(pools, fed):
-            ids, tables, lens, valid, positions, at = fed
-            sub = GPTKVCache(
-                "prefill", cache.page_size, *wrap(pools), *wrap(
-                    (tables, lens, valid, positions)),
-                use_pallas=cache.use_pallas, mesh=cache.mesh, aux={})
-            h, pools = self.backbone(Tensor(ids, stop_gradient=True),
-                                     cache=sub)
-            h = jnp.take_along_axis(
-                h._data, at.astype(jnp.int32)[:, None, None], axis=1)
-            counted = sub.aux.get("moe", [])
-            return data(pools), (h, jnp.stack(counted) if counted
-                                 else jnp.zeros((0,), jnp.int32))
-
-        pools, (h, counted) = jax.lax.scan(
-            block, data((cache.k, cache.v)),
-            tuple(blocks(t) for t in (
-                input_ids, cache.block_tables, cache.ctx_len, cache.valid,
-                cache.positions, cache.logits_at)))
-        if cache.aux is not None and counted.shape[-1]:
-            cache.aux.setdefault("moe", []).extend(jnp.sum(counted, axis=0))
-        h = h.reshape(n * per, 1, h.shape[-1])[:rows]
-        return Tensor(h, stop_gradient=True), wrap(pools)
-
     def forward(self, input_ids, cache=None):
         if cache is None:
             h, pools = self.backbone(input_ids), None
         else:
-            rows, length = input_ids.shape
-            per = max(1, self.prefill_block_tokens // length)
-            if cache.kind == "prefill" and cache.logits_at is not None \
-                    and rows > per:
-                h, pools = self._prefill_in_row_blocks(input_ids, cache,
-                                                       per)
-            else:
-                h, pools = self.backbone(input_ids, cache=cache)
-                if cache.logits_at is not None:
-                    # the head meets one position a row, not the window
-                    h = apply_op(
-                        "take_positions",
-                        lambda h, at: jnp.take_along_axis(
-                            h, at.astype(jnp.int32)[:, None, None], axis=1),
-                        h, cache.logits_at)
+            h, pools = cached_hidden(self.backbone, input_ids, cache,
+                                     self.prefill_block_tokens)
         # the logits leave the product's float32 accumulator unrounded
         logits = apply_op("lm_head", lambda h, w: jnp.einsum(
             "bsh,hv->bsv", h, w, preferred_element_type=jnp.float32),
@@ -599,27 +531,27 @@ class NemotronHForCausalLM(Layer):
         return logits if cache is None else (logits, pools)
 
     # ---- cache plumbing (serving.generation engine)
+    def _state_slot(self):
+        """One slot of an ``M`` layer's two pools: the tail in the
+        parameters' type, the state in ``ssm_state_dtype``."""
+        from ..ops.ssm import slot_shapes
+        cfg = self.config
+        tail, s = slot_shapes(conv_kernel=cfg.conv_kernel, **cfg.ssm_sizes())
+        return ((tail, self.backbone.embeddings._data.dtype),
+                (s, cfg.ssm_state_dtype))
+
     def init_kv_pools(self, num_pages: int, page_size: int, dtype=None,
                       window_pages=None, state_slots=None):
         """Zeroed pools, a pair a layer: a ``*`` layer paged K and V
         pools of ``num_pages`` pages (``ops.paged_attention``; page 0
         the trash page), an ``M`` layer a tail pool and a state pool of
-        ``state_slots`` slots (``ops.ssm.state_pool_shapes``; slot 0
-        the trash slot; the tail in the parameters' type, the state in
-        ``ssm_state_dtype``), an ``E`` layer ``()`` twice. ``dtype``
-        is the K/V pools' (``"int8"`` too); raw jax arrays."""
+        ``state_slots`` slots (``state_cache.state_pools``; slot 0 the
+        trash slot), an ``E`` layer ``()`` twice. ``dtype`` is the K/V
+        pools' (``"int8"`` too); raw jax arrays."""
         from ..ops.paged_attention import new_kv_pool
-        from ..ops.ssm import state_pool_shapes
         cfg = self.config
         own = self.backbone.embeddings._data.dtype
         k, v = [], []
-        if cfg.layers_of("M") and not state_slots:
-            raise ValueError(
-                "a model with state-space layers sizes their pools from "
-                "the lanes: init_kv_pools needs state_slots")
-        tail_shape, s_shape = state_pool_shapes(
-            state_slots or 1, conv_kernel=cfg.conv_kernel,
-            **cfg.ssm_sizes())
         for kind in cfg.kinds:
             if kind == "*":
                 k.append(new_kv_pool(num_pages, page_size, cfg.num_kv_heads,
@@ -627,8 +559,9 @@ class NemotronHForCausalLM(Layer):
                 v.append(new_kv_pool(num_pages, page_size, cfg.num_kv_heads,
                                      cfg.head_dim, dtype or own))
             elif kind == "M":
-                k.append(jnp.zeros(tail_shape, own))
-                v.append(jnp.zeros(s_shape, cfg.ssm_state_dtype))
+                tail, s = state_pools(self._state_slot(), state_slots)
+                k.append(tail)
+                v.append(s)
             else:
                 k.append(())
                 v.append(())
@@ -637,21 +570,14 @@ class NemotronHForCausalLM(Layer):
     def kv_cache_spec(self, kv_dtype: str = "") -> dict:
         """Geometry the decode engine sizes its cache from. ``kinds``:
         ``full`` the ``*`` layers (the whole context, paged), ``state``
-        the ``M`` layers (``bytes_per_slot`` a sequence, all layers
-        together, however long it grows; absent where the model has
-        none); ``E`` layers keep nothing."""
+        the ``M`` layers (``state_cache.state_kind``; absent where the
+        model has none); ``E`` layers keep nothing."""
         from ..ops.paged_attention import kv_pool_bytes
         cfg = self.config
         full, state = cfg.layers_of("*"), cfg.layers_of("M")
         kinds = {"full": {"layers": full, "window": None}}
         if state:
-            own = np.dtype(self.backbone.embeddings._data.dtype).itemsize
-            # the cache manager compares the pools it is handed with
-            # this: the state's precision is a size that is checked
-            kinds["state"] = {
-                "layers": state,
-                "bytes_per_slot": len(state)
-                * cfg.state_bytes_per_slot(own)}
+            kinds["state"] = state_kind(state, self._state_slot())
         return {"num_layers": cfg.num_layers,
                 "num_heads": cfg.num_heads,
                 "num_kv_heads": cfg.num_kv_heads,

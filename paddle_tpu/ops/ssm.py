@@ -44,18 +44,17 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["ssm_prefill", "ssm_decode_step", "ssm_decode_pools",
-           "gated_group_norm", "state_pool_shapes", "write_slots"]
+           "gated_group_norm", "slot_shapes", "write_slots"]
 
 _F32 = jnp.float32
 
 
-def state_pool_shapes(slots: int, *, heads: int, head_dim: int,
-                      groups: int, state: int, conv_kernel: int) -> tuple:
-    """Shapes of one layer's two pools of ``slots`` slots: the
-    convolution tails and the SSM states."""
+def slot_shapes(*, heads: int, head_dim: int, groups: int, state: int,
+                conv_kernel: int) -> tuple:
+    """Shapes of one slot of a layer's two pools: the convolution tail
+    and the SSM state."""
     channels = heads * head_dim + 2 * groups * state
-    return ((int(slots), conv_kernel - 1, channels),
-            (int(slots), heads, head_dim, state))
+    return ((conv_kernel - 1, channels), (heads, head_dim, state))
 
 
 def _split(xbc, heads, head_dim, groups, state):

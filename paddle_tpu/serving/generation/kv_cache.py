@@ -41,6 +41,10 @@ finds a row's slot in its table row. A slot is a sequence's own, handed
 out at admission and taken back with its pages; what it held is not
 cleared (a prefill writes the slot from zeros, it never reads it).
 
+A model whose ``full`` kind lists no layer (every layer keeps a state)
+holds no pages: a sequence needs none, and its table row is the state
+slot alone.
+
 A latent attention (``kinds["full"]["latent"]``) keeps the full kind's
 pages and its one table, but a layer's pool holds one row a token for
 every head (the latent and the rotary key part all heads share) and no
@@ -101,6 +105,9 @@ class PagedKVCache:
         self.kv_dtype = dtype if isinstance(dtype, str) else ""
         # the window kind: a ring a lane, a pool sized from the lanes
         spec = model.kv_cache_spec()
+        # the full kind: no layer of it, no page a sequence
+        self.paged = bool(((spec.get("kinds") or {}).get("full") or {})
+                          .get("layers", True))
         self.window = window_of(spec)
         self.ring_pages = 0
         self.window_num_pages = 0
@@ -184,7 +191,10 @@ class PagedKVCache:
             + len(self._state_held)
 
     def pages_for(self, tokens: int) -> int:
-        """Pages needed to hold ``tokens`` positions."""
+        """Pages needed to hold ``tokens`` positions (none where no
+        layer keeps pages)."""
+        if not self.paged:
+            return 0
         return max(1, math.ceil(tokens / self.page_size))
 
     # ---- the window kind ----
@@ -325,6 +335,8 @@ class PagedKVCache:
         None (and take nothing) if fewer are free."""
         if n_pages > len(self._free):
             return None
+        if not n_pages:
+            return []
         taken = self._free[-n_pages:]
         del self._free[-n_pages:]
         for p in taken:

@@ -913,3 +913,109 @@ def test_the_latent_cells_programs_compile_over_their_pools(
     if program == "decode":
         assert "mla/absorb" in text and "mla/v_up" in text
         assert plan.temp_size_in_bytes < 256 * 1024 ** 2
+
+
+# -- power retention: a 32.5 MiB float32 state a lane a layer -------------
+# the retention configuration's serving geometry: 8 K/V heads of 128 (so
+# S [8, 8256, 128] and z [8, 65, 128] a slot), 5 query heads each, 32
+# lanes over 33 slots, a table row of the slot alone, five layers
+RT_LANES, RT_KV, RT_D, RT_BIG = 32, 8, 128, 8256
+
+
+def test_retention_decode_kernel_compiles_at_the_cells_widths(
+        one_chip, compiled_kernels):
+    """``retention_decode_pools`` as the retention configuration's decode
+    step calls it: one kernel a layer, both pools aliased in to out and
+    never copied, nothing of a pool's size beside them."""
+    from paddle_tpu.ops.retention import retention_decode_pools, slot_shapes
+    s_shape, z_shape = slot_shapes(RT_KV, RT_D)
+    assert s_shape == (RT_KV, RT_BIG, RT_D) and z_shape == (RT_KV, 65, RT_D)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda *a: retention_decode_pools(*a, use_pallas=True),
+        donate_argnums=(6, 7)).lower(
+            sds((RT_LANES, RT_KV, 5, RT_D), jnp.bfloat16),
+            sds((RT_LANES, RT_KV, RT_D), jnp.bfloat16),
+            sds((RT_LANES, RT_KV, RT_D), jnp.bfloat16),
+            sds((RT_LANES, RT_KV), jnp.float32),
+            sds((RT_LANES,), jnp.int32), sds((RT_LANES,), jnp.bool_),
+            sds((1 + RT_LANES,) + s_shape, jnp.float32),
+            sds((1 + RT_LANES,) + z_shape, jnp.float32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "retention/state_update" in text
+    pool = rf"f32\[{1 + RT_LANES},{RT_KV},{RT_BIG},{RT_D}\]"
+    assert not re.findall(pool + r"\S* copy\(", text)
+    plan = compiled.memory_analysis()
+    assert plan.alias_size_in_bytes > (1 + RT_LANES) * RT_KV * RT_BIG \
+        * RT_D * 4
+    assert plan.temp_size_in_bytes < 64 * 1024 ** 2
+
+
+@pytest.mark.parametrize("program", ["decode", (16, 2048)],
+                         ids=["decode-32", "prefill-16x2048"])
+def test_the_retention_cells_programs_fit_the_chip_beside_their_pools(
+        one_chip, compiled_kernels, program):
+    """The decode program and the largest prefill program of the cell
+    ``serve-brumby-backlog`` (five layers, abstract bfloat16 weights),
+    compiled for the chip over donated state pools of 33 slots: weights,
+    pools and temporaries stay under the chip's 15.75 GiB (a [16, 2048]
+    window at once did not: its rows now go through the layers a block
+    of 1,024 tokens at a time), the pools are written where they lie,
+    the decode step holds the kernel and keeps nothing of a pool's size
+    beside the pools."""
+    import paddle_tpu as paddle
+    from paddle_tpu import models
+    from paddle_tpu.jit.functional import state_arrays
+    from paddle_tpu.serving.generation.model_fns import CachedDecoder
+    with paddle.LazyGuard():
+        model = models.GPTForCausalLM(models.brumby_14b_base(num_layers=5))
+    model.eval()
+    assert model.prefill_block_tokens == 1024
+    dec = CachedDecoder(model, max_batch=RT_LANES, page_size=PAGE,
+                        pages_per_seq=1, donate=True, max_positions=4096,
+                        kv_dtype="")
+    assert dec.use_pallas is True
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    def described(tree, cast):
+        return jax.tree_util.tree_map(lambda a: sds(
+            a.shape, jnp.bfloat16 if cast and jnp.issubdtype(
+                a.dtype, jnp.floating) else a.dtype), tree)
+
+    params, buffers = described(state_arrays(model), True)
+    k, v = described(jax.eval_shape(lambda: model.init_kv_pools(
+        2, PAGE, state_slots=1 + RT_LANES)), False)
+    assert tuple(k[0].shape) == (1 + RT_LANES, RT_KV, RT_BIG, RT_D)
+    if program == "decode":
+        rows = RT_LANES
+        lowered = dec._decode_jit.lower(
+            params, buffers, sds((rows,), jnp.int32), sds((rows,), jnp.int32),
+            sds((rows,), jnp.bool_), sds((rows,), jnp.int32),
+            sds((rows, 1), jnp.int32), sds((rows,), jnp.float32),
+            sds((rows,), jnp.float32), k, v)
+    else:
+        rows, seq = program
+        lowered = dec._prefill_jit.lower(
+            params, buffers, sds((rows, seq), jnp.int64),
+            sds((rows,), jnp.int32), sds((rows, 1), jnp.int32),
+            sds((rows,), jnp.float32), sds((rows,), jnp.float32), k, v)
+    compiled = lowered.compile()
+    plan = compiled.memory_analysis()
+    assert plan.argument_size_in_bytes + plan.temp_size_in_bytes < CHIP_BYTES
+    assert plan.alias_size_in_bytes > 5 * 1024 ** 3
+    text = compiled.as_text()
+    pool = rf"f32\[{1 + RT_LANES},{RT_KV},{RT_BIG},{RT_D}\]"
+    assert not re.findall(pool + r"\S* copy\(", text)
+    assert "retention/qkv" in text and "retention/out" in text
+    if program == "decode":
+        assert text.count("tpu_custom_call") == 5      # one a layer
+        assert plan.temp_size_in_bytes < 256 * 1024 ** 2
+    else:
+        assert "retention/chunk_scan" in text
+        assert plan.temp_size_in_bytes < 2 * 1024 ** 3
